@@ -58,25 +58,22 @@ _CACHE_ON = False
 
 
 def _enable_compile_cache() -> None:
-    """Point jax's persistent compilation cache at a scratch dir:
-    every replay builds FRESH engines (fresh jit closures), so without
-    it each of the explorer's ~50+ schedules per drive pays full XLA
-    compiles (~3s); with it, replays pay tracing plus a disk hit
-    (~0.5s).  Best-effort — an unwritable dir just means slow."""
+    """Turn on jax's persistent compilation cache (placed by
+    ``platform.compile_cache``) and admit small entries: every replay
+    builds FRESH engines (fresh jit closures), so without it each of
+    the explorer's ~50+ schedules per drive pays full XLA compiles
+    (~3s); with it, replays pay tracing plus a disk hit (~0.5s)."""
     global _CACHE_ON
     if _CACHE_ON:
         return
     _CACHE_ON = True
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir",
-                          "/tmp/paddle_tpu_conc_cache")
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    from paddle_tpu.platform.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def _model():

@@ -96,7 +96,7 @@ RULE_NAMES = ("contract-mismatch", "implicit-all-gather",
 
 _DEFAULT_CONTRACT = SiteContract()
 
-_COLLECTIVES = {"psum": "ar", "psum2": "ar", "all_reduce": "ar",
+_COLLECTIVES = {"psum": "ar", "psum_invariant": "ar", "all_reduce": "ar",
                 "all_gather": "ag", "all_gather_invariant": "ag",
                 "psum_scatter": "rs", "reduce_scatter": "rs",
                 "all_to_all": "a2a", "ppermute": "pp", "pshuffle": "pp"}
@@ -860,6 +860,7 @@ def _walk_jaxpr(st: _Walk, obj, in_specs: Sequence[VSpec],
     the outvars' VSpecs.  ``in_specs`` aligns positionally with the
     jaxpr's invars (missing/short -> unknown)."""
     import jax
+    from jax.extend import core as jex_core
 
     jaxpr, _consts = _as_closed(obj)
     env: Dict[int, VSpec] = {}
@@ -872,7 +873,7 @@ def _walk_jaxpr(st: _Walk, obj, in_specs: Sequence[VSpec],
         env[id(v)] = vs if vs is not None else _UNKNOWN
 
     def read(v) -> VSpec:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jex_core.Literal):
             return _repl(len(_shape(v)))
         return env.get(id(v), _UNKNOWN)
 
@@ -887,7 +888,7 @@ def _walk_jaxpr(st: _Walk, obj, in_specs: Sequence[VSpec],
 
 def _align_last(ins: List[VSpec], n: int) -> List[VSpec]:
     """Align outer operand specs onto ``n`` inner invars the way the
-    drift rule does: the LAST n operands map positionally (pjit and
+    drift rule does: the LAST n operands map positionally (jit and
     custom_* calls pass consts first)."""
     if n <= len(ins):
         return ins[-n:]
@@ -901,7 +902,7 @@ def _run_eqn(st: _Walk, eqn, ins: List[VSpec], path: str) -> List[VSpec]:
         return rule(st, eqn, ins, path)
     if name in _COLLECTIVES:
         return _rule_collective(st, eqn, ins, path)
-    if name == "pjit" or name == "closed_call" or name == "remat" \
+    if name == "jit" or name == "closed_call" or name == "remat" \
             or name == "checkpoint" or name == "custom_jvp_call" \
             or name == "custom_vjp_call" or name == "custom_vjp_call_jaxpr":
         inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr") \

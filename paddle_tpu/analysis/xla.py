@@ -81,7 +81,7 @@ _DRIFT_SINKS = {"dot_general", "conv_general_dilated", "reduce_sum",
                 "reduce_prod"}
 _CALLBACKS = {"pure_callback", "io_callback", "debug_callback", "callback",
               "infeed", "outfeed"}
-_COLLECTIVES = {"psum", "psum2", "all_gather", "all_gather_invariant",
+_COLLECTIVES = {"psum", "psum_invariant", "all_gather", "all_gather_invariant",
                 "all_to_all", "ppermute", "pshuffle", "psum_scatter",
                 "reduce_scatter", "all_reduce"}
 
@@ -116,14 +116,15 @@ def _sub_jaxprs(eqn) -> List:
     """Closed sub-jaxprs of an eqn (pjit, scan, while, cond branches,
     custom_* calls, shard_map) as (ClosedJaxpr-or-Jaxpr) values."""
     import jax
+    from jax.extend import core as jex_core
 
     out = []
 
     def add(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             out.append(v)
-        elif isinstance(v, jax.core.Jaxpr):
-            out.append(jax.core.ClosedJaxpr(v, ()))
+        elif isinstance(v, jex_core.Jaxpr):
+            out.append(jex_core.ClosedJaxpr(v, ()))
 
     for v in eqn.params.values():
         add(v)
@@ -201,16 +202,17 @@ def estimate_jaxpr(closed) -> Tuple[int, float]:
     everything elementwise, dense-upper-bound for conv, and nested
     jaxprs fold in as described in the module doc."""
     import jax
+    from jax.extend import core as jex_core
 
     jaxpr = closed.jaxpr
     last_use: Dict[int, int] = {}
     n_eqns = len(jaxpr.eqns)
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[id(v)] = i
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             last_use[id(v)] = n_eqns
 
     live: Dict[int, int] = {}
@@ -250,7 +252,7 @@ def estimate_jaxpr(closed) -> Tuple[int, float]:
             cur += b
         peak = max(peak, cur)
         dying = {id(v) for v in eqn.invars
-                 if not isinstance(v, jax.core.Literal)}
+                 if not isinstance(v, jex_core.Literal)}
         for vid in dying:
             if last_use.get(vid) == i and vid in live:
                 cur -= live.pop(vid)
@@ -373,6 +375,7 @@ def _rule_donation(site, closed, call, jit_kwargs, contract,
 def _rule_dtype_drift(site, closed, call, jit_kwargs, contract,
                       est) -> List[Diagnostic]:
     import jax
+    from jax.extend import core as jex_core
 
     allow = set(contract.allow_upcast)
     out: List[Diagnostic] = []
@@ -386,7 +389,7 @@ def _rule_dtype_drift(site, closed, call, jit_kwargs, contract,
             # slot in as None) — sub-jaxpr invars align positionally
             # with eqn.invars, so filtering literals first would shift
             # every origin onto the wrong inner operand
-            in_orig = [None if isinstance(v, jax.core.Literal)
+            in_orig = [None if isinstance(v, jex_core.Literal)
                        else origin.get(id(v)) for v in eqn.invars]
             if name == "convert_element_type":
                 v0 = eqn.invars[0]

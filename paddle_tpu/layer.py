@@ -31,6 +31,7 @@ from paddle_tpu.ops import pool as ppool
 from paddle_tpu.ops import rnn as prnn
 from paddle_tpu.ops import sequence_ops as pseq
 from paddle_tpu.ops.embedding import embedding_lookup
+from paddle_tpu.ops.kernel_util import per_device
 from paddle_tpu.platform.enforce import EnforceError, enforce_that
 from paddle_tpu.sequence import SequenceBatch
 from paddle_tpu.topology import (Context, LayerOutput, ParamSpec, StateSpec,
@@ -1317,9 +1318,11 @@ def lstmemory(input, size: int = None, reverse: bool = False, act=None,
     def compute(ctx, p, ins):
         sb: SequenceBatch = ins[0]
         padded, mask = sb.to_padded()
-        hs, _ = prnn.lstm_scan(
-            padded, mask, None, p["w"], p.get("b"), reverse=reverse,
-            gate_act=g_act.fn, cell_act=s_act.fn, out_act=out_act.fn)
+        hs, _ = per_device(
+            lambda x, m, w, b: prnn.lstm_scan(
+                x, m, None, w, b, reverse=reverse, gate_act=g_act.fn,
+                cell_act=s_act.fn, out_act=out_act.fn),
+            ctx.mesh)(padded, mask, p["w"], p.get("b"))
         out = SequenceBatch.from_padded(hs, sb.lengths, capacity=sb.capacity)
         return _apply_extra(ctx, name, out, layer_attr)
 
@@ -1348,8 +1351,10 @@ def grumemory(input, size: int = None, reverse: bool = False, act=None,
     def compute(ctx, p, ins):
         sb: SequenceBatch = ins[0]
         padded, mask = sb.to_padded()
-        hs, _ = prnn.gru_scan(padded, mask, None, p["w"], p.get("b"),
-                              reverse=reverse)
+        hs, _ = per_device(
+            lambda x, m, w, b: prnn.gru_scan(x, m, None, w, b,
+                                             reverse=reverse),
+            ctx.mesh)(padded, mask, p["w"], p.get("b"))
         out = SequenceBatch.from_padded(hs, sb.lengths, capacity=sb.capacity)
         return _apply_extra(ctx, name, out, layer_attr)
 
@@ -1787,9 +1792,12 @@ def multi_head_attention(query, key=None, value=None, *, num_heads: int,
             1, cap_k, num_heads, head_dim)
         v = pmath.matmul(vs.data, p["wv"]).astype(qkv_t).reshape(
             1, cap_k, num_heads, head_dim)
-        out = pattn.flash_attention(
-            q, k, v, segment_ids=qs.segment_ids[None, :],
-            kv_segment_ids=ks.segment_ids[None, :], causal=causal)
+        out = per_device(
+            lambda q, k, v, q_seg, k_seg: pattn.flash_attention(
+                q, k, v, segment_ids=q_seg, kv_segment_ids=k_seg,
+                causal=causal),
+            ctx.mesh)(q, k, v, qs.segment_ids[None, :],
+                      ks.segment_ids[None, :])
         y = pmath.matmul(out.reshape(cap_q, size), p["wo"])
         y = qs.with_data(y.astype(pmath.dense_activation_dtype()))
         return _apply_extra(ctx, name, y, layer_attr)

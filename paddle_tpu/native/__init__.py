@@ -6,7 +6,7 @@ side — recordio file handling and the async shuffling data pool
 (PyDataProvider2's pool thread, DataProvider double buffering) — plus the
 C inference ABI (paddle/capi) built from native/src/.
 
-The shared library builds on demand with g++ (cached by source mtime);
+The shared library builds on demand with g++ (cached by source hash);
 everything degrades gracefully when no toolchain is present
 (``available()`` returns False and the pure-python paths keep working).
 """
@@ -14,6 +14,7 @@ everything degrades gracefully when no toolchain is present
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Iterable, List, Optional
@@ -21,7 +22,6 @@ from typing import Iterable, List, Optional
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _SRC = os.path.join(_NATIVE_DIR, "src")
 _BUILD = os.path.join(_NATIVE_DIR, "build")
-_LIB_PATH = os.path.join(_BUILD, "libptn.so")
 
 _lib = None
 _load_error: Optional[str] = None
@@ -38,54 +38,65 @@ def _deps() -> List[str]:
     return _sources() + glob.glob(os.path.join(_SRC, "*.h"))
 
 
+def _compile(out_name: str, deps: List[str], cmd_for, force: bool) -> str:
+    """Compile ``cmd_for(out_path)`` into native/build/``out_name``
+    unless a library built from EXACTLY these sources with this command
+    is already there.  The stamp beside the library is a hash of the
+    command and of every dependency's bytes — not an mtime, which a
+    clone, an archive or a copy rewrites freely, so a stale library
+    could otherwise stand in for newer sources."""
+    os.makedirs(_BUILD, exist_ok=True)
+    out = os.path.join(_BUILD, out_name)
+    cmd = cmd_for(out)
+    h = hashlib.sha256("\0".join(cmd).encode())
+    for dep in sorted(deps):
+        with open(dep, "rb") as f:
+            h.update(f.read())
+    want = h.hexdigest()
+    stamp = out + ".srchash"
+    if not force and os.path.exists(out) and os.path.exists(stamp):
+        with open(stamp) as f:
+            if f.read() == want:
+                return out
+    subprocess.run(cmd, check=True, capture_output=True)
+    with open(stamp, "w") as f:
+        f.write(want)
+    return out
+
+
 def build(force: bool = False) -> str:
     """Compile native/src → native/build/libptn.so (no python linkage —
     the capi library builds separately via build_capi)."""
-    os.makedirs(_BUILD, exist_ok=True)
-    srcs = _sources()
-    if (not force and os.path.exists(_LIB_PATH)
-            and all(os.path.getmtime(_LIB_PATH) >= os.path.getmtime(s)
-                    for s in _deps())):
-        return _LIB_PATH
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-           "-o", _LIB_PATH] + srcs + ["-lpthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _LIB_PATH
+    return _compile(
+        "libptn.so", _deps(),
+        lambda out: ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
+                     "-o", out] + _sources() + ["-lpthread"], force)
 
 
 def build_capi(force: bool = False) -> str:
     """Compile the C inference ABI (embeds CPython) → libptpu_capi.so."""
     import sysconfig
 
-    os.makedirs(_BUILD, exist_ok=True)
-    out = os.path.join(_BUILD, "libptpu_capi.so")
     src = os.path.join(_SRC, "capi.cpp")
-    if (not force and os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        return out
     inc = sysconfig.get_paths()["include"]
     libdir = sysconfig.get_config_var("LIBDIR")
     ver = sysconfig.get_config_var("LDVERSION")
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-           f"-I{inc}", "-o", out, src,
-           f"-L{libdir}", f"-lpython{ver}", "-lpthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return _compile(
+        "libptpu_capi.so", [src],
+        lambda out: ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
+                     f"-I{inc}", "-o", out, src,
+                     f"-L{libdir}", f"-lpython{ver}", "-lpthread"], force)
 
 
 def build_aot(force: bool = False) -> str:
     """Compile the interpreter-free AOT inference runtime →
     libptpu_aot.so. PURE C++ — no Python, no jax, no XLA linked; this is
     the embedded-deployment artifact (paddle/capi Android analog)."""
-    os.makedirs(_BUILD, exist_ok=True)
-    out = os.path.join(_BUILD, "libptpu_aot.so")
     src = os.path.join(_SRC, "aot_runtime.cpp")
-    if (not force and os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        return out
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-o", out, src]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return _compile(
+        "libptpu_aot.so", [src],
+        lambda out: ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
+                     "-o", out, src], force)
 
 
 def _pjrt_include_dir():
@@ -103,19 +114,14 @@ def build_pjrt(force: bool = False) -> str:
     """Compile the PJRT C-API inference runtime → libptpu_pjrt.so.
     Pure C++ + libdl; the PJRT plugin (libtpu.so on TPU hosts) is
     dlopen'd at runtime, never linked."""
-    os.makedirs(_BUILD, exist_ok=True)
-    out = os.path.join(_BUILD, "libptpu_pjrt.so")
     src = os.path.join(_SRC, "pjrt_capi.cpp")
-    if (not force and os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        return out
     inc = _pjrt_include_dir()
     if inc is None:
         raise RuntimeError("no pjrt_c_api.h found in site-packages")
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", f"-I{inc}",
-           "-o", out, src, "-ldl"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return _compile(
+        "libptpu_pjrt.so", [src],
+        lambda out: ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
+                     f"-I{inc}", "-o", out, src, "-ldl"], force)
 
 
 def _load():

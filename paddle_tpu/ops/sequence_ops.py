@@ -3,7 +3,7 @@
 Reference: paddle/gserver/layers/SequencePoolLayer.cpp (max/avg/sum over each
 sequence), SequenceLastInstanceLayer.cpp (seqlastins/first), ExpandLayer.cpp,
 SequenceConcatLayer.cpp, SequenceReshapeLayer.cpp, SeqSliceLayer.cpp,
-SubNestedSequenceLayer.cpp, KmaxSeqScoreLayer.cpp, MaxIdLayer.cpp, and the
+SubNestedSequenceLayer.cpp, KmaxSeqScore (gserver/layers), MaxIdLayer.cpp, and the
 sequence_softmax activation (ActivationFunction.cpp).
 
 TPU-native: all ops work on the flat segment-ids form (paddle_tpu.sequence.
@@ -151,7 +151,7 @@ def seq_slice(sb: SequenceBatch, starts: jax.Array, ends: jax.Array) -> Sequence
 
 def kmax_seq_score(sb: SequenceBatch, k: int) -> jax.Array:
     """Indices (positions within each sequence) of the top-k scores
-    (reference: KmaxSeqScoreLayer.cpp). data: [capacity] or [capacity,1].
+    (reference: KmaxSeqScore (gserver/layers)). data: [capacity] or [capacity,1].
     Returns [num_seqs, k] int32 positions (padded with -1)."""
     scores, mask = sb.with_data(
         sb.data[..., 0] if sb.data.ndim > 1 else sb.data).to_padded()

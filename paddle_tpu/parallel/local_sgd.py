@@ -24,17 +24,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.platform.enforce import enforce_that
 
-try:
-    from jax import shard_map                      # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
-from paddle_tpu.parallel.compat import no_rep_check_kw
 
 
 def _tree_norm(tree) -> jax.Array:
@@ -115,5 +109,5 @@ class LocalSGD:
         fn = shard_map(local, mesh=self.mesh,
                        in_specs=(P(axis), P(), P(axis)),
                        out_specs=(P(axis), P()),
-                       **no_rep_check_kw())
+                       check_vma=False)
         return jax.jit(fn)

@@ -37,11 +37,11 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.platform.enforce import enforce_that
 
-from paddle_tpu.parallel.compat import no_rep_check_kw, shard_map
 
 # the audited compiled-path site every expert-parallel dispatch runs
 # through; its contract (below) declares the closed-form collective
@@ -343,7 +343,7 @@ def _moe_jit(mesh, axis: str, e: int, cap: int, d: int, act, top_k: int,
         in_specs=(P(axis, None), P(None, None), P(axis, None, None),
                   P(axis, None), P(axis, None, None), P(axis, None)),
         out_specs=out_specs,
-        **no_rep_check_kw())
+        check_vma=False)
 
     from paddle_tpu.analysis.retrace import audit_jit
 

@@ -37,11 +37,10 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel.compat import no_rep_check_kw, shard_map
 
 # the audited compiled-path site every pipeline_apply dispatch runs
 # through; its contract (below) declares the closed-form collective
@@ -213,10 +212,9 @@ def _pipeline_jit(mesh: Mesh, stage_fn, axis: str, m: int, first_fn,
         stage = lax.axis_index(axis)
         acc0 = jnp.zeros((m,) + tuple(out_shape), out_dtype)
         recv0 = jnp.zeros(tuple(x_shape), x_dtype)
-        if hasattr(lax, "pvary"):
-            # newer shard_map tracks varying-manual-axes (VMA): the carry
-            # becomes stage-varying after one tick, so it must start so
-            acc0, recv0 = lax.pvary((acc0, recv0), (axis,))
+        # shard_map tracks varying-manual-axes (VMA): the carry becomes
+        # stage-varying after one tick, so it must start so
+        acc0, recv0 = lax.pcast((acc0, recv0), (axis,), to="varying")
 
         def tick(carry, t):
             acc, recv = carry
@@ -258,17 +256,11 @@ def _pipeline_jit(mesh: Mesh, stage_fn, axis: str, m: int, first_fn,
     def run(stacked_params, first_params, last_params, microbatches):
         in_params_spec = jax.tree.map(lambda _: P(axis), stacked_params)
         repl = lambda tree: jax.tree.map(lambda _: P(), tree)  # noqa: E731
-        # replication checking off: under jit (the audited dispatch)
-        # the scan carry's replication-type inference rejects the
-        # pvary'd carry on the grad path ("mismatched replication
-        # types" — the workaround jax itself suggests); the
-        # grads-match-sequential parity test pins the math unchanged
         return shard_map(per_device, mesh=mesh,
                          in_specs=(in_params_spec, repl(first_params),
                                    repl(last_params), repl(microbatches)),
-                         out_specs=P(),
-                         **no_rep_check_kw())(stacked_params, first_params,
-                                              last_params, microbatches)
+                         out_specs=P())(stacked_params, first_params,
+                                        last_params, microbatches)
 
     from paddle_tpu.analysis.retrace import audit_jit
 

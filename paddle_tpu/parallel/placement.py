@@ -27,7 +27,7 @@ from typing import Dict, Optional, Sequence, Tuple
 from paddle_tpu.attr import ExtraAttr, ParamAttr
 
 
-#: The mesh-axis taxonomy every placement plan draws from (one axis,
+#: The mesh-axis vocabulary every placement plan draws from (one axis,
 #: one meaning — MIGRATION.md "Pod-scale training" spells out the
 #: composition rules):
 #:   data   — batch replication; the grad-psum / ZeRO domain

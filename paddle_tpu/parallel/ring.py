@@ -27,20 +27,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.ops.attention import DEFAULT_MASK_VALUE, flash_attention
-from paddle_tpu.parallel.compat import no_rep_check_kw, shard_map
-
-
-def _mark_varying(tree, axis: str):
-    """Start shard_map carries as axis-varying where the jax version
-    tracks varying-manual-axes (VMA) — ``lax.pvary`` on jax >= 0.6,
-    a no-op on older jax whose shard_map has no VMA inference (the
-    same guard parallel/pipeline.py uses for its scan carry)."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(tree, (axis,))
-    return tree
 
 
 def _chunk_attn(q, k, v, q_seg, k_seg, q_off, k_off, causal, sm_scale):
@@ -113,10 +103,13 @@ def ring_attention(q, k, v, mesh, axis: str = "seq", segment_ids=None,
             segc = jax.lax.ppermute(segc, axis, perm)
             return acc, m, l, kc, vc, segc
 
-        acc0, m0, l0 = _mark_varying(
+        # shard_map tracks varying-manual-axes: the carry becomes
+        # axis-varying after one step, so it must start that way
+        acc0, m0, l0 = jax.lax.pcast(
             (jnp.zeros((batch, local, heads, head_dim), jnp.float32),
              jnp.full((batch, heads, local), -jnp.inf, jnp.float32),
-             jnp.zeros((batch, heads, local), jnp.float32)), axis)
+             jnp.zeros((batch, heads, local), jnp.float32)),
+            (axis,), to="varying")
         acc, m, l, _, _, _ = jax.lax.fori_loop(
             0, n, step, (acc0, m0, l0, k, v, seg))
         l = jnp.where(l == 0.0, 1.0, l)
@@ -125,12 +118,9 @@ def ring_attention(q, k, v, mesh, axis: str = "seq", segment_ids=None,
 
     spec = P(None, axis, None, None)
     seg_spec = P(None, axis)
-    # replication checking off (compat kw): the fori_loop carry's VMA
-    # inference rejects the pvary'd carry on older jax grad paths —
-    # the ring-matches-flash parity tests pin the math unchanged
     fn = shard_map(body, mesh=mesh,
                    in_specs=(spec, spec, spec, seg_spec),
-                   out_specs=spec, **no_rep_check_kw())
+                   out_specs=spec)
     return fn(q, k, v, segment_ids)
 
 
@@ -170,8 +160,8 @@ def ulysses_attention(q, k, v, mesh, axis: str = "seq", segment_ids=None,
 
     spec = P(None, axis, None, None)
     # replication check off: pallas_call inside shard_map doesn't
-    # annotate vma yet (check_vma on new jax, check_rep on 0.4.x)
+    # annotate vma
     fn = shard_map(body, mesh=mesh,
                    in_specs=(spec, spec, spec, P(None, axis)),
-                   out_specs=spec, **no_rep_check_kw())
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v, segment_ids)

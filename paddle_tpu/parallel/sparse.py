@@ -25,16 +25,11 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.platform.enforce import enforce_that
 
-try:
-    from jax import shard_map                      # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-from paddle_tpu.parallel.compat import no_rep_check_kw
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +151,7 @@ def sharded_lookup(mesh, table: jax.Array, ids: jax.Array,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axis, None), id_spec),
                    out_specs=id_spec,
-                   **no_rep_check_kw())
+                   check_vma=False)
     return fn(table, ids)
 
 
@@ -180,7 +175,7 @@ def sharded_row_update(mesh, table: jax.Array, grad: SelectedRows,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axis, None), P(), P()),
                    out_specs=P(axis, None),
-                   **no_rep_check_kw())
+                   check_vma=False)
     return fn(table, grad.ids, grad.rows)
 
 
@@ -213,7 +208,7 @@ def alltoall_lookup(mesh, table: jax.Array, ids: jax.Array,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axis, None), P(axis)),
                    out_specs=P(axis),
-                   **no_rep_check_kw())
+                   check_vma=False)
     return fn(table, ids)
 
 

@@ -13,6 +13,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu.platform.compile_cache import enable_compile_cache
 from paddle_tpu.platform.enforce import EnforceError, enforce_that
 from paddle_tpu.platform.flags import FLAGS
 
@@ -80,6 +81,7 @@ def init(**kwargs) -> None:
     FLAGS.update(**kwargs)
     if FLAGS.platform:
         jax.config.update("jax_platforms", FLAGS.platform)
+    enable_compile_cache()
     if FLAGS.check_nan:
         jax.config.update("jax_debug_nans", True)
 

@@ -93,7 +93,7 @@ def attention_path(head_dim: int, page_size: int, *,
 
     Native-compile gate: the kernel's tiles are (page, D) and
     (rows*group, D) — lane-aligned D and sublane-aligned pages avoid
-    relayouts on real hardware; int8 additionally wants lane-aligned
+    layout changes on real hardware; int8 additionally wants lane-aligned
     pages for its (page,) scale vectors.  ``use_kernel`` (not None)
     forces the answer either way (tests run the kernel under
     ``interpret=True``)."""
@@ -176,15 +176,19 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
     # grid (row_blocks, kv_heads, pages-per-seq): the page axis is
     # streamed; (m, l, acc) persist in VMEM scratch across it.
     # blk_seq/pt/len are the scalar-prefetched block→sequence map [NB],
-    # page table [S, Pm] and KV lengths [S] (SMEM).  qpos_ref: (1, RBG)
-    # — per-score-row absolute positions, already group-expanded.
-    # q_ref/o_ref: (1, 1, RBG, D); k_ref/v_ref: (1, 1, page, D);
-    # quantized adds ks/vs (1, 1, page) scale rows.
+    # page table [S, Pm] and KV lengths [S] (SMEM).  qpos_ref:
+    # (1, RBG, 1) — per-score-row absolute positions, already
+    # group-expanded, as a sublane column so the mask broadcasts over
+    # the (RBG, page) scores without a layout change.  q_ref/o_ref:
+    # (1, 1, RBG, D); k_ref/v_ref: (1, page, D) — this KV head's lane
+    # slice of one page; quantized adds ks/vs (1, page, KVH) scale
+    # blocks (all KV heads: a (page, 1) block is not a legal TPU tile).
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     ib = pl.program_id(0)
+    hi = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -199,19 +203,26 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0]                    # (RBG, D)
-        kb = k_ref[0, 0]                   # (page, D)
-        vb = v_ref[0, 0]
+        kb = k_ref[0]                      # (page, D)
+        vb = v_ref[0]
         if quantized:
-            # in-register dequant: HBM traffic stays 1 byte/element
-            kb = kb.astype(jnp.float32) * ks_ref[0, 0][:, None]
-            vb = vb.astype(jnp.float32) * vs_ref[0, 0][:, None]
+            # in-register dequant: HBM traffic stays 1 byte/element.
+            # This KV head's scale column is picked out of the
+            # (page, KVH) block with a masked lane reduction.
+            def head_scale(ref):
+                sc = ref[0]
+                lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+                return jnp.sum(jnp.where(lane == hi, sc, 0.0),
+                               axis=1, keepdims=True)      # (page, 1)
+            kb = kb.astype(jnp.float32) * head_scale(ks_ref)
+            vb = vb.astype(jnp.float32) * head_scale(vs_ref)
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                   # (RBG, page)
         tok = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # ONE inequality is the whole mask: causal for prefill rows,
         # length for decode rows, everything for padded rows (qpos −1)
-        s = jnp.where(tok <= qpos_ref[0][:, None], s, DEFAULT_MASK_VALUE)
+        s = jnp.where(tok <= qpos_ref[0], s, DEFAULT_MASK_VALUE)
 
         m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)
         l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
@@ -256,50 +267,57 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
 
     blk_seq = row_seq.reshape(nb, BLOCK_ROWS)[:, 0].astype(jnp.int32)
     qpos_rows = jnp.repeat(qpos.astype(jnp.int32).reshape(nb, BLOCK_ROWS),
-                           g, axis=1)                     # (NB, RBG)
+                           g, axis=1)[..., None]          # (NB, RBG, 1)
     # [T, H, D] -> [KVH, NB, RB*G, D]: each block packs its G query
     # heads per KV head next to each other, so one K/V page load feeds
     # the whole head group
     q5 = q.reshape(nb, BLOCK_ROWS, kvh, g, d).transpose(2, 0, 1, 3, 4)
     q5 = q5.reshape(kvh, nb, rbg, d)
-    # [P, page, KVH, D] -> [KVH, P, page, D]: per-kv-head pages are
-    # contiguous blocks the index map can address as (h, page_id, 0, 0)
-    kt = k_pages.transpose(2, 0, 1, 3)
-    vt = v_pages.transpose(2, 0, 1, 3)
+    # [P, page, KVH, D] viewed as [P, page, KVH*D]: KV head h of a page
+    # is the lane block h of that page's (page, KVH*D) slab, so the
+    # index map addresses it as (page_id, 0, h) with a legal (page, D)
+    # tile and no transpose.  (On the TPU the reshape is still a
+    # re-tiling copy of the layer's slice — PERF.md, section 5.)
+    kt = k_pages.reshape(-1, page, kvh * d)
+    vt = v_pages.reshape(-1, page, kvh * d)
     pt = page_table.astype(jnp.int32)
     ln = kv_lens.astype(jnp.int32)
 
+    # TPU block shapes must end in (8k, 128k) or the array's own last
+    # two dims — every spec below is written to that rule
+
     def qpos_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
-        return (ib, 0)
+        return (ib, 0, 0)
 
     def q_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
         return (hi, ib, 0, 0)
 
-    def kv_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
+    def live_page(ib, j, blk_ref, pt_ref, len_ref):
         # clamp dead pages (j past the block's sequence's last live
         # page) to the last live one so their DMA is elided by
         # revisiting; pl.when skips their compute.  max(len-1, 0) keeps
         # length-0 sequences legal.
         seq = blk_ref[ib]
         last = jnp.maximum(len_ref[seq] - 1, 0) // page
-        return (hi, pt_ref[seq, jnp.minimum(j, last)], 0, 0)
+        return pt_ref[seq, jnp.minimum(j, last)]
+
+    def kv_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
+        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, hi)
 
     def scale_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
-        seq = blk_ref[ib]
-        last = jnp.maximum(len_ref[seq] - 1, 0) // page
-        return (hi, pt_ref[seq, jnp.minimum(j, last)], 0)
+        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, rbg), qpos_idx),
+        pl.BlockSpec((1, rbg, 1), qpos_idx),
         pl.BlockSpec((1, 1, rbg, d), q_idx),
-        pl.BlockSpec((1, 1, page, d), kv_idx),
-        pl.BlockSpec((1, 1, page, d), kv_idx),
+        pl.BlockSpec((1, page, d), kv_idx),
+        pl.BlockSpec((1, page, d), kv_idx),
     ]
     args = [qpos_rows, q5, kt, vt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, page), scale_idx),
-                     pl.BlockSpec((1, 1, page), scale_idx)]
-        args += [k_scale.transpose(2, 0, 1), v_scale.transpose(2, 0, 1)]
+        in_specs += [pl.BlockSpec((1, page, kvh), scale_idx),
+                     pl.BlockSpec((1, page, kvh), scale_idx)]
+        args += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -339,8 +357,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
 
     ``use_kernel=None`` auto-selects through :func:`attention_path`; the
     kernel additionally requires block-uniform :data:`BLOCK_ROWS`
-    packing (the engine's packer guarantees it), falling back to the
-    reference path otherwise."""
+    packing (the engine's packer guarantees it).  A kernel that was
+    chosen runs or raises — it never degrades to the reference path."""
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
@@ -350,7 +368,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
                           num_kv_heads=k_pages.shape[2],
                           quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
-    if path == "kernel" and q.shape[0] % BLOCK_ROWS == 0:
+    if path == "kernel":
         return _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale,
                               page_table.astype(jnp.int32),
                               kv_lens.astype(jnp.int32),
@@ -386,9 +404,8 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
     rejects — odd head dims, tiny pages — fall back to the plain
     reference path, which needs no ``shard_map`` because GSPMD
     partitions its gathers/einsums over the head dim natively."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from paddle_tpu.parallel.compat import no_rep_check_kw, shard_map
 
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
@@ -400,7 +417,7 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
                           num_kv_heads=k_pages.shape[2] // tp,
                           quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
-    if path != "kernel" or q.shape[0] % BLOCK_ROWS != 0:
+    if path != "kernel":
         return _ragged_reference_blocked(
             q, k_pages, v_pages, page_table, kv_lens, row_seq, qpos,
             k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
@@ -418,7 +435,7 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
                               float(sm_scale), bool(interpret))
 
     fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=head, **no_rep_check_kw())
+                   out_specs=head, check_vma=False)
     args = [q, k_pages, v_pages, page_table.astype(jnp.int32),
             kv_lens.astype(jnp.int32), row_seq.astype(jnp.int32),
             qpos.astype(jnp.int32)]

@@ -206,21 +206,16 @@ def init_kv_pages(cfg: PagedKVConfig, mesh=None, axis: str = "model"
     (batching-dim ops never move the head dim)."""
     shape = (cfg.num_layers, cfg.num_pages, cfg.page_size, cfg.kv_heads,
              cfg.head_dim)
+    # allocate every leaf ALREADY sharded: a pool sized per chip is tp x
+    # that in total, and staging it whole on device 0 first could not fit
+    sh = None if mesh is None else kv_pool_sharding(mesh, axis)
     if cfg.quantized:
-        kv = KVPages(jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(shape[:-1], jnp.float32),
-                     jnp.zeros(shape[:-1], jnp.float32))
-    else:
-        kv = KVPages(jnp.zeros(shape, cfg.dtype),
-                     jnp.zeros(shape, cfg.dtype))
-    if mesh is None:
-        return kv
-    sh = kv_pool_sharding(mesh, axis)
-    return KVPages(
-        jax.device_put(kv.k, sh), jax.device_put(kv.v, sh),
-        None if kv.k_scale is None else jax.device_put(kv.k_scale, sh),
-        None if kv.v_scale is None else jax.device_put(kv.v_scale, sh))
+        return KVPages(jnp.zeros(shape, jnp.int8, device=sh),
+                       jnp.zeros(shape, jnp.int8, device=sh),
+                       jnp.zeros(shape[:-1], jnp.float32, device=sh),
+                       jnp.zeros(shape[:-1], jnp.float32, device=sh))
+    return KVPages(jnp.zeros(shape, cfg.dtype, device=sh),
+                   jnp.zeros(shape, cfg.dtype, device=sh))
 
 
 def kv_pool_specs(axis: str = "model") -> Tuple[Optional[str], ...]:

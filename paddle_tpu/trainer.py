@@ -140,7 +140,9 @@ class SGD:
         # unconditional (including None): a reused optimizer instance must
         # not carry a previous trainer's plan into this one
         self.optimizer.set_zero_plan(self._zero_plan)
-        self.opt_state = self.optimizer.init_state(parameters.as_dict())
+        self.opt_state = self._replicate_unplaced(
+            self.optimizer.init_state(parameters.as_dict()))
+        self.model_state = self._replicate_unplaced(self.model_state)
         self._rng = jax.random.PRNGKey(FLAGS.seed or 0)
         self._step_fn = None
         self._test_fn = None
@@ -582,7 +584,28 @@ class SGD:
             if key in new_state:
                 new_state[key] = {
                     k: _slot_put(k, v) for k, v in new_state[key].items()}
-        self.opt_state = new_state
+        self.opt_state = self._replicate_unplaced(new_state)
+        self.model_state = self._replicate_unplaced(self.model_state)
+
+    def _replicate_unplaced(self, tree):
+        """Commit the leaves no placement plan covers (the step counter,
+        averaging counts, model state) replicated on the mesh.  Left
+        unplaced they come back from the first step typed on the mesh,
+        the second step's inputs differ from the first's, and the whole
+        train step compiles twice."""
+        if self.mesh is None:
+            return tree
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        repl = NamedSharding(self.mesh, P())
+
+        def place(x):
+            sh = getattr(x, "sharding", None)
+            if isinstance(sh, NamedSharding) and sh.mesh == self.mesh:
+                return x
+            return _put_global(x, repl)
+
+        return jax.tree.map(place, tree)
 
     def _shard_feeds(self, feeds):
         if self.mesh is None:

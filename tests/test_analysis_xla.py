@@ -218,7 +218,7 @@ def test_const_captured_weights_flagged(audit):
 
 
 def test_collective_in_decode_site_is_error(audit):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("i",))

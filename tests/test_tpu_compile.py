@@ -1,0 +1,187 @@
+"""AOT compiles of the main-path pallas kernels for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described (``get_topology_desc``) and not attached, so these
+tests catch what interpret mode cannot: block shapes Mosaic refuses,
+VMEM overflows, kernels that cannot be partitioned under ``shard_map``.
+Nothing runs — a compile that passes is a compile, not a chip run.
+
+Shapes are the real widths of the d2048 train/serve cells.  The
+persistent compile cache is switched off around the module: an entry
+written for a described device cannot be read back without a chip, and
+the next run would warn and recompile.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import rnn
+from paddle_tpu.ops.attention import flash_attention
+from paddle_tpu.platform.flags import FLAGS
+from paddle_tpu.serving.decode_attention import (ragged_paged_attention,
+                                                 ragged_paged_attention_tp)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu in this installation
+        pytest.skip(f"cannot describe a TPU v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *avals):
+    """Compile ``fn`` for the avals' (described) devices; returns the
+    number of Mosaic kernels in the optimized program."""
+    compiled = jax.jit(fn).lower(*avals).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _on(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+# ---- flash attention: the train cell's shape ------------------------------
+
+@pytest.mark.parametrize("mode", ["fwd", "bwd_pallas", "bwd_scan"])
+def test_flash_attention_compiles_for_v5e(topo, mode):
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    q = aval((4, 1024, 16, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    was = FLAGS.use_pallas
+    FLAGS.use_pallas = mode != "bwd_scan"
+    try:
+        n = _compile(fwd if mode == "fwd" else
+                     jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    finally:
+        FLAGS.use_pallas = was
+    # fwd kernel, plus dKV and dQ kernels on the pallas backward
+    assert n == {"fwd": 1, "bwd_pallas": 3, "bwd_scan": 1}[mode]
+
+
+def test_flash_attention_under_a_mesh_needs_per_device(topo):
+    """GSPMD cannot partition a Mosaic kernel: a jit that spans four
+    chips refuses the bare call and takes it through
+    ``kernel_util.per_device`` — what the attention and recurrent layers
+    do under ``SGD(mesh=...)``."""
+    from paddle_tpu.ops.kernel_util import per_device
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    q = _on(NamedSharding(mesh, P()))((1, 1024, 16, 128), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(attend, q, q, q)
+    assert _compile(per_device(attend, mesh), q, q, q) == 1
+
+
+# ---- ragged paged attention: the serve cell's shape -----------------------
+
+def _ragged_avals(aval_for, t, h, kvh, dtype, d=128, page=128, pages=64,
+                  slots=8, pm=8):
+    """(q, k, v, table, lens, row_seq, qpos[, k_scale, v_scale]) avals;
+    ``aval_for(spec)`` places one argument."""
+    head, pool, scale = (P(None, "model", None), P(None, None, "model", None),
+                         P(None, None, "model"))
+    quant = dtype == "int8"
+    q = aval_for(head)((t, h, d), jnp.float32 if quant else dtype)
+    kv = aval_for(pool)((pages, page, kvh, d), jnp.int8 if quant else dtype)
+    i32 = lambda *shape: aval_for(P())(shape, jnp.int32)  # noqa: E731
+    out = [q, kv, kv, i32(slots, pm), i32(slots), i32(t), i32(t)]
+    if quant:
+        sc = aval_for(scale)((pages, page, kvh), jnp.float32)
+        out += [sc, sc]
+    return out
+
+
+def _scales(rest):
+    return dict(k_scale=rest[0], v_scale=rest[1]) if rest else {}
+
+
+RAGGED_CASES = [
+    # rows, heads, kv heads, pool dtype
+    (64, 16, 16, jnp.float32),
+    (64, 16, 16, jnp.bfloat16),
+    (64, 16, 4, jnp.bfloat16),          # GQA: 4 query heads per KV head
+    (64, 16, 16, "int8"),
+    (64, 16, 4, "int8"),
+    (8 * 8 + 512, 16, 16, jnp.float32),  # a full tick: 8 slots + 512 prefill
+]
+
+
+@pytest.mark.parametrize("t,h,kvh,dtype", RAGGED_CASES)
+def test_ragged_paged_attention_compiles_for_v5e(topo, t, h, kvh, dtype):
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def fn(q, k, v, table, lens, row_seq, qpos, *rest):
+        return ragged_paged_attention(q, k, v, table, lens, row_seq, qpos,
+                                      use_kernel=True, interpret=False,
+                                      **_scales(rest))
+
+    assert _compile(fn, *_ragged_avals(lambda spec: _on(one), t, h, kvh,
+                                       dtype)) == 1
+
+
+@pytest.mark.parametrize("t,h,kvh,dtype", RAGGED_CASES[:5])
+def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
+    """The same kernel under ``shard_map`` over a four-chip model axis
+    (the TP=4 engine's attention): each chip gets H/4 query and KVH/4 KV
+    heads, down to ONE KV head per chip for GQA 16/4."""
+    mesh = Mesh(np.asarray(topo.devices), ("model",))
+
+    def fn(q, k, v, table, lens, row_seq, qpos, *rest):
+        return ragged_paged_attention_tp(mesh, "model", q, k, v, table, lens,
+                                         row_seq, qpos, use_kernel=True,
+                                         interpret=False, **_scales(rest))
+
+    avals = _ragged_avals(lambda spec: _on(NamedSharding(mesh, spec)),
+                          t, h, kvh, dtype)
+    assert _compile(fn, *avals) == 1
+
+
+# ---- fused recurrent cells: the LSTM guard cell's shape -------------------
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_fused_rnn_cell_compiles_for_v5e(topo, cell):
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    b, t, hid = 64, 4, 512
+    gates = 4 if cell == "lstm" else 3
+    scan = rnn.lstm_scan if cell == "lstm" else rnn.gru_scan
+
+    def loss(x, mask, w_h, bias):
+        hs, _ = scan(x, mask, None, w_h, bias, interpret=False)
+        return jnp.sum(hs)
+
+    n = _compile(jax.grad(loss, argnums=(0, 2)),
+                 aval((b, t, gates * hid), jnp.float32),
+                 aval((b, t), jnp.float32),
+                 aval((hid, gates * hid), jnp.float32),
+                 aval((gates * hid,), jnp.float32))
+    assert n >= 1
