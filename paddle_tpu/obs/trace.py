@@ -17,7 +17,12 @@ timeline:
 - pool edges: ``page_alloc`` / ``page_ref`` / ``page_free`` /
   ``page_evict``;
 - compile edges: the retrace auditor reports each ``jit_compile`` when
-  a tracer is attached (``RetraceAuditor.attach_tracer``).
+  a tracer is attached (``RetraceAuditor.attach_tracer``);
+- phases: ``phase("tick.schedule", tick=7)`` names one stretch of the
+  serving tick.  It is always a
+  ``jax.profiler.TraceAnnotation("pt:tick.schedule")``, so whoever has
+  a profiler session open finds it on the device trace's clock; an
+  enabled tracer also records it as an ``X`` event on its own clock.
 
 Design contracts (the same ones the rest of the repo pins):
 
@@ -59,11 +64,16 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from paddle_tpu.platform.flags import FLAGS
 
 __all__ = ["Event", "Tracer", "NULL_TRACER", "tracer_for"]
 
 _POSTMORTEM_SEQ = itertools.count()
+
+# what a reader of a profiler trace finds the program's own phases by
+_PHASE_PREFIX = "pt:"
 
 
 @dataclass
@@ -142,6 +152,25 @@ class _Span:
         return False
 
 
+class _Phase(_Span):
+    """A :class:`_Span` inside the profiler annotation of the same name,
+    so both clocks see the same stretch of code."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, tracer, name, cat, replica, slot, args):
+        super().__init__(tracer, name, cat, replica, slot, args)
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        return self._ann.__exit__(*exc)
+
+
 class Tracer:
     """Span/event recorder on an injected clock (see module doc).
 
@@ -201,6 +230,15 @@ class Tracer:
         (zero-width under a ManualClock that advances per tick, real
         durations on a wall clock)."""
         return _Span(self, name, cat, replica, slot, args)
+
+    def phase(self, name: str, cat: str = "serving",
+              replica: Optional[int] = None, slot: Optional[int] = None,
+              **args) -> _Phase:
+        """``with tracer.phase("tick.upload", tick=7): ...`` — the span
+        ``pt:tick.upload`` in the profiler's trace (when a session is
+        open) and in the ring; ``args`` carry the tick number, which
+        the phases of one tick share."""
+        return _Phase(self, _PHASE_PREFIX + name, cat, replica, slot, args)
 
     def instant(self, name: str, cat: str = "serving",
                 replica: Optional[int] = None, slot: Optional[int] = None,
@@ -326,6 +364,11 @@ class _ScopedTracer:
         merged.update(kw)
         return self._base.span(name, **merged)
 
+    def phase(self, name: str, **kw):
+        merged = dict(self._labels)
+        merged.update(kw)
+        return self._base.phase(name, **merged)
+
     def instant(self, name: str, **kw) -> None:
         merged = dict(self._labels)
         merged.update(kw)
@@ -379,7 +422,8 @@ class _NullTracer:
     """The obs-off tracer: every method is a constant no-op.  One shared
     instance (:data:`NULL_TRACER`) serves the whole process, so a
     disabled engine pays one attribute call per instrumentation point —
-    no events, no clock reads, no device work."""
+    no events, no clock reads, no device work.  ``phase`` alone returns
+    something live: the profiler's own annotation (see module doc)."""
 
     enabled = False
     registry = None
@@ -393,6 +437,11 @@ class _NullTracer:
 
     def span(self, name: str, **kw) -> _NullContext:
         return _NULL_CTX
+
+    def phase(self, name: str, **kw) -> TraceAnnotation:
+        # the one thing the obs-off tracer does: the annotation, which
+        # is inert unless a profiler session is open
+        return TraceAnnotation(_PHASE_PREFIX + name)
 
     def instant(self, name: str, **kw) -> None:
         pass

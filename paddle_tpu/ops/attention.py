@@ -273,6 +273,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         ],
         compiler_params=_dim_semantics(4, interpret),
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt, q_seg, kv_seg)
     return out_t.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -477,6 +478,7 @@ def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
         ],
         compiler_params=_dim_semantics(4, interpret),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, q_seg, kv_seg, dot, lse_t, delta)
 
     # --- dQ: grid (B, H, q-blocks, k-blocks), key axis streamed ---
@@ -505,6 +507,7 @@ def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_dim_semantics(4, interpret),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, q_seg, kv_seg, dot, lse_t, delta)
 
     return (dq_t.transpose(0, 2, 1, 3), dk_t.transpose(0, 2, 1, 3),

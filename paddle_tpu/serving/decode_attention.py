@@ -338,6 +338,7 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
         out_shape=jax.ShapeDtypeStruct((kvh, nb, rbg, d), q.dtype),
         compiler_params=_dim_semantics(3, interpret),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(blk_seq, pt, ln, *args)
     out = out.reshape(kvh, nb, BLOCK_ROWS, g, d).transpose(1, 2, 0, 3, 4)
     return out.reshape(t, h, d)

@@ -357,13 +357,17 @@ class DecoderLM(DecodeModel):
         # row-parallel output projection: contraction over the sharded
         # feature dim -> partial sums -> ONE psum (the _tp_psum
         # constraint), then the replicated residual add
-        a = x + self._tp_psum(flat @ params[f"l{layer}.wo"])
-        up = self._tp_sharded(_rms(a) @ params[f"l{layer}.w1"], 0)
-        # row-parallel FFN-down projection: the block's second psum
-        return a + self._tp_psum(jax.nn.gelu(up) @ params[f"l{layer}.w2"])
+        with jax.named_scope("attn"):
+            a = x + self._tp_psum(flat @ params[f"l{layer}.wo"])
+        with jax.named_scope("ffn"):
+            up = self._tp_sharded(_rms(a) @ params[f"l{layer}.w1"], 0)
+            # row-parallel FFN-down projection: the block's second psum
+            return a + self._tp_psum(
+                jax.nn.gelu(up) @ params[f"l{layer}.w2"])
 
     def logits(self, params, x):
-        return _rms(x) @ params["out"]
+        with jax.named_scope("head"):
+            return _rms(x) @ params["out"]
 
 
 def greedy_decode_reference(model: DecodeModel, params, prompt: List[int],
@@ -999,12 +1003,17 @@ class ServingEngine:
             row_seq = jnp.concatenate([d_seq, p_seq])
             qpos = jnp.concatenate([jnp.where(dv, dp, -1), p_qpos])
             for l in range(cfg.num_layers):
-                q, k, v = model.qkv(params, l, x)
-                kv = append_token(kv, l, jnp.where(wmask, k, 0.0),
-                                  jnp.where(wmask, v, 0.0), pages, offs)
-                ctx = self._attend(kv, l, q, table, att_lens, row_seq,
-                                   qpos, k1=k1)
-                x = model.attn_out(params, l, ctx, x)
+                # named_scope: blocks and their parts show up by name in
+                # xplane/profiler traces (as topology.forward's layers)
+                with jax.named_scope(f"l{l}"):
+                    with jax.named_scope("attn"):
+                        q, k, v = model.qkv(params, l, x)
+                        kv = append_token(kv, l, jnp.where(wmask, k, 0.0),
+                                          jnp.where(wmask, v, 0.0), pages,
+                                          offs)
+                        ctx = self._attend(kv, l, q, table, att_lens,
+                                           row_seq, qpos, k1=k1)
+                    x = model.attn_out(params, l, ctx, x)
             # logits only where the host will read them: the B*k1
             # decode/verify rows + each slot's chunk-final row
             sel = jnp.concatenate([jnp.arange(bd), p_last])
@@ -1171,8 +1180,55 @@ class ServingEngine:
         """One engine tick: shed expired/unmeetable work, grow/preempt,
         admit + prefill, one fused decode over all running sequences
         (with transient-error retry, finite-logits isolation, and the
-        progress watchdog).  Returns True if any work remains."""
-        tick, sched, m = self._tick, self.scheduler, self.metrics
+        progress watchdog).  Returns True if any work remains.
+
+        The tick names its phases for the profiler (``pt:tick`` and,
+        inside it, ``pt:tick.schedule`` here, ``.assemble``, ``.upload``,
+        ``.wait`` and ``.sample`` in :meth:`_do_step`, ``.sample`` again
+        for the bookkeeping that closes the tick)."""
+        tick, m = self._tick, self.metrics
+        phase = self._tracer.phase
+        with phase("tick", tick=tick):
+            with phase("tick.schedule", tick=tick):
+                running, chunks, total_rows, drafts, busy = \
+                    self._schedule_tick(tick, now)
+            if running or chunks:
+                for req, start, n, _ in chunks:
+                    self._tracer.instant("prefill_chunk", rid=req.rid,
+                                         slot=req.slot, start=start, n=n,
+                                         tick=tick)
+                # span keeps its historical name: it IS the fused tick
+                with self._tracer.span("decode_tick", tick=tick,
+                                       n=len(running),
+                                       prefill_rows=total_rows):
+                    if self._fuse_tick or not (running and chunks):
+                        self._step_with_retry(running, chunks, total_rows,
+                                              tick, drafts)
+                    else:
+                        # fuse_tick=False: the v1 tick-interleave shape —
+                        # prefill and decode as separate dispatches (bench
+                        # control; same math, token-identical)
+                        self._step_with_retry([], chunks, total_rows, tick,
+                                              {})
+                        self._step_with_retry(running, [], 0, tick, drafts)
+            with phase("tick.sample", tick=tick):
+                self._prev_tick_busy = busy
+                self._watchdog_sweep(tick)
+                m.on_tick(self.scheduler.queue_depth, self.pool.num_live,
+                          self.pool.num_cached,
+                          self.cache.evictions if self.cache is not None
+                          else 0)
+                if self.host_tier is not None:
+                    m.on_host_tier(self.host_tier.snapshot(),
+                                   self._host_hits)
+            self._tick = tick + 1
+        return self.has_work
+
+    def _schedule_tick(self, tick: int, now: Optional[float]):
+        """The host's decisions before a tick's dispatch: what is shed,
+        grown, preempted, admitted, and which rows ride the step.
+        Returns ``(running, chunks, total_rows, drafts, busy)``."""
+        sched, m = self.scheduler, self.metrics
         if self.faults is not None:
             self.faults.tick_begin(tick)
             self.faults.apply_page_pressure(tick, self.pool)
@@ -1246,35 +1302,8 @@ class ServingEngine:
         # loop (drafting mutates proposer state — it must run once per
         # tick, and the position-keyed RNG keeps it deterministic)
         drafts = self._propose_drafts(running, under_pressure=npreempt > 0)
-        if running or chunks:
-            for req, start, n, _ in chunks:
-                self._tracer.instant("prefill_chunk", rid=req.rid,
-                                     slot=req.slot, start=start, n=n,
-                                     tick=tick)
-            # span keeps its historical name: it IS the fused tick
-            with self._tracer.span("decode_tick", tick=tick,
-                                   n=len(running),
-                                   prefill_rows=total_rows):
-                if self._fuse_tick or not (running and chunks):
-                    self._step_with_retry(running, chunks, total_rows,
-                                          tick, drafts)
-                else:
-                    # fuse_tick=False: the v1 tick-interleave shape —
-                    # prefill and decode as separate dispatches (bench
-                    # control; same math, token-identical)
-                    self._step_with_retry([], chunks, total_rows, tick,
-                                          {})
-                    self._step_with_retry(running, [], 0, tick, drafts)
-        self._prev_tick_busy = (bool(running) or bool(admitted) or
-                                bool(prefilling))
-        self._watchdog_sweep(tick)
-        m.on_tick(sched.queue_depth, self.pool.num_live,
-                  self.pool.num_cached,
-                  self.cache.evictions if self.cache is not None else 0)
-        if self.host_tier is not None:
-            m.on_host_tier(self.host_tier.snapshot(), self._host_hits)
-        self._tick = tick + 1
-        return self.has_work
+        busy = bool(running) or bool(admitted) or bool(prefilling)
+        return running, chunks, total_rows, drafts, busy
 
     def run(self, max_ticks: Optional[int] = None) -> Dict[int, List[int]]:
         """Tick until drained (or ``max_ticks``); returns
@@ -1831,6 +1860,30 @@ class ServingEngine:
         the lookahead pages back (``scheduler.rollback_pages``) — the
         rejected rows' K/V beyond the new length is masked junk the
         next real tokens overwrite."""
+        k1, tick, phase = self._k1, self._tick, self._tracer.phase
+        with phase("tick.assemble", tick=tick):
+            host = self._assemble(running, chunks, total_rows, drafts)
+        pb = host[3].shape[0]                   # p_tokens: the bucket
+        with phase("tick.upload", tick=tick):
+            dev = [jnp.asarray(a) for a in host]
+            d_logits, p_logits, self._kv = self._step_fn(pb, k1)(
+                self.params, self._kv, *dev)
+        with phase("tick.wait", tick=tick):
+            d_logits = np.asarray(d_logits)   # forces device sync; [B,k1,V]
+            p_logits = np.asarray(p_logits)
+        with phase("tick.sample", tick=tick):
+            self.metrics.on_step(
+                sum(1 + len(drafts.get(r.rid, ((),))[0]) for r in running),
+                total_rows, pb - sum(c[2] for c in chunks),
+                n_slots=len(running),
+                h2d_bytes=sum(a.nbytes for a in host),
+                d2h_bytes=d_logits.nbytes + p_logits.nbytes)
+            self._walk_results(running, chunks, drafts, d_logits, p_logits)
+
+    def _assemble(self, running: List[Request], chunks, total_rows: int,
+                  drafts: Dict[int, Tuple]) -> Tuple[np.ndarray, ...]:
+        """The nine host arrays of one unified step, in the order
+        ``_step_fn`` takes them after the parameters and the pool."""
         b, k1 = self._max_slots, self._k1
         cfg = self.kv_cfg
         d_tokens = np.zeros((b, k1), np.int32)
@@ -1873,18 +1926,14 @@ class ServingEngine:
             att_lens[s] = start + n
             table[s, :len(req.pages)] = req.pages
             off += rows
-        d_logits, p_logits, self._kv = self._step_fn(pb, k1)(
-            self.params, self._kv, jnp.asarray(d_tokens),
-            jnp.asarray(d_pos), jnp.asarray(d_valid),
-            jnp.asarray(p_tokens), jnp.asarray(p_qpos),
-            jnp.asarray(p_seq), jnp.asarray(p_last), jnp.asarray(table),
-            jnp.asarray(att_lens))
-        d_logits = np.asarray(d_logits)   # forces device sync; [B,k1,V]
-        p_logits = np.asarray(p_logits)
-        self.metrics.on_step(
-            sum(1 + len(drafts.get(r.rid, ((),))[0]) for r in running),
-            total_rows, pb - sum(c[2] for c in chunks),
-            n_slots=len(running))
+        return (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
+                table, att_lens)
+
+    def _walk_results(self, running: List[Request], chunks,
+                      drafts: Dict[int, Tuple], d_logits: np.ndarray,
+                      p_logits: np.ndarray) -> None:
+        """What the host does with a step's logits: chunk bookkeeping
+        first, decode/verify emissions second (see :meth:`_do_step`)."""
         # stamp AFTER the sync so TTFT includes the step compute
         now = self._time()
         for req, start, n, _rows in chunks:

@@ -61,6 +61,8 @@ class ServingMetrics:
         #                               running slot per step)
         self.prefill_rows = 0         # prefill-chunk rows shipped (padded)
         self.prefill_pad_rows = 0     # of the bucket, padding/alignment
+        self.h2d_bytes = 0            # the steps' nine input arrays
+        self.d2h_bytes = 0            # the steps' two logits arrays
         # speculative decoding (round 18)
         self.spec_ticks = 0           # verify ticks with >= 1 drafted token
         self.spec_tokens_proposed = 0  # drafted tokens shipped to verify
@@ -114,7 +116,8 @@ class ServingMetrics:
         self.prefill_tokens += n_tokens
 
     def on_step(self, n_decode_rows: int, n_prefill_rows: int,
-                n_pad_rows: int, n_slots: Optional[int] = None) -> None:
+                n_pad_rows: int, n_slots: Optional[int] = None,
+                h2d_bytes: int = 0, d2h_bytes: int = 0) -> None:
         """One unified-step dispatch: how many decode/verify rows and
         (padded) prefill rows rode it, and how much of the prefill
         bucket was padding.  ``n_slots`` is the running-slot
@@ -122,13 +125,17 @@ class ServingMetrics:
         speculation, 1/k1 of it with (each speculating slot ships k1
         verify rows).  ``fuse_tick=False`` (the v1 two-dispatch
         control) calls this twice per busy tick — the dispatch-count
-        delta IS the A/B."""
+        delta IS the A/B.  ``h2d_bytes``/``d2h_bytes`` are what the
+        dispatch moved between host and device: its input arrays up,
+        its logits down."""
         self.step_dispatches += 1
         self.decode_rows += n_decode_rows
         self.decode_slots += n_slots if n_slots is not None \
             else n_decode_rows
         self.prefill_rows += n_prefill_rows
         self.prefill_pad_rows += max(0, n_pad_rows)
+        self.h2d_bytes += h2d_bytes
+        self.d2h_bytes += d2h_bytes
 
     def on_prefix(self, requested: int, saved: int) -> None:
         """One admission's prefix-cache outcome: ``requested`` tokens
@@ -302,6 +309,8 @@ class ServingMetrics:
             "decode_slots": self.decode_slots,
             "prefill_rows": self.prefill_rows,
             "prefill_pad_rows": self.prefill_pad_rows,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
             "prefix_hit_rate": round(self.prefix_hit_rate(), 4),
             "spec_ticks": self.spec_ticks,
             "spec_tokens_proposed": self.spec_tokens_proposed,

@@ -166,6 +166,53 @@ def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
     assert _compile(fn, *avals) == 1
 
 
+# ---- names in the device trace ---------------------------------------------
+
+def _kernel_names(fn, *avals):
+    """The HLO instruction names of the Mosaic kernels of the optimized
+    program: what a profiler's device trace lists them under."""
+    import re
+
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    return sorted(re.match(r"\s*(?:ROOT )?%([A-Za-z_\-]+)", line).group(1)
+                  for line in text.splitlines() if "tpu_custom_call" in line)
+
+
+@pytest.mark.parametrize("which", ["flash", "ragged", "ragged_tp4"])
+def test_kernels_carry_stable_names(topo, which):
+    """``name=`` on the ``pallas_call`` sites the benchmark's cells run
+    reaches the compiled program, alone and under ``shard_map``."""
+    one = SingleDeviceSharding(topo.devices[0])
+    if which == "flash":
+        q = _on(one)((4, 1024, 16, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True,
+                                           interpret=False)
+                           .astype(jnp.float32))
+
+        # under autodiff the names come wrapped (``jvp_flash_fwd_``)
+        got = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+        assert len(got) == 3 and all(
+            sum(want in name for name in got) == 1
+            for want in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")), got
+        return
+    mesh = Mesh(np.asarray(topo.devices), ("model",))
+
+    def fn(*a):
+        if which == "ragged":
+            return ragged_paged_attention(*a, use_kernel=True,
+                                          interpret=False)
+        return ragged_paged_attention_tp(mesh, "model", *a, use_kernel=True,
+                                         interpret=False)
+
+    place = (lambda spec: _on(one)) if which == "ragged" else \
+        (lambda spec: _on(NamedSharding(mesh, spec)))
+    assert _kernel_names(fn, *_ragged_avals(place, 64, 16, 16,
+                                            jnp.float32)) == \
+        ["ragged_paged_attention"]
+
+
 # ---- fused recurrent cells: the LSTM guard cell's shape -------------------
 
 @pytest.mark.parametrize("cell", ["lstm", "gru"])
