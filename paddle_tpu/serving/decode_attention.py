@@ -28,6 +28,16 @@ ragged invocation:
   ``num_heads // num_kv_heads``) is packed against each K/V page load,
   so K/V HBM traffic drops by the group factor — the pool stores KV
   heads only.
+- **All of a chip's KV heads in one grid cell.**  A grid step costs a
+  fixed quarter of a microsecond whatever it does, and a page's
+  ``(page, KVH * D)`` slab is contiguous in the pool, so a cell takes
+  ``hb`` KV heads at once (:func:`heads_per_cell`: as many as fit the
+  VMEM budget — all of them at the widths served so far): ONE DMA of
+  the slab where there were ``hb`` strided ones, the page's liveness
+  and the mask evaluated once, then the per-head online-softmax update
+  for each head over its lane slice ``[:, h * D:(h + 1) * D]``.  Per
+  head the arithmetic and its order over pages are those of ``hb = 1``,
+  bit for bit; only the number of grid steps changes.
 - **int8 pages, dequant in-register.**  Quantized pools ship per-token,
   per-kv-head f32 scales next to the int8 pages; the kernel (and the
   gather fallback — see ``kv_cache.dequantize_kv``, the ONE shared
@@ -36,15 +46,17 @@ ragged invocation:
 Two paths with identical semantics, selected by :func:`attention_path`
 — the single dispatch gate every paged-attention call routes through:
 
-- **Pallas kernel**: grid ``(row_blocks, kv_heads, pages)``, online-
-  softmax carry (m, l, acc) in VMEM scratch across the page axis.
+- **Pallas kernel**: grid ``(row_blocks, kv_heads / hb, pages)``,
+  online-softmax carry (m, l, acc) per head in VMEM scratch across the
+  page axis.
 - **Reference path** (CPU/interpreter fallback and the parity oracle):
   page-table gather + masked softmax in f32 — no new math to trust,
   reading the SAME stored (possibly quantized) values.
 
 Decode rows are bandwidth-bound (a [G, D] x [page, D] product per
-page), so the kernel's job there is DMA shape; prefill rows add real
-MXU work that v1 paid in a second dispatch.
+page and head), so the kernel's job there is DMA shape and few grid
+steps; prefill rows add real MXU work that v1 paid in a second
+dispatch.
 """
 
 from __future__ import annotations
@@ -112,6 +124,31 @@ def attention_path(head_dim: int, page_size: int, *,
     return "kernel"
 
 
+# What the double-buffered K and V slabs (and scale blocks) of one grid
+# cell may take: half of the 16 MiB of VMEM a Mosaic kernel is scoped
+# to by default on a TPU v5e, the rest left to q, out, the softmax
+# carries and the (rows, page) scores of the body.
+_KV_VMEM_BUDGET = 8 << 20
+
+
+def heads_per_cell(num_kv_heads: int, page_size: int, head_dim: int,
+                   kv_itemsize: int, quantized: bool = False) -> int:
+    """``hb``: how many of the (shard's) KV heads one grid cell of the
+    ragged kernel takes — the largest divisor of ``num_kv_heads`` whose
+    K and V tiles ``(page, hb * D)``, double-buffered, fit
+    :data:`_KV_VMEM_BUDGET` beside the two ``(page, KVH)`` scale blocks
+    an int8 pool ships whole.  A pure function of shapes: 8 and 16
+    float32 heads of 128 at page 128 fold whole (2 and 4 MiB); a slab
+    that does not fit falls to a divisor, down to one head a cell."""
+    scales = 2 * 2 * page_size * num_kv_heads * 4 if quantized else 0
+    per_head = 2 * 2 * page_size * head_dim * kv_itemsize
+    for hb in range(num_kv_heads, 1, -1):
+        if num_kv_heads % hb == 0 and \
+                hb * per_head + scales <= _KV_VMEM_BUDGET:
+            return hb
+    return 1
+
+
 def _kernel_shape_ok(head_dim: int, page_size: int) -> bool:
     """Back-compat shim over :func:`attention_path` (v1 name)."""
     return attention_path(head_dim, page_size, interpret=False) == "kernel"
@@ -171,25 +208,31 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 # ---------------------------------------------------------------------------
 
 def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
-                   v_ref, *rest, page_size: int, num_pb: int,
+                   v_ref, *rest, page_size: int, num_pb: int, hb: int,
                    sm_scale: float, quantized: bool):
-    # grid (row_blocks, kv_heads, pages-per-seq): the page axis is
-    # streamed; (m, l, acc) persist in VMEM scratch across it.
-    # blk_seq/pt/len are the scalar-prefetched block→sequence map [NB],
-    # page table [S, Pm] and KV lengths [S] (SMEM).  qpos_ref:
-    # (1, RBG, 1) — per-score-row absolute positions, already
-    # group-expanded, as a sublane column so the mask broadcasts over
-    # the (RBG, page) scores without a layout change.  q_ref/o_ref:
-    # (1, 1, RBG, D); k_ref/v_ref: (1, page, D) — this KV head's lane
-    # slice of one page; quantized adds ks/vs (1, page, KVH) scale
-    # blocks (all KV heads: a (page, 1) block is not a legal TPU tile).
+    # grid (row_blocks, kv_head_groups, pages-per-seq), ``hb`` KV heads
+    # a group: the page axis is streamed; every head's (m, l, acc)
+    # persist in VMEM scratch across it.  blk_seq/pt/len are the
+    # scalar-prefetched block→sequence map [NB], page table [S, Pm] and
+    # KV lengths [S] (SMEM).  qpos_ref: (1, RBG, 1) — per-score-row
+    # absolute positions, already group-expanded, as a sublane column
+    # so the mask broadcasts over the (RBG, page) scores without a
+    # layout change.  q_ref/o_ref: (hb, 1, RBG, D); k_ref/v_ref:
+    # (1, page, hb * D) — the group's lane slab of one page, contiguous
+    # in the pool (the whole page when hb is all heads), head h of the
+    # group at lanes [h * D, (h + 1) * D); quantized adds ks/vs
+    # (1, page, KVH) scale blocks (all KV heads: a (page, 1) block is
+    # not a legal TPU tile).  Scratch: m/l (hb, RBG, LANES), acc
+    # (hb, RBG, D).  The body tests the page's liveness and builds the
+    # mask once, then runs the per-head update hb times, unrolled.
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     ib = pl.program_id(0)
-    hi = pl.program_id(1)
+    hg = pl.program_id(1)
     j = pl.program_id(2)
+    rbg, d = q_ref.shape[2:]
 
     @pl.when(j == 0)
     def _init():
@@ -202,45 +245,51 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                    # (RBG, D)
-        kb = k_ref[0]                      # (page, D)
-        vb = v_ref[0]
-        if quantized:
-            # in-register dequant: HBM traffic stays 1 byte/element.
-            # This KV head's scale column is picked out of the
-            # (page, KVH) block with a masked lane reduction.
-            def head_scale(ref):
-                sc = ref[0]
-                lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-                return jnp.sum(jnp.where(lane == hi, sc, 0.0),
-                               axis=1, keepdims=True)      # (page, 1)
-            kb = kb.astype(jnp.float32) * head_scale(ks_ref)
-            vb = vb.astype(jnp.float32) * head_scale(vs_ref)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale                   # (RBG, page)
-        tok = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        tok = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rbg, page_size), 1)
         # ONE inequality is the whole mask: causal for prefill rows,
         # length for decode rows, everything for padded rows (qpos −1)
-        s = jnp.where(tok <= qpos_ref[0], s, DEFAULT_MASK_VALUE)
+        seen = tok <= qpos_ref[0]
+        if quantized:
+            # in-register dequant: HBM traffic stays 1 byte/element.
+            # A KV head's scale column is picked out of the
+            # (page, KVH) block with a masked lane reduction.
+            ksc, vsc = ks_ref[0], vs_ref[0]
+            lane = jax.lax.broadcasted_iota(jnp.int32, ksc.shape, 1)
 
-        m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)
-        l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+            def head_scale(sc, head):
+                return jnp.sum(jnp.where(lane == head, sc, 0.0),
+                               axis=1, keepdims=True)      # (page, 1)
+        for h in range(hb):
+            q = q_ref[h, 0]                        # (RBG, D)
+            kb = k_ref[0, :, h * d:(h + 1) * d]    # (page, D)
+            vb = v_ref[0, :, h * d:(h + 1) * d]
+            if quantized:
+                kb = kb.astype(jnp.float32) * head_scale(ksc, hg * hb + h)
+                vb = vb.astype(jnp.float32) * head_scale(vsc, hg * hb + h)
+            s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * sm_scale                       # (RBG, page)
+            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+
+            m_prev = jnp.max(m_scr[h], axis=1, keepdims=True)
+            l_prev = jnp.max(l_scr[h], axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(j == num_pb - 1)
     def _finalize():
-        l = jnp.max(l_scr[...], axis=1, keepdims=True)
-        l = jnp.where(l == 0.0, 1.0, l)    # length-0 rows -> zeros, not NaN
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for h in range(hb):
+            l = jnp.max(l_scr[h], axis=1, keepdims=True)
+            l = jnp.where(l == 0.0, 1.0, l)  # length-0 rows -> zeros, not NaN
+            o_ref[h, 0] = (acc_scr[h] / l).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
@@ -254,12 +303,28 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
     packing and tests pin it against the reference path."""
     t, h, d = q.shape
     _, page, kvh, _ = k_pages.shape
-    pm = page_table.shape[1]
     enforce_that(t % BLOCK_ROWS == 0,
                  f"ragged kernel rows ({t}) must pack to BLOCK_ROWS "
                  f"({BLOCK_ROWS})", context="serving")
     enforce_that(h % kvh == 0, f"num_heads ({h}) must be a multiple of "
                  f"num_kv_heads ({kvh})", context="serving")
+    hb = heads_per_cell(kvh, page, d, k_pages.dtype.itemsize,
+                        k_scale is not None)
+    return _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table,
+                        kv_lens, row_seq, qpos, hb=hb, sm_scale=sm_scale,
+                        interpret=interpret)
+
+
+# jitted on its own so that a step program of L layers traces and lowers
+# the kernel once and calls it L times: the body is unrolled over the
+# cell's heads, and Pallas lowers in Python in every process, persistent
+# compile cache or not — per layer that is seconds of set-up
+@functools.partial(jax.jit, static_argnames=("hb", "sm_scale", "interpret"))
+def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
+                 row_seq, qpos, *, hb: int, sm_scale: float, interpret: bool):
+    t, h, d = q.shape
+    _, page, kvh, _ = k_pages.shape
+    pm = page_table.shape[1]
     g = h // kvh
     nb = t // BLOCK_ROWS
     rbg = BLOCK_ROWS * g
@@ -273,11 +338,12 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
     # the whole head group
     q5 = q.reshape(nb, BLOCK_ROWS, kvh, g, d).transpose(2, 0, 1, 3, 4)
     q5 = q5.reshape(kvh, nb, rbg, d)
-    # [P, page, KVH, D] viewed as [P, page, KVH*D]: KV head h of a page
-    # is the lane block h of that page's (page, KVH*D) slab, so the
-    # index map addresses it as (page_id, 0, h) with a legal (page, D)
-    # tile and no transpose.  (On the TPU the reshape is still a
-    # re-tiling copy of the layer's slice — PERF.md, section 5.)
+    # [P, page, KVH, D] viewed as [P, page, KVH*D]: the KV heads of
+    # group hg are lanes [hg*hb*D, (hg+1)*hb*D) of that page's
+    # (page, KVH*D) slab, so the index map addresses them as
+    # (page_id, 0, hg) with a legal (page, hb*D) tile and no transpose.
+    # (On the TPU the reshape is still a re-tiling copy of the layer's
+    # slice — PERF.md, section 5.)
     kt = k_pages.reshape(-1, page, kvh * d)
     vt = v_pages.reshape(-1, page, kvh * d)
     pt = page_table.astype(jnp.int32)
@@ -286,11 +352,11 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
     # TPU block shapes must end in (8k, 128k) or the array's own last
     # two dims — every spec below is written to that rule
 
-    def qpos_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
+    def qpos_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
         return (ib, 0, 0)
 
-    def q_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
-        return (hi, ib, 0, 0)
+    def q_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
+        return (hg, ib, 0, 0)
 
     def live_page(ib, j, blk_ref, pt_ref, len_ref):
         # clamp dead pages (j past the block's sequence's last live
@@ -301,17 +367,17 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
         last = jnp.maximum(len_ref[seq] - 1, 0) // page
         return pt_ref[seq, jnp.minimum(j, last)]
 
-    def kv_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
-        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, hi)
+    def kv_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
+        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, hg)
 
-    def scale_idx(ib, hi, j, blk_ref, pt_ref, len_ref):
+    def scale_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
         return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, rbg, 1), qpos_idx),
-        pl.BlockSpec((1, 1, rbg, d), q_idx),
-        pl.BlockSpec((1, page, d), kv_idx),
-        pl.BlockSpec((1, page, d), kv_idx),
+        pl.BlockSpec((hb, 1, rbg, d), q_idx),
+        pl.BlockSpec((1, page, hb * d), kv_idx),
+        pl.BlockSpec((1, page, hb * d), kv_idx),
     ]
     args = [qpos_rows, q5, kt, vt]
     if quantized:
@@ -321,17 +387,17 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(nb, kvh, pm),
+        grid=(nb, kvh // hb, pm),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rbg, d), q_idx),
+        out_specs=pl.BlockSpec((hb, 1, rbg, d), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((rbg, _LANES), jnp.float32),
-            pltpu.VMEM((rbg, _LANES), jnp.float32),
-            pltpu.VMEM((rbg, d), jnp.float32),
+            pltpu.VMEM((hb, rbg, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rbg, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rbg, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_ragged_kernel, page_size=page, num_pb=pm,
-                               sm_scale=sm_scale, quantized=quantized)
+                               hb=hb, sm_scale=sm_scale, quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -108,7 +108,8 @@ from paddle_tpu.ops.attention import mha_reference
 from paddle_tpu.platform.flags import FLAGS
 from paddle_tpu.serving.decode_attention import (
     BLOCK_ROWS, _ragged_reference_blocked, attention_path,
-    expand_decode_rows, ragged_paged_attention, ragged_paged_attention_tp)
+    expand_decode_rows, heads_per_cell, ragged_paged_attention,
+    ragged_paged_attention_tp)
 from paddle_tpu.serving.faults import (FaultPlan, InjectedDeviceError,
                                        PageLeakError)
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
@@ -613,6 +614,12 @@ class ServingEngine:
                 quantized=self.kv_cfg.quantized) == "kernel"
         else:
             self._ragged_kernel = bool(use_kernel)
+        # KV-head groups of the kernel's grid on one chip (its middle
+        # axis), for the grid counters of ``_attn_cells``
+        kvh = self.kv_cfg.kv_heads // self.tp
+        self._attn_head_groups = kvh // heads_per_cell(
+            kvh, self.kv_cfg.page_size, self.kv_cfg.head_dim,
+            jnp.dtype(self.kv_cfg.dtype).itemsize, self.kv_cfg.quantized)
         self._buckets = tuple(sorted(int(b) for b in buckets)) if buckets \
             else _parse_buckets(FLAGS.serving_prefill_buckets)
         self._max_slots = max_slots
@@ -1877,8 +1884,30 @@ class ServingEngine:
                 total_rows, pb - sum(c[2] for c in chunks),
                 n_slots=len(running),
                 h2d_bytes=sum(a.nbytes for a in host),
-                d2h_bytes=d_logits.nbytes + p_logits.nbytes)
+                d2h_bytes=d_logits.nbytes + p_logits.nbytes,
+                attn_cells=self._attn_cells(host[5], host[8]))
             self._walk_results(running, chunks, drafts, d_logits, p_logits)
+
+    def _attn_cells(self, p_seq: np.ndarray, att_lens: np.ndarray
+                    ) -> Tuple[int, int, int]:
+        """(kernel calls, grid steps, live grid steps) of one step on
+        one chip, counted as ``_ragged_pallas`` lays its grid out:
+        ``(row blocks, KV-head groups, max_pages_per_seq)`` per layer,
+        a step live where its page holds a token of its block's
+        sequence (``j * page < att_lens[seq]`` — the kernel's own test,
+        so the decode block of a prefilling slot counts, masked rows
+        and all).  Zeros on the reference path."""
+        if not self._ragged_kernel:
+            return (0, 0, 0)
+        cfg, groups = self.kv_cfg, self._attn_head_groups
+        blocks_per_slot = -(-self._k1 // BLOCK_ROWS)
+        nb = self._max_slots * blocks_per_slot + len(p_seq) // BLOCK_ROWS
+        pages = -(-att_lens // cfg.page_size)        # live pages per slot
+        live = blocks_per_slot * int(pages.sum()) + \
+            int(pages[p_seq[::BLOCK_ROWS]].sum())
+        calls = cfg.num_layers
+        return (calls, calls * nb * groups * cfg.max_pages_per_seq,
+                calls * live * groups)
 
     def _assemble(self, running: List[Request], chunks, total_rows: int,
                   drafts: Dict[int, Tuple]) -> Tuple[np.ndarray, ...]:

@@ -20,7 +20,7 @@ shed): of the demand that wanted completion, the fraction that missed.
 from __future__ import annotations
 
 from collections import deque
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 # latency percentiles run over a bounded recent window, not full
 # history: a long-lived engine must not grow metric memory per request
@@ -63,6 +63,12 @@ class ServingMetrics:
         self.prefill_pad_rows = 0     # of the bucket, padding/alignment
         self.h2d_bytes = 0            # the steps' nine input arrays
         self.d2h_bytes = 0            # the steps' two logits arrays
+        # the ragged kernel's grid, on one chip (PR 27): a grid step
+        # costs its fixed part whether its page is live or not, so the
+        # kernel's time follows the steps, not the tokens
+        self.attn_kernel_calls = 0    # one per layer and step
+        self.attn_grid_cells = 0      # grid steps those calls dispatched
+        self.attn_live_cells = 0      # of them, steps whose page is live
         # speculative decoding (round 18)
         self.spec_ticks = 0           # verify ticks with >= 1 drafted token
         self.spec_tokens_proposed = 0  # drafted tokens shipped to verify
@@ -117,7 +123,8 @@ class ServingMetrics:
 
     def on_step(self, n_decode_rows: int, n_prefill_rows: int,
                 n_pad_rows: int, n_slots: Optional[int] = None,
-                h2d_bytes: int = 0, d2h_bytes: int = 0) -> None:
+                h2d_bytes: int = 0, d2h_bytes: int = 0,
+                attn_cells: Tuple[int, int, int] = (0, 0, 0)) -> None:
         """One unified-step dispatch: how many decode/verify rows and
         (padded) prefill rows rode it, and how much of the prefill
         bucket was padding.  ``n_slots`` is the running-slot
@@ -127,7 +134,9 @@ class ServingMetrics:
         control) calls this twice per busy tick — the dispatch-count
         delta IS the A/B.  ``h2d_bytes``/``d2h_bytes`` are what the
         dispatch moved between host and device: its input arrays up,
-        its logits down."""
+        its logits down.  ``attn_cells`` is the dispatch's (ragged
+        kernel calls, grid steps of those calls, steps whose page is
+        live), zeros on the reference path."""
         self.step_dispatches += 1
         self.decode_rows += n_decode_rows
         self.decode_slots += n_slots if n_slots is not None \
@@ -136,6 +145,9 @@ class ServingMetrics:
         self.prefill_pad_rows += max(0, n_pad_rows)
         self.h2d_bytes += h2d_bytes
         self.d2h_bytes += d2h_bytes
+        self.attn_kernel_calls += attn_cells[0]
+        self.attn_grid_cells += attn_cells[1]
+        self.attn_live_cells += attn_cells[2]
 
     def on_prefix(self, requested: int, saved: int) -> None:
         """One admission's prefix-cache outcome: ``requested`` tokens
@@ -311,6 +323,9 @@ class ServingMetrics:
             "prefill_pad_rows": self.prefill_pad_rows,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
+            "attn_kernel_calls": self.attn_kernel_calls,
+            "attn_grid_cells": self.attn_grid_cells,
+            "attn_live_cells": self.attn_live_cells,
             "prefix_hit_rate": round(self.prefix_hit_rate(), 4),
             "spec_ticks": self.spec_ticks,
             "spec_tokens_proposed": self.spec_tokens_proposed,

@@ -2,7 +2,8 @@
 prefill+decode batches, GQA head-group packing, int8-quantized KV pages.
 
 Covers the kernel/reference parity matrix (mixed batches, ragged
-lengths, offset masks, GQA, int8), the single dispatch chooser, the
+lengths, offset masks, GQA, int8, and every number of KV heads a grid
+cell may take), the single dispatch chooser, the
 bytes-per-page accounting behind ``FLAGS.serving_kv_dtype`` and
 ``ServingEngine(pool_bytes=...)``, the unified-step engine (fused vs
 v1-shaped split ticks, token-identical), GQA greedy parity against a
@@ -23,9 +24,11 @@ from paddle_tpu.serving import (BLOCK_ROWS, DecoderLM, FaultPlan,
                                 pack_prefill_chunks, pages_for_budget,
                                 quantize_kv, ragged_paged_attention,
                                 ragged_paged_attention_reference)
+from paddle_tpu.serving import decode_attention
 from paddle_tpu.serving.decode_attention import (QUANT_DRIFT_BOUND,
                                                  _ragged_pallas,
                                                  check_quant_drift,
+                                                 heads_per_cell,
                                                  quant_parity_error)
 from paddle_tpu.ops.attention import mha_reference
 
@@ -247,6 +250,198 @@ def test_int8_quant_parity_harness_within_bound(rng):
 
 
 # ---------------------------------------------------------------------------
+# KV heads a grid cell (PR 27): one kernel, hb computed from shapes
+# ---------------------------------------------------------------------------
+
+
+def _parent_ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, table,
+                          kv_lens, row_seq, qpos, sm_scale):
+    """The kernel as it stood before KV heads were folded into the cell
+    (grid ``(row blocks, KV heads, pages)``, one ``(page, D)`` tile of
+    one head a cell), kept word for word as the yardstick of "the same
+    arithmetic in fewer steps".  Interpret mode only."""
+    import functools
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.attention import DEFAULT_MASK_VALUE
+
+    t, h, d = q.shape
+    _, page, kvh, _ = k_pages.shape
+    pm, g, nb = table.shape[1], h // kvh, t // BLOCK_ROWS
+    rbg, quantized = BLOCK_ROWS * g, k_scale is not None
+
+    def kernel(blk_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref, v_ref,
+               *rest):
+        if quantized:
+            ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        else:
+            o_ref, m_scr, l_scr, acc_scr = rest
+        ib, hi, j = (pl.program_id(a) for a in range(3))
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        @pl.when(j * page < len_ref[blk_ref[ib]])
+        def _compute():
+            qb, kb, vb = q_ref[0, 0], k_ref[0], v_ref[0]
+            if quantized:
+                def head_scale(ref):
+                    sc = ref[0]
+                    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+                    return jnp.sum(jnp.where(lane == hi, sc, 0.0),
+                                   axis=1, keepdims=True)
+                kb = kb.astype(jnp.float32) * head_scale(ks_ref)
+                vb = vb.astype(jnp.float32) * head_scale(vs_ref)
+            s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            tok = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(tok <= qpos_ref[0], s, DEFAULT_MASK_VALUE)
+            m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)
+            l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(j == pm - 1)
+        def _finalize():
+            l = jnp.max(l_scr[...], axis=1, keepdims=True)
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+    def live_page(ib, j, blk_ref, pt_ref, len_ref):
+        seq = blk_ref[ib]
+        last = jnp.maximum(len_ref[seq] - 1, 0) // page
+        return pt_ref[seq, jnp.minimum(j, last)]
+
+    q_idx = lambda ib, hi, j, *_: (hi, ib, 0, 0)                # noqa: E731
+    kv_idx = lambda ib, hi, j, *r: (live_page(ib, j, *r), 0, hi)  # noqa: E731
+    sc_idx = lambda ib, hi, j, *r: (live_page(ib, j, *r), 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, rbg, 1), lambda ib, hi, j, *_: (ib, 0, 0)),
+                pl.BlockSpec((1, 1, rbg, d), q_idx),
+                pl.BlockSpec((1, page, d), kv_idx),
+                pl.BlockSpec((1, page, d), kv_idx)]
+    args = [jnp.repeat(qpos.reshape(nb, BLOCK_ROWS), g, axis=1)[..., None],
+            q.reshape(nb, BLOCK_ROWS, kvh, g, d).transpose(2, 0, 1, 3, 4)
+            .reshape(kvh, nb, rbg, d),
+            k_pages.reshape(-1, page, kvh * d),
+            v_pages.reshape(-1, page, kvh * d)]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, page, kvh), sc_idx)] * 2
+        args += [k_scale, v_scale]
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(nb, kvh, pm), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, rbg, d), q_idx),
+            scratch_shapes=[pltpu.VMEM((rbg, 128), jnp.float32),
+                            pltpu.VMEM((rbg, 128), jnp.float32),
+                            pltpu.VMEM((rbg, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((kvh, nb, rbg, d), q.dtype),
+        interpret=True,
+    )(row_seq.reshape(nb, BLOCK_ROWS)[:, 0], table, kv_lens, *args)
+    return out.reshape(kvh, nb, BLOCK_ROWS, g, d).transpose(1, 2, 0, 3, 4) \
+        .reshape(t, h, d)
+
+
+def _budget_for(hb, kvh, page, d, itemsize, quantized):
+    """A ``_KV_VMEM_BUDGET`` under which the chooser gives exactly
+    ``hb``: what that many heads' K and V tiles take, double-buffered,
+    beside an int8 pool's two scale blocks."""
+    return 2 * 2 * page * d * itemsize * hb + \
+        (2 * 2 * page * kvh * 4 if quantized else 0)
+
+
+# k1 > 1: 5 verify rows a slot at consecutive positions (one block,
+# three padding rows), beside a decode slot and a prefill chunk
+VERIFY_CASE = [(13, 5, 8), (30, 5, 25), (7, 1, 0), (21, 9, 12)]
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("hb", [4, 2, 1])     # all, a proper divisor, 1
+@pytest.mark.parametrize("pool", ["float32", "int8"])
+@pytest.mark.parametrize("kvh,h", [(4, 4), (4, 8)])   # MHA and GQA
+@pytest.mark.parametrize("case", [MIXED_CASES[0], MIXED_CASES[2],
+                                  VERIFY_CASE],
+                         ids=["mixed", "multiblock", "verify_k5"])
+def test_ragged_kernel_heads_per_cell_parity(rng, monkeypatch, case, kvh, h,
+                                             pool, hb):
+    """The kernel against the oracle (and, on an int8 pool, against the
+    reference that reads the same stored values) at every number of KV
+    heads a grid cell may take, forced through the budget constant.
+    One head a cell is the grid this kernel had before: its outputs are
+    the former kernel's bit for bit, and so are the folded grids'."""
+    page, pm, num_pages, d = 8, 4, 32, 16
+    q, kp, vp, table, kv_lens, row_seq, qpos, kc, vc = _build_mixed(
+        rng, case, page, pm, num_pages, kvh, d, h)
+    kp, vp, ks, vs = jnp.asarray(kp), jnp.asarray(vp), None, None
+    quantized = pool == "int8"
+    if quantized:
+        kp, ks = quantize_kv(kp)
+        vp, vs = quantize_kv(vp)
+    monkeypatch.setattr(
+        decode_attention, "_KV_VMEM_BUDGET",
+        _budget_for(hb, kvh, page, d, kp.dtype.itemsize, quantized))
+    assert heads_per_cell(kvh, page, d, kp.dtype.itemsize, quantized) == hb
+    args = (jnp.asarray(q), kp, vp, ks, vs, jnp.asarray(table),
+            jnp.asarray(kv_lens), jnp.asarray(row_seq), jnp.asarray(qpos),
+            float(d) ** -0.5)
+    ker = np.asarray(_ragged_pallas(*args, True))
+    real = qpos >= 0
+    if quantized:
+        want = np.asarray(ragged_paged_attention_reference(
+            args[0], kp, vp, *args[5:9], k_scale=ks, v_scale=vs))
+    else:
+        want = _oracle(q, kc, vc, kv_lens, row_seq, qpos, h)
+    np.testing.assert_allclose(ker[real], want[real], rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(ker, np.asarray(
+        _parent_ragged_pallas(*args)))
+
+
+@ragged
+@serving
+def test_heads_per_cell_chooser(monkeypatch):
+    """``hb`` is a function of shapes: the widths served so far fold
+    whole, a slab over the budget falls to a divisor, and whatever the
+    shapes it divides the head count."""
+    budget = decode_attention._KV_VMEM_BUDGET
+    # 8 (the 6.7B's shard) and 16 (the 1.3B) float32 heads of 128 at
+    # page 128: 2 and 4 MiB of double-buffered K and V
+    assert heads_per_cell(8, 128, 128, 4) == 8
+    assert heads_per_cell(16, 128, 128, 4) == 16
+    assert heads_per_cell(32, 128, 128, 4) == 32      # 8 MiB: the edge
+    assert heads_per_cell(64, 128, 128, 4) == 32      # 16 MiB: halves
+    assert heads_per_cell(64, 128, 128, 2) == 64      # bf16 pool
+    assert heads_per_cell(64, 128, 128, 1, quantized=True) == 64
+    assert heads_per_cell(12, 256, 128, 4) == 12      # 6 MiB
+    assert heads_per_cell(24, 256, 128, 4) == 12      # 12 MiB: a divisor
+    assert heads_per_cell(7, 1024, 128, 4) == 1       # prime, 2 MiB a head
+    # the scale blocks of an int8 pool count against the budget
+    monkeypatch.setattr(decode_attention, "_KV_VMEM_BUDGET",
+                        _budget_for(4, 8, 128, 128, 1, False))
+    assert heads_per_cell(8, 128, 128, 1) == 4
+    assert heads_per_cell(8, 128, 128, 1, quantized=True) == 2
+    monkeypatch.setattr(decode_attention, "_KV_VMEM_BUDGET", 0)
+    assert heads_per_cell(8, 128, 128, 4) == 1        # never under one
+    monkeypatch.setattr(decode_attention, "_KV_VMEM_BUDGET", budget)
+    for kvh in range(1, 41):
+        for page in (8, 128, 512):
+            for item in (1, 2, 4):
+                hb = heads_per_cell(kvh, page, 128, item, item == 1)
+                assert 1 <= hb <= kvh and kvh % hb == 0
+
+
+# ---------------------------------------------------------------------------
 # the single dispatch chooser
 # ---------------------------------------------------------------------------
 
@@ -446,6 +641,69 @@ def test_engine_kernel_fallback_parity_mixed(rng):
     for p, toks in zip(prompts, out_ref):
         mt = 10 if len(p) < 8 else 4
         assert toks == greedy_decode_reference(model, params, p, mt, 1)
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("hb", [4, 2])
+def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
+    """``attn_kernel_calls`` / ``attn_grid_cells`` / ``attn_live_cells``:
+    a call's grid is ``nb x (KVH / hb) x max_pages_per_seq`` for both
+    compiled buckets (decode-only: one block a slot; with the 8-row
+    prefill bucket: one more), and a step is live where its page holds
+    a token of its block's sequence — counted here by hand from the
+    slots' lengths, tick by tick."""
+    model = DecoderLM(vocab_size=50, num_layers=2, num_heads=4, head_dim=8,
+                      max_positions=128)
+    params = model.init_params(jax.random.PRNGKey(0))
+    # page 4, 4 slots, 10 pages a sequence, chunks and smallest bucket 8
+    monkeypatch.setattr(decode_attention, "_KV_VMEM_BUDGET",
+                        _budget_for(hb, 4, 4, 8, 4, False))
+    eng = _engine(model, params, use_kernel=True, eos_id=50)
+    layers, groups, pm = 2, 4 // hb, 10
+    seen = []
+
+    def step():
+        m = eng.metrics
+        before = (m.attn_kernel_calls, m.attn_grid_cells, m.attn_live_cells)
+        eng.step()
+        seen.append((m.attn_kernel_calls - before[0],
+                     m.attn_grid_cells - before[1],
+                     m.attn_live_cells - before[2]))
+
+    eng.submit(rng.randint(2, 50, size=5).tolist(), max_tokens=20)
+    step()      # A prefills 5 rows in one 8-row bucket: length 5
+    step()      # A decodes: length 6
+    eng.submit(rng.randint(2, 50, size=11).tolist(), max_tokens=20)
+    step()      # A decodes at 7; B's first chunk, 8 rows: length 8
+    step()      # A at 8; B's last chunk, 3 rows: length 11
+    step()      # A at 9, B at 12: decode-only again
+
+    def pages(n):
+        return -(-n // 4)
+
+    want_live = [
+        pages(5) + pages(5),              # A's decode block, A's chunk
+        pages(6),
+        pages(7) + pages(8) + pages(8),   # A; B's decode block and chunk
+        pages(8) + pages(11) + pages(11),
+        pages(9) + pages(12),
+    ]
+    want_blocks = [4 + 1, 4, 4 + 1, 4 + 1, 4]
+    assert seen == [(layers, layers * nb * groups * pm,
+                     layers * live * groups)
+                    for nb, live in zip(want_blocks, want_live)]
+    snap = eng.metrics.snapshot()
+    assert snap["attn_kernel_calls"] == 5 * layers
+    assert snap["attn_grid_cells"] == sum(c for _, c, _ in seen)
+    assert snap["attn_live_cells"] == sum(c for _, _, c in seen)
+    # the reference path dispatches no kernel: the counters stay at zero
+    ref = _engine(model, params, use_kernel=False, eos_id=50)
+    ref.submit([3, 4, 5], max_tokens=2)
+    ref.run(max_ticks=20)
+    assert ref.metrics.step_dispatches > 0
+    assert ref.metrics.snapshot()["attn_kernel_calls"] == 0
+    assert ref.metrics.snapshot()["attn_grid_cells"] == 0
 
 
 @ragged

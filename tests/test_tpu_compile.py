@@ -133,6 +133,10 @@ RAGGED_CASES = [
     (64, 16, 16, "int8"),
     (64, 16, 4, "int8"),
     (8 * 8 + 512, 16, 16, jnp.float32),  # a full tick: 8 slots + 512 prefill
+    # the 6.7B's 32 heads: 8 a chip under TP=4 (the benchmark's cell,
+    # all of them in one grid cell); on one chip all 32 still fold, at
+    # the edge of heads_per_cell's VMEM budget (8 MiB of K and V tiles)
+    (8 * 8 + 256, 32, 32, jnp.float32),
 ]
 
 
@@ -149,7 +153,7 @@ def test_ragged_paged_attention_compiles_for_v5e(topo, t, h, kvh, dtype):
                                        dtype)) == 1
 
 
-@pytest.mark.parametrize("t,h,kvh,dtype", RAGGED_CASES[:5])
+@pytest.mark.parametrize("t,h,kvh,dtype", RAGGED_CASES[:5] + RAGGED_CASES[6:])
 def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
     """The same kernel under ``shard_map`` over a four-chip model axis
     (the TP=4 engine's attention): each chip gets H/4 query and KVH/4 KV
