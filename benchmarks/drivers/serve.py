@@ -26,65 +26,44 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from harness import measure, weights
+from harness import cells, measure, weights
 from harness.measure import say
 
 
-def weight_spec(config: dict) -> dict:
-    """The leaves ``serving.DecoderLM`` has: no biases, parameter-free
-    RMSNorm, an untied head (departures, in the configuration file)."""
-    return {"vocab": config["vocab_size"], "positions": config["n_positions"],
-            "hidden": config["n_embd"], "ffn": config["n_inner"],
-            "layers": config["serve"]["n_layer"], "attn_bias": False,
-            "ffn_bias": False, "norm_params": False, "untied_head": True,
-            "head_bias": False}
-
-
-def program_names(spec: dict) -> Dict[str, str]:
-    """{DecoderLM's parameter name: the reference's flat name}."""
-    out = {"emb": "wte", "pos": "wpe", "out": "head"}
-    for l in range(spec["layers"]):
-        for n in ("wq", "wk", "wv", "wo", "w1", "w2"):
-            out[f"l{l}.{n}"] = f"blocks.{l}.{n}"
-    return out
-
-
 def build_engine(cell, seed: int, devs):
-    """(engine, weights by the reference's names, spec).  The weights are
-    made once, placed as the engine's own plan says, and shared by the
-    engine and the reference (neither makes them)."""
+    """(engine, weights by the reference's names, the family's program).
+    The weights are made once, placed as the family's program says (the
+    engine's own plan), and shared by the engine and the reference
+    (neither makes them)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import make_mesh
-    from paddle_tpu.serving import DecoderLM, ServingEngine
+    from paddle_tpu.serving import ServingEngine
 
     cfg, dep = cell.config, cell.config["serve"]
-    spec = weight_spec(cfg)
-    names = program_names(spec)
-    model = DecoderLM(
-        vocab_size=cfg["vocab_size"], num_layers=spec["layers"],
-        num_heads=cfg["n_head"], head_dim=cfg["n_embd"] // cfg["n_head"],
-        ffn_mult=cfg["n_inner"] // cfg["n_embd"],
-        max_positions=cfg["n_positions"])
-    mesh = shardings = None
-    if len(devs) > 1:
-        mesh = make_mesh((len(devs),), ("model",), devs)
-        plan = model.shard_plan(axis="model", tp=len(devs))
-        shardings = {ref: NamedSharding(mesh, P(*plan.get(prog, ())))
-                     for prog, ref in names.items()}
-    made = weights.make(spec, seed, shardings)
-    params = {prog: made[ref] for prog, ref in names.items()}
+    prog = cell.family().serve_program(cfg, devs)
+    model, mesh, names = prog["model"], prog["mesh"], prog["names"]
+    leaves = cell.family().leaves(cfg, "serve")
+    if set(leaves) != set(names.values()) or len(leaves) != len(names):
+        raise cells.CellError(
+            "the model's parameters and the reference's leaves differ: "
+            f"{sorted(set(leaves) ^ set(names.values()))}")
+    shardings = None
+    if mesh is not None:
+        shardings = {ref: NamedSharding(mesh, P(*prog["placement"].get(
+            name, ()))) for name, ref in names.items()}
+    made = weights.make(leaves, seed, shardings)
+    params = {name: made[ref] for name, ref in names.items()}
     longest = int(cell.traffic["prompt"]["max"]) + \
         int(cell.traffic["answer"]["max"])
     page = int(dep["page_size"])
     eng = ServingEngine(
-        model, params, eos_id=cfg["vocab_size"],   # no token takes it
+        model, params, eos_id=model.vocab_size,   # no token takes it
         page_size=page, max_slots=int(dep["max_slots"]),
         pool_bytes=int(dep["pool_bytes"]),
         max_pages_per_seq=-(-longest // page),
         buckets=tuple(dep["prefill_buckets"]),
         prefill_chunk=int(dep["prefill_chunk"]), mesh=mesh)
-    return eng, made, spec
+    return eng, made, prog
 
 
 class Client:
@@ -97,7 +76,7 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
         broken=None):
     """``broken`` (tests only) may alter a token where it is produced."""
     traffic = cell.traffic
-    eng, made, spec = build_engine(cell, args.seed, devs)
+    eng, made, prog = build_engine(cell, args.seed, devs)
     say(f"engine: {eng.kv_cfg.num_pages} pages of {eng.kv_cfg.page_size}, "
         f"{eng._max_slots} slots, kernel path {eng._ragged_kernel}, "
         f"tp {eng.tp}")
@@ -229,7 +208,7 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
         "memory_peak_bytes": memory_peak, "tracing": tracing,
         "counters": {k: v - counters0[k] for k, v in counters1.items()
                      if isinstance(v, int)},
-        "max_slots": eng._max_slots, "layers_run": spec["layers"],
+        "max_slots": eng._max_slots, "layers_run": prog["layers"],
         "tp": eng.tp,
         "end_to_end": {
             "serve_tokens_per_s": len(emitted) / args.seconds,
@@ -285,15 +264,14 @@ def make_forward(cell, pad_to: int, mode: str):
     import jax
     import jax.numpy as jnp
 
-    ref = cell.reference()
-    cfg = cell.config
+    fam, ref = cell.family(), cell.reference()
 
     @jax.jit
     def forward(tree, toks):
         pos = jnp.arange(pad_to, dtype=jnp.int32)
-        return ref.forward_logits(
-            tree, toks, pos, jnp.zeros((pad_to,), jnp.int32),
-            n_head=cfg["n_head"], norm="rms_noparam", mode=mode,
+        return fam.reference_logits(
+            ref, cell.config, tree, toks, pos,
+            jnp.zeros((pad_to,), jnp.int32), mode=mode,
             block_rows=int(cell.traffic["check"]["block_rows"]))
 
     return forward
@@ -360,7 +338,8 @@ def check(cell, made: dict, finished: List[dict], sample: List[dict],
     say(f"check: reference (f32) over {len(sample)} of {len(finished)} "
         f"finished requests, {got['tokens_compared']} served tokens, took "
         f"{time.perf_counter() - t:.1f} s")
-    return {"correct": ok, "rows": {k: v[0] for k, v in rows.items()}}
+    return {"correct": ok, "rows": {k: v[0] for k, v in rows.items()},
+            "limits": {k: v[1] for k, v in rows.items()}}
 
 
 def control(cell, args, devs, started, watch) -> dict:

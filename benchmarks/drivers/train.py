@@ -28,64 +28,48 @@ import numpy as np
 from harness import cells, measure, weights
 from harness.measure import say
 
-FEEDING = {"tokens": 0, "pos": 1, "target": 2}
 CHECK_STEPS = 3
 
 
-def weight_spec(config: dict) -> dict:
-    """The leaves ``models.transformer.build`` has (its departures from
-    the published model are in the configuration file)."""
-    return {"vocab": config["vocab_size"], "positions": config["n_positions"],
-            "hidden": config["n_embd"], "ffn": config["n_inner"],
-            "layers": config["train"]["n_layer"], "attn_bias": False,
-            "ffn_bias": True, "norm_params": True, "untied_head": True,
-            "head_bias": True}
-
-
-def program_names(spec: dict) -> Dict[str, str]:
-    """{the trainer's parameter name: the reference's flat name}."""
-    out = {"tok_embed.w": "wte", "pos_embed.w": "wpe",
-           "final_ln.gamma": "lnf_g", "final_ln.beta": "lnf_b",
-           "lm_head.w0": "head", "lm_head.b": "head_b"}
-    for l in range(spec["layers"]):
-        p, r = f"blk{l}_", f"blocks.{l}."
-        for n in ("wq", "wk", "wv", "wo"):
-            out[f"{p}attn.{n}"] = r + n
-        out.update({f"{p}ffn_up.w0": r + "w1", f"{p}ffn_up.b": r + "b1",
-                    f"{p}ffn_down.w0": r + "w2", f"{p}ffn_down.b": r + "b2",
-                    f"{p}ln1.gamma": r + "ln1_g", f"{p}ln1.beta": r + "ln1_b",
-                    f"{p}ln2.gamma": r + "ln2_g", f"{p}ln2.beta": r + "ln2_b"})
-    return out
+def updated(cell, leaves: weights.Leaves) -> weights.Leaves:
+    """Those of the train group's leaves that the optimiser updates: all
+    of them, less those the family's ``frozen`` names (a router's bias,
+    say)."""
+    fam = cell.family()
+    frozen = set(fam.frozen(cell.config)) if hasattr(fam, "frozen") else ()
+    return {k: v for k, v in leaves.items() if k not in frozen}
 
 
 def build_trainer(cell, seed: int):
+    """(trainer, the family's program, the family's leaves).  The weights
+    are made here from the seed and put into the trainer's ``Parameters``
+    under the family's name map, which has to cover the trainer's
+    parameters and the family's leaves alike, each exactly once."""
     import paddle_tpu as paddle
     from paddle_tpu import optimizer, trainer
-    from paddle_tpu.models import transformer
 
     cfg, opt = cell.config, cell.config["train"]["optimizer"]
-    spec = weight_spec(cfg)
+    leaves = cell.family().leaves(cfg, "train")
     paddle.topology.reset_name_scope()
-    *_, cost = transformer.build(
-        vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"],
-        n_layers=spec["layers"], n_heads=cfg["n_head"],
-        max_len=cfg["n_positions"], ffn_mult=cfg["n_inner"] // cfg["n_embd"])
-    params = paddle.Parameters.from_topology(paddle.topology.Topology([cost]))
-    names = program_names(spec)
-    missing = set(params.names()) ^ set(names)
-    if missing:
+    prog = cell.family().train_program(cfg)
+    cost, names = prog["cost"], prog["names"]
+    params = paddle.Parameters.from_topology(paddle.topology.Topology(
+        cost if isinstance(cost, list) else [cost]))
+    missing = (set(params.names()) ^ set(names)) | \
+        (set(names.values()) ^ set(leaves))
+    if missing or len(set(names.values())) != len(names):
         raise cells.CellError(f"the trainer's parameters and the "
                               f"reference's leaves differ: {sorted(missing)}")
-    made = weights.make(spec, seed)
-    for prog, ref in names.items():
-        params[prog] = made[ref]
+    made = weights.make(leaves, seed)
+    for prog_name, ref_name in names.items():
+        params[prog_name] = made[ref_name]
     del made
     sgd = trainer.SGD(cost=cost, parameters=params,
                       update_equation=optimizer.Adam(
                           learning_rate=opt["learning_rate"],
                           beta1=opt["beta1"], beta2=opt["beta2"],
                           epsilon=opt["epsilon"]))
-    return sgd, spec, names
+    return sgd, prog, leaves
 
 
 def warm_log_flush() -> None:
@@ -99,6 +83,15 @@ def warm_log_flush() -> None:
     if FLAGS.log_period:
         cost = jnp.zeros((), jnp.float32)
         np.asarray(jnp.stack([cost] * int(FLAGS.log_period)))
+
+
+def registry_change(before: Dict[str, float], after: Dict[str, float]
+                    ) -> Dict[str, float]:
+    """What the program published into ``paddle_tpu.obs``'s default
+    registry between two snapshots, series by series (one that is new
+    counts from 0)."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if isinstance(v, (int, float))}
 
 
 def to_host(tree):
@@ -125,9 +118,12 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
     import jax
 
     from paddle_tpu import event
+    from paddle_tpu.obs import default_registry
 
     traffic = cell.traffic
-    sgd, spec, names = build_trainer(cell, args.seed)
+    sgd, prog, leaves = build_trainer(cell, args.seed)
+    names = prog["names"]
+    moving = updated(cell, leaves)
     beta1 = cell.config["train"]["optimizer"]["beta1"]
     gen = cell.generator().make(traffic, cell.config, args.seed)
     spans = measure.Spans()
@@ -136,19 +132,19 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
     seen: Dict[str, object] = {"losses": [], "first_grad": None,
                                "change": None}
     steps: List[dict] = []          # window steps: tokens, done
-    win = {"t0": None}
+    win = {"t0": None, "counters": None}
     pending: List = []              # [(event, tokens, phase)]
     def settle() -> None:
         ev, tokens, phase = pending.pop(0)
         with spans.span("train_step_wait"):
             cost = ev.cost                   # waits for that step
-        now = time.perf_counter()
         if phase == "check":
             seen["losses"].append(cost)
-        elif phase == "warm":
-            win["t0"] = now                  # the last one stands
+        elif phase == "warm":                # the last one stands
+            win["counters"] = default_registry().snapshot()
+            win["t0"] = time.perf_counter()
         else:
-            steps.append({"tokens": tokens, "done": now})
+            steps.append({"tokens": tokens, "done": time.perf_counter()})
 
     batches = iter(gen)
     state = {"pass": 0, "tokens": 0, "phase": "check"}
@@ -204,25 +200,28 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
                 settle()
             if ev.pass_id == 0:
                 slots = sgd.opt_state["slots"]
-                read = weights.grad_readings(spec)
+                read = weights.grad_readings(moving)
                 got = jax.jit(lambda m, key: read(
                     {k: v / (1.0 - beta1) for k, v in m.items()}, key))(
-                    {names[k]: slots["m"][k] for k in names},
+                    {ref: slots["m"][prog] for prog, ref in names.items()
+                     if ref in moving},
                     weights.sketch_key(args.seed))
                 seen["first_grad"] = to_host(got)
             elif ev.pass_id == 1:
                 now = sgd.parameters.as_dict()
                 seen["change"] = weights.change_norms(
-                    {ref: now[prog] for prog, ref in names.items()}, spec,
+                    {ref: now[prog] for prog, ref in names.items()}, leaves,
                     args.seed)
 
     warm_log_flush()
     if broken is not None:
         broken(sgd)
-    sgd.train(reader, num_passes=3, event_handler=on_event, feeding=FEEDING)
+    sgd.train(reader, num_passes=3, event_handler=on_event,
+              feeding=prog["feeding"])
     if tracing is not None and tracing.on:
         spans.close("trace_window")
         tracing.stop()
+    counters = registry_change(win["counters"], default_registry().snapshot())
     t0 = win["t0"]
     inside = [s for s in steps if s["done"] <= t0 + args.seconds]
     # the window ends with the last step that ended inside --seconds, so
@@ -236,8 +235,8 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
         "attempted": len(steps), "failed": 0,
         "compiles_in_window": watch.inside(t0, t1),
         "memory_peak_bytes": measure.memory_peak_bytes(devs),
-        "tracing": tracing, "counters": {"steps": len(inside)},
-        "layers_run": spec["layers"],
+        "tracing": tracing, "counters": {**counters, "steps": len(inside)},
+        "layers_run": prog["layers"],
         "layouts": gen.layouts,
         "end_to_end": {
             "train_tokens_per_s": sum(s["tokens"] for s in inside)
@@ -249,28 +248,27 @@ def run(cell, args, devs, started: float, watch: measure.CompileWatch,
         f"{record['tokens']} real tokens")
     del sgd
     gc.collect()
-    record["check"] = check(cell, args, spec, seen, mode="f32")
+    record["check"] = check(cell, args, seen, mode="f32")
     return record
 
 
-def reference_readings(cell, args, spec: dict, mode: str) -> dict:
+def reference_readings(cell, args, mode: str) -> dict:
     """The plain reference over the first three batches of the seed, from
     the seed's weights: its losses, its first gradient's norms and its
     parameters' change, leaf by leaf."""
     import jax
     import jax.numpy as jnp
 
-    cfg, opt = cell.config, cell.config["train"]["optimizer"]
-    ref = cell.reference()
-    read = weights.grad_readings(spec)
-    step_fn = ref.make_train_step(
-        n_head=cfg["n_head"], norm="layernorm", mode=mode,
-        lr=opt["learning_rate"], b1=opt["beta1"], b2=opt["beta2"],
-        eps=opt["epsilon"],
+    cfg = cell.config
+    leaves = cell.family().leaves(cfg, "train")
+    read = weights.grad_readings(updated(cell, leaves))
+    step_fn = cell.family().reference_train_step(
+        cell.reference(), cfg, mode=mode,
+        optimizer=cfg["train"]["optimizer"],
         reduce_grads=lambda g, key: read(weights.flatten(g), key),
         block_rows=int(cell.traffic["check"]["block_rows"]),
         head_rows=int(cell.traffic["check"]["head_rows"]))
-    w = weights.unflatten(weights.make(spec, args.seed))
+    w = weights.unflatten(weights.make(leaves, args.seed))
     zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
     m, v = zeros(w), zeros(w)
     cap = int(cell.traffic["tokens_per_step"])
@@ -294,7 +292,7 @@ def reference_readings(cell, args, spec: dict, mode: str) -> dict:
         losses.append(float(loss))
         if step == 0:
             first = to_host(reduced)
-    change = weights.change_norms(weights.flatten(w), spec, args.seed)
+    change = weights.change_norms(weights.flatten(w), leaves, args.seed)
     return {"losses": losses, "first_grad": first, "change": change}
 
 
@@ -317,9 +315,9 @@ def compare(got: dict, want: dict, limits: dict) -> dict:
     return rows
 
 
-def check(cell, args, spec: dict, seen: dict, mode: str) -> dict:
+def check(cell, args, seen: dict, mode: str) -> dict:
     t = time.perf_counter()
-    want = reference_readings(cell, args, spec, mode)
+    want = reference_readings(cell, args, mode)
     rows = compare(seen, want, cell.limits)
     ok = len(seen["losses"]) == CHECK_STEPS
     for name, (value, limit) in rows.items():
@@ -330,16 +328,15 @@ def check(cell, args, spec: dict, seen: dict, mode: str) -> dict:
     say(f"check: reference ({mode}) took {time.perf_counter() - t:.1f} s; "
         f"losses {seen['losses']} vs {want['losses']}")
     return {"correct": ok, "rows": {k: v[0] for k, v in rows.items()},
-            "reference": want}
+            "limits": {k: v[1] for k, v in rows.items()}, "reference": want}
 
 
 def control(cell, args, devs, started, watch) -> dict:
     """The control of ``correct``: the reference in the precision below
     the stated one (float8 operands) put in the program's place and held to
     the same limits.  Needs no window and no trainer."""
-    spec = weight_spec(cell.config)
-    got = reference_readings(cell, args, spec, "fp8")
-    want = reference_readings(cell, args, spec, "f32")
+    got = reference_readings(cell, args, "fp8")
+    want = reference_readings(cell, args, "f32")
     rows = compare(got, want, cell.limits)
     return {"correct": all(v <= lim for v, lim in rows.values()),
             "rows": {k: v[0] for k, v in rows.items()}}

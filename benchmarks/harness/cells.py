@@ -69,6 +69,7 @@ class Cell:
         # limits of `correct`, set per cell from readings (PERF.md s2)
         self.limits = load_json(os.path.join(
             base, "cells", workload + ".json"))["limits"]
+        self._loaded: dict = {}
 
     def _reports(self, metric: dict, e2e_names: Optional[List[str]] = None
                  ) -> bool:
@@ -91,12 +92,30 @@ class Cell:
     def driver(self):
         return load_module(os.path.join(ROOT, "drivers", self.kind + ".py"))
 
+    def _own(self, folder: str, name: str):
+        """``<folder>/<name>.py``, under the manifest's base directory
+        where it has one (a rehearsal may bring a family or a reader of
+        its own) and under ``benchmarks/`` otherwise; loaded once."""
+        key = (folder, name)
+        if key not in self._loaded:
+            paths = [os.path.join(root, folder, name + ".py")
+                     for root in (self.base, ROOT)]
+            found = [p for p in paths if os.path.isfile(p)]
+            if not found:
+                raise CellError(f"no such file: {' nor '.join(paths)}")
+            self._loaded[key] = load_module(found[0])
+        return self._loaded[key]
+
+    def family(self):
+        """What the benchmark knows of the model's family
+        (``families/<family>.py``; its questions are in the README)."""
+        return self._own("families", self.config["family"])
+
     def reference(self):
-        return load_module(os.path.join(
-            ROOT, "references", self.config["family"] + ".py"))
+        return self._own("references", self.config["family"])
 
     def layer_metric(self, name: str):
-        return load_module(os.path.join(ROOT, "layer_metrics", name + ".py"))
+        return self._own("layer_metrics", name)
 
 
 def kernel(name: str):

@@ -179,9 +179,12 @@ class Tracing:
 
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, dict], device: dict,
-                breakdown: Optional[dict] = None) -> str:
+                breakdown: Optional[dict], compared: Dict[str, list]) -> str:
+    """``compared`` ({name: [number, limit]}, what decided ``correct``)
+    comes last."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["compared"] = compared
     return json.dumps(out)

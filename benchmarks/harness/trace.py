@@ -25,7 +25,8 @@ Interval = Tuple[float, float]            # seconds on the trace's clock
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
-SPAN_PREFIX = "bench:"
+SPAN_PREFIX = "bench:"        # the benchmark's own spans, kept without it
+PROGRAM_PREFIX = "pt:"        # the program's phases, kept with it
 WINDOW_SPAN = "trace_window"
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -69,7 +70,10 @@ class Chip:
 @dataclasses.dataclass
 class Trace:
     chips: List[Chip]
-    spans: List[Tuple[str, float, float]]   # (name without prefix, t0, t1)
+    # (name, t0, t1): the benchmark's spans by their bare names, which
+    # the readers select by, and the program's as ``pt:<phase>``, so that an
+    # idle gap is named by the phase of the tick that covers it
+    spans: List[Tuple[str, float, float]]
     window: Interval
 
     @property
@@ -120,9 +124,13 @@ def load(path: str) -> Trace:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIX):
-                        t0 = e.start_ns * 1e-9
-                        spans.append((e.name[len(SPAN_PREFIX):], t0,
-                                      t0 + e.duration_ns * 1e-9))
+                        name = e.name[len(SPAN_PREFIX):]
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        name = e.name
+                    else:
+                        continue
+                    t0 = e.start_ns * 1e-9
+                    spans.append((name, t0, t0 + e.duration_ns * 1e-9))
     chips.sort(key=lambda c: c.index)
     spans.sort(key=lambda s: s[1])
     marked = [s for s in spans if s[0] == WINDOW_SPAN]
@@ -130,7 +138,8 @@ def load(path: str) -> Trace:
         window = (marked[0][1], marked[0][2])
     else:
         every = [(o.start, o.end) for c in chips for o in c.ops] + \
-            [(a, b) for _, a, b in spans]
+            [(a, b) for name, a, b in spans
+             if not name.startswith(PROGRAM_PREFIX)]
         window = (min(a for a, _ in every), max(b for _, b in every)) \
             if every else (0.0, 0.0)
     return Trace(chips, [s for s in spans if s[0] != WINDOW_SPAN], window)

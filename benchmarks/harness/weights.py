@@ -1,11 +1,14 @@
-"""Random GPT-2-family weights from a seed: made on the device, in one
-jitted call, in float32 (the type both programs train and serve in).
+"""Random weights from a seed: made on the device, in one jitted call, in
+float32 (the type both programs train and serve in).
 
 The benchmark makes the weights, not the program, so that the program and
 the plain reference get the same arrays and neither takes anything from
-the other.  ``spec`` says which leaves the architecture under test has;
-names are the reference's (``references/gpt2_family.py``), flattened with
-dots: ``wte``, ``wpe``, ``blocks.<l>.wq``, ..., ``head``.
+the other.  Which leaves a model has is its family's answer
+(``families/<family>.py``: ``leaves``): ``{flat name: (shape, kind)}``
+with kind ``matrix``, ``gain`` or ``bias``, of any rank from 1 up.  Names
+are the reference's, flattened with dots (``wte``, ``blocks.<l>.wq``,
+``blocks.<l>.experts.w1``, ...); a part that is a number is an index into
+a list of the reference's tree.
 """
 
 from __future__ import annotations
@@ -27,42 +30,13 @@ def key_of(seed: int):
                               seed >> 31)
 
 
-def leaf_shapes(spec: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """{flat name: (shape, kind)} with kind ``matrix``, ``gain`` or
-    ``bias``.  ``spec`` keys: vocab, positions, hidden, ffn, layers, and
-    the booleans attn_bias, ffn_bias, norm_params, untied_head, head_bias."""
-    e, f, v = spec["hidden"], spec["ffn"], spec["vocab"]
-    out = {"wte": ((v, e), "matrix"),
-           "wpe": ((spec["positions"], e), "matrix")}
-    for l in range(spec["layers"]):
-        b = f"blocks.{l}."
-        for n in ("wq", "wk", "wv", "wo"):
-            out[b + n] = ((e, e), "matrix")
-        out[b + "w1"] = ((e, f), "matrix")
-        out[b + "w2"] = ((f, e), "matrix")
-        if spec["attn_bias"]:
-            for n in ("bq", "bk", "bv", "bo"):
-                out[b + n] = ((e,), "bias")
-        if spec["ffn_bias"]:
-            out[b + "b1"] = ((f,), "bias")
-            out[b + "b2"] = ((e,), "bias")
-        if spec["norm_params"]:
-            for n in ("ln1", "ln2"):
-                out[b + n + "_g"] = ((e,), "gain")
-                out[b + n + "_b"] = ((e,), "bias")
-    if spec["norm_params"]:
-        out["lnf_g"] = ((e,), "gain")
-        out["lnf_b"] = ((e,), "bias")
-    if spec["untied_head"]:
-        out["head"] = ((e, v), "matrix")
-    if spec["head_bias"]:
-        out["head_b"] = ((v,), "bias")
-    return out
+Leaves = Dict[str, Tuple[Tuple[int, ...], str]]
 
 
-def builder(spec: dict):
-    """The traceable function key -> {flat name: array}."""
-    shapes = leaf_shapes(spec)
+def builder(shapes: Leaves):
+    """The traceable function key -> {flat name: array}.  A leaf's values
+    depend on the seed, its shape, its kind and its place among the sorted
+    names, and on nothing else."""
     names = sorted(shapes)
 
     def build(key):
@@ -78,21 +52,21 @@ def builder(spec: dict):
     return build
 
 
-def make(spec: dict, seed: int, shardings: Optional[dict] = None
+def make(shapes: Leaves, seed: int, shardings: Optional[dict] = None
          ) -> Dict[str, jax.Array]:
     """All leaves in one jitted call.  ``shardings`` ({flat name:
     Sharding}) places each leaf as it is made, so a model larger than one
     chip never sits whole on any."""
-    fn = jax.jit(builder(spec), out_shardings=shardings) if shardings else \
-        jax.jit(builder(spec))
+    fn = jax.jit(builder(shapes), out_shardings=shardings) if shardings \
+        else jax.jit(builder(shapes))
     return fn(key_of(seed))
 
 
-def change_norms(now: Dict[str, jax.Array], spec: dict, seed: int
+def change_norms(now: Dict[str, jax.Array], shapes: Leaves, seed: int
                  ) -> Dict[str, float]:
     """Norm of (leaf now - leaf as made from the seed), leaf by leaf, in
     one jitted call that makes each seeded leaf only to subtract it."""
-    build = builder(spec)
+    build = builder(shapes)
 
     @jax.jit
     def norms(now, key):
@@ -104,35 +78,49 @@ def change_norms(now: Dict[str, jax.Array], spec: dict, seed: int
 
 
 def unflatten(flat: Dict[str, jax.Array]) -> dict:
-    """The reference's tree from the flat names."""
+    """The reference's tree from the flat names: nested dictionaries, and
+    a list where every key of a level is a number."""
     tree: dict = {}
-    blocks: Dict[int, dict] = {}
     for name, arr in flat.items():
-        if name.startswith("blocks."):
-            _, l, leaf = name.split(".")
-            blocks.setdefault(int(l), {})[leaf] = arr
-        else:
-            tree[name] = arr
-    tree["blocks"] = [blocks[l] for l in sorted(blocks)]
-    return tree
+        *path, leaf = name.split(".")
+        node = tree
+        for part in path:
+            node = node.setdefault(part, {})
+        node[leaf] = arr
+
+    def lists(node):
+        if not isinstance(node, dict):
+            return node
+        node = {k: lists(v) for k, v in node.items()}
+        if all(k.isdigit() for k in node):
+            return [node[k] for k in sorted(node, key=int)]
+        return node
+
+    return lists(tree)
+
+
+def flatten(tree, prefix: str = "") -> Dict[str, jax.Array]:
+    """The flat names from the reference's tree."""
+    if isinstance(tree, dict):
+        items = tree.items()
+    elif isinstance(tree, (list, tuple)):
+        items = enumerate(tree)
+    else:
+        return {prefix[:-1]: tree}
+    out: Dict[str, jax.Array] = {}
+    for k, v in items:
+        out.update(flatten(v, f"{prefix}{k}."))
+    return out
 
 
 SKETCH_K = 32
-
-
-def flatten(tree: dict) -> Dict[str, jax.Array]:
-    """The flat names from the reference's tree."""
-    out = {k: v for k, v in tree.items() if k != "blocks"}
-    for l, b in enumerate(tree["blocks"]):
-        out.update({f"blocks.{l}.{k}": v for k, v in b.items()})
-    return out
 
 
 def sketch_key(seed: int):
     return jax.random.fold_in(key_of(seed), 0x5ce7c4)
 
 
-def grad_readings(spec: dict):
+def grad_readings(shapes: Leaves):
     """The traceable function ({flat name: gradient leaf}, key) -> {"norm":
     {name: scalar}, "sketch": {name: [SKETCH_K]}}.  The key
     (``sketch_key(seed)``) is an argument, not a constant of the program,
@@ -142,8 +130,9 @@ def grad_readings(spec: dict):
     . g`` for a vector) with signs u, v drawn from the seed.  Two
     gradients' sketches differ, relative to the sketch's own norm, by about
     the relative norm of the gradients' difference - which the norms alone
-    cannot show, since rounding noise hardly changes a norm."""
-    shapes = leaf_shapes(spec)
+    cannot show, since rounding noise hardly changes a norm.  A leaf of
+    rank 3 or more (a stack of experts) is projected as the matrix of its
+    last axis by all the others."""
     names = sorted(shapes)
 
     def signs(k, n):
@@ -153,6 +142,8 @@ def grad_readings(spec: dict):
         norm, sketch = {}, {}
         for i, name in enumerate(names):
             g = grads[name].astype(jnp.float32)
+            if g.ndim > 2:
+                g = g.reshape(-1, g.shape[-1])
             ku, kv = jax.random.split(jax.random.fold_in(key, i))
             norm[name] = jnp.sqrt(jnp.sum(jnp.square(g)))
             u = signs(ku, g.shape[0])

@@ -13,7 +13,9 @@ may not name a cell of ``BENCHMARK.json``.
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics (a profiler trace covers a few seconds of the window),
 ``device.busy_s``/``window_s`` and ``breakdown``.  The last line of
-standard output is the result; everything else is on earlier lines.
+standard output is the result; everything else is on earlier lines.  The
+numbers that decided ``correct`` stand beside their limits in the result
+(``compared``, its last key) and on the last lines of standard error.
 """
 
 import time
@@ -21,6 +23,7 @@ import time
 STARTED = time.perf_counter()      # before anything heavy is imported
 
 import argparse
+import math
 import os
 import sys
 from typing import Optional, Sequence
@@ -110,9 +113,19 @@ def main(argv: Optional[Sequence[str]] = None, broken=None) -> int:
                    for m in cell.end_to_end()}
     for name, m in metrics.items():
         say(f"metric {name}: {m['value']} {m['unit']}")
-    print(measure.result_line(record["check"]["correct"],
-                              record["attempted"], record["failed"], metrics,
-                              device, breakdown), flush=True)
+    check = record["check"]
+    # JSON has no NaN: a number that is not finite goes as its name
+    compared = {k: [v if math.isfinite(v) else str(v), check["limits"][k]]
+                for k, v in check["rows"].items()}
+    print(measure.result_line(check["correct"], record["attempted"],
+                              record["failed"], metrics, device, breakdown,
+                              compared), flush=True)
+    # what decided `correct`, once more, as the last lines on standard
+    # error: where a run is not correct little else of it is kept
+    for name, (value, limit) in compared.items():
+        print(f"[bench] compared {name}: {value} (limit {limit})",
+              file=sys.stderr)
+    print(f"[bench] correct: {check['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

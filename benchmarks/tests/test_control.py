@@ -13,21 +13,26 @@ from harness import cells, measure
 TESTS = os.path.dirname(os.path.abspath(__file__))
 
 
-def cell_of(name):
-    m = cells.load_json(os.path.join(TESTS, "rehearsal.json"))
+def cell_of(name, manifest="rehearsal.json"):
+    m = cells.load_json(os.path.join(TESTS, manifest))
     return cells.Cell(m, TESTS, name)
 
 
-@pytest.mark.parametrize("seed", [11, 12, 13])
-def test_train_control_is_not_correct(seed):
-    cell = cell_of("rehearse-train-seq")
+@pytest.mark.parametrize("seed,workload,manifest", [
+    (11, "rehearse-train-seq", "rehearsal.json"),
+    (12, "rehearse-train-seq", "rehearsal.json"),
+    (13, "rehearse-train-seq", "rehearsal.json"),
+    # the control goes through the family's file too
+    (12, "rehearse-gated-train", "rehearsal_family.json"),
+])
+def test_train_control_is_not_correct(seed, workload, manifest):
+    cell = cell_of(workload, manifest)
     drv = cell.driver()
     args = argparse.Namespace(seed=seed, seconds=1.0, trace=0)
     out = drv.control(cell, args, None, time.perf_counter(), None)
     assert out["correct"] is False, out["rows"]
-    spec = drv.weight_spec(cell.config)
-    want = drv.reference_readings(cell, args, spec, "f32")
-    stated = drv.reference_readings(cell, args, spec, "bf16")
+    want = drv.reference_readings(cell, args, "f32")
+    stated = drv.reference_readings(cell, args, "bf16")
     rows = drv.compare(stated, want, cell.limits)
     assert all(v <= lim for v, lim in rows.values()), rows
 
