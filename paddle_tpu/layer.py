@@ -2078,8 +2078,14 @@ def moe_ffn(input, num_experts: int = 0, expert_hidden: int = 0,
             capacity_factor: float = 1.25, aux_weight: float = 0.01,
             top_k: int = 1, config=None,
             name: Optional[str] = None, param_attr=None):
-    """Mixture-of-Experts FFN layer (new-build extension; parallel/moe.py
-    holds the kernels): Switch-style top-1 — or, with ``top_k=2``,
+    """Mixture-of-Experts FFN layer WITH A CAPACITY (new-build extension;
+    parallel/moe.py holds the kernels): a softmax router, two-matrix GELU
+    experts and a dense one-hot dispatch into ``ceil(T / E *
+    capacity_factor)`` slots an expert; (token, choice) pairs past an
+    expert's capacity are DROPPED.  The dropless path (a bias-corrected
+    sigmoid router over all experts, a rank's held experts by a sort and
+    grouped matrix products, a shared expert, nothing dropped) is
+    :func:`moe_dropless`.  Switch-style top-1 — or, with ``top_k=2``,
     GShard-style top-2 with renormalized gates — routing into per-expert
     two-layer FFNs. Returns ``(out, aux_cost)`` — add ``aux_cost`` to the
     SGD cost list (multi-cost training, the MultiNetwork path) so routing
@@ -2176,6 +2182,188 @@ def moe_ffn(input, num_experts: int = 0, expert_hidden: int = 0,
     aux_node = LayerOutput(name=f"{name}_aux", layer_type="moe_aux",
                            inputs=[core], fn=pick_aux, size=1, is_cost=True)
     return out_node, aux_node
+
+
+@_export
+def rms_norm(input, name: Optional[str] = None, epsilon: float = 1e-5,
+             param_attr=None) -> LayerOutput:
+    """Weighted RMSNorm over the feature axis (ops/norm.py rms_norm): no
+    mean, no bias, one gain (``gamma``, initialised to 1)."""
+    inp = input
+    name = name or unique_name("rms_norm")
+    params = {"gamma": ParamSpec((inp.size,), ParamAttr.to_attr(param_attr)
+                                 if param_attr
+                                 else ParamAttr(initializer=Constant(1.0)))}
+
+    def compute(ctx, p, ins):
+        v = ins[0]
+        return _like(v, pnorm.rms_norm(_data_of(v), p["gamma"], epsilon))
+
+    return LayerOutput(name=name, layer_type="rms_norm", inputs=[inp],
+                       fn=compute, params=params, size=inp.size,
+                       is_sequence=inp.is_sequence)
+
+
+@_export
+def swiglu_ffn(input, size: int, name: Optional[str] = None,
+               param_attr=None) -> LayerOutput:
+    """Gated feed-forward ``(silu(x Wg) * (x Wu)) Wd`` of inner width
+    ``size``, no biases (ops/math.py swiglu)."""
+    inp = input
+    name = name or unique_name("swiglu_ffn")
+    attr = ParamAttr.to_attr(param_attr)
+    d = inp.size
+    params = {"w_gate": ParamSpec((d, size), attr),
+              "w_up": ParamSpec((d, size), attr),
+              "w_down": ParamSpec((size, d), attr)}
+
+    def compute(ctx, p, ins):
+        v = ins[0]
+        y = pmath.swiglu(_data_of(v), p["w_gate"], p["w_up"], p["w_down"])
+        return _like(v, y.astype(pmath.dense_activation_dtype()))
+
+    return LayerOutput(name=name, layer_type="swiglu_ffn", inputs=[inp],
+                       fn=compute, params=params, size=d,
+                       is_sequence=inp.is_sequence)
+
+
+@_export
+def mla_attention(input, positions, *, num_heads: int, q_lora_rank: int,
+                  kv_lora_rank: int, qk_nope_head_dim: int,
+                  qk_rope_head_dim: int, v_head_dim: int,
+                  rope_theta: float = 10000.0, epsilon: float = 1e-5,
+                  name: Optional[str] = None, param_attr=None
+                  ) -> LayerOutput:
+    """Causal multi-head latent attention over packed sequences, the
+    expanded (training) form: low-rank query and key/value paths with a
+    weighted RMSNorm inside, a rotary part beside a position-free part in
+    every head, one rotary key shared by the heads, values of their own
+    width (ops/mla.py).  ``positions`` is the integer sequence of each
+    token's position inside its own sequence.  No biases, no cache."""
+    from paddle_tpu.ops.mla import mla_attention as mla
+
+    _need_seq(input, "mla_attention")
+    _need_seq(positions, "mla_attention")
+    name = name or unique_name("mla")
+    attr = ParamAttr.to_attr(param_attr)
+    gain = ParamAttr(initializer=Constant(1.0))
+    d, h = input.size, num_heads
+    params = {
+        "wq_a": ParamSpec((d, q_lora_rank), attr),
+        "q_norm": ParamSpec((q_lora_rank,), gain),
+        "wq_b": ParamSpec((q_lora_rank,
+                           h * (qk_nope_head_dim + qk_rope_head_dim)), attr),
+        "wkv_a": ParamSpec((d, kv_lora_rank + qk_rope_head_dim), attr),
+        "kv_norm": ParamSpec((kv_lora_rank,), gain),
+        "wkv_b": ParamSpec((kv_lora_rank,
+                            h * (qk_nope_head_dim + v_head_dim)), attr),
+        "wo": ParamSpec((h * v_head_dim, d), attr),
+    }
+
+    def compute(ctx, p, ins):
+        xs, pos = ins
+        y = mla(xs.data, pos.data.reshape(-1), xs.segment_ids, p,
+                num_heads=h, qk_nope_dim=qk_nope_head_dim,
+                qk_rope_dim=qk_rope_head_dim, v_dim=v_head_dim,
+                eps=epsilon, theta=rope_theta, mesh=ctx.mesh)
+        return xs.with_data(y.astype(pmath.dense_activation_dtype()))
+
+    return LayerOutput(name=name, layer_type="mla_attention",
+                       inputs=[input, positions], fn=compute, params=params,
+                       size=d, is_sequence=True)
+
+
+@_export
+def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
+                 expert_hidden: int, top_k: int, scaling: float = 1.0,
+                 shared_hidden: int = 0, name: Optional[str] = None,
+                 param_attr=None) -> LayerOutput:
+    """One rank's share of a DROPLESS expert layer (parallel/moe.py
+    moe_dropless): a bias-corrected sigmoid router over all ``n_routed``
+    experts, the ``held = (first, count)`` experts this rank holds
+    computed by grouped matrix products over the sorted (token, choice)
+    pairs, and a shared expert of width ``shared_hidden``; SwiGLU experts,
+    no capacity, nothing dropped, no auxiliary loss.  ``bias`` (the
+    router's correction bias) is a static parameter: it enters the choice
+    of experts only and the optimiser leaves it alone.  (The path with a
+    capacity and dropped tokens is :func:`moe_ffn`.)
+
+    Publishes per step, labelled ``layer=<name>``: ``moe_rows_total``,
+    ``moe_rows_held_total``, ``moe_max_expert_rows``."""
+    from paddle_tpu.parallel import moe as pmoe
+
+    inp = input
+    name = name or unique_name("moe_dropless")
+    attr = ParamAttr.to_attr(param_attr)
+    d, (first, count) = inp.size, held
+    enforce_that(0 <= first and first + count <= n_routed and count > 0,
+                 f"held experts {held} are not among {n_routed}",
+                 context="moe_dropless")
+    params = {
+        "router": ParamSpec((d, n_routed), attr),
+        "bias": ParamSpec((n_routed,), ParamAttr(
+            initializer=Constant(0.0), is_static=True)),
+        "w_gate": ParamSpec((count, d, expert_hidden), attr),
+        "w_up": ParamSpec((count, d, expert_hidden), attr),
+        "w_down": ParamSpec((count, expert_hidden, d), attr),
+    }
+    if shared_hidden:
+        params.update({
+            "shared_gate": ParamSpec((d, shared_hidden), attr),
+            "shared_up": ParamSpec((d, shared_hidden), attr),
+            "shared_down": ParamSpec((shared_hidden, d), attr)})
+
+    def compute(ctx, p, ins):
+        v = ins[0]
+        valid = v.valid_mask if isinstance(v, SequenceBatch) else None
+        y, stats = pmoe.moe_dropless(_data_of(v), p, top_k=top_k, held=held,
+                                     scaling=scaling, valid=valid)
+        ctx.count("moe_rows_total", stats["rows_total"], layer=name)
+        ctx.count("moe_rows_held_total", stats["rows_held"], layer=name)
+        ctx.count("moe_max_expert_rows", stats["max_expert_rows"],
+                  layer=name)
+        if valid is not None:
+            y = jnp.where(valid[:, None], y, 0)
+        return _like(v, y.astype(pmath.dense_activation_dtype()))
+
+    return LayerOutput(name=name, layer_type="moe_dropless", inputs=[inp],
+                       fn=compute, params=params, size=d,
+                       is_sequence=inp.is_sequence)
+
+
+@_export
+def next_token_cost(input, label, *, shift: int = 0, weight: float = 1.0,
+                    publish: Optional[str] = None,
+                    name: Optional[str] = None) -> LayerOutput:
+    """Softmax cross-entropy of a packed sequence's logits against
+    ``label`` moved ``shift`` rows up inside each sequence: row ``i`` is
+    scored against ``label[i + shift]``, and a row whose target lies
+    beyond its own sequence is masked.  ``shift=1`` on the next-token
+    column is a depth-1 multi-token-prediction loss (the token after
+    next) with no second feed column.  The per-token loss is scaled by
+    ``weight``.  ``publish`` names a gauge that shows the unscaled loss
+    as the trainer reduces it (summed over tokens, per sequence)."""
+    _need_seq(input, "next_token_cost")
+    name = name or unique_name("next_token_cost")
+
+    def compute(ctx, p, ins):
+        logits, lab = ins
+        ids = lab.data.reshape(-1).astype(jnp.int32)
+        seg = logits.segment_ids
+        ok = logits.valid_mask
+        if shift:
+            cap = ids.shape[0]
+            ids = jnp.roll(ids, -shift)
+            ok = ok & (jnp.roll(seg, -shift) == seg) & \
+                (jnp.arange(cap) < cap - shift)
+        loss = jnp.where(ok, ploss.softmax_cross_entropy(logits.data, ids),
+                         0.0)
+        if publish:
+            ctx.gauge(publish,
+                      jnp.sum(loss) / jnp.maximum(logits.num_seqs, 1))
+        return logits.with_data(loss * weight)
+
+    return _cost_node(name, "next_token_cost", [input, label], compute)
 
 
 @_export

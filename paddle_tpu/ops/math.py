@@ -57,6 +57,13 @@ def fc(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:
     return y
 
 
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array) -> jax.Array:
+    """Gated feed-forward ``(silu(x Wg) * (x Wu)) Wd`` (no biases), each
+    product under the global matmul policy, the gate in f32."""
+    return matmul(jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up), w_down)
+
+
 def outer_product_update(x, y):
     """Rank-1 accumulate helper (reference Matrix::mul with trans variants)."""
     return matmul(x, y, trans_a=True)

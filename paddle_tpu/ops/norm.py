@@ -75,6 +75,15 @@ def layer_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array,
             + beta).astype(x.dtype)
 
 
+def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """Root-mean-square normalization with a learned gain, no mean and no
+    bias: ``x * rsqrt(mean(x^2) + eps) * gamma``.  Statistics reduce in
+    f32 as in ``layer_norm``; output in the input dtype."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps) * gamma).astype(x.dtype)
+
+
 def cross_map_norm(x: jax.Array, size: int = 5, scale: float = 1e-4,
                    power: float = 0.75) -> jax.Array:
     """Local response normalization across channels (reference:

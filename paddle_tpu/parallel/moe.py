@@ -11,9 +11,17 @@ the scaling-book recipe:
   --all_to_all--> each shard runs ITS experts' FFN on tokens from every
   shard --all_to_all--> gated combine back to token order.
 
-``moe_ffn_reference`` is the collectives-free dense formulation used for
-single-device runs and as the parity oracle; ``moe_ffn`` is the
-shard_map/all_to_all version. Tokens over capacity are DROPPED (pass
+Two paths live here.  The CAPACITY path: ``moe_ffn_reference`` is the
+collectives-free dense formulation used for single-device runs and as
+the parity oracle; ``moe_ffn`` is the shard_map/all_to_all version.  The
+DROPLESS path (``moe_dropless``, further down): one rank's share of an
+expert-parallel layer with a bias-corrected sigmoid router over all the
+experts, the held experts computed by grouped matrix products over the
+sorted (token, choice) pairs (``ops/grouped_matmul.py``), a shared
+expert, no capacity and nothing dropped; it has no exchange yet and
+computes what its own experts give.
+
+In the capacity path tokens over capacity are DROPPED (pass
 through as zeros — callers add the residual), the Switch convention.
 Top-2 routing renormalizes the two gates to sum to 1 (GShard); per-
 expert capacity is UNCHANGED by ``top_k`` — k token-choices compete for
@@ -352,9 +360,162 @@ def _moe_jit(mesh, axis: str, e: int, cap: int, d: int, act, top_k: int,
                                                with_stats))
 
 
+# ---------------------------------------------------------------------------
+# The dropless path: one rank's share of an expert-parallel layer
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid_topk(x, router_w, bias, top_k: int, scaling: float = 1.0):
+    """Bias-corrected sigmoid routing (``noaux_tc``): scores
+    ``s = sigmoid(x W_r)`` in float32 at the highest matmul precision;
+    the ``top_k`` experts of ``s + bias`` are chosen; their weights are
+    ``s[chosen] / sum(s[chosen]) * scaling``, without the bias.
+    Returns (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    g = jnp.take_along_axis(s, experts, axis=-1)
+    g = g / jnp.sum(g, axis=-1, keepdims=True) * scaling
+    return experts.astype(jnp.int32), g
+
+
+def _take_rows(a, idx):
+    """Rows of ``a`` at ``idx``; an index past the end gives a zero row."""
+    return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _dispatch(x, row_token, dest):
+    """Token rows into the sorted buffer: ``xs[r] = x[row_token[r]]``
+    (zeros where a row holds no pair).  Pair and row are one to one, so
+    the gradient is a gather too: ``dx[t] = sum_j dxs[dest[t, j]]``."""
+    return _take_rows(x, row_token)
+
+
+def _dispatch_fwd(x, row_token, dest):
+    return _take_rows(x, row_token), (dest, jnp.zeros((), x.dtype))
+
+
+def _dispatch_bwd(res, dxs):
+    dest, like = res
+    dx = sum(_take_rows(dxs, dest[:, j]).astype(jnp.float32)
+             for j in range(dest.shape[1]))
+    return dx.astype(like.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, g, dest, row_token, row_pair):
+    """Sorted rows back to tokens, weighted: ``out[t] = sum_j g[t, j]
+    y[dest[t, j]]`` (a pair on an expert that is not held adds nothing).
+    Both gradients are gathers."""
+    return sum(g[:, j, None] * _take_rows(y, dest[:, j])
+               for j in range(dest.shape[1]))
+
+
+def _combine_fwd(y, g, dest, row_token, row_pair):
+    return (_combine(y, g, dest, row_token, row_pair),
+            (y, g, dest, row_token, row_pair))
+
+
+def _combine_bwd(res, dout):
+    y, g, dest, row_token, row_pair = res
+    row_w = _take_rows(g.reshape(-1), row_pair)
+    dy = (_take_rows(dout, row_token) * row_w[:, None]).astype(y.dtype)
+    dg = jnp.stack([jnp.sum(_take_rows(y, dest[:, j]) * dout, axis=-1)
+                    for j in range(dest.shape[1])], axis=1)
+    return dy, dg.astype(g.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
+                 held: Tuple[int, int], scaling: float = 1.0,
+                 valid: Optional[jax.Array] = None, tile_m: int = 0):
+    """What one rank of an expert-parallel group computes of a dropless
+    expert layer: it routes over ALL the experts (``p["router"]`` is
+    [D, n_routed], ``p["bias"]`` the correction bias), holds the
+    ``held = (first, count)`` of them whose gated feed-forward matrices it
+    is given (``w_gate``, ``w_up`` [count, D, F]; ``w_down`` [count, F,
+    D]), and returns its own experts' part of the result plus, where
+    ``p`` has one, the shared expert (``shared_gate``, ``shared_up``,
+    ``shared_down``), which every rank computes alike.  A chosen expert
+    that is not held adds nothing here; no token is dropped and there is
+    no capacity.
+
+    (token, choice) pairs on held experts are placed expert by expert
+    into a buffer whose groups start at multiples of ``tile_m``
+    (``ops/grouped_matmul.py``); it has ``T * top_k + count * tile_m``
+    rows, the worst case a step can meet (every choice of every token on
+    a held expert), and only the tiles that hold rows are computed.
+    ``valid`` [T] keeps padding rows of a packed buffer out.
+
+    x: [T, D].  Returns (y [T, D] float32, stats) with the step's
+    ``rows_total`` (valid tokens x top_k), ``rows_held`` (pairs that
+    landed on held experts) and ``max_expert_rows`` (the fullest held
+    expert), as device scalars."""
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.ops import math as pmath
+
+    tile_m = tile_m or gm.TILE_M
+    first, count = held
+    t, _ = x.shape
+    with jax.named_scope("moe.route"):
+        experts, g = route_sigmoid_topk(x, p["router"], p["bias"], top_k,
+                                        scaling)
+        local = experts - first
+        on = (local >= 0) & (local < count)
+        if valid is not None:
+            on = on & valid[:, None]
+        local = jnp.where(on, local, count).reshape(-1)
+        onehot = (local[:, None] == jnp.arange(count)[None, :]
+                  ).astype(jnp.int32)                       # [T k, count]
+        counts = jnp.sum(onehot, axis=0)
+        rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot,
+                       axis=-1)
+        rows = gm.padded_rows(t * top_k, count, tile_m)
+        offsets, tile_group, n_active = gm.tile_plan(
+            counts, rows // tile_m, tile_m)
+        dest = jnp.where(on.reshape(-1),
+                         offsets[jnp.minimum(local, count - 1)] + rank,
+                         rows).astype(jnp.int32)            # rows = nowhere
+        pair = jnp.arange(t * top_k, dtype=jnp.int32)
+        row_pair = jnp.full((rows,), t * top_k, jnp.int32).at[dest].set(
+            pair, mode="drop")
+        row_token = jnp.where(row_pair < t * top_k, row_pair // top_k, t)
+        dest = dest.reshape(t, top_k)
+    with jax.named_scope("moe.experts"):
+        ct = pmath.compute_dtype(x)
+        xs = _dispatch(x.astype(ct), row_token, dest)
+        h = gm.grouped_matmul(xs, p["w_gate"], tile_group, n_active, tile_m)
+        u = gm.grouped_matmul(xs, p["w_up"], tile_group, n_active, tile_m)
+        a = (jax.nn.silu(h) * u).astype(ct)
+        y = gm.grouped_matmul(a, p["w_down"], tile_group, n_active, tile_m)
+        out = _combine(y, g, dest, row_token, row_pair)
+    if "shared_gate" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + pmath.swiglu(x, p["shared_gate"], p["shared_up"],
+                                     p["shared_down"])
+    n_valid = t if valid is None else jnp.sum(valid)
+    stats = {"rows_total": jnp.asarray(n_valid * top_k, jnp.float32),
+             "rows_held": jnp.sum(counts).astype(jnp.float32),
+             "max_expert_rows": jnp.max(counts).astype(jnp.float32)}
+    return out, stats
+
+
 def record_moe_stats(stats, registry=None, prefix: str = "moe") -> None:
-    """Land one step's routing statistics on the obs metrics registry
-    (host-side: call OUTSIDE jit, on concrete step outputs):
+    """Land one step's routing statistics OF THE CAPACITY PATH
+    (``moe_ffn`` / ``moe_ffn_reference`` with ``return_stats``) on the obs
+    metrics registry (host-side: call OUTSIDE jit, on concrete step
+    outputs).  The dropless path drops nothing and needs no such call:
+    ``layer.moe_dropless`` publishes ``moe_rows_total``,
+    ``moe_rows_held_total`` and ``moe_max_expert_rows`` from inside the
+    compiled train step (``Context.count``), and ``trainer.SGD`` adds them
+    to the same registry where it reads the costs.
 
       - ``{prefix}_drop_rate`` gauge — fraction of (token, choice)
         dispatch slots past capacity this step;

@@ -105,6 +105,10 @@ class StateSpec:
     dtype: Any = jnp.float32
 
 
+def _labels(labels: Dict[str, Any]) -> tuple:
+    return tuple(sorted((k, str(v)) for k, v in labels.items()))
+
+
 class Context:
     """Per-forward execution context handed to each node's compute fn.
 
@@ -118,6 +122,10 @@ class Context:
         self._rng = rng
         self.state_in = state
         self.state_out: Dict[str, Dict[str, jax.Array]] = {}
+        # device scalars a layer publishes beside its value: they leave a
+        # compiled train step with the cost and reach the obs registry a
+        # log window late (trainer.SGD), keyed (kind, name, labels)
+        self.counters: Dict[tuple, jax.Array] = {}
         self._current: Optional[str] = None
         self.mesh = mesh
 
@@ -127,6 +135,23 @@ class Context:
         # stable per-node stream derived from the step key
         h = int.from_bytes(hashlib.md5(node_name.encode()).digest()[:4], "little")
         return jax.random.fold_in(self._rng, h)
+
+    def count(self, name: str, value, **labels) -> None:
+        """Add ``value`` (a device scalar) to the counter ``name`` of this
+        step; the trainer adds each step's sum to the registry's counter."""
+        self.publish(("counter", name, _labels(labels)), value)
+
+    def gauge(self, name: str, value, **labels) -> None:
+        """Set the gauge ``name`` for this step (the last step of a log
+        window is what the registry's gauge shows)."""
+        self.publish(("gauge", name, _labels(labels)), value)
+
+    def publish(self, key: tuple, value) -> None:
+        """A counter adds to what the step has under ``key``, a gauge
+        replaces it."""
+        value = jnp.asarray(value, jnp.float32)
+        add = key[0] == "counter" and key in self.counters
+        self.counters[key] = self.counters[key] + value if add else value
 
     def get_state(self, node_name: str, key: str) -> jax.Array:
         return self.state_in[node_name][key]
@@ -272,13 +297,19 @@ class Topology:
                 feeds: Dict[str, Any], *, train: bool = False,
                 rng: Optional[jax.Array] = None,
                 outputs: Optional[Sequence[LayerOutput]] = None,
-                mesh=None
+                mesh=None, counters: Optional[Dict[tuple, jax.Array]] = None
                 ) -> Tuple[List[Any], Dict[str, Dict[str, jax.Array]]]:
+        """``counters``, where given, is filled with what the layers
+        published through ``Context.count`` / ``Context.gauge``."""
         wanted = list(outputs) if outputs is not None else self.outputs
         ctx = Context(train=train, rng=rng, state=state, mesh=mesh)
         values: Dict[str, Any] = {}
         order = topological_order(wanted)
         done_groups: set = set()
+        # feeds first: a remat group may read a data layer (positions,
+        # say) that the order reaches only through one of its own nodes
+        values.update((n.name, feeds[n.name]) for n in order
+                      if n.fn is None and n.name in feeds)
         for node in order:
             if node.fn is None:  # data layers and frame/memory placeholders
                 if node.name not in feeds:
@@ -315,6 +346,8 @@ class Topology:
             # per-slot merge: a node updating one slot must not drop the
             # namespace's other slots
             new_state[ns] = {**new_state.get(ns, {}), **slots}
+        if counters is not None:
+            counters.update(ctx.counters)
         return [values[w.name] for w in wanted], new_state
 
     def _run_remat_group(self, group: str, order: List[LayerOutput],
@@ -372,16 +405,18 @@ class Topology:
                         f"(type={n.layer_type}, remat group {group!r}, "
                         f"inputs={[i.name for i in n.inputs]})")
                     raise
-            return [local[nm] for nm in ext_out], sub.state_out
+            return [local[nm] for nm in ext_out], sub.state_out, sub.counters
 
         with jax.named_scope(f"remat_{group}"):
-            outs, state_out = jax.checkpoint(segment)(
+            outs, state_out, counted = jax.checkpoint(segment)(
                 {k: params[k] for k in pkeys}, rng_arg,
                 [values[nm] for nm in ext_in])
         for nm, v in zip(ext_out, outs):
             values[nm] = v
         for ns, slots in state_out.items():
             ctx.state_out.setdefault(ns, {}).update(slots)
+        for key, v in counted.items():
+            ctx.publish(key, v)
 
     def __repr__(self):
         return f"Topology({len(self.nodes)} nodes, outputs={[o.name for o in self.outputs]})"

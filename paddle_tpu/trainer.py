@@ -386,12 +386,21 @@ class SGD:
 
         def forward_backward(params, model_state, rng, feeds):
             def loss_fn(p):
+                counted: Dict[tuple, jax.Array] = {}
                 outs, new_state = topo.forward(p, model_state, feeds,
-                                               train=True, rng=rng, mesh=mesh)
+                                               train=True, rng=rng, mesh=mesh,
+                                               counters=counted)
                 cost_vals = [_reduce_cost(o) for o in outs[:n_costs]]
                 total = functools.reduce(jnp.add, cost_vals)
                 metric_vals = {name: _metric_scalar(o) for name, o in
                                zip(metric_names, outs[n_costs:])}
+                if counted:
+                    # the layers' counters leave the step as ONE vector
+                    # beside the cost (its keys are fixed at trace time)
+                    # and are read with the costs, a log window late
+                    self._counter_keys = sorted(counted)
+                    metric_vals["__counters__"] = jnp.stack(
+                        [counted[k] for k in self._counter_keys])
                 return total, (new_state, metric_vals)
 
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -831,12 +840,16 @@ class SGD:
                 pending: List = []
                 pending_metrics: Dict[str, List] = {
                     n: [] for n in self.metrics}
+                pending_counters: List = []
 
                 def flush():
                     if pending:
                         pass_costs.extend(
                             np.asarray(jnp.stack(pending)).tolist())
                         pending.clear()
+                    if pending_counters:
+                        self._publish_counters(pending_counters)
+                        pending_counters.clear()
                     for k, buf in pending_metrics.items():
                         if buf:
                             pass_metrics[k].extend(
@@ -880,6 +893,9 @@ class SGD:
                                               key, feeds)
                     self._global_step += 1
                     pstats = metric_vals.pop("__param_stats__", None)
+                    counted = metric_vals.pop("__counters__", None)
+                    if counted is not None:
+                        pending_counters.append(counted)
                     period = getattr(self, "_stats_period", 0)
                     if pstats is not None and period > 0 \
                             and (batch_id + 1) % period == 0:
@@ -954,6 +970,22 @@ class SGD:
             # durability barrier: train() returning means the newest
             # checkpoint is committed (writer errors surface here)
             self._async_ckpt.wait()
+
+    def _publish_counters(self, vectors: List) -> None:
+        """The layers' per-step counters of one log window (one device
+        vector a step, ``Context.count`` / ``Context.gauge``) into
+        ``obs.default_registry()``: a counter grows by the window's sum, a
+        gauge shows the window's last step.  Called where the costs are
+        read, so it waits for nothing the cost flush does not."""
+        rows = np.stack(jax.device_get(vectors))        # [steps, keys]
+        reg = default_registry()
+        for col, (kind, name, labels) in enumerate(self._counter_keys):
+            if kind == "counter":
+                reg.counter(name).labels(**dict(labels)).inc(
+                    float(rows[:, col].sum()))
+            else:
+                reg.gauge(name).labels(**dict(labels)).set(
+                    float(rows[-1, col]))
 
     # ------------------------------------------------------------------
     # bad-step guard + cursor-checkpoint plumbing (paddle_tpu.resilience)
@@ -1327,6 +1359,7 @@ class SGD:
                                               jax.random.PRNGKey(step),
                                               feeds)
                     metric_vals.pop("__param_stats__", None)
+                    metric_vals.pop("__counters__", None)
                     step += 1
                     self._global_step = step
                     unacked.append(task_id)

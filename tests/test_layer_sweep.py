@@ -1147,6 +1147,60 @@ def _detection_output():
 
 
 # ---------------------------------------------------------------------------
+# the latent-attention / expert-layer family (models/glm_moe_lite.py)
+# ---------------------------------------------------------------------------
+
+
+@case("rms_norm")
+def _rms_norm():
+    x, fx = dense("x", 6)
+    check_layer_grad(layer.rms_norm(x), {"x": fx}, check_inputs=["x"])
+
+
+@case("swiglu_ffn")
+def _swiglu_ffn():
+    x, fx = dense("x", 6)
+    check_layer_grad(layer.swiglu_ffn(x, size=8), {"x": fx},
+                     check_inputs=["x"])
+
+
+@case("mla_attention")
+def _mla_attention():
+    s, fs = make_seq("s", 8, [5, 3])
+    pos, _ = int_seq("pos", 8, [5, 3])
+    fpos = SequenceBatch(jnp.asarray([0, 1, 2, 3, 4, 0, 1, 2], jnp.int32),
+                         fs.segment_ids, fs.lengths, max_len=5)
+    out = layer.mla_attention(s, pos, num_heads=2, q_lora_rank=6,
+                              kv_lora_rank=4, qk_nope_head_dim=4,
+                              qk_rope_head_dim=4, v_head_dim=8)
+    check_layer_grad(layer.pooling(out), {"s": fs, "pos": fpos}, delta=5e-3,
+                     rtol=8e-2)
+
+
+@case("moe_dropless")
+def _moe_dropless():
+    # the sum of the output: a perturbation that moves a token to another
+    # expert would break the numeric gradient, so the step is small
+    x, fx = dense("x", 6)
+    out = layer.moe_dropless(x, n_routed=4, held=(1, 2), expert_hidden=5,
+                             top_k=2, scaling=1.5, shared_hidden=5)
+    check_layer_grad(out, {"x": fx}, delta=1e-4, rtol=8e-2)
+
+
+@case("next_token_cost")
+def _next_token_cost():
+    s, fs = make_seq("s", 6, [4, 3])
+    lab, flab = int_seq("lab", 5, [4, 3])
+    out = layer.next_token_cost(layer.fc(s, size=5), lab, shift=1,
+                                weight=0.5)
+    check_layer_grad(out, {"s": fs, "lab": flab})
+    got, _ = forward(out, {"s": fs, "lab": flab})
+    # the last row of each sequence has no target one row up: masked
+    assert float(got.data[3]) == 0.0 and float(got.data[6]) == 0.0
+    assert float(jnp.min(jnp.delete(got.data, jnp.asarray([3, 6])))) > 0.0
+
+
+# ---------------------------------------------------------------------------
 # completeness gates
 # ---------------------------------------------------------------------------
 

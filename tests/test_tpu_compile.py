@@ -170,6 +170,47 @@ def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
     assert _compile(fn, *avals) == 1
 
 
+# ---- the latent-attention and expert-layer kernels at their cell's widths ---
+
+@pytest.mark.parametrize("mode", ["fwd", "bwd_pallas"])
+def test_flash_attention_at_head_width_256_compiles_for_v5e(topo, mode):
+    """MLA's heads (192 + 64 for q/k, 256 for v) give the three kernels
+    D = 256 at block 512: twice the VMEM of every other cell's tiles."""
+    q = _on(SingleDeviceSharding(topo.devices[0]))((1, 4096, 20, 256),
+                                                   jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    n = _compile(fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2)),
+                 q, q, q)
+    assert n == {"fwd": 1, "bwd_pallas": 3}[mode]
+
+
+@pytest.mark.parametrize("matrix", ["up", "down"])
+def test_grouped_matmul_compiles_for_v5e(topo, matrix, monkeypatch):
+    """``moe_gmm`` and, through the gradient, its transposed form and
+    ``moe_tgmm``, over the worst-case buffer of 8192 tokens x 4 choices on
+    8 held experts of 2048 x 1536."""
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "interpret_default", lambda: False)
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    rows = gm.padded_rows(8192 * 4, 8)
+    a, b = (2048, 1536) if matrix == "up" else (1536, 2048)
+    avals = (aval((rows, a), jnp.bfloat16), aval((8, a, b), jnp.float32),
+             aval((rows // gm.TILE_M,), jnp.int32), aval((1,), jnp.int32))
+
+    def loss(x, w, tile_group, n_active):
+        return jnp.sum(gm.grouped_matmul(x, w, tile_group, n_active))
+
+    assert _compile(gm.grouped_matmul, *avals) == 1
+    assert _compile(jax.grad(loss, argnums=(0, 1)), *avals) == 2
+
+
 # ---- names in the device trace ---------------------------------------------
 
 def _kernel_names(fn, *avals):
