@@ -28,7 +28,8 @@ from paddle_tpu.serving.fleet import FleetRouter, Replica, ReplicaState
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, KVPages, PagedKVConfig,
                                          PagePool, PrefixCache, append_token,
                                          dequantize_kv, fork_page, gather_kv,
-                                         init_kv_pages, pages_for_budget,
+                                         init_kv_pages, layer_pages,
+                                         pages_for_budget,
                                          prefix_chain_hashes, quantize_kv,
                                          resolve_kv_dtype, write_prompt)
 from paddle_tpu.serving.metrics import FleetMetrics, ServingMetrics
@@ -48,8 +49,9 @@ __all__ = [
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",
     "validate_tp",
     "PagedKVConfig", "KVPages", "PagePool", "PrefixCache", "NULL_PAGE",
-    "init_kv_pages", "append_token", "write_prompt", "gather_kv",
-    "fork_page", "prefix_chain_hashes", "quantize_kv", "dequantize_kv",
+    "init_kv_pages", "layer_pages", "append_token", "write_prompt",
+    "gather_kv", "fork_page", "prefix_chain_hashes", "quantize_kv",
+    "dequantize_kv",
     "pages_for_budget", "resolve_kv_dtype",
     "ContinuousBatchingScheduler", "Request", "RequestStatus",
     "SchedulerConfig", "bucket_for", "pack_prefill_chunks",

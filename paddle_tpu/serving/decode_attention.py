@@ -28,6 +28,15 @@ ragged invocation:
   ``num_heads // num_kv_heads``) is packed against each K/V page load,
   so K/V HBM traffic drops by the group factor — the pool stores KV
   heads only.
+- **The pool where it lies.**  The kernel's K and V operands are the
+  pool's own leaves, stored ``[L, pages, page, KVH * D]``
+  (``kv_cache.KVPages``) and addressed as ``[L * pages, page,
+  KVH * D]`` — a merge of leading, untiled dims, free on a TPU — with
+  the layer as a fourth scalar-prefetch operand: the index maps add
+  ``layer * pages`` to the page id.  So the compiled serving step
+  neither slices a layer out of the pool nor re-tiles it; the layer is
+  a traced operand, so one lowering of the kernel serves all ``L``
+  calls of a step.
 - **All of a chip's KV heads in one grid cell.**  A grid step costs a
   fixed quarter of a microsecond whatever it does, and a page's
   ``(page, KVH * D)`` slab is contiguous in the pool, so a cell takes
@@ -73,7 +82,9 @@ from paddle_tpu.ops.attention import (DEFAULT_MASK_VALUE, _dim_semantics,
                                       mha_reference)
 from paddle_tpu.ops.kernel_util import interpret_default as _interpret_default
 from paddle_tpu.platform.enforce import enforce_that
-from paddle_tpu.serving.kv_cache import dequantize_kv, quantize_kv
+from paddle_tpu.serving.kv_cache import (KVPages, dequantize_kv,
+                                         kv_pool_specs, layer_pages,
+                                         quantize_kv)
 
 _LANES = 128     # lane width of the (rows, _LANES) m/l scratch carries
 BLOCK_ROWS = 8   # sublane row-block granularity of the sequence packing
@@ -207,17 +218,18 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
-                   v_ref, *rest, page_size: int, num_pb: int, hb: int,
+def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
+                   k_ref, v_ref, *rest, page_size: int, num_pb: int, hb: int,
                    sm_scale: float, quantized: bool):
     # grid (row_blocks, kv_head_groups, pages-per-seq), ``hb`` KV heads
     # a group: the page axis is streamed; every head's (m, l, acc)
     # persist in VMEM scratch across it.  blk_seq/pt/len are the
     # scalar-prefetched block→sequence map [NB], page table [S, Pm] and
-    # KV lengths [S] (SMEM).  qpos_ref: (1, RBG, 1) — per-score-row
-    # absolute positions, already group-expanded, as a sublane column
-    # so the mask broadcasts over the (RBG, page) scores without a
-    # layout change.  q_ref/o_ref: (hb, 1, RBG, D); k_ref/v_ref:
+    # KV lengths [S] (SMEM); layer_ref [1] rides with them for the
+    # index maps alone (the body never reads it).  qpos_ref:
+    # (1, RBG, 1) — per-score-row absolute positions, already
+    # group-expanded, as a sublane column so the mask broadcasts over
+    # the (RBG, page) scores without a layout change.  q_ref/o_ref: (hb, 1, RBG, D); k_ref/v_ref:
     # (1, page, hb * D) — the group's lane slab of one page, contiguous
     # in the pool (the whole page when hb is all heads), head h of the
     # group at lanes [h * D, (h + 1) * D); quantized adds ks/vs
@@ -292,25 +304,48 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, qpos_ref, q_ref, k_ref,
             o_ref[h, 0] = (acc_scr[h] / l).astype(o_ref.dtype)
 
 
-def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
+def _stored(k_pages, v_pages, k_scale, v_scale, layer):
+    """The two pool forms the public entries take, as the stored one:
+    ``(k, v, k_scale, v_scale, layer)`` with k/v ``[L, pages, page,
+    KVH * D]`` (``kv_cache.KVPages``' leaves) and scales ``[L, pages,
+    page, KVH]``.  With a ``layer`` the operands already are the pool.
+    ``layer=None`` says ``k_pages`` is ONE layer's published
+    ``[pages, page, KVH, D]``: it is taken as a pool of one layer
+    (tests and the v1 decode wrapper come this way; on a TPU that
+    reshape re-tiles the layer, which is why the engine does not)."""
+    if layer is not None:
+        return k_pages, v_pages, k_scale, v_scale, layer
+    lanes = (1,) + k_pages.shape[:2] + (k_pages.shape[2] * k_pages.shape[3],)
+    if k_scale is not None:
+        k_scale, v_scale = k_scale[None], v_scale[None]
+    return (k_pages.reshape(lanes), v_pages.reshape(lanes), k_scale,
+            v_scale, 0)
+
+
+def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
                    kv_lens, row_seq, qpos, sm_scale, interpret: bool):
-    """Kernel-path entry.  REQUIRES block-uniform packing: T a multiple
-    of :data:`BLOCK_ROWS` and every aligned block of rows belonging to
-    ONE sequence (callers pad each sequence's rows to the block size —
-    decode slots to one block, chunks to whole blocks).  The block map
-    is read as ``row_seq[::BLOCK_ROWS]``; rows that violate uniformity
-    would silently attend over the wrong pages, so the engine owns the
-    packing and tests pin it against the reference path."""
+    """Kernel-path entry, on the STORED pool (``[L, pages, page,
+    KVH * D]``) and a layer index.  REQUIRES block-uniform packing: T a
+    multiple of :data:`BLOCK_ROWS` and every aligned block of rows
+    belonging to ONE sequence (callers pad each sequence's rows to the
+    block size — decode slots to one block, chunks to whole blocks).
+    The block map is read as ``row_seq[::BLOCK_ROWS]``; rows that
+    violate uniformity would silently attend over the wrong pages, so
+    the engine owns the packing and tests pin it against the reference
+    path."""
     t, h, d = q.shape
-    _, page, kvh, _ = k_pages.shape
+    page, kvh = k_pool.shape[2], k_pool.shape[3] // d
     enforce_that(t % BLOCK_ROWS == 0,
                  f"ragged kernel rows ({t}) must pack to BLOCK_ROWS "
                  f"({BLOCK_ROWS})", context="serving")
     enforce_that(h % kvh == 0, f"num_heads ({h}) must be a multiple of "
                  f"num_kv_heads ({kvh})", context="serving")
-    hb = heads_per_cell(kvh, page, d, k_pages.dtype.itemsize,
+    hb = heads_per_cell(kvh, page, d, k_pool.dtype.itemsize,
                         k_scale is not None)
-    return _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table,
+    # the layer is an OPERAND, never a static argument: one trace and
+    # one lowering of the kernel then serve every layer of a step
+    return _ragged_call(q, k_pool, v_pool, k_scale, v_scale,
+                        jnp.asarray(layer, jnp.int32).reshape(1), page_table,
                         kv_lens, row_seq, qpos, hb=hb, sm_scale=sm_scale,
                         interpret=interpret)
 
@@ -320,10 +355,12 @@ def _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale, page_table,
 # cell's heads, and Pallas lowers in Python in every process, persistent
 # compile cache or not — per layer that is seconds of set-up
 @functools.partial(jax.jit, static_argnames=("hb", "sm_scale", "interpret"))
-def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
-                 row_seq, qpos, *, hb: int, sm_scale: float, interpret: bool):
+def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
+                 kv_lens, row_seq, qpos, *, hb: int, sm_scale: float,
+                 interpret: bool):
     t, h, d = q.shape
-    _, page, kvh, _ = k_pages.shape
+    _, pages, page, lanes = k_pool.shape
+    kvh = lanes // d
     pm = page_table.shape[1]
     g = h // kvh
     nb = t // BLOCK_ROWS
@@ -338,40 +375,40 @@ def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
     # the whole head group
     q5 = q.reshape(nb, BLOCK_ROWS, kvh, g, d).transpose(2, 0, 1, 3, 4)
     q5 = q5.reshape(kvh, nb, rbg, d)
-    # [P, page, KVH, D] viewed as [P, page, KVH*D]: the KV heads of
+    # the pool [L, P, page, KVH*D] addressed as [L*P, page, KVH*D]: the
+    # two tiled dims stay as they are, so this is no copy on a TPU, and
+    # page p of the layer is row ``layer * P + p``.  The KV heads of
     # group hg are lanes [hg*hb*D, (hg+1)*hb*D) of that page's
     # (page, KVH*D) slab, so the index map addresses them as
-    # (page_id, 0, hg) with a legal (page, hb*D) tile and no transpose.
-    # (On the TPU the reshape is still a re-tiling copy of the layer's
-    # slice — PERF.md, section 5.)
-    kt = k_pages.reshape(-1, page, kvh * d)
-    vt = v_pages.reshape(-1, page, kvh * d)
+    # (row, 0, hg) with a legal (page, hb*D) tile and no transpose.
+    kt = k_pool.reshape(-1, page, lanes)
+    vt = v_pool.reshape(-1, page, lanes)
     pt = page_table.astype(jnp.int32)
     ln = kv_lens.astype(jnp.int32)
 
     # TPU block shapes must end in (8k, 128k) or the array's own last
     # two dims — every spec below is written to that rule
 
-    def qpos_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
+    def qpos_idx(ib, hg, j, blk_ref, pt_ref, len_ref, layer_ref):
         return (ib, 0, 0)
 
-    def q_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
+    def q_idx(ib, hg, j, blk_ref, pt_ref, len_ref, layer_ref):
         return (hg, ib, 0, 0)
 
-    def live_page(ib, j, blk_ref, pt_ref, len_ref):
+    def live_row(ib, j, blk_ref, pt_ref, len_ref, layer_ref):
         # clamp dead pages (j past the block's sequence's last live
         # page) to the last live one so their DMA is elided by
         # revisiting; pl.when skips their compute.  max(len-1, 0) keeps
         # length-0 sequences legal.
         seq = blk_ref[ib]
         last = jnp.maximum(len_ref[seq] - 1, 0) // page
-        return pt_ref[seq, jnp.minimum(j, last)]
+        return layer_ref[0] * pages + pt_ref[seq, jnp.minimum(j, last)]
 
-    def kv_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
-        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, hg)
+    def kv_idx(ib, hg, j, *refs):
+        return (live_row(ib, j, *refs), 0, hg)
 
-    def scale_idx(ib, hg, j, blk_ref, pt_ref, len_ref):
-        return (live_page(ib, j, blk_ref, pt_ref, len_ref), 0, 0)
+    def scale_idx(ib, hg, j, *refs):
+        return (live_row(ib, j, *refs), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, rbg, 1), qpos_idx),
@@ -383,10 +420,11 @@ def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
     if quantized:
         in_specs += [pl.BlockSpec((1, page, kvh), scale_idx),
                      pl.BlockSpec((1, page, kvh), scale_idx)]
-        args += [k_scale, v_scale]
+        args += [k_scale.reshape(-1, page, kvh),
+                 v_scale.reshape(-1, page, kvh)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(nb, kvh // hb, pm),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((hb, 1, rbg, d), q_idx),
@@ -405,7 +443,7 @@ def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
         compiler_params=_dim_semantics(3, interpret),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(blk_seq, pt, ln, *args)
+    )(blk_seq, pt, ln, layer, *args)
     out = out.reshape(kvh, nb, BLOCK_ROWS, g, d).transpose(1, 2, 0, 3, 4)
     return out.reshape(t, h, d)
 
@@ -414,13 +452,29 @@ def _ragged_call(q, k_pages, v_pages, k_scale, v_scale, page_table, kv_lens,
 # Public API
 # ---------------------------------------------------------------------------
 
+def _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer, *rest,
+                        sm_scale):
+    """The (row-blocked) reference path on one layer of a stored pool."""
+    k, v, ks, vs = layer_pages(
+        KVPages(k_pool, v_pool, k_scale, v_scale, head_dim=q.shape[-1]),
+        layer)
+    return _ragged_reference_blocked(q, k, v, *rest, k_scale=ks, v_scale=vs,
+                                     sm_scale=sm_scale)
+
+
 def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
-                           row_seq, qpos, *, k_scale=None, v_scale=None,
-                           sm_scale: Optional[float] = None,
+                           row_seq, qpos, *, layer=None, k_scale=None,
+                           v_scale=None, sm_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None):
     """Ragged paged attention over a sequence-packed mixed batch (see
-    :func:`ragged_paged_attention_reference` for shapes/semantics).
+    :func:`ragged_paged_attention_reference` for the semantics).
+
+    With ``layer`` (an int or a traced scalar) ``k_pages``/``v_pages``
+    are the WHOLE stored pool ``[L, pages, page, KVH * D]`` (scales
+    ``[L, pages, page, KVH]``) — how the engine calls, so that the
+    kernel reads the pool where it lies; without, one layer's
+    ``[pages, page, KVH, D]`` (see :func:`_stored`).
 
     ``use_kernel=None`` auto-selects through :func:`attention_path`; the
     kernel additionally requires block-uniform :data:`BLOCK_ROWS`
@@ -430,26 +484,26 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    path = attention_path(q.shape[-1], k_pages.shape[1],
-                          num_heads=q.shape[1],
-                          num_kv_heads=k_pages.shape[2],
+    pool = _stored(k_pages, v_pages, k_scale, v_scale, layer)
+    _, _, page, lanes = pool[0].shape
+    path = attention_path(q.shape[-1], page, num_heads=q.shape[1],
+                          num_kv_heads=lanes // q.shape[-1],
                           quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
     if path == "kernel":
-        return _ragged_pallas(q, k_pages, v_pages, k_scale, v_scale,
-                              page_table.astype(jnp.int32),
+        return _ragged_pallas(q, *pool, page_table.astype(jnp.int32),
                               kv_lens.astype(jnp.int32),
                               row_seq.astype(jnp.int32),
                               qpos.astype(jnp.int32),
                               float(sm_scale), bool(interpret))
-    return _ragged_reference_blocked(
-        q, k_pages, v_pages, page_table, kv_lens, row_seq, qpos,
-        k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
+    return _reference_on_layer(q, *pool, page_table, kv_lens, row_seq, qpos,
+                               sm_scale=sm_scale)
 
 
 def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
-                              kv_lens, row_seq, qpos, *, k_scale=None,
-                              v_scale=None, sm_scale: Optional[float] = None,
+                              kv_lens, row_seq, qpos, *, layer=None,
+                              k_scale=None, v_scale=None,
+                              sm_scale: Optional[float] = None,
                               use_kernel: Optional[bool] = None,
                               interpret: Optional[bool] = None):
     """Tensor-parallel ragged attention: the pallas kernel wrapped in a
@@ -457,14 +511,16 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
 
     Heads are embarrassingly parallel in attention, so each chip runs
     the UNCHANGED kernel on its local slice — q ``[T, H/TP, D]`` against
-    its ``[P, page, H_kv/TP, D]`` pool shard (scales ride along) — and
-    no collective crosses the region: the psum lives downstream in the
-    row-parallel output projection, exactly the megatron pattern.  A
-    bare ``pallas_call`` under GSPMD would instead force the sharded
-    operands replicated (XLA cannot partition a custom kernel), which
-    is why the TP engine routes its kernel path through here.  The GQA
-    group factor is shard-invariant (``(H/TP) / (H_kv/TP) == H/H_kv``),
-    so head-group packing is untouched.
+    its shard of the stored pool, ``[L, P, page, (H_kv/TP) * D]``: the
+    lanes of its own heads, passed through whole with the layer index
+    (scales ride along) — and no collective crosses the region: the
+    psum lives downstream in the row-parallel output projection,
+    exactly the megatron pattern.  A bare ``pallas_call`` under GSPMD
+    would instead force the sharded operands replicated (XLA cannot
+    partition a custom kernel), which is why the TP engine routes its
+    kernel path through here.  The GQA group factor is shard-invariant
+    (``(H/TP) / (H_kv/TP) == H/H_kv``), so head-group packing is
+    untouched.  Pool forms as in :func:`ragged_paged_attention`.
 
     Dispatch routes through :func:`attention_path` like every other
     entry point (the per-SHARD head counts decide): shapes the chooser
@@ -479,33 +535,34 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
     if interpret is None:
         interpret = _interpret_default()
     tp = int(mesh.shape[axis])
-    path = attention_path(q.shape[-1], k_pages.shape[1],
-                          num_heads=q.shape[1] // tp,
-                          num_kv_heads=k_pages.shape[2] // tp,
+    k_pool, v_pool, k_scale, v_scale, layer = _stored(
+        k_pages, v_pages, k_scale, v_scale, layer)
+    _, _, page, lanes = k_pool.shape
+    path = attention_path(q.shape[-1], page, num_heads=q.shape[1] // tp,
+                          num_kv_heads=lanes // q.shape[-1] // tp,
                           quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
     if path != "kernel":
-        return _ragged_reference_blocked(
-            q, k_pages, v_pages, page_table, kv_lens, row_seq, qpos,
-            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
+        return _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale,
+                                   layer, page_table, kv_lens, row_seq, qpos,
+                                   sm_scale=sm_scale)
     head = P(None, axis, None)
-    pool = P(None, None, axis, None)
-    scale = P(None, None, axis)
+    pool = P(*kv_pool_specs(axis))
     repl = P()
-    in_specs = [head, pool, pool, repl, repl, repl, repl]
+    in_specs = [head, pool, pool, repl, repl, repl, repl, repl]
     if k_scale is not None:
-        in_specs += [scale, scale]
+        in_specs += [pool, pool]
 
-    def local(qs, ks, vs, pt, ln, rs, qp, *scales):
+    def local(qs, ks, vs, lyr, pt, ln, rs, qp, *scales):
         kss, vss = scales if scales else (None, None)
-        return _ragged_pallas(qs, ks, vs, kss, vss, pt, ln, rs, qp,
+        return _ragged_pallas(qs, ks, vs, kss, vss, lyr, pt, ln, rs, qp,
                               float(sm_scale), bool(interpret))
 
     fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=head, check_vma=False)
-    args = [q, k_pages, v_pages, page_table.astype(jnp.int32),
-            kv_lens.astype(jnp.int32), row_seq.astype(jnp.int32),
-            qpos.astype(jnp.int32)]
+    args = [q, k_pool, v_pool, jnp.asarray(layer, jnp.int32),
+            page_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+            row_seq.astype(jnp.int32), qpos.astype(jnp.int32)]
     if k_scale is not None:
         args += [k_scale, v_scale]
     return fn(*args)
@@ -645,7 +702,8 @@ def expand_decode_rows(q, qpos, rows_per_seq: int = 1):
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, sm_scale,
                          interpret: bool, k_scale=None, v_scale=None):
     qe, row_seq, qpos = expand_decode_rows(q, lengths.astype(jnp.int32) - 1)
-    out = _ragged_pallas(qe, k_pages, v_pages, k_scale, v_scale,
+    out = _ragged_pallas(qe, *_stored(k_pages, v_pages, k_scale, v_scale,
+                                      None),
                          page_table.astype(jnp.int32),
                          lengths.astype(jnp.int32), row_seq, qpos,
                          float(sm_scale), bool(interpret))

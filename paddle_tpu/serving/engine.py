@@ -72,8 +72,9 @@ places the model megatron-style over a ``model`` mesh axis — attention
 heads (and GQA KV heads) + FFN columns column-parallel, the output/FFN-
 down projections row-parallel with ONE psum each per layer — using the
 model's ``shard_plan()`` as the single placement source of truth, and
-the paged pool shards its KV-head dim the same way
-(``[L, pages, page, H_kv/TP, D]``, int8 scales riding along), so every
+the paged pool shards its KV heads the same way (a chip's shard of
+the stored ``[L, pages, page, H_kv * D]`` is the lanes of its own
+``H_kv/TP`` heads, int8 scales riding along), so every
 pool byte number becomes per-chip and the same budget admits tp x the
 pages.  The unified step, chunk prefill, ``fork_page``/``zero_pages``
 and the decode kernel (via ``shard_map``) all run on the sharded
@@ -117,7 +118,8 @@ from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
                                          PrefixCache, append_token,
                                          dequantize_kv, fork_page,
                                          init_kv_pages, kv_pool_specs,
-                                         pages_for_budget, pages_spanned,
+                                         layer_pages, pages_for_budget,
+                                         pages_spanned,
                                          read_pages, resolve_kv_dtype,
                                          write_pages, zero_pages)
 from paddle_tpu.serving.metrics import ServingMetrics
@@ -883,7 +885,7 @@ class ServingEngine:
 
     def _tp_kv(self, kv: KVPages) -> KVPages:
         """Pin the returned pool to its canonical per-chip layout
-        (``[L, pages, page, H_kv/TP, D]``, THE ``kv_pool_sharding``
+        (``[L, pages, page, (H_kv/TP) * D]``, THE ``kv_pool_sharding``
         layout — same source of truth as placement and the contract) so
         the donated-in/aliased-out pair stays shard-identical across
         ticks (no-op replicated)."""
@@ -891,12 +893,9 @@ class ServingEngine:
             return kv
         from paddle_tpu.serving.kv_cache import kv_pool_sharding
 
-        wsc = jax.lax.with_sharding_constraint
         sh = kv_pool_sharding(self.mesh, self.tp_axis)
-        return KVPages(
-            wsc(kv.k, sh), wsc(kv.v, sh),
-            None if kv.k_scale is None else wsc(kv.k_scale, sh),
-            None if kv.v_scale is None else wsc(kv.v_scale, sh))
+        return jax.tree.map(
+            lambda a: jax.lax.with_sharding_constraint(a, sh), kv)
 
     def _tp_ctx(self, ctx):
         """Re-assert the head sharding on an attention output (no-op on
@@ -926,14 +925,13 @@ class ServingEngine:
         model axis (heads are attention-local, so each chip runs the
         unchanged kernel on its head shard) and both paths re-assert
         the head sharding on the context."""
-        ks = kv.k_scale[layer] if kv.k_scale is not None else None
-        vs = kv.v_scale[layer] if kv.v_scale is not None else None
         if not self._ragged_kernel:
             # row-blocked fallback: identical math to the oracle, with
             # the per-row K/V gather bounded to one block of rows
+            k, v, ks, vs = layer_pages(kv, layer)
             return self._tp_ctx(_ragged_reference_blocked(
-                q, kv.k[layer], kv.v[layer], table, att_lens, row_seq,
-                qpos, k_scale=ks, v_scale=vs))
+                q, k, v, table, att_lens, row_seq, qpos, k_scale=ks,
+                v_scale=vs))
         b, rb = self._max_slots, BLOCK_ROWS
         bd = b * k1                      # compact decode/verify rows
         rbk = -(-k1 // rb) * rb          # padded rows per slot
@@ -947,15 +945,17 @@ class ServingEngine:
         qe = jnp.concatenate([qd, q[bd:]])
         rs = jnp.concatenate([rsd, row_seq[bd:]])
         qp = jnp.concatenate([qpd, qpos[bd:]])
+        # the kernel takes the pool's own leaves and the layer's index:
+        # no slice of the layer, no re-tiling, no copy of either
+        pool = dict(layer=layer, k_scale=kv.k_scale, v_scale=kv.v_scale,
+                    use_kernel=True)
         if self.mesh is not None and self.tp > 1:
             ctx = ragged_paged_attention_tp(
-                self.mesh, self.tp_axis, qe, kv.k[layer], kv.v[layer],
-                table, att_lens, rs, qp, k_scale=ks, v_scale=vs,
-                use_kernel=True)
+                self.mesh, self.tp_axis, qe, kv.k, kv.v, table, att_lens,
+                rs, qp, **pool)
         else:
-            ctx = ragged_paged_attention(
-                qe, kv.k[layer], kv.v[layer], table, att_lens, rs, qp,
-                k_scale=ks, v_scale=vs, use_kernel=True)
+            ctx = ragged_paged_attention(qe, kv.k, kv.v, table, att_lens,
+                                         rs, qp, **pool)
         cd = ctx[:td].reshape(b, rbk, h, d)[:, :k1].reshape(bd, h, d)
         return self._tp_ctx(jnp.concatenate([cd, ctx[td:]]))
 

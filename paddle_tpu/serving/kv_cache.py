@@ -9,6 +9,17 @@ one page at a time, so HBM is shared at page granularity across
 concurrently-decoding requests with zero fragmentation beyond the last
 partial page.
 
+**Stored layout.**  On the device a page's heads are MERGED into its
+lanes: ``[L, num_pages, page_size, H * D]``, KV head ``h`` at lanes
+``[h * D, (h + 1) * D)`` (:class:`KVPages`).  That is the tile the
+ragged kernel DMAs, so the serving step hands the kernel the pool's own
+leaves and a layer index — no per-layer slice, no re-tiling copy.  The
+published ``[..., H, D]`` shape lives on at the boundaries: rows come in
+as ``[B, H, D]`` (:func:`append_token`), whole pages leave and enter as
+``[L, n, page, H, D]`` (:func:`read_pages` / :func:`write_pages`), and
+the non-kernel read paths take one layer's ``[pages, page, H, D]`` view
+from :func:`layer_pages`.
+
 Split of responsibilities:
 
 - **Device side** (pure functions, jit-safe): ``append_token`` /
@@ -38,10 +49,12 @@ as a reclaimable pool; LRU eviction returns them under pressure.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import zlib
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+from typing import (Callable, Dict, List, Optional, Sequence,
                     Set, Tuple)
 
 import jax
@@ -179,51 +192,93 @@ def pages_for_budget(pool_bytes: int, num_layers: int, num_heads: int,
     return max(2, int(pool_bytes) // probe.bytes_per_page())
 
 
-class KVPages(NamedTuple):
-    """The device-resident pool: ``k``/``v`` are
-    [num_layers, num_pages, page_size, num_kv_heads, head_dim].  With
-    int8 pages, ``k_scale``/``v_scale`` are the matching per-token,
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("k", "v", "k_scale", "v_scale"),
+                   meta_fields=("head_dim",))
+@dataclass(frozen=True)
+class KVPages:
+    """The device-resident pool, STORED in the tile layout the ragged
+    kernel reads: ``k``/``v`` are
+    [num_layers, num_pages, page_size, num_kv_heads * head_dim], KV head
+    ``h`` at lanes ``[h * head_dim, (h + 1) * head_dim)`` of a token's
+    row.  On a TPU the last two dims are the tiled ones, so a
+    ``(page, hb * head_dim)`` block of this array is what the kernel
+    DMAs as it stands, and merging the two LEADING dims (``[L * pages,
+    ...]``, how the kernel addresses a layer) is free — where a
+    ``[..., H_kv, D] -> [..., H_kv * D]`` reshape of the old 5-d pool
+    was a re-tiling copy of every layer on every tick.  With int8
+    pages, ``k_scale``/``v_scale`` are the matching per-token,
     per-kv-head f32 scales [num_layers, num_pages, page_size,
     num_kv_heads]; None for float pools (the two layouts share every
-    code path through ``is-None`` checks that resolve at trace time)."""
+    code path through ``is-None`` checks that resolve at trace time).
+
+    ``head_dim`` is static metadata (not a leaf): it is what splits the
+    merged dim back into ``[H_kv, D]`` wherever the published shape is
+    wanted (:func:`layer_pages`, :func:`read_pages`)."""
 
     k: jax.Array
     v: jax.Array
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    head_dim: int = field(kw_only=True)
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    @property
+    def kv_heads(self) -> int:
+        return self.k.shape[-1] // self.head_dim
+
+
+def layer_pages(kv: KVPages, layer):
+    """One layer of the pool in the published shape: ``(k, v, k_scale,
+    v_scale)`` with k/v ``[num_pages, page_size, H_kv, D]`` (scales
+    ``[num_pages, page_size, H_kv]`` or None).  THE one place the
+    non-kernel read paths (the reference attention, :func:`gather_kv`,
+    the draft model's step) get a layer from: on the CPU the view is
+    free; on a TPU it is the slice and re-tiling copy the kernel path
+    exists to avoid, so nothing a chip serves with goes through here."""
+    shape = kv.k.shape[1:3] + (kv.kv_heads, kv.head_dim)
+    if kv.quantized:
+        return (kv.k[layer].reshape(shape), kv.v[layer].reshape(shape),
+                kv.k_scale[layer], kv.v_scale[layer])
+    return kv.k[layer].reshape(shape), kv.v[layer].reshape(shape), None, None
+
 
 def init_kv_pages(cfg: PagedKVConfig, mesh=None, axis: str = "model"
                   ) -> KVPages:
-    """Allocate the pool.  With a ``mesh``, every leaf is placed with
-    its KV-head dim sharded over ``axis`` (see :func:`kv_pool_specs`)
-    so the ``[L, pages, page, H_kv/TP, D]`` per-chip layout exists from
-    tick zero — the scatters/gathers of the serving step keep it there
-    (batching-dim ops never move the head dim)."""
-    shape = (cfg.num_layers, cfg.num_pages, cfg.page_size, cfg.kv_heads,
-             cfg.head_dim)
+    """Allocate the pool in its stored layout ``[L, pages, page,
+    H_kv * D]``.  With a ``mesh``, every leaf is placed with its merged
+    head dim sharded over ``axis`` (see :func:`kv_pool_specs`): a chip's
+    shard is the lanes of its ``H_kv / TP`` heads, ``[L, pages, page,
+    (H_kv / TP) * D]``, from tick zero — the scatters of the serving
+    step keep it there (they index dims 0-2 only)."""
+    shape = (cfg.num_layers, cfg.num_pages, cfg.page_size,
+             cfg.kv_heads * cfg.head_dim)
     # allocate every leaf ALREADY sharded: a pool sized per chip is tp x
     # that in total, and staging it whole on device 0 first could not fit
     sh = None if mesh is None else kv_pool_sharding(mesh, axis)
     if cfg.quantized:
+        scales = shape[:-1] + (cfg.kv_heads,)
         return KVPages(jnp.zeros(shape, jnp.int8, device=sh),
                        jnp.zeros(shape, jnp.int8, device=sh),
-                       jnp.zeros(shape[:-1], jnp.float32, device=sh),
-                       jnp.zeros(shape[:-1], jnp.float32, device=sh))
+                       jnp.zeros(scales, jnp.float32, device=sh),
+                       jnp.zeros(scales, jnp.float32, device=sh),
+                       head_dim=cfg.head_dim)
     return KVPages(jnp.zeros(shape, cfg.dtype, device=sh),
-                   jnp.zeros(shape, cfg.dtype, device=sh))
+                   jnp.zeros(shape, cfg.dtype, device=sh),
+                   head_dim=cfg.head_dim)
 
 
 def kv_pool_specs(axis: str = "model") -> Tuple[Optional[str], ...]:
-    """THE canonical pool layout, as one leading-dims PartitionSpec
-    entry covering every :class:`KVPages` leaf: ``k``/``v`` are 5-d
-    with the KV-head dim at position 3 and the scale arrays 4-d with it
-    at position 3 too, so ``(None, None, None, axis)`` shards exactly
-    the head dim of each (trailing dims replicated).  Single source of
+    """THE canonical pool layout, as one PartitionSpec covering every
+    :class:`KVPages` leaf: all are 4-d with the heads at position 3 —
+    ``k``/``v`` as the merged ``H_kv * D`` lanes, head-major, so an
+    even split over ``axis`` gives each chip the lanes of its own
+    ``H_kv / TP`` heads and no other's; the scale arrays as ``H_kv``
+    itself — so ``(None, None, None, axis)`` shards exactly the heads
+    of each.  Single source of
     truth — the TP :class:`~paddle_tpu.analysis.retrace.SiteContract`s
     declare it for the pool argument/outputs, :func:`init_kv_pages`
     places with it, and the engine's per-tick output constraint
@@ -268,8 +323,11 @@ def append_token(kv: KVPages, layer: int, k_new: jax.Array, v_new: jax.Array,
 
     k_new/v_new: [B, H_kv, D]; page_ids/offsets: [B] int32 (inactive
     rows pass page_ids == NULL_PAGE — duplicates on the null page are
-    fine, nothing reads it).  Quantized pools quantize on write (the
-    scale lands at the same [layer, page, offset, head] address).
+    fine, nothing reads it).  A row lands as the ``H_kv * D`` lanes of
+    the stored layout at ``[layer, page, offset]`` (merging a row's
+    heads is a reshape of ``B`` rows, not of the pool).  Quantized
+    pools quantize on write, per head, BEFORE the merge (the scale
+    lands at the same [layer, page, offset, head] address).
     Pure; returns the updated pool.
 
     This is also the MULTI-TOKEN scatter of the speculative verify
@@ -282,17 +340,21 @@ def append_token(kv: KVPages, layer: int, k_new: jax.Array, v_new: jax.Array,
     inequality until the real tokens overwrite it, while the lookahead
     PAGES past the length return to the pool
     (``scheduler.rollback_pages`` — rollback-to-length)."""
+    rows = (k_new.shape[0], kv.k.shape[-1])
+    scales = {}
     if kv.quantized:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        return KVPages(
-            kv.k.at[layer, page_ids, offsets].set(kq),
-            kv.v.at[layer, page_ids, offsets].set(vq),
-            kv.k_scale.at[layer, page_ids, offsets].set(ks),
-            kv.v_scale.at[layer, page_ids, offsets].set(vs))
-    k = kv.k.at[layer, page_ids, offsets].set(k_new.astype(kv.k.dtype))
-    v = kv.v.at[layer, page_ids, offsets].set(v_new.astype(kv.v.dtype))
-    return KVPages(k, v)
+        k_new, ks = quantize_kv(k_new)
+        v_new, vs = quantize_kv(v_new)
+        scales = dict(
+            k_scale=kv.k_scale.at[layer, page_ids, offsets].set(ks),
+            v_scale=kv.v_scale.at[layer, page_ids, offsets].set(vs))
+    return dataclasses.replace(
+        kv,
+        k=kv.k.at[layer, page_ids, offsets].set(
+            k_new.astype(kv.k.dtype).reshape(rows)),
+        v=kv.v.at[layer, page_ids, offsets].set(
+            v_new.astype(kv.v.dtype).reshape(rows)),
+        **scales)
 
 
 def write_prompt(kv: KVPages, layer: int, k_seq: jax.Array, v_seq: jax.Array,
@@ -328,12 +390,8 @@ def zero_pages(kv: KVPages, page_ids: jax.Array) -> KVPages:
     the failure path keeps the pool finite-by-construction.  (int8
     pools can't store non-finite VALUES, but their scale arrays can —
     both are scrubbed.)"""
-    k = kv.k.at[:, page_ids].set(jnp.zeros((), kv.k.dtype))
-    v = kv.v.at[:, page_ids].set(jnp.zeros((), kv.v.dtype))
-    if kv.quantized:
-        return KVPages(k, v, kv.k_scale.at[:, page_ids].set(0.0),
-                       kv.v_scale.at[:, page_ids].set(0.0))
-    return KVPages(k, v)
+    return jax.tree.map(
+        lambda a: a.at[:, page_ids].set(jnp.zeros((), a.dtype)), kv)
 
 
 def fork_page(kv: KVPages, src: jax.Array, dst: jax.Array) -> KVPages:
@@ -344,13 +402,7 @@ def fork_page(kv: KVPages, src: jax.Array, dst: jax.Array) -> KVPages:
     replica of a shared cached page, so a sequence whose tail must write
     into the last shared page of its prefix does so without corrupting
     the other holders.  Pure; returns the updated pool."""
-    k = kv.k.at[:, dst].set(kv.k[:, src])
-    v = kv.v.at[:, dst].set(kv.v[:, src])
-    if kv.quantized:
-        return KVPages(k, v,
-                       kv.k_scale.at[:, dst].set(kv.k_scale[:, src]),
-                       kv.v_scale.at[:, dst].set(kv.v_scale[:, src]))
-    return KVPages(k, v)
+    return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), kv)
 
 
 def read_pages(kv: KVPages, page_ids: Sequence[int]):
@@ -358,16 +410,19 @@ def read_pages(kv: KVPages, page_ids: Sequence[int]):
     of the page-migration plane (``serving/migrate.py``).
 
     page_ids: n page ids.  Returns ``(k, v, k_scale, v_scale)`` numpy
-    arrays, k/v shaped [L, n, page, H_kv, D] in the pool dtype and the
-    scales [L, n, page, H_kv] f32 (None for float pools).  int8 pages
-    are NOT dequantized: migration moves the quantized bytes plus their
-    scales verbatim, so the destination reads bit-identical K/V and the
-    transfer costs ~1/4 the f32 bytes."""
-    import numpy as np
-
+    arrays, k/v in the PUBLISHED shape [L, n, page, H_kv, D] (the
+    stored lanes split back into heads on the host, where a reshape is
+    free) in the pool dtype and the scales [L, n, page, H_kv] f32 (None
+    for float pools).  int8 pages are NOT dequantized: migration moves
+    the quantized bytes plus their scales verbatim, so the destination
+    reads bit-identical K/V and the transfer costs ~1/4 the f32
+    bytes."""
     ids = jnp.asarray(list(page_ids), jnp.int32)
+    heads = (kv.kv_heads, kv.head_dim)
     k = np.asarray(kv.k[:, ids])
     v = np.asarray(kv.v[:, ids])
+    k = k.reshape(k.shape[:-1] + heads)
+    v = v.reshape(v.shape[:-1] + heads)
     if kv.quantized:
         return (k, v, np.asarray(kv.k_scale[:, ids]),
                 np.asarray(kv.v_scale[:, ids]))
@@ -378,22 +433,28 @@ def write_pages(kv: KVPages, page_ids: jax.Array, k: jax.Array,
                 v: jax.Array, k_scale: Optional[jax.Array] = None,
                 v_scale: Optional[jax.Array] = None) -> KVPages:
     """Splice whole pages into the pool — the import half of the
-    migration plane, shape-compatible with :func:`read_pages` output.
+    migration plane, shape-compatible with :func:`read_pages` output
+    (k/v [L, n, page, H_kv, D]; merged into the stored lanes here, a
+    reshape of the n imported pages only).
 
     page_ids: [n] int32 destination ids (pad rows with NULL_PAGE and
     zero payload: nothing reads the null page, so padded writes keep
     the jitted import ladder shape-stable).  Stored values go in
     verbatim — no re-quantization — so an exported int8 page arrives
     bit-identical, scales included.  Pure; returns the updated pool."""
-    kk = kv.k.at[:, page_ids].set(k.astype(kv.k.dtype))
-    vv = kv.v.at[:, page_ids].set(v.astype(kv.v.dtype))
+    lanes = k.shape[:3] + (kv.k.shape[-1],)
+    scales = {}
     if kv.quantized:
-        return KVPages(kk, vv,
-                       kv.k_scale.at[:, page_ids].set(
-                           k_scale.astype(jnp.float32)),
-                       kv.v_scale.at[:, page_ids].set(
-                           v_scale.astype(jnp.float32)))
-    return KVPages(kk, vv)
+        scales = dict(
+            k_scale=kv.k_scale.at[:, page_ids].set(
+                k_scale.astype(jnp.float32)),
+            v_scale=kv.v_scale.at[:, page_ids].set(
+                v_scale.astype(jnp.float32)))
+    return dataclasses.replace(
+        kv,
+        k=kv.k.at[:, page_ids].set(k.astype(kv.k.dtype).reshape(lanes)),
+        v=kv.v.at[:, page_ids].set(v.astype(kv.v.dtype).reshape(lanes)),
+        **scales)
 
 
 def gather_kv(kv: KVPages, layer: int, page_table: jax.Array):
@@ -406,14 +467,14 @@ def gather_kv(kv: KVPages, layer: int, page_table: jax.Array):
     Quantized pools are dequantized here with the shared
     :func:`dequantize_kv` rule, so the fallback reads the SAME stored
     values the kernel does and parity stays pinned."""
-    kl, vl = kv.k[layer], kv.v[layer]
+    kl, vl, ksl, vsl = layer_pages(kv, layer)
     b, pm = page_table.shape
     _, page, h, d = kl.shape
     k = kl[page_table]
     v = vl[page_table]
     if kv.quantized:
-        k = dequantize_kv(k, kv.k_scale[layer][page_table])
-        v = dequantize_kv(v, kv.v_scale[layer][page_table])
+        k = dequantize_kv(k, ksl[page_table])
+        v = dequantize_kv(v, vsl[page_table])
     return (k.reshape(b, pm * page, h, d), v.reshape(b, pm * page, h, d))
 
 

@@ -392,11 +392,9 @@ class DraftProposer(Proposer):
                 kv = append_token(kv, l, jnp.where(wmask, k, 0.0),
                                   jnp.where(wmask, vv, 0.0), pages, offs)
                 ctx = ragged_paged_attention(
-                    q, kv.k[l], kv.v[l], table, att_lens, seq, qpos,
-                    k_scale=kv.k_scale[l] if kv.k_scale is not None
-                    else None,
-                    v_scale=kv.v_scale[l] if kv.v_scale is not None
-                    else None, use_kernel=use_kernel)
+                    q, kv.k, kv.v, table, att_lens, seq, qpos, layer=l,
+                    k_scale=kv.k_scale, v_scale=kv.v_scale,
+                    use_kernel=use_kernel)
                 x = model.attn_out(params, l, ctx, x)
             logits = model.logits(params, x)
             return logits.reshape(b, r, -1), kv

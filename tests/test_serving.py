@@ -25,6 +25,8 @@ from paddle_tpu.serving import (DecoderLM, PagePool, PagedKVConfig, Request,
                                 paged_decode_attention,
                                 paged_decode_attention_reference)
 from paddle_tpu.serving.decode_attention import _paged_decode_pallas
+from paddle_tpu.serving.kv_cache import (fork_page, layer_pages, read_pages,
+                                         write_pages, zero_pages)
 from paddle_tpu.topology import LayerOutput, ParamSpec
 
 from conftest import assert_serving_drained as assert_drained  # noqa: E402
@@ -123,6 +125,88 @@ def test_append_token_and_gather_roundtrip(rng):
     np.testing.assert_allclose(np.asarray(k)[1, :7], toks[0, 1, :7], atol=0)
     # layer 0 untouched
     assert float(jnp.abs(kv.k[0]).max()) == 0.0
+
+
+def _filled_pool(rng, dtype):
+    """A 3-layer pool of 2 KV heads x 4 lanes with every token slot of
+    pages 1..5 written through ``append_token``; and what was written,
+    ``[kv, L, page, offset, H, D]``."""
+    cfg = PagedKVConfig(num_layers=3, num_heads=4, head_dim=4, page_size=4,
+                        num_pages=8, max_pages_per_seq=3, num_kv_heads=2,
+                        dtype=dtype)
+    kv = init_kv_pages(cfg)
+    rows = rng.randn(2, 3, 5, 4, 2, 4).astype(np.float32)
+    ids = jnp.asarray(np.repeat(np.arange(1, 6), 4), jnp.int32)
+    offs = jnp.asarray(np.tile(np.arange(4), 5), jnp.int32)
+    for l in range(3):
+        kv = append_token(kv, l, jnp.asarray(rows[0, l].reshape(20, 2, 4)),
+                          jnp.asarray(rows[1, l].reshape(20, 2, 4)), ids,
+                          offs)
+    return cfg, kv, rows
+
+
+@serving
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8],
+                         ids=["float32", "int8"])
+def test_pool_keeps_the_published_shape_at_the_host_boundary(rng, dtype):
+    """The pool is STORED ``[L, pages, page, H_kv * D]``; what leaves
+    and enters the device keeps the published ``[L, n, page, H_kv, D]``
+    (scales ``[L, n, page, H_kv]``): ``read_pages`` -> ``write_pages``
+    is the identity on stored values, ``fork_page`` and ``zero_pages``
+    move whole pages of every layer, and ``layer_pages`` is the same
+    bytes one layer at a time."""
+    cfg, kv, rows = _filled_pool(rng, dtype)
+    quant = dtype == jnp.int8
+    assert kv.k.shape == kv.v.shape == (3, 8, 4, 2 * 4)
+    assert kv.kv_heads == 2 and kv.quantized == quant
+    assert (kv.k_scale.shape == (3, 8, 4, 2)) if quant \
+        else kv.k_scale is None
+
+    k, v, ks, vs = read_pages(kv, [2, 5, 1])
+    assert k.shape == v.shape == (3, 3, 4, 2, 4) and k.dtype == dtype
+    if quant:
+        assert ks.shape == vs.shape == (3, 3, 4, 2)
+        got_k, got_v = k * ks[..., None], v * vs[..., None]
+        tol = np.abs(rows).max() / 127
+    else:
+        assert ks is None and vs is None
+        got_k, got_v, tol = k, v, 0.0
+    for i, page in enumerate([2, 5, 1]):
+        np.testing.assert_allclose(got_k[:, i], rows[0, :, page - 1],
+                                   atol=tol)
+        np.testing.assert_allclose(got_v[:, i], rows[1, :, page - 1],
+                                   atol=tol)
+    # one layer at a time: the same stored bytes, heads split out
+    for l in range(3):
+        kl, vl, ksl, vsl = layer_pages(kv, l)
+        assert kl.shape == (8, 4, 2, 4)
+        np.testing.assert_array_equal(np.asarray(kl)[[2, 5, 1]], k[l])
+        np.testing.assert_array_equal(np.asarray(vl)[[2, 5, 1]], v[l])
+        if quant:
+            np.testing.assert_array_equal(np.asarray(ksl)[[2, 5, 1]], ks[l])
+
+    # the splice: into a fresh pool at other page ids, bit for bit
+    dst = write_pages(init_kv_pages(cfg), jnp.asarray([6, 3, 7], jnp.int32),
+                      *(None if a is None else jnp.asarray(a)
+                        for a in (k, v, ks, vs)))
+    assert dst.k.shape == kv.k.shape and dst.head_dim == kv.head_dim
+    for a, b_ in zip(read_pages(dst, [6, 3, 7]), (k, v, ks, vs)):
+        assert (a is None and b_ is None) or np.array_equal(a, b_)
+    assert float(jnp.abs(dst.k[:, 1].astype(jnp.float32)).max()) == 0.0
+
+    # copy-on-write fork and the failure scrub, every layer at once
+    forked = fork_page(kv, jnp.int32(2), jnp.int32(7))
+    for a, b_ in zip(read_pages(forked, [7]), read_pages(kv, [2])):
+        assert (a is None and b_ is None) or np.array_equal(a, b_)
+    for a, b_ in zip(read_pages(forked, [1, 2, 3, 4, 5]),
+                     read_pages(kv, [1, 2, 3, 4, 5])):
+        assert (a is None and b_ is None) or np.array_equal(a, b_)
+    scrubbed = zero_pages(forked, jnp.asarray([2, 7], jnp.int32))
+    for a in read_pages(scrubbed, [2, 7]):
+        assert a is None or (a.shape[:3] == (3, 2, 4) and not a.any())
+    for a, b_ in zip(read_pages(scrubbed, [1, 3, 4, 5]),
+                     read_pages(kv, [1, 3, 4, 5])):
+        assert (a is None and b_ is None) or np.array_equal(a, b_)
 
 
 # ---------------------------------------------------------------------------

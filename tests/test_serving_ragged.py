@@ -27,6 +27,7 @@ from paddle_tpu.serving import (BLOCK_ROWS, DecoderLM, FaultPlan,
 from paddle_tpu.serving import decode_attention
 from paddle_tpu.serving.decode_attention import (QUANT_DRIFT_BOUND,
                                                  _ragged_pallas,
+                                                 _stored,
                                                  check_quant_drift,
                                                  heads_per_cell,
                                                  quant_parity_error)
@@ -49,6 +50,12 @@ def f32():
 # ---------------------------------------------------------------------------
 # mixed-batch construction helpers
 # ---------------------------------------------------------------------------
+
+
+def _ragged_pallas_layer(q, kp, vp, ks, vs, *rest):
+    """The kernel on ONE layer's published ``[P, page, KVH, D]`` pages,
+    taken as a stored pool of one layer."""
+    return _ragged_pallas(q, *_stored(kp, vp, ks, vs, None), *rest)
 
 
 def _build_mixed(rng, seqs, page, pm, num_pages, kvh, d, h):
@@ -131,7 +138,7 @@ def test_ragged_mixed_batch_matches_oracle(rng, case, kvh, h):
         jnp.asarray(qpos)))
     np.testing.assert_allclose(ref[real], want[real], rtol=2e-5, atol=2e-5)
 
-    ker = np.asarray(_ragged_pallas(
+    ker = np.asarray(_ragged_pallas_layer(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), None, None,
         jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(row_seq),
         jnp.asarray(qpos), float(d) ** -0.5, True))
@@ -213,7 +220,7 @@ def test_ragged_kernel_int8_reads_what_reference_reads(rng):
             jnp.asarray(qpos))
     ref = np.asarray(ragged_paged_attention_reference(
         jnp.asarray(q), kq, vq, *args, k_scale=ks, v_scale=vs))
-    ker = np.asarray(_ragged_pallas(
+    ker = np.asarray(_ragged_pallas_layer(
         jnp.asarray(q), kq, vq, ks, vs, *args, float(d) ** -0.5, True))
     real = qpos >= 0
     np.testing.assert_allclose(ker[real], ref[real], rtol=2e-5, atol=2e-5)
@@ -396,7 +403,7 @@ def test_ragged_kernel_heads_per_cell_parity(rng, monkeypatch, case, kvh, h,
     args = (jnp.asarray(q), kp, vp, ks, vs, jnp.asarray(table),
             jnp.asarray(kv_lens), jnp.asarray(row_seq), jnp.asarray(qpos),
             float(d) ** -0.5)
-    ker = np.asarray(_ragged_pallas(*args, True))
+    ker = np.asarray(_ragged_pallas_layer(*args, True))
     real = qpos >= 0
     if quantized:
         want = np.asarray(ragged_paged_attention_reference(
@@ -406,6 +413,164 @@ def test_ragged_kernel_heads_per_cell_parity(rng, monkeypatch, case, kvh, h,
     np.testing.assert_allclose(ker[real], want[real], rtol=2e-5, atol=2e-5)
     np.testing.assert_array_equal(ker, np.asarray(
         _parent_ragged_pallas(*args)))
+
+
+# ---------------------------------------------------------------------------
+# the pool where it lies (PR 30): whole stored pool + a layer index
+# ---------------------------------------------------------------------------
+
+
+def _stored_pool(rng, layers, layer, kp, vp, quantized):
+    """A stored pool ``[L, P, page, KVH * D]`` whose layer ``layer`` is
+    ``kp``/``vp`` and whose other layers are other random values (a read
+    of the wrong layer cannot pass), int8 with its ``[L, P, page, KVH]``
+    scales where asked; and the layer's own published view."""
+    k5 = rng.randn(layers, *kp.shape).astype(np.float32)
+    v5 = rng.randn(layers, *vp.shape).astype(np.float32)
+    k5[layer], v5[layer] = kp, vp
+    k5, v5, ks, vs = jnp.asarray(k5), jnp.asarray(v5), None, None
+    if quantized:
+        k5, ks = quantize_kv(k5)
+        v5, vs = quantize_kv(v5)
+    lanes = k5.shape[:3] + (k5.shape[3] * k5.shape[4],)
+    view = (k5[layer], v5[layer],
+            None if ks is None else ks[layer],
+            None if vs is None else vs[layer])
+    return (k5.reshape(lanes), v5.reshape(lanes), ks, vs), view
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("pool", ["float32", "int8"])
+@pytest.mark.parametrize("kvh,h", [(4, 4), (4, 8)])   # MHA and GQA
+@pytest.mark.parametrize("case", [MIXED_CASES[0], VERIFY_CASE],
+                         ids=["mixed", "verify_k5"])
+def test_ragged_kernel_on_the_whole_pool_reads_its_layer(rng, case, kvh, h,
+                                                        pool, layer):
+    """The kernel handed the WHOLE stored pool and a layer index reads
+    that layer and no other: bit for bit what it computes on the
+    layer's own view taken as a one-layer pool, and the reference path
+    on that view (which reads the same stored values, scales indexed by
+    the same layer) to float tolerance."""
+    page, pm, num_pages, d, layers = 8, 4, 32, 16, 5
+    q, kp, vp, table, kv_lens, row_seq, qpos, _, _ = _build_mixed(
+        rng, case, page, pm, num_pages, kvh, d, h)
+    (kl, vl, ksl, vsl), view = _stored_pool(rng, layers, layer, kp, vp,
+                                            pool == "int8")
+    rest = (jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(row_seq),
+            jnp.asarray(qpos))
+    q = jnp.asarray(q)
+    got = np.asarray(ragged_paged_attention(
+        q, kl, vl, *rest, layer=layer, k_scale=ksl, v_scale=vsl,
+        use_kernel=True, interpret=True))
+    alone = np.asarray(_ragged_pallas_layer(q, *view, *rest, float(d) ** -0.5,
+                                            True))
+    np.testing.assert_array_equal(got, alone)
+    ref = np.asarray(ragged_paged_attention_reference(
+        q, view[0], view[1], *rest, k_scale=view[2], v_scale=view[3]))
+    real = qpos >= 0
+    np.testing.assert_allclose(got[real], ref[real], rtol=2e-5, atol=2e-5)
+    # the reference path of the same entry takes the same layer
+    fall = np.asarray(ragged_paged_attention(
+        q, kl, vl, *rest, layer=layer, k_scale=ksl, v_scale=vsl,
+        use_kernel=False))
+    np.testing.assert_array_equal(fall[real], ref[real])
+
+
+@ragged
+@serving
+def test_ragged_kernel_layer_is_an_operand_not_a_constant(rng):
+    """The layer rides in as a traced scalar: ONE compiled program
+    serves every layer, and each call reads its own."""
+    page, pm, num_pages, kvh, h, d, layers = 8, 4, 32, 2, 4, 16, 3
+    q, kp, vp, table, kv_lens, row_seq, qpos, _, _ = _build_mixed(
+        rng, MIXED_CASES[0], page, pm, num_pages, kvh, d, h)
+    (kl, vl, _, _), _ = _stored_pool(rng, layers, 1, kp, vp, False)
+    rest = (jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(row_seq),
+            jnp.asarray(qpos))
+
+    @jax.jit
+    def attend(layer):
+        return ragged_paged_attention(jnp.asarray(q), kl, vl, *rest,
+                                      layer=layer, use_kernel=True,
+                                      interpret=True)
+
+    outs = [np.asarray(attend(jnp.int32(l))) for l in range(layers)]
+    assert attend._cache_size() == 1
+    for l in range(layers):
+        k4 = kl[l].reshape(num_pages, page, kvh, d)
+        v4 = vl[l].reshape(num_pages, page, kvh, d)
+        np.testing.assert_array_equal(outs[l], np.asarray(
+            _ragged_pallas_layer(jnp.asarray(q), k4, v4, None, None, *rest,
+                                 float(d) ** -0.5, True)))
+    assert not np.array_equal(outs[0], outs[1])
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` at any depth, with the
+    jaxpr it sits in."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append((eqn, jaxpr))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_engine_step_hands_the_kernel_the_pool_itself(kv_dtype):
+    """The compiled serving step, kernel path on: every ``pallas_call``
+    takes K and V (and an int8 pool's scales) from the pool's own
+    leaves through a reshape of LEADING dims only — no slice of a
+    layer, no transpose, no reshape that touches the two tiled dims —
+    and the kernel is traced once for all L layers."""
+    layers = 3
+    model = DecoderLM(vocab_size=50, num_layers=layers, num_heads=4,
+                      head_dim=8, num_kv_heads=2, max_positions=128)
+    eng = _engine(model, model.init_params(jax.random.PRNGKey(0)),
+                  use_kernel=True, kv_dtype=kv_dtype, page_size=8)
+    pb, k1, b = 8, 1, eng._max_slots
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)   # noqa: E731
+    closed = jax.make_jaxpr(eng._step_fn(pb, k1))(
+        eng.params, eng._kv, i32(b, k1), i32(b, k1),
+        jnp.zeros((b, k1), bool), i32(pb), i32(pb) - 1, i32(pb), i32(b),
+        i32(b, eng.kv_cfg.max_pages_per_seq), i32(b))
+    calls = [e for e, _ in _eqns(closed.jaxpr, "jit")
+             if e.params["name"] == "_ragged_call"]
+    assert len(calls) == layers
+    # one trace of the kernel, shared by the L call sites
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    pool_shapes = {"k": eng._kv.k.shape, "s": None if eng._kv.k_scale is None
+                   else eng._kv.k_scale.shape}
+    n_pool = 2 if kv_dtype == "float32" else 4
+    for e in calls:
+        # operands 1.. of _ragged_call are the pool's leaves, whole
+        got = [tuple(v.aval.shape) for v in e.invars[1:1 + n_pool]]
+        assert got[:2] == [pool_shapes["k"]] * 2, got
+        if n_pool == 4:
+            assert got[2:] == [pool_shapes["s"]] * 2, got
+    pallas = {id(e): (e, j) for e, j in _eqns(closed.jaxpr, "pallas_call")}
+    assert len(pallas) == 1          # inside the one shared trace
+    (eqn, inner), = pallas.values()
+    made_by = {v: q for q in inner.eqns for v in q.outvars}
+    # scalar prefetch (blk_seq, table, lens, layer), qpos, q, then K, V
+    # (and the scales)
+    for v in eqn.invars[6:6 + n_pool]:
+        src = made_by[v]
+        assert src.primitive.name == "reshape", src
+        (pool,) = src.invars
+        assert pool in inner.invars
+        assert tuple(pool.aval.shape)[2:] == tuple(v.aval.shape)[1:]
+        assert v.aval.shape[0] == pool.aval.shape[0] * pool.aval.shape[1]
+    # and nowhere in the step is a layer cut out of the pool
+    for name in ("slice", "dynamic_slice", "gather"):
+        for q, _ in _eqns(closed.jaxpr, name):
+            assert tuple(q.invars[0].aval.shape) not in (
+                pool_shapes["k"], pool_shapes["s"]), q
 
 
 @ragged
@@ -757,8 +922,11 @@ def test_gqa_engine_parity_vs_head_replicated_mha_oracle(rng):
     res = eng.run(max_ticks=200)
     for p, rid in zip(prompts, rids):
         assert res[rid] == greedy_decode_reference(mha, mp, p, 8, 1)
-    # the pool really stores only the KV heads
-    assert eng._kv.k.shape[3] == 2
+    # the pool really stores only the KV heads: 2 heads of 8 lanes each
+    # in the merged dim of the stored layout
+    assert eng._kv.kv_heads == 2
+    assert eng._kv.k.shape == (2, eng.kv_cfg.num_pages, eng.kv_cfg.page_size,
+                               2 * 8)
     assert_drained(eng)
 
 

@@ -453,3 +453,63 @@ def test_kernel_shard_map_matches_reference(rng):
     np.testing.assert_allclose(np.asarray(auto)[real],
                                np.asarray(want)[real],
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the stored pool under TP (PR 30): a chip's shard is its heads' lanes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kv_dtype,use_kernel", [
+    ("float32", False), ("float32", True), ("int8", False)],
+    ids=["float32", "float32_kernel", "int8"])
+def test_pool_shard_is_the_lanes_of_the_chips_own_heads(rng, kv_dtype,
+                                                       use_kernel):
+    """The pool is stored ``[L, pages, page, H_kv * D]`` and sharded on
+    the merged dim: device ``i`` of a TP=2 mesh holds lanes
+    ``[i * (H_kv/2) * D, (i+1) * (H_kv/2) * D)`` — its own KV heads, whole,
+    and no other's (an int8 pool's scales: its heads' columns) — the
+    kernel path (a ``shard_map`` handing each chip that shard and the
+    layer index) and the reference path serve the replicated engine's
+    tokens, and the shards hold what the replicated pool holds."""
+    from paddle_tpu.serving.kv_cache import read_pages
+
+    tp, kvh, d = 2, 4, 8
+    model = _model(num_heads=4, num_kv_heads=kvh, head_dim=d, layers=3)
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompts = [rng.randint(2, 64, size=n).tolist() for n in (5, 11, 17)]
+    kw = dict(kv_dtype=kv_dtype, use_kernel=use_kernel, prefix_cache=False)
+    rep = _engine(model, params, **kw)
+    eng = _engine(model, params, mesh=_mesh(tp), **kw)
+    want = _run_prompts(rep, prompts)
+    if kv_dtype == "float32":
+        # (int8 agreement across placements is a chip-level contract)
+        assert _run_prompts(eng, prompts) == want
+    else:
+        _run_prompts(eng, prompts)
+    lanes = kvh * d
+    assert eng._kv.k.shape == (3, 64, 4, lanes)
+    pages = list(range(1, 64))
+    whole = read_pages(eng._kv, pages)       # published [L, n, page, H, D]
+    for leaf, pub, width in ((eng._kv.k, whole[0], lanes),
+                             (eng._kv.v, whole[1], lanes),
+                             (eng._kv.k_scale, whole[2], kvh),
+                             (eng._kv.v_scale, whole[3], kvh)):
+        if leaf is None:
+            assert pub is None
+            continue
+        shards = sorted(leaf.addressable_shards, key=lambda s: s.device.id)
+        assert len(shards) == tp
+        for i, sh in enumerate(shards):
+            assert sh.index[3] == slice(i * width // tp,
+                                        (i + 1) * width // tp)
+            assert sh.data.shape == (3, 64, 4, width // tp)
+            heads = pub[:, :, :, i * kvh // tp:(i + 1) * kvh // tp]
+            np.testing.assert_array_equal(
+                np.asarray(sh.data)[:, 1:],
+                heads.reshape(heads.shape[:3] + (-1,)))
+    if kv_dtype == "float32":
+        # the same served traffic left the same K/V in both pools
+        for a, b in zip(whole[:2], read_pages(rep._kv, pages)[:2]):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert any(np.asarray(whole[0]).any(axis=(0, 2, 3, 4)))

@@ -170,6 +170,61 @@ def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
     assert _compile(fn, *avals) == 1
 
 
+# ---- the pool where it lies: the step's kernel on the WHOLE stored pool ----
+
+POOL_CASES = [
+    # chips, layers, pages, heads (= KV heads), rows: a decode + prefill tick
+    (4, 32, 128, 32, 32 * 8 + 256),   # serve-6.7b-tp4-chat: a chip's shard
+                                      # is [32 * 128, 128, 1024] f32 to the
+                                      # kernel, 2.1 GB each of K and V
+    (1, 24, 128, 16, 32 * 8 + 256),   # the one-chip 1.3B: [24 * 128, 128,
+                                      # 2048], 3.2 GB each
+]
+
+
+@pytest.mark.parametrize("chips,layers,pages,h,t", POOL_CASES,
+                         ids=["6.7b_tp4_shard", "1.3b_one_chip"])
+def test_ragged_kernel_on_the_whole_pool_compiles_for_v5e(topo, chips, layers,
+                                                          pages, h, t):
+    """The serving step's call: K and V are the pool's leaves as stored
+    (``[L, pages, page, H * D]``), the layer a traced scalar that rides
+    with the scalar-prefetch operands.  The optimized program holds the
+    kernel and NO copy, slice or transpose of the pool or of a layer of
+    it: the kernel's operand is a bitcast of the parameter."""
+    import re
+
+    d, page, slots, pm = 128, 128, 32, 10
+    if chips == 1:
+        place = lambda spec: _on(SingleDeviceSharding(topo.devices[0]))  # noqa: E731,E501
+    else:
+        mesh = Mesh(np.asarray(topo.devices), ("model",))
+        place = lambda spec: _on(NamedSharding(mesh, spec))  # noqa: E731
+    q = place(P(None, "model", None))((t, h, d), jnp.float32)
+    pool = place(P(None, None, None, "model"))((layers, pages, page, h * d),
+                                               jnp.float32)
+    i32 = lambda *shape: place(P())(shape, jnp.int32)  # noqa: E731
+
+    def fn(q, k, v, layer, table, lens, row_seq, qpos):
+        kw = dict(layer=layer, use_kernel=True, interpret=False)
+        if chips == 1:
+            return ragged_paged_attention(q, k, v, table, lens, row_seq,
+                                          qpos, **kw)
+        return ragged_paged_attention_tp(mesh, "model", q, k, v, table, lens,
+                                         row_seq, qpos, **kw)
+
+    text = jax.jit(fn).lower(q, pool, pool, i32(), i32(slots, pm),
+                             i32(slots), i32(t), i32(t)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    shard = f"{layers},{pages},{page},{h * d // chips}"
+    rows = f"{layers * pages},{page},{h * d // chips}"
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf" = f32\[(?:{shard}|{rows}|{pages},{page},)"
+                          r"[\d,]*\][^ ]* "
+                          r"(?:copy|slice|dynamic-slice|transpose|reshape|"
+                          r"fusion|copy-start)\(", line)]
+    assert not moved, moved
+
+
 # ---- the latent-attention and expert-layer kernels at their cell's widths ---
 
 @pytest.mark.parametrize("mode", ["fwd", "bwd_pallas"])
