@@ -48,8 +48,7 @@ class SmokeConfig:
     seed: int = 0
     # train: the batch that fit one v5e with Momentum state.  The cost is
     # a per-sequence SUM over seq tokens, so its gradient is ~seq times a
-    # per-token mean's: lr 0.01 (bench.py's, never checked against the
-    # cost) diverges on the chip by the third step
+    # per-token mean's: lr 0.01 diverges on the chip by the third step
     batch: int = 4
     steps: int = 6
     lr: float = 5e-4

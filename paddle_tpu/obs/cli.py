@@ -29,8 +29,8 @@ def seeded_chaos(num_replicas: int = 4, seed: int = 0,
     2 slowed to every other tick.  Returns ``(tracer, fleet, frids)``
     after a full drain (conservation checked).
 
-    Lives here (not in a test) so the CLI, the bench, and the obs tests
-    all replay the SAME trace — and so "byte-identical across two
+    Lives here (not in a test) so the CLI and the obs tests both
+    replay the SAME trace — and so "byte-identical across two
     replays" is checked against one definition of the replay."""
     import jax
     import numpy as np

@@ -4,15 +4,15 @@ series, one ``snapshot()`` / ``to_text()`` surface.
 Before this module every layer kept its own counters —
 ``ServingMetrics`` (per engine), ``FleetMetrics`` (per router),
 ``platform/stats.StatSet`` (the trainer's timer table), the engine's
-``healthz()`` — each with a private dict shape, so a scraper (or
-``bench.py``) had to know every layer's spelling.  Now each of those
+``healthz()`` — each with a private dict shape, so a scraper had to
+know every layer's spelling.  Now each of those
 *publishes into* one :class:`MetricsRegistry` (``ServingMetrics.publish``
 / ``FleetMetrics.publish`` / ``StatSet.publish``) and everything reads
 one surface:
 
 - ``snapshot()`` — flat JSON-able dict ``{"name{k=v,...}": value}``
   (histograms contribute ``_count`` / ``_sum`` / ``_max`` series), the
-  shape ``bench.py`` workers and ``healthz()`` consume;
+  shape ``healthz()`` and the benchmark's train driver consume;
 - ``to_text()`` — Prometheus-style exposition for an external scraper.
 
 Series are keyed by sorted label tuples, so two publishers using the
@@ -48,7 +48,7 @@ def _label_str_quoted(key: LabelKey) -> str:
     """Exposition-format spelling: label VALUES are double-quoted
     (``replica="0"``) — a real Prometheus scraper rejects the whole
     scrape otherwise.  ``snapshot()`` keys keep the unquoted spelling
-    (the compact bench/healthz dict contract)."""
+    (the compact healthz dict contract)."""
     return ",".join(f'{k}="{v}"' for k, v in key)
 
 
@@ -242,7 +242,7 @@ class MetricsRegistry:
         """Flat ``{"name{labels}": value}`` dict (deterministic order:
         names, then label keys).  Histograms flatten to ``_count`` /
         ``_sum`` / ``_max`` entries, so the whole snapshot is one level
-        of JSON-able floats — the ``bench.py`` one-line contract."""
+        of JSON-able floats."""
         out: Dict[str, float] = {}
         for m in self.metrics():
             for key, s in m.series():

@@ -24,8 +24,8 @@ Design notes (TPU-first):
     through the grid, dQ with the KEY axis streamed), each recomputing P
     blockwise from (q, k, lse) — the S x S score matrix never exists in
     either direction, and neither kernel holds a full sequence in VMEM.
-    FLAGS.use_pallas=False falls back to a blockwise lax.scan in plain JAX
-    with identical semantics.
+    There is no plain-JAX backward: ``jax.grad`` through ``mha_reference``
+    is the oracle the tests compare against.
   - causal masking skips fully-masked blocks with pl.when AND clamps the
     streamed-tile index maps, so the revisiting optimisation elides the DMA
     for blocks that would be skipped (~half the grid for causal).
@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+_BLOCK_LADDER = (512, 256, 128)   # default tile edges, largest first
 
 
 from paddle_tpu.ops.kernel_util import interpret_default as _interpret_default
@@ -515,55 +516,6 @@ def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# Backward: blockwise scan over key blocks (plain JAX fallback)
-# ---------------------------------------------------------------------------
-
-def _flash_bwd(res, do, *, causal, sm_scale, block_k):
-    q, k, v, q_seg, kv_seg, out, lse = res
-    batch, seq_q, heads, head_dim = q.shape
-    seq_k = k.shape[1]
-    block_k = min(block_k, seq_k)
-    nkb = seq_k // block_k
-
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    delta = jnp.sum(dof * out.astype(jnp.float32), axis=-1)  # (B,Sq,H)
-    q_ids = jnp.arange(seq_q)
-    k_ids_all = jnp.arange(seq_k).reshape(nkb, block_k)
-    k_blocks = k.reshape(batch, nkb, block_k, heads, head_dim)
-    v_blocks = v.reshape(batch, nkb, block_k, heads, head_dim)
-    kseg_blocks = kv_seg.reshape(batch, nkb, block_k)
-
-    def one_block(dq_acc, blk):
-        kb, vb, ksegb, kids = blk  # kb: (B, block_k, H, D)
-        s = jnp.einsum("bqhd,bkhd->bqhk", qf, kb.astype(jnp.float32))
-        s = s * sm_scale
-        mask = (q_seg[:, :, None, None] == ksegb[:, None, None, :])
-        if causal:
-            mask = mask & (q_ids[None, :, None, None] >= kids[None, None, None, :])
-        s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse.transpose(0, 2, 1)[:, :, :, None])  # (B,Sq,H,bk)
-        p = jnp.where(mask, p, 0.0)
-        dv = jnp.einsum("bqhk,bqhd->bkhd", p, dof)
-        dp = jnp.einsum("bqhd,bkhd->bqhk", dof, vb.astype(jnp.float32))
-        ds = p * (dp - delta[:, :, :, None]) * sm_scale
-        dq_acc = dq_acc + jnp.einsum("bqhk,bkhd->bqhd", ds,
-                                     kb.astype(jnp.float32))
-        dk = jnp.einsum("bqhk,bqhd->bkhd", ds, qf)
-        return dq_acc, (dk, dv)
-
-    dq0 = jnp.zeros((batch, seq_q, heads, head_dim), jnp.float32)
-    dq, (dk_b, dv_b) = jax.lax.scan(
-        one_block, dq0,
-        (k_blocks.transpose(1, 0, 2, 3, 4), v_blocks.transpose(1, 0, 2, 3, 4),
-         kseg_blocks.transpose(1, 0, 2), k_ids_all))
-    dk = dk_b.transpose(1, 0, 2, 3, 4).reshape(batch, seq_k, heads, head_dim)
-    dv = dv_b.transpose(1, 0, 2, 3, 4).reshape(batch, seq_k, heads, head_dim)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-            None, None)
-
-
-# ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
 
@@ -583,14 +535,9 @@ def _fwd_rule(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
 
 
 def _bwd_rule(causal, sm_scale, block_q, block_k, interpret, pv_f32, res, do):
-    from paddle_tpu.platform.flags import FLAGS
-
-    if FLAGS.use_pallas:
-        return _flash_bwd_pallas(res, do, causal=causal, sm_scale=sm_scale,
-                                 block_q=block_q, block_k=block_k,
-                                 interpret=interpret, pv_f32=pv_f32)
-    return _flash_bwd(res, do, causal=causal, sm_scale=sm_scale,
-                      block_k=block_k)
+    return _flash_bwd_pallas(res, do, causal=causal, sm_scale=sm_scale,
+                             block_q=block_q, block_k=block_k,
+                             interpret=interpret, pv_f32=pv_f32)
 
 
 _flash_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -616,21 +563,17 @@ def flash_attention(q, k, v, segment_ids=None, kv_segment_ids=None,
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    # FLAGS.attn_block retunes the DEFAULT tile edge only — call sites that
-    # chose their blocks explicitly (ring/ulysses shard-sized tiles, tests)
-    # are never trampled.  The auto default picks the largest tile that
-    # divides the sequence: streaming keeps VMEM at O(block^2), so big tiles
-    # are free memory-wise and each grid cell amortizes its fixed cost over
-    # 16x more MXU work than a 128 tile (measured: 128 tiles at seq 4096 =
-    # 32k grid cells of ~760ns overhead each, dwarfing the matmuls).
     from paddle_tpu.platform.flags import FLAGS
 
+    # The default tile edge is the largest of the ladder that divides the
+    # sequence (call sites that chose their blocks — ring/ulysses
+    # shard-sized tiles, tests — keep them): streaming keeps VMEM at
+    # O(block^2), so big tiles are free memory-wise and each grid cell
+    # amortizes its fixed cost over 16x more MXU work than a 128 tile
+    # (measured: 128 tiles at seq 4096 = 32k grid cells of ~760ns overhead
+    # each, dwarfing the matmuls).
     def _auto_block(seq):
-        # the flag retunes the preferred edge but still falls through the
-        # ladder when it doesn't divide this call's sequence (a global flag
-        # must never crash an oddly-sized layer the auto path handles)
-        preferred = (int(FLAGS.attn_block),) if FLAGS.attn_block else ()
-        for edge in preferred + (512, 256, 128):
+        for edge in _BLOCK_LADDER:
             if seq % edge == 0:
                 return edge
         return 128  # small/ragged seqs: min() below clamps to seq

@@ -282,7 +282,7 @@ def host_tree(tree):
 
 def opt_state_bytes_per_device(tree) -> int:
     """Exact per-device bytes of a (possibly sharded) state pytree — the
-    bench/acceptance metric for the N x optimizer-state reduction."""
+    acceptance metric for the N x optimizer-state reduction."""
     import jax
 
     total = 0

@@ -89,9 +89,9 @@ FLAGS.define("mesh_shape", "", "comma dims for the device mesh, e.g. '8' or '2,4
 FLAGS.define("mesh_axes", "data", "comma axis names matching mesh_shape")
 FLAGS.define("use_bf16", True, "compute matmuls/convs in bfloat16 on TPU")
 FLAGS.define("use_pallas", True,
-             "use hand-written pallas TPU kernels for the hot ops "
-             "(flash-attention backward, fused LSTM cell); off = plain "
-             "JAX/XLA fallbacks with identical semantics")
+             "use the hand-written pallas fused LSTM/GRU cells of "
+             "ops/rnn.py; off = their plain JAX/XLA scans with identical "
+             "semantics (attention has no such switch)")
 FLAGS.define("bf16_activations", True,
              "store inter-layer image activations in bfloat16 (halves HBM "
              "traffic between fused conv blocks; stats/losses stay f32). "
@@ -101,14 +101,6 @@ FLAGS.define("bf16_dense_activations", False,
              "residual stream) in bfloat16. Norm statistics and losses "
              "still reduce in f32. Off by default: flip for bandwidth-"
              "bound dense models. Only active when use_bf16 is also on.")
-FLAGS.define("attn_block", 0,
-             "flash-attention tile edge (query AND key block size). 0 = "
-             "auto: the largest of 512/256/128 that divides the sequence "
-             "(small/ragged seqs clamp to the sequence length). A nonzero "
-             "value is tried first, falling through the same ladder when "
-             "it does not divide. Larger tiles amortize per-block "
-             "overhead; VMEM use is O(block^2) so 256/512 still fit.",
-             parser=int)
 FLAGS.define("attn_pv_f32", False,
              "keep the flash-attention PV-matmul operands (softmax probs "
              "and V, plus the backward dS/P operands) in f32 instead of "

@@ -1,5 +1,5 @@
 """Virtual-CPU platform forcing, shared by every driver-facing entry
-point (__graft_entry__, bench.py, tests/conftest.py).
+point (__graft_entry__, tests/conftest.py).
 
 The simulation trick: XLA's host platform splits into N virtual devices
 when ``--xla_force_host_platform_device_count=N`` is set BEFORE the CPU

@@ -8,17 +8,16 @@ gradient-poison schedule:
   blob write and meta commit, step-granular async checkpoints, and the
   resume supervisor restarting after every death.
 
-The acceptance bar (ISSUE 14 / ``worker_train_chaos``): the chaos run's
+The acceptance bar (ISSUE 14): the chaos run's
 final parameters and optimizer slots are BIT-IDENTICAL to the control's,
 its per-step loss trajectory matches exactly, every injected non-finite
 step was skipped with slots untouched, every death resumed from a
 verified checkpoint, no surviving artifact is corrupt, and the torn save
-left the previous checkpoint loadable.  The bench worker reports the
-numbers; ``python -m paddle_tpu.resilience check`` turns any violation
-into exit 1 (tier-1 ladder exit 10); tests/test_resilience.py pins the
-pieces individually.
+left the previous checkpoint loadable.  ``python -m
+paddle_tpu.resilience check`` turns any violation into exit 1 (tier-1
+ladder exit 10); tests/test_resilience.py pins the pieces individually.
 
-Shared by CLI, bench and tests so "bit-identical across chaos" has ONE
+Shared by CLI and tests so "bit-identical across chaos" has ONE
 definition (the ``obs.cli.seeded_chaos`` precedent).
 """
 
@@ -40,7 +39,7 @@ KILL_SAVE = {4: "meta"}
 
 def _build_trainer(guard=None, faults=None, tracer=None, seed=5, lr=0.1):
     """The scenario's small classifier — ONE definition shared by the
-    CLI gate, the bench worker AND tests/test_resilience.py, so every
+    CLI gate AND tests/test_resilience.py, so every
     consumer of "bit-identical across chaos" pins the same model."""
     import paddle_tpu as paddle
     from paddle_tpu import layer, optimizer, trainer

@@ -27,8 +27,7 @@ Timing is accounted on an injectable clock-free basis (perf counters on
 the host; this module is trainer-side, not under the serving/obs
 injected-clock lint scope): ``stall_s`` totals what the train loop
 actually waited (snapshot + any wait on a previous write), ``write_s``
-totals background disk time — the bench's headline async win is their
-ratio.
+totals background disk time — the async win is their ratio.
 """
 
 from __future__ import annotations
@@ -62,7 +61,7 @@ class AsyncCheckpointer:
         self._thread: Optional[threading.Thread] = None
         # guarded_by(serialized: depth-one writer; join happens-before)
         self._error: Optional[BaseException] = None
-        # counters (host-side bookkeeping, read by bench/tests)
+        # counters (host-side bookkeeping, read by tests)
         self.saves = 0   # guarded_by(serialized: training thread only)
         # guarded_by(serialized: writer thread, join() happens-before)
         self.commits = 0

@@ -107,7 +107,7 @@ def select_good(good, new_tree, old_tree):
     pytrees — the skip-step select.  On a good step this is the
     identity on ``new``; on a bad one params/slots/model-state come out
     bit-identical to their pre-step values (pinned vs an uninterrupted
-    control by the chaos bench)."""
+    control by ``resilience.chaos.seeded_chaos``)."""
     import jax
     import jax.numpy as jnp
 

@@ -8,8 +8,7 @@ control and preemption (``scheduler``), and the user-facing
 """
 
 from paddle_tpu.serving.decode_attention import (
-    BLOCK_ROWS, attention_path, paged_decode_attention,
-    paged_decode_attention_reference, ragged_paged_attention,
+    BLOCK_ROWS, attention_path, ragged_paged_attention,
     ragged_paged_attention_reference, ragged_paged_attention_tp)
 from paddle_tpu.serving.control import (DEFAULT_CLASSES, AdmissionLedger,
                                         Autoscaler, AutoscalePolicy,
@@ -31,7 +30,7 @@ from paddle_tpu.serving.kv_cache import (NULL_PAGE, KVPages, PagedKVConfig,
                                          init_kv_pages, layer_pages,
                                          pages_for_budget,
                                          prefix_chain_hashes, quantize_kv,
-                                         resolve_kv_dtype, write_prompt)
+                                         resolve_kv_dtype)
 from paddle_tpu.serving.metrics import FleetMetrics, ServingMetrics
 from paddle_tpu.serving.migrate import (MigrationBlob,
                                         check_migration_conservation,
@@ -44,12 +43,11 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
 
 __all__ = [
     "ServingEngine", "DecodeModel", "DecoderLM", "greedy_decode_reference",
-    "paged_decode_attention", "paged_decode_attention_reference",
     "ragged_paged_attention", "ragged_paged_attention_reference",
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",
     "validate_tp",
     "PagedKVConfig", "KVPages", "PagePool", "PrefixCache", "NULL_PAGE",
-    "init_kv_pages", "layer_pages", "append_token", "write_prompt",
+    "init_kv_pages", "layer_pages", "append_token",
     "gather_kv", "fork_page", "prefix_chain_hashes", "quantize_kv",
     "dequantize_kv",
     "pages_for_budget", "resolve_kv_dtype",

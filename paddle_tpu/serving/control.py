@@ -276,7 +276,7 @@ class WeightedFairQueue:
     flooding 10x traffic only pushes ITS OWN finish tags far into the
     virtual future — other tenants' tags stay near ``vtime`` and keep
     being served at their weighted share, which is exactly the
-    cross-tenant isolation the storm bench asserts."""
+    cross-tenant isolation tests/test_serving_control.py asserts."""
 
     def __init__(self):
         self._queues: Dict[str, Deque[Tuple[float, object]]] = {}
@@ -372,7 +372,7 @@ class AutoscalePolicy:
 class Autoscaler:
     """Joins/drains replicas from registry signals on the fleet's
     clock.  Stateless between fleets; all counters are public so the
-    bench and the gate can assert the loop actually acted:
+    tests and the gate can assert the loop actually acted:
 
     - ``scale_ups`` / ``scale_downs`` — actions taken;
     - ``replica_ticks`` — alive-replica x tick integral, the

@@ -78,8 +78,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.attention import (DEFAULT_MASK_VALUE, _dim_semantics,
-                                      mha_reference)
+from paddle_tpu.ops.attention import DEFAULT_MASK_VALUE, _dim_semantics
 from paddle_tpu.ops.kernel_util import interpret_default as _interpret_default
 from paddle_tpu.platform.enforce import enforce_that
 from paddle_tpu.serving.kv_cache import (KVPages, dequantize_kv,
@@ -109,7 +108,7 @@ def attention_path(head_dim: int, page_size: int, *,
                    use_kernel: Optional[bool] = None,
                    interpret: Optional[bool] = None) -> str:
     """THE chooser: every paged-attention dispatch (ragged kernel,
-    decode wrapper, engine step builder) routes through this one gate,
+    engine step builder) routes through this one gate,
     so odd head dims / tiny pages / mismatched head groups fall back to
     the reference path at a single point instead of per-call-site
     guesswork.  Returns ``"kernel"`` or ``"reference"``.
@@ -158,11 +157,6 @@ def heads_per_cell(num_kv_heads: int, page_size: int, head_dim: int,
                 hb * per_head + scales <= _KV_VMEM_BUDGET:
             return hb
     return 1
-
-
-def _kernel_shape_ok(head_dim: int, page_size: int) -> bool:
-    """Back-compat shim over :func:`attention_path` (v1 name)."""
-    return attention_path(head_dim, page_size, interpret=False) == "kernel"
 
 
 # ---------------------------------------------------------------------------
@@ -302,24 +296,6 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
             l = jnp.max(l_scr[h], axis=1, keepdims=True)
             l = jnp.where(l == 0.0, 1.0, l)  # length-0 rows -> zeros, not NaN
             o_ref[h, 0] = (acc_scr[h] / l).astype(o_ref.dtype)
-
-
-def _stored(k_pages, v_pages, k_scale, v_scale, layer):
-    """The two pool forms the public entries take, as the stored one:
-    ``(k, v, k_scale, v_scale, layer)`` with k/v ``[L, pages, page,
-    KVH * D]`` (``kv_cache.KVPages``' leaves) and scales ``[L, pages,
-    page, KVH]``.  With a ``layer`` the operands already are the pool.
-    ``layer=None`` says ``k_pages`` is ONE layer's published
-    ``[pages, page, KVH, D]``: it is taken as a pool of one layer
-    (tests and the v1 decode wrapper come this way; on a TPU that
-    reshape re-tiles the layer, which is why the engine does not)."""
-    if layer is not None:
-        return k_pages, v_pages, k_scale, v_scale, layer
-    lanes = (1,) + k_pages.shape[:2] + (k_pages.shape[2] * k_pages.shape[3],)
-    if k_scale is not None:
-        k_scale, v_scale = k_scale[None], v_scale[None]
-    return (k_pages.reshape(lanes), v_pages.reshape(lanes), k_scale,
-            v_scale, 0)
 
 
 def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
@@ -462,19 +438,41 @@ def _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer, *rest,
                                      sm_scale=sm_scale)
 
 
-def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
-                           row_seq, qpos, *, layer=None, k_scale=None,
+def _pool_dims(q, k_pool, v_pool, k_scale):
+    """``(page, KVH)`` of the stored pool the public entries take,
+    refusing an operand that cannot be one for this ``q``.  (Shapes
+    cannot tell one layer's f32 ``[pages, page, KVH, D]`` from a
+    one-head pool ``[L, pages, page, D]``: the required ``layer`` is
+    what asks the caller which it holds.)"""
+    d = q.shape[-1]
+    enforce_that(
+        k_pool.ndim == 4 and k_pool.shape == v_pool.shape
+        and k_pool.shape[3] % d == 0
+        and (k_scale is None or
+             k_scale.shape == k_pool.shape[:3] + (k_pool.shape[3] // d,)),
+        f"ragged attention takes the STORED pool [L, pages, page, KVH * D] "
+        f"(int8 scales [L, pages, page, KVH]) and a layer index, not one "
+        f"layer's [pages, page, KVH, D]; got k {tuple(k_pool.shape)}, "
+        f"v {tuple(v_pool.shape)}, scales "
+        f"{None if k_scale is None else tuple(k_scale.shape)} for head "
+        f"dim {d}", context="serving")
+    return k_pool.shape[2], k_pool.shape[3] // d
+
+
+def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
+                           row_seq, qpos, *, layer, k_scale=None,
                            v_scale=None, sm_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None):
     """Ragged paged attention over a sequence-packed mixed batch (see
     :func:`ragged_paged_attention_reference` for the semantics).
 
-    With ``layer`` (an int or a traced scalar) ``k_pages``/``v_pages``
-    are the WHOLE stored pool ``[L, pages, page, KVH * D]`` (scales
-    ``[L, pages, page, KVH]``) — how the engine calls, so that the
-    kernel reads the pool where it lies; without, one layer's
-    ``[pages, page, KVH, D]`` (see :func:`_stored`).
+    ``k_pool``/``v_pool`` are the WHOLE stored pool ``[L, pages, page,
+    KVH * D]`` (``kv_cache.KVPages``' leaves; scales ``[L, pages, page,
+    KVH]``) and ``layer`` (an int or a traced scalar) the layer to
+    attend over, so that the kernel reads the pool where it lies.  One
+    layer's ``[pages, page, KVH, D]`` is the reference's operand
+    (``kv_cache.layer_pages``), not this entry's.
 
     ``use_kernel=None`` auto-selects through :func:`attention_path`; the
     kernel additionally requires block-uniform :data:`BLOCK_ROWS`
@@ -484,24 +482,24 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, kv_lens,
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    pool = _stored(k_pages, v_pages, k_scale, v_scale, layer)
-    _, _, page, lanes = pool[0].shape
+    page, kvh = _pool_dims(q, k_pool, v_pool, k_scale)
     path = attention_path(q.shape[-1], page, num_heads=q.shape[1],
-                          num_kv_heads=lanes // q.shape[-1],
-                          quantized=k_scale is not None,
+                          num_kv_heads=kvh, quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
     if path == "kernel":
-        return _ragged_pallas(q, *pool, page_table.astype(jnp.int32),
+        return _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
+                              page_table.astype(jnp.int32),
                               kv_lens.astype(jnp.int32),
                               row_seq.astype(jnp.int32),
                               qpos.astype(jnp.int32),
                               float(sm_scale), bool(interpret))
-    return _reference_on_layer(q, *pool, page_table, kv_lens, row_seq, qpos,
+    return _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer,
+                               page_table, kv_lens, row_seq, qpos,
                                sm_scale=sm_scale)
 
 
-def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
-                              kv_lens, row_seq, qpos, *, layer=None,
+def ragged_paged_attention_tp(mesh, axis, q, k_pool, v_pool, page_table,
+                              kv_lens, row_seq, qpos, *, layer,
                               k_scale=None, v_scale=None,
                               sm_scale: Optional[float] = None,
                               use_kernel: Optional[bool] = None,
@@ -520,7 +518,7 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
     partition a custom kernel), which is why the TP engine routes its
     kernel path through here.  The GQA group factor is shard-invariant
     (``(H/TP) / (H_kv/TP) == H/H_kv``), so head-group packing is
-    untouched.  Pool forms as in :func:`ragged_paged_attention`.
+    untouched.  Operands as in :func:`ragged_paged_attention`.
 
     Dispatch routes through :func:`attention_path` like every other
     entry point (the per-SHARD head counts decide): shapes the chooser
@@ -535,11 +533,9 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pages, v_pages, page_table,
     if interpret is None:
         interpret = _interpret_default()
     tp = int(mesh.shape[axis])
-    k_pool, v_pool, k_scale, v_scale, layer = _stored(
-        k_pages, v_pages, k_scale, v_scale, layer)
-    _, _, page, lanes = k_pool.shape
+    page, kvh = _pool_dims(q, k_pool, v_pool, k_scale)
     path = attention_path(q.shape[-1], page, num_heads=q.shape[1] // tp,
-                          num_kv_heads=lanes // q.shape[-1] // tp,
+                          num_kv_heads=kvh // tp,
                           quantized=k_scale is not None,
                           use_kernel=use_kernel, interpret=interpret)
     if path != "kernel":
@@ -645,33 +641,10 @@ def check_quant_drift(q, k_pages, v_pages, page_table, kv_lens, row_seq,
     return err
 
 
-# ---------------------------------------------------------------------------
-# Decode-only wrappers (v1 API, now thin views over the ragged paths)
-# ---------------------------------------------------------------------------
-
-def paged_decode_attention_reference(q, k_pages, v_pages, page_table,
-                                     lengths, sm_scale: Optional[float]
-                                     = None, *, k_scale=None, v_scale=None):
-    """Decode-only oracle: one row per sequence at position len-1.
-
-    q: [B, H, D]; k_pages/v_pages: [num_pages, page, H_kv, D];
-    page_table: [B, max_pages_per_seq] int32; lengths: [B] int32 (the
-    query's K/V already appended, so lengths INCLUDES it).  Rows with
-    length 0 return an arbitrary finite value; the engine never reads
-    them."""
-    b = q.shape[0]
-    row_seq = jnp.arange(b, dtype=jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    return ragged_paged_attention_reference(
-        q, k_pages, v_pages, page_table, lengths, row_seq, lengths - 1,
-        k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
-
-
 def expand_decode_rows(q, qpos, rows_per_seq: int = 1):
     """Pad per-sequence decode/verify rows to whole :data:`BLOCK_ROWS`
     blocks — THE one copy of the kernel's one-sequence-per-block
-    packing for decode rows (the decode wrapper and the engine's
-    unified step both build on it, so the contract can't silently fork).
+    packing for decode rows.
 
     ``q`` is ``[B * rows_per_seq, H, D]`` sequence-major: sequence
     ``i`` owns rows ``i*rows_per_seq .. (i+1)*rows_per_seq - 1``
@@ -697,42 +670,3 @@ def expand_decode_rows(q, qpos, rows_per_seq: int = 1):
                  ((0, 0), (0, pad)),
                  constant_values=-1).reshape(b * rbk)
     return qe, row_seq, qp
-
-
-def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, sm_scale,
-                         interpret: bool, k_scale=None, v_scale=None):
-    qe, row_seq, qpos = expand_decode_rows(q, lengths.astype(jnp.int32) - 1)
-    out = _ragged_pallas(qe, *_stored(k_pages, v_pages, k_scale, v_scale,
-                                      None),
-                         page_table.astype(jnp.int32),
-                         lengths.astype(jnp.int32), row_seq, qpos,
-                         float(sm_scale), bool(interpret))
-    return out[::BLOCK_ROWS]
-
-
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                           sm_scale: Optional[float] = None,
-                           use_kernel: Optional[bool] = None,
-                           interpret: Optional[bool] = None,
-                           k_scale=None, v_scale=None):
-    """Decode-step attention over a paged KV cache (v1 entry point,
-    kept for callers that only ever decode).  Dispatch routes through
-    :func:`attention_path` like everything else."""
-    if sm_scale is None:
-        sm_scale = float(q.shape[-1]) ** -0.5
-    if interpret is None:
-        interpret = _interpret_default()
-    path = attention_path(q.shape[-1], k_pages.shape[1],
-                          num_heads=q.shape[1],
-                          num_kv_heads=k_pages.shape[2],
-                          quantized=k_scale is not None,
-                          use_kernel=use_kernel, interpret=interpret)
-    if path != "kernel":
-        return paged_decode_attention_reference(
-            q, k_pages, v_pages, page_table, lengths, sm_scale=sm_scale,
-            k_scale=k_scale, v_scale=v_scale).astype(q.dtype)
-    return _paged_decode_pallas(q, k_pages, v_pages,
-                                page_table.astype(jnp.int32),
-                                lengths.astype(jnp.int32),
-                                float(sm_scale), bool(interpret),
-                                k_scale=k_scale, v_scale=v_scale)

@@ -25,8 +25,7 @@ their K/V into pages (quantizing on write when the pool is int8 — see
 (``ragged_paged_attention``: sequence-packed rows, GQA head-group
 packing, in-register dequant) over the whole mixed batch — where the
 v1 engine paid two dispatches and two softmax passes per tick with
-in-flight prefill.  ``fuse_tick=False`` keeps the v1 two-dispatch
-shape as a bench control (same math, token-identical).
+in-flight prefill.
 
 Decoding is greedy (argmax) by default — the deterministic contract
 the parity tests pin.  ``submit(..., sampling=SamplingParams(...))``
@@ -415,7 +414,6 @@ class ServingEngine:
                  max_queue: Optional[int] = None,
                  dtype=None, kv_dtype=None,
                  pool_bytes: Optional[int] = None,
-                 fuse_tick: bool = True,
                  use_kernel: Optional[bool] = None,
                  queue_deadline_s: Optional[float] = None,
                  preempt_budget: Optional[int] = None,
@@ -625,7 +623,6 @@ class ServingEngine:
         self._buckets = tuple(sorted(int(b) for b in buckets)) if buckets \
             else _parse_buckets(FLAGS.serving_prefill_buckets)
         self._max_slots = max_slots
-        self._fuse_tick = bool(fuse_tick)
         # prefill-row packing: the kernel needs each sequence's rows
         # padded to whole BLOCK_ROWS blocks; the per-tick row budget
         # bounds the (decode_bucket, prefill_bucket) jit-pair ladder
@@ -1208,16 +1205,8 @@ class ServingEngine:
                 with self._tracer.span("decode_tick", tick=tick,
                                        n=len(running),
                                        prefill_rows=total_rows):
-                    if self._fuse_tick or not (running and chunks):
-                        self._step_with_retry(running, chunks, total_rows,
-                                              tick, drafts)
-                    else:
-                        # fuse_tick=False: the v1 tick-interleave shape —
-                        # prefill and decode as separate dispatches (bench
-                        # control; same math, token-identical)
-                        self._step_with_retry([], chunks, total_rows, tick,
-                                              {})
-                        self._step_with_retry(running, [], 0, tick, drafts)
+                    self._step_with_retry(running, chunks, total_rows,
+                                          tick, drafts)
             with phase("tick.sample", tick=tick):
                 self._prev_tick_busy = busy
                 self._watchdog_sweep(tick)

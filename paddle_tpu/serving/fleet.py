@@ -25,7 +25,7 @@ key (updated at every successful dispatch, dropped on replica death);
 healthz-driven load balancing (``queue_depth`` / ``free_pages``) is the
 tiebreak for unkeyed traffic and the overflow path when the prefix
 owner is saturated.  ``routing="round_robin"`` keeps the naive policy
-alive as the bench's A/B control.
+alive as the control of tests/test_serving_fleet.py.
 
 Replica lifecycle::
 
@@ -1448,7 +1448,7 @@ class FleetRouter:
 
     def snapshot(self) -> Dict[str, object]:
         """Fleet metrics + per-replica prefix stats in one JSON-able
-        dict (the bench's one-line contract)."""
+        dict."""
         snap = self.metrics.snapshot()
         requested = sum(r.engine.metrics.prefix_requested_tokens
                         for r in self.replicas)

@@ -22,9 +22,9 @@ from :func:`layer_pages`.
 
 Split of responsibilities:
 
-- **Device side** (pure functions, jit-safe): ``append_token`` /
-  ``write_prompt`` scatter new K/V into pages, ``gather_kv`` linearizes a
-  page table back into a contiguous view (the oracle/fallback path).
+- **Device side** (pure functions, jit-safe): ``append_token`` scatters
+  new K/V into pages, ``gather_kv`` linearizes a page table back into a
+  contiguous view (the oracle/fallback path).
   These take page ids and offsets as *arrays*, so one jit specialization
   serves every allocation pattern.
 - **Host side**: :class:`PagePool` is the free list.  Allocation is a
@@ -355,17 +355,6 @@ def append_token(kv: KVPages, layer: int, k_new: jax.Array, v_new: jax.Array,
         v=kv.v.at[layer, page_ids, offsets].set(
             v_new.astype(kv.v.dtype).reshape(rows)),
         **scales)
-
-
-def write_prompt(kv: KVPages, layer: int, k_seq: jax.Array, v_seq: jax.Array,
-                 dest_pages: jax.Array, offsets: jax.Array) -> KVPages:
-    """Scatter a whole (padded) prompt into pages at prefill.
-
-    k_seq/v_seq: [T, H_kv, D]; dest_pages/offsets: [T] int32, with
-    padded positions (t >= true length) steered to NULL_PAGE by the
-    caller.  Same quantize-on-write rule as :func:`append_token` (the
-    scatter shape is identical — one row per position)."""
-    return append_token(kv, layer, k_seq, v_seq, dest_pages, offsets)
 
 
 def pages_spanned(start: int, count: int, page_size: int) -> range:

@@ -1,8 +1,9 @@
-"""Serving metrics: the counters the bench (and any scraper) reads.
+"""Serving metrics: the counters ``benchmarks/drivers/serve.py`` (and
+any scraper) reads.
 
 Kept deliberately flat — ``snapshot()`` returns one JSON-able dict so
-``bench.py``'s one-line-of-JSON contract and an external exporter see
-the same numbers.  Time handling: the engine stamps events with its
+the benchmark's result line and an external exporter see the same
+numbers.  Time handling: the engine stamps events with its
 clock (``time.monotonic`` or an injected fault-plan clock) and the
 throughput window runs from the first submission to the last emitted
 token, so idle tails (drained engine waiting for arrivals) don't
@@ -53,7 +54,7 @@ class ServingMetrics:
         self.prefill_tokens = 0       # tokens actually forwarded at prefill
         # unified-step shape (round 12): dispatches and row mix — the
         # whole point of the ragged kernel is fewer dispatches per unit
-        # of work, so the bench reads these directly
+        # of work, so the benchmark's serve driver reads these directly
         self.step_dispatches = 0      # unified-step device dispatches
         self.decode_rows = 0          # decode/verify rows shipped across
         #                               steps (k1 per speculating slot)
@@ -130,9 +131,7 @@ class ServingMetrics:
         bucket was padding.  ``n_slots`` is the running-slot
         participation count — equal to the row count without
         speculation, 1/k1 of it with (each speculating slot ships k1
-        verify rows).  ``fuse_tick=False`` (the v1 two-dispatch
-        control) calls this twice per busy tick — the dispatch-count
-        delta IS the A/B.  ``h2d_bytes``/``d2h_bytes`` are what the
+        verify rows).  ``h2d_bytes``/``d2h_bytes`` are what the
         dispatch moved between host and device: its input arrays up,
         its logits down.  ``attn_cells`` is the dispatch's (ragged
         kernel calls, grid steps of those calls, steps whose page is
@@ -366,8 +365,8 @@ class ServingMetrics:
 
 
 class FleetMetrics:
-    """Fleet-level counters (round 11): what the fleet bench and an
-    external scraper read about the WHOLE deployment, as opposed to the
+    """Fleet-level counters (round 11): what an external scraper reads
+    about the WHOLE deployment, as opposed to the
     per-replica :class:`ServingMetrics` each engine keeps.
 
     The load-bearing invariants live here as plain counters so the

@@ -110,3 +110,20 @@ def assert_serving_drained(eng):
     assert eng.pool.num_free + eng.pool.num_reclaimable == \
         eng.pool.num_usable
     eng.check_page_conservation()
+
+
+def stored_pool(*pages):
+    """One layer's published pages ``[P, page, KVH, D]`` (and int8 scales
+    ``[P, page, KVH]``; ``None`` passes through) as a stored pool of ONE
+    layer, ``[1, P, page, KVH * D]`` (scales ``[1, P, page, KVH]``): what
+    ``ragged_paged_attention(..., layer=0)`` takes.  The inverse, for
+    the reference, is ``kv_cache.layer_pages``."""
+    import jax.numpy as jnp
+
+    def one(a):
+        if a is None:
+            return None
+        a = jnp.asarray(a)
+        return a.reshape(1, *a.shape[:2], -1) if a.ndim == 4 else a[None]
+
+    return tuple(one(a) for a in pages)

@@ -102,25 +102,6 @@ def test_recurrent_vs_group_elman():
     compare_topologies(a, b, {"x": _seq(H, [3, 5], cap=8)})
 
 
-def test_flash_vs_plain_attention_kernels():
-    """The SAME attention topology under the pallas flash kernel vs the
-    plain-XLA fallback must agree in outputs and every projection grad —
-    kernel choice is an implementation detail, not semantics."""
-    paddle.topology.reset_name_scope()
-    D = 8
-    s = layer.data(name="s", type=paddle.data_type.dense_vector_sequence(D))
-    # same layer NAME on both sides links wq/wk/wv/wo automatically
-    a = layer.multi_head_attention(s, num_heads=2, name="attn")
-    paddle.topology.reset_name_scope()
-    s = layer.data(name="s", type=paddle.data_type.dense_vector_sequence(D))
-    b = layer.multi_head_attention(s, num_heads=2, name="attn")
-    sb = _seq(D, [4, 3], cap=8)
-    compare_topologies(a, b, {"s": sb},
-                       flags_a={"use_pallas": True},
-                       flags_b={"use_pallas": False},
-                       rtol=2e-4, atol=2e-5)
-
-
 def test_img_conv_vs_conv_operator():
     """img_conv (static filter parameter) == conv_operator in mixed (filter
     arriving as a layer value) when the operator is fed the conv's weight."""

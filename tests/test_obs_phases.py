@@ -144,33 +144,6 @@ def test_ring_holds_a_ticks_phases_in_order(small_model):
     assert set(TICK_PHASES) | {"pt:tick"} <= names
 
 
-def test_two_dispatches_a_tick_put_their_phases_under_one_tick(small_model):
-    """``fuse_tick=False`` dispatches prefill and decode apart: a mixed
-    tick then holds assemble, upload, wait and sample twice, all under
-    its one ``pt:tick`` and its tick number (the readers sum them)."""
-    model, params = small_model
-    clk = ManualClock(tick_s=0.01)
-    tracer = Tracer(time_fn=clk)
-    eng = make_engine(model, params, clk, tracer=tracer, fuse_tick=False)
-    eng.submit([2, 3, 4, 5], max_tokens=6)
-    eng.step()
-    eng.step()                                  # the first is decoding now
-    eng.submit([3, 4, 5, 6, 7], max_tokens=2)   # and this one prefills
-    eng.run()
-    by_tick = {}
-    for e in tracer.ring:
-        if e.name.startswith("pt:tick"):
-            by_tick.setdefault(e.args["tick"], []).append(e.name)
-    twice = [names for names in by_tick.values()
-             if names.count("pt:tick.wait") == 2]
-    assert twice, by_tick
-    for names in twice:
-        assert names == ["pt:tick.schedule"] + 2 * TICK_PHASES[1:] + \
-            ["pt:tick.sample", "pt:tick"]
-    assert eng.metrics.step_dispatches == sum(
-        names.count("pt:tick.wait") for names in by_tick.values())
-
-
 def test_profiler_session_finds_the_ticks_phases(small_model, tmp_path):
     """An obs-off engine under ``jax.profiler.trace``: the host plane has
     ``pt:tick`` and the five phases, the children inside their tick."""

@@ -22,14 +22,16 @@ from paddle_tpu.serving import (DecoderLM, PagePool, PagedKVConfig, Request,
                                 append_token, bucket_for,
                                 ContinuousBatchingScheduler, gather_kv,
                                 greedy_decode_reference, init_kv_pages,
-                                paged_decode_attention,
-                                paged_decode_attention_reference)
-from paddle_tpu.serving.decode_attention import _paged_decode_pallas
+                                ragged_paged_attention,
+                                ragged_paged_attention_reference)
+from paddle_tpu.serving.decode_attention import (BLOCK_ROWS,
+                                                 expand_decode_rows)
 from paddle_tpu.serving.kv_cache import (fork_page, layer_pages, read_pages,
                                          write_pages, zero_pages)
 from paddle_tpu.topology import LayerOutput, ParamSpec
 
 from conftest import assert_serving_drained as assert_drained  # noqa: E402
+from conftest import stored_pool  # noqa: E402
 
 serving = pytest.mark.serving
 
@@ -87,22 +89,20 @@ def test_paged_decode_attention_matches_oracle(rng, lens):
         jnp.asarray(q)[:, None], jnp.asarray(kc), jnp.asarray(vc),
         segment_ids=q_seg, kv_segment_ids=kv_seg)[:, 0])
 
-    ref = np.asarray(paged_decode_attention_reference(
+    # a decode tick's rows: one per sequence, at position len - 1
+    kv_lens = jnp.asarray(lens)
+    ref = np.asarray(ragged_paged_attention_reference(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lens)))
+        jnp.asarray(table), kv_lens, jnp.arange(len(lens)), kv_lens - 1))
     np.testing.assert_allclose(ref, want, rtol=1e-5, atol=1e-5)
 
-    # pallas kernel, interpret mode (the ragged page-table path)
-    ker = np.asarray(_paged_decode_pallas(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lens), float(d) ** -0.5, True))
+    # the kernel (interpret mode) as the engine calls it: rows padded to
+    # one block a sequence, the stored pool and a layer index
+    qe, row_seq, qpos = expand_decode_rows(jnp.asarray(q), kv_lens - 1)
+    ker = np.asarray(ragged_paged_attention(
+        qe, *stored_pool(kp, vp), jnp.asarray(table), kv_lens, row_seq,
+        qpos, layer=0, use_kernel=True, interpret=True))[::BLOCK_ROWS]
     np.testing.assert_allclose(ker, want, rtol=1e-5, atol=1e-5)
-
-    # public entry, kernel forced
-    pub = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lens), use_kernel=True))
-    np.testing.assert_allclose(pub, want, rtol=1e-5, atol=1e-5)
 
 
 @serving
@@ -309,6 +309,28 @@ def test_engine_parity_vs_nonpaged_oracle(rng):
     assert snap["requests_completed"] == len(prompts) + 1
     assert snap["tokens_generated"] >= len(prompts) + 1
     assert snap["page_occupancy"] == 0.0 and snap["page_occupancy_peak"] > 0
+
+
+@serving
+def test_one_tick_shape_and_one_attention_entry_in_the_package():
+    """The tick has one shape and the serving attention one entry form:
+    the engine takes no ``fuse_tick``, and the package exports none of
+    the v1 decode wrappers or aliases, and only names that resolve."""
+    from paddle_tpu import serving
+    from paddle_tpu.serving import decode_attention, kv_cache
+
+    model, params = _small_model(num_layers=1)
+    with pytest.raises(TypeError, match="fuse_tick"):
+        ServingEngine(model, params, eos_id=1, fuse_tick=False)
+    gone = {"paged_decode_attention", "paged_decode_attention_reference",
+            "_paged_decode_pallas", "_kernel_shape_ok", "_stored",
+            "write_prompt"}
+    assert not gone & set(serving.__all__)
+    assert not [n for n in gone
+                if any(hasattr(mod, n)
+                       for mod in (serving, decode_attention, kv_cache))]
+    assert not [n for n in serving.__all__ if not hasattr(serving, n)]
+    assert len(set(serving.__all__)) == len(serving.__all__)
 
 
 @serving

@@ -219,33 +219,42 @@ def test_class_deadline_stamped_when_submit_has_none(model_params):
 def test_wfq_isolates_non_storming_tenants_deadlines(model_params):
     """The tentpole behavior: under a one-tenant prompt storm, WFQ-on
     keeps every NON-storming tenant's deadline misses at zero — the
-    storm's backlog is charged to the storming tenant alone."""
+    storm's backlog is charged to the storming tenant alone — where the
+    same arrivals through the FIFO router (``wfq=False``) make the
+    polite tenants miss."""
     model, params = model_params
-    reg = TenantRegistry()
-    reg.register("alice", "interactive", deadline_s=0.6)
-    reg.register("bob", "standard", deadline_s=0.6)
-    reg.register("storm", "batch")
-    plan = FleetFaultPlan(seed=0, clock=ManualClock(tick_s=0.02),
-                          tenant_storm=("storm", 0, 6, 10))
-    fl, _ = _make_fleet(model, params, n=2, plan=plan, tenants=reg,
-                        wfq=True)
-    rng = np.random.RandomState(0)
-    tick = 0
-    while tick < 6 or fl.has_work:
-        if tick < 6 and tick % 2 == 0:
-            for tenant in ("alice", "bob", "storm"):
-                for _ in range(plan.storm_factor(tick, tenant)):
-                    fl.submit(rng.randint(2, 50, size=6).tolist(),
-                              max_tokens=3, tenant=tenant)
-        fl.step()
-        tick += 1
-        assert tick < 600, "fleet failed to drain"
-    check_control_conservation(fl)
-    tenants = fl.healthz()["tenants"]
-    assert tenants["alice"]["deadline_misses"] == 0
-    assert tenants["bob"]["deadline_misses"] == 0
-    led = fl.ledger.snapshot()
-    assert led["storm"]["submitted"] > led["alice"]["submitted"] * 5
+    misses = {}
+    for wfq in (True, False):
+        # 0.25 s sits inside the band (0.2-0.3) where the two routers
+        # part on this trace: looser and FIFO misses nothing either,
+        # tighter and nothing could serve the polite tenants in time
+        reg = TenantRegistry()
+        reg.register("alice", "interactive", deadline_s=0.25)
+        reg.register("bob", "standard", deadline_s=0.25)
+        reg.register("storm", "batch")
+        plan = FleetFaultPlan(seed=0, clock=ManualClock(tick_s=0.02),
+                              tenant_storm=("storm", 0, 6, 10))
+        fl, _ = _make_fleet(model, params, n=2, plan=plan, tenants=reg,
+                            wfq=wfq)
+        rng = np.random.RandomState(0)
+        tick = 0
+        while tick < 6 or fl.has_work:
+            if tick < 6 and tick % 2 == 0:
+                for tenant in ("alice", "bob", "storm"):
+                    for _ in range(plan.storm_factor(tick, tenant)):
+                        fl.submit(rng.randint(2, 50, size=6).tolist(),
+                                  max_tokens=3, tenant=tenant)
+            fl.step()
+            tick += 1
+            assert tick < 600, "fleet failed to drain"
+        check_control_conservation(fl)
+        tenants = fl.healthz()["tenants"]
+        misses[wfq] = (tenants["alice"]["deadline_misses"],
+                       tenants["bob"]["deadline_misses"])
+        led = fl.ledger.snapshot()
+        assert led["storm"]["submitted"] > led["alice"]["submitted"] * 5
+    assert misses[True] == (0, 0)
+    assert sum(misses[False]) > 0, misses
 
 
 def test_wfq_buffered_requests_expire_and_cancel_balance_ledger(
@@ -451,7 +460,9 @@ def test_autoscaler_grows_under_storm_and_shrinks_after(model_params):
     assert 1 <= len(alive) <= 3
     check_control_conservation(fl)
     snap = fl.snapshot()
-    assert snap["control_replica_ticks"] > 0
+    # what scaling is for: fewer replica-ticks than a fleet pinned at the
+    # ceiling would have burned over the same run
+    assert 0 < snap["control_replica_ticks"] < 3 * fl._tick
 
 
 def test_autoscaler_never_drains_last_prefill_replica(model_params):

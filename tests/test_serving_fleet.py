@@ -418,6 +418,37 @@ def test_round_robin_control_policy_spreads_evenly(model_params):
     _assert_fleet_drained(fl)
 
 
+def test_affinity_keeps_prefix_hits_that_round_robin_spreads_thin(
+        model_params):
+    """The two policies on ONE sequenced trace of three tenants, each
+    with its own two-page system prompt: affinity gives a prefix one
+    home, so all but a tenant's first request hit; round-robin makes
+    both replicas serve every tenant and pays each prefix's prefill
+    twice (a share of prompt tokens counted, not a rate).  What a request completes
+    with is the same under both, and the oracle's."""
+    model, params = model_params
+    rng = np.random.RandomState(12)
+    systems = [rng.randint(2, 50, size=2 * PAGE).tolist() for _ in range(3)]
+    prompts = [systems[j % 3] + rng.randint(2, 50, size=3).tolist()
+               for j in range(12)]
+    outs, snaps = {}, {}
+    for routing in ("affinity", "round_robin"):
+        fl, _ = _make_fleet(model, params, n=2, routing=routing)
+        frids = []
+        for p in prompts:               # sequenced: a hit needs the
+            frids.append(fl.submit(p, max_tokens=3))    # writer admitted
+            fl.step()
+        _drain_all(fl)
+        _assert_fleet_drained(fl)
+        outs[routing] = [fl.result(f) for f in frids]
+        snaps[routing] = fl.snapshot()
+    assert outs["affinity"] == outs["round_robin"]
+    assert outs["affinity"][0] == greedy_decode_reference(
+        model, params, prompts[0], 3, EOS)
+    assert snaps["affinity"]["fleet_prefix_hit_rate"] > \
+        snaps["round_robin"]["fleet_prefix_hit_rate"]
+
+
 def test_slow_replica_fault_and_fleet_still_drains(model_params):
     """A slow replica (steps every 3rd fleet tick) stretches the drain
     in FLEET ticks — its per-engine work is unchanged, it just runs

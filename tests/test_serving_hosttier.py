@@ -201,6 +201,9 @@ class TestEngineSwapIn:
         eng = _engine(*model_params)
         cold, warm = _roundtrip(eng, _prompt())
         assert warm == cold
+        # the warm serve forwarded fewer prompt tokens than the cold one
+        # (both are in the total: under twice the prompt in all)
+        assert eng.metrics.prefill_tokens < 2 * len(_prompt())
         snap = eng.host_tier.snapshot()
         assert snap["host_swap_outs"] >= 4     # 4 full pages spilled
         assert snap["host_swap_ins"] >= 4      # ... and all came back

@@ -438,25 +438,31 @@ def test_failed_tail_keeps_shared_prefix_cached(rng):
     # rollback scope: a request whose UNIQUE TAIL overflows forgets only
     # the pages it wrote — the shared system prompt it stitched was
     # finite-vouched by its original owner and must stay hittable
-    model, params = _small_model()
-    params = dict(params)
-    params["emb"] = params["emb"].at[7].set(np.inf)
-    eng = _engine(model, params)
+    model, clean = _small_model()
     system = rng.randint(8, 50, size=8).tolist()       # 2 clean pages
-    a = eng.submit(system + rng.randint(8, 50, size=2).tolist(),
-                   max_tokens=4)
+    a_prompt = system + rng.randint(8, 50, size=2).tolist()
+    c_prompt = system + rng.randint(8, 50, size=3).tolist()
+    # the poisoned id is one no clean request can meet: prompts draw from
+    # [8, 50), and a clean request feeds back what it emits, so the id
+    # is chosen from the model among those it emits for neither
+    emitted = {t for p in (a_prompt, c_prompt)
+               for t in greedy_decode_reference(model, clean, p, 4, 1)}
+    poison = next(t for t in range(2, 8) if t not in emitted)
+    params = dict(clean)
+    params["emb"] = params["emb"].at[poison].set(np.inf)
+    eng = _engine(model, params)
+    a = eng.submit(a_prompt, max_tokens=4)
     eng.run(max_ticks=50)
     assert eng.status(a) is RequestStatus.COMPLETED
     cached_before = len(eng.cache)
     assert cached_before == 2
-    bad = eng.submit(system + [7, 8], max_tokens=4)    # poisoned tail
+    bad = eng.submit(system + [poison, 8], max_tokens=4)    # poisoned tail
     eng.run(max_ticks=50)
     assert eng.status(bad) is RequestStatus.FAILED
     assert len(eng.cache) == cached_before             # prefix survived
     # and it still serves hits
     saved_before = eng.metrics.prefill_tokens_saved
-    c = eng.submit(system + rng.randint(8, 50, size=3).tolist(),
-                   max_tokens=4)
+    c = eng.submit(c_prompt, max_tokens=4)
     eng.run(max_ticks=50)
     assert eng.status(c) is RequestStatus.COMPLETED
     assert eng.metrics.prefill_tokens_saved - saved_before == 8

@@ -11,6 +11,8 @@ head-replicated MHA oracle, int8 chaos conservation, and the
 QUANT-DRIFT parity harness the tier-1 ladder greps (exit 7).
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -27,13 +29,13 @@ from paddle_tpu.serving import (BLOCK_ROWS, DecoderLM, FaultPlan,
 from paddle_tpu.serving import decode_attention
 from paddle_tpu.serving.decode_attention import (QUANT_DRIFT_BOUND,
                                                  _ragged_pallas,
-                                                 _stored,
                                                  check_quant_drift,
                                                  heads_per_cell,
                                                  quant_parity_error)
 from paddle_tpu.ops.attention import mha_reference
 
 from conftest import assert_serving_drained as assert_drained  # noqa: E402
+from conftest import stored_pool  # noqa: E402
 
 ragged = pytest.mark.ragged
 serving = pytest.mark.serving
@@ -55,7 +57,7 @@ def f32():
 def _ragged_pallas_layer(q, kp, vp, ks, vs, *rest):
     """The kernel on ONE layer's published ``[P, page, KVH, D]`` pages,
     taken as a stored pool of one layer."""
-    return _ragged_pallas(q, *_stored(kp, vp, ks, vs, None), *rest)
+    return _ragged_pallas(q, *stored_pool(kp, vp, ks, vs), 0, *rest)
 
 
 def _build_mixed(rng, seqs, page, pm, num_pages, kvh, d, h):
@@ -146,9 +148,9 @@ def test_ragged_mixed_batch_matches_oracle(rng, case, kvh, h):
 
     # public entry, kernel forced (interpret on CPU)
     pub = np.asarray(ragged_paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), *stored_pool(kp, vp),
         jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(row_seq),
-        jnp.asarray(qpos), use_kernel=True))
+        jnp.asarray(qpos), layer=0, use_kernel=True))
     np.testing.assert_allclose(pub[real], want[real], rtol=2e-5, atol=2e-5)
 
 
@@ -505,6 +507,50 @@ def test_ragged_kernel_layer_is_an_operand_not_a_constant(rng):
             _ragged_pallas_layer(jnp.asarray(q), k4, v4, None, None, *rest,
                                  float(d) ** -0.5, True)))
     assert not np.array_equal(outs[0], outs[1])
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("entry", ["one_chip", "tp"])
+def test_ragged_entries_take_the_stored_pool_and_a_layer_only(rng, entry):
+    """One operand form: the pool as stored and a layer.  A call without
+    ``layer`` is a TypeError; one layer's published pages (int8, scales
+    ``[P, page, KVH]``), the published ``[L, P, page, KVH, D]`` and lanes
+    that are no multiple of the head dim are refused with a message
+    that names the stored form.  (An f32 ``[P, page, KVH, D]`` alone has
+    the shape of a one-head pool ``[L, P, page, 1 * D]``: no check on
+    shapes can tell them apart, which is why ``layer`` is required.)"""
+    from jax.sharding import Mesh
+    from paddle_tpu.platform.enforce import EnforceError
+    from paddle_tpu.serving.decode_attention import ragged_paged_attention_tp
+
+    page, pm, num_pages, kvh, h, d = 8, 4, 32, 2, 4, 16
+    q, kp, vp, table, kv_lens, row_seq, qpos, _, _ = _build_mixed(
+        rng, MIXED_CASES[0], page, pm, num_pages, kvh, d, h)
+    rest = (jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(row_seq),
+            jnp.asarray(qpos))
+    if entry == "one_chip":
+        attend = functools.partial(ragged_paged_attention, jnp.asarray(q))
+    else:
+        mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
+        attend = functools.partial(ragged_paged_attention_tp, mesh, "model",
+                                   jnp.asarray(q))
+    k1, v1 = stored_pool(kp, vp)
+    with pytest.raises(TypeError, match="layer"):
+        attend(k1, v1, *rest)
+    kq, ks = quantize_kv(jnp.asarray(kp))
+    vq, vs = quantize_kv(jnp.asarray(vp))
+    stored = r"STORED pool \[L, pages, page, KVH \* D\]"
+    with pytest.raises(EnforceError, match=stored):
+        attend(kq, vq, *rest, layer=0, k_scale=ks, v_scale=vs)
+    with pytest.raises(EnforceError, match=stored):
+        attend(jnp.asarray(kp)[None], jnp.asarray(vp)[None], *rest, layer=0)
+    with pytest.raises(EnforceError, match=stored):
+        attend(k1[..., :-1], v1[..., :-1], *rest, layer=0)
+    # and the stored form of the same operands is taken
+    got = attend(*stored_pool(kq, vq), *rest, layer=0,
+                 **dict(zip(("k_scale", "v_scale"), stored_pool(ks, vs))))
+    assert got.shape == q.shape
 
 
 def _eqns(jaxpr, name):
@@ -869,26 +915,6 @@ def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
     assert ref.metrics.step_dispatches > 0
     assert ref.metrics.snapshot()["attn_kernel_calls"] == 0
     assert ref.metrics.snapshot()["attn_grid_cells"] == 0
-
-
-@ragged
-@serving
-def test_fused_vs_split_tick_token_identical(rng):
-    """fuse_tick=False reproduces the v1 two-dispatch tick shape as the
-    bench A/B control: token-identical outputs, strictly more
-    dispatches for the same work."""
-    model = DecoderLM(vocab_size=50, num_layers=2, num_heads=2, head_dim=8,
-                      max_positions=128)
-    params = model.init_params(jax.random.PRNGKey(0))
-    fused = _engine(model, params)
-    split = _engine(model, params, fuse_tick=False)
-    _, _, out_f = _mixed_traffic(fused)
-    _, _, out_s = _mixed_traffic(split)
-    assert out_f == out_s
-    assert split.metrics.step_dispatches > fused.metrics.step_dispatches
-    assert fused.metrics.prefill_rows == split.metrics.prefill_rows
-    assert_drained(fused)
-    assert_drained(split)
 
 
 @ragged

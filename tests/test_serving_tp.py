@@ -40,6 +40,7 @@ from paddle_tpu.serving.engine import greedy_decode_reference, validate_tp
 from paddle_tpu.serving.kv_cache import PagedKVConfig, pages_for_budget
 
 from conftest import assert_serving_drained as assert_drained  # noqa: E402
+from conftest import stored_pool  # noqa: E402
 
 pytestmark = [pytest.mark.serving, pytest.mark.shard]
 
@@ -439,17 +440,18 @@ def test_kernel_shard_map_matches_reference(rng):
     want = ragged_paged_attention_reference(q, kp, vp, table, lens,
                                             row_seq, qpos)
     mesh = _mesh(2)
-    got = ragged_paged_attention_tp(mesh, "model", q, kp, vp, table,
-                                    lens, row_seq, qpos, use_kernel=True,
-                                    interpret=True)
+    pool = stored_pool(kp, vp)
+    got = ragged_paged_attention_tp(mesh, "model", q, *pool, table,
+                                    lens, row_seq, qpos, layer=0,
+                                    use_kernel=True, interpret=True)
     real = qpos >= 0                       # padded rows are undefined
     np.testing.assert_allclose(np.asarray(got)[real],
                                np.asarray(want)[real],
                                rtol=2e-5, atol=2e-5)
     # the auto chooser on CPU routes to the reference fallback — same
     # semantics, no shard_map needed
-    auto = ragged_paged_attention_tp(mesh, "model", q, kp, vp, table,
-                                     lens, row_seq, qpos)
+    auto = ragged_paged_attention_tp(mesh, "model", q, *pool, table,
+                                     lens, row_seq, qpos, layer=0)
     np.testing.assert_allclose(np.asarray(auto)[real],
                                np.asarray(want)[real],
                                rtol=2e-5, atol=2e-5)

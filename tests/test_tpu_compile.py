@@ -25,7 +25,6 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import rnn
 from paddle_tpu.ops.attention import flash_attention
-from paddle_tpu.platform.flags import FLAGS
 from paddle_tpu.serving.decode_attention import (ragged_paged_attention,
                                                  ragged_paged_attention_tp)
 
@@ -62,7 +61,7 @@ def _on(sharding):
 
 # ---- flash attention: the train cell's shape ------------------------------
 
-@pytest.mark.parametrize("mode", ["fwd", "bwd_pallas", "bwd_scan"])
+@pytest.mark.parametrize("mode", ["fwd", "bwd_pallas"])
 def test_flash_attention_compiles_for_v5e(topo, mode):
     aval = _on(SingleDeviceSharding(topo.devices[0]))
     q = aval((4, 1024, 16, 128), jnp.bfloat16)
@@ -73,15 +72,10 @@ def test_flash_attention_compiles_for_v5e(topo, mode):
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
-    was = FLAGS.use_pallas
-    FLAGS.use_pallas = mode != "bwd_scan"
-    try:
-        n = _compile(fwd if mode == "fwd" else
-                     jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    finally:
-        FLAGS.use_pallas = was
-    # fwd kernel, plus dKV and dQ kernels on the pallas backward
-    assert n == {"fwd": 1, "bwd_pallas": 3, "bwd_scan": 1}[mode]
+    n = _compile(fwd if mode == "fwd" else jax.grad(loss, argnums=(0, 1, 2)),
+                 q, q, q)
+    # fwd kernel, plus dKV and dQ kernels on the backward
+    assert n == {"fwd": 1, "bwd_pallas": 3}[mode]
 
 
 def test_flash_attention_under_a_mesh_needs_per_device(topo):
@@ -106,17 +100,18 @@ def test_flash_attention_under_a_mesh_needs_per_device(topo):
 
 def _ragged_avals(aval_for, t, h, kvh, dtype, d=128, page=128, pages=64,
                   slots=8, pm=8):
-    """(q, k, v, table, lens, row_seq, qpos[, k_scale, v_scale]) avals;
-    ``aval_for(spec)`` places one argument."""
-    head, pool, scale = (P(None, "model", None), P(None, None, "model", None),
-                         P(None, None, "model"))
+    """(q, k, v, table, lens, row_seq, qpos[, k_scale, v_scale]) avals,
+    K and V a stored pool of ONE layer; ``aval_for(spec)`` places one
+    argument."""
+    head, pool = P(None, "model", None), P(None, None, None, "model")
     quant = dtype == "int8"
     q = aval_for(head)((t, h, d), jnp.float32 if quant else dtype)
-    kv = aval_for(pool)((pages, page, kvh, d), jnp.int8 if quant else dtype)
+    kv = aval_for(pool)((1, pages, page, kvh * d),
+                        jnp.int8 if quant else dtype)
     i32 = lambda *shape: aval_for(P())(shape, jnp.int32)  # noqa: E731
     out = [q, kv, kv, i32(slots, pm), i32(slots), i32(t), i32(t)]
     if quant:
-        sc = aval_for(scale)((pages, page, kvh), jnp.float32)
+        sc = aval_for(pool)((1, pages, page, kvh), jnp.float32)
         out += [sc, sc]
     return out
 
@@ -146,8 +141,8 @@ def test_ragged_paged_attention_compiles_for_v5e(topo, t, h, kvh, dtype):
 
     def fn(q, k, v, table, lens, row_seq, qpos, *rest):
         return ragged_paged_attention(q, k, v, table, lens, row_seq, qpos,
-                                      use_kernel=True, interpret=False,
-                                      **_scales(rest))
+                                      layer=0, use_kernel=True,
+                                      interpret=False, **_scales(rest))
 
     assert _compile(fn, *_ragged_avals(lambda spec: _on(one), t, h, kvh,
                                        dtype)) == 1
@@ -162,8 +157,9 @@ def test_ragged_paged_attention_tp4_compiles_for_v5e(topo, t, h, kvh, dtype):
 
     def fn(q, k, v, table, lens, row_seq, qpos, *rest):
         return ragged_paged_attention_tp(mesh, "model", q, k, v, table, lens,
-                                         row_seq, qpos, use_kernel=True,
-                                         interpret=False, **_scales(rest))
+                                         row_seq, qpos, layer=0,
+                                         use_kernel=True, interpret=False,
+                                         **_scales(rest))
 
     avals = _ragged_avals(lambda spec: _on(NamedSharding(mesh, spec)),
                           t, h, kvh, dtype)
@@ -301,10 +297,10 @@ def test_kernels_carry_stable_names(topo, which):
 
     def fn(*a):
         if which == "ragged":
-            return ragged_paged_attention(*a, use_kernel=True,
+            return ragged_paged_attention(*a, layer=0, use_kernel=True,
                                           interpret=False)
-        return ragged_paged_attention_tp(mesh, "model", *a, use_kernel=True,
-                                         interpret=False)
+        return ragged_paged_attention_tp(mesh, "model", *a, layer=0,
+                                         use_kernel=True, interpret=False)
 
     place = (lambda spec: _on(one)) if which == "ragged" else \
         (lambda spec: _on(NamedSharding(mesh, spec)))
