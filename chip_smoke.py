@@ -111,20 +111,10 @@ def count_kernels_in_step(eng, pb: int) -> int:
     """Mosaic kernels in the engine's lowered unified step for prefill
     bucket ``pb`` — fails unless the engine chose the pallas path AND the
     program it dispatches carries the kernel."""
-    import jax
-    import jax.numpy as jnp
-
     check(eng._ragged_kernel,
           "the engine chose the reference attention path, not the kernel")
-    b, k1 = eng._max_slots, eng._k1
-
-    def aval(*shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    lowered = eng._step_fn(pb, k1).lower(
-        eng.params, eng._kv, aval(b, k1), aval(b, k1),
-        aval(b, k1, dtype=jnp.bool_), aval(pb), aval(pb), aval(pb), aval(b),
-        aval(b, eng.kv_cfg.max_pages_per_seq), aval(b))
+    lowered = eng._step_fn(pb, eng._k1).lower(
+        eng.params, eng._kv, eng._empty_tick(pb, eng._k1))
     n = lowered.as_text().count("tpu_custom_call")
     check(n > 0, f"no tpu_custom_call in the lowered serving step (pb={pb})")
     return n

@@ -93,6 +93,7 @@ exposing its projection weights.
 
 from __future__ import annotations
 
+import math
 import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
@@ -469,6 +470,10 @@ class ServingEngine:
         self.tp = 1
         self._shard_plan: Optional[Dict[str, Tuple]] = None
         self.param_sharding = None
+        # where a tick's input buffer is placed: replicated over the
+        # mesh, as the compiled step reads it (None: the default device,
+        # where the one-chip engine's pool lies)
+        self._tick_sharding = None
         if mesh is not None:
             enforce_that(
                 self.tp_axis in mesh.axis_names,
@@ -498,6 +503,7 @@ class ServingEngine:
                 for name in params}
             params = {name: jax.device_put(v, self.param_sharding[name])
                       for name, v in params.items()}
+            self._tick_sharding = NamedSharding(mesh, P())
             if hasattr(model, "bind_tp"):
                 # a TP-bound VIEW (bind_tp must not mutate): the bound
                 # forward asserts the activation shardings, so each
@@ -739,7 +745,7 @@ class ServingEngine:
             # per-leaf param specs (keyed by name: the auditor resolves
             # dict entries against the pytree path) + the pool spec for
             # both the donated input and the aliased output
-            step_in = (dict(self._shard_plan), kvspec) + ((),) * 9
+            step_in = (dict(self._shard_plan), kvspec, ())
             step_out = ((), ()) + (kvspec,) * 4
             kv_in = (kvspec, (), ())
             kv_out = (kvspec,) * 4
@@ -978,8 +984,11 @@ class ServingEngine:
         b, page = self._max_slots, cfg.page_size
         bd = b * k1
 
-        def raw(params, kv: KVPages, d_tokens, d_pos, d_valid, p_tokens,
-                p_qpos, p_seq, p_last, table, att_lens):
+        def raw(params, kv: KVPages, packed):
+            # packed: the tick's one int32 input buffer, replicated;
+            # taken apart by static slices (a chip reads its own copy)
+            (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
+             table, att_lens) = self._tick_parts(packed, k1)
             # d_tokens/d_pos/d_valid: [B, k1] — row 0 of a slot is the
             # plain decode token, rows 1..k its drafted lookahead
             # (invalid rows write the null page and produce garbage
@@ -993,7 +1002,7 @@ class ServingEngine:
             d_seq = jnp.repeat(jnp.arange(b), k1)
             dt = d_tokens.reshape(bd)
             dp = d_pos.reshape(bd)
-            dv = d_valid.reshape(bd)
+            dv = d_valid.reshape(bd) != 0
             p_act = p_qpos >= 0
             pq = jnp.maximum(p_qpos, 0)
             tokens = jnp.concatenate([dt, p_tokens])
@@ -1187,7 +1196,8 @@ class ServingEngine:
         progress watchdog).  Returns True if any work remains.
 
         The tick names its phases for the profiler (``pt:tick`` and,
-        inside it, ``pt:tick.schedule`` here, ``.assemble``, ``.upload``,
+        inside it, ``pt:tick.schedule`` here, ``.assemble``, ``.upload``
+        (and inside that ``.dispatch``, the compiled step's call alone),
         ``.wait`` and ``.sample`` in :meth:`_do_step`, ``.sample`` again
         for the bookkeeping that closes the tick)."""
         tick, m = self._tick, self.metrics
@@ -1858,12 +1868,19 @@ class ServingEngine:
         next real tokens overwrite."""
         k1, tick, phase = self._k1, self._tick, self._tracer.phase
         with phase("tick.assemble", tick=tick):
-            host = self._assemble(running, chunks, total_rows, drafts)
-        pb = host[3].shape[0]                   # p_tokens: the bucket
+            packed = self._assemble(running, chunks, total_rows, drafts)
+        parts = self._tick_parts(packed, k1)
+        p_seq, att_lens = parts[5], parts[8]
+        pb = p_seq.shape[0]                     # the prefill bucket
         with phase("tick.upload", tick=tick):
-            dev = [jnp.asarray(a) for a in host]
-            d_logits, p_logits, self._kv = self._step_fn(pb, k1)(
-                self.params, self._kv, *dev)
+            # ONE placement, already in the layout the step wants
+            # (replicated over the engine's mesh), so the call re-lays
+            # nothing: its other arguments are committed there too
+            step = self._step_fn(pb, k1)
+            placed = jax.device_put(packed, self._tick_sharding)
+            with phase("tick.dispatch", tick=tick):
+                d_logits, p_logits, self._kv = step(self.params, self._kv,
+                                                    placed)
         with phase("tick.wait", tick=tick):
             d_logits = np.asarray(d_logits)   # forces device sync; [B,k1,V]
             p_logits = np.asarray(p_logits)
@@ -1872,9 +1889,9 @@ class ServingEngine:
                 sum(1 + len(drafts.get(r.rid, ((),))[0]) for r in running),
                 total_rows, pb - sum(c[2] for c in chunks),
                 n_slots=len(running),
-                h2d_bytes=sum(a.nbytes for a in host),
+                h2d_bytes=packed.nbytes,
                 d2h_bytes=d_logits.nbytes + p_logits.nbytes,
-                attn_cells=self._attn_cells(host[5], host[8]))
+                attn_cells=self._attn_cells(p_seq, att_lens))
             self._walk_results(running, chunks, drafts, d_logits, p_logits)
 
     def _attn_cells(self, p_seq: np.ndarray, att_lens: np.ndarray
@@ -1898,17 +1915,53 @@ class ServingEngine:
         return (calls, calls * nb * groups * cfg.max_pages_per_seq,
                 calls * live * groups)
 
+    def _tick_shapes(self, pb: int, k1: int) -> Tuple[Tuple[int, ...], ...]:
+        """A tick's nine input arrays in the order they lie in its one
+        packed int32 buffer: ``d_tokens``, ``d_pos``, ``d_valid`` (0/1)
+        ``[B, k1]``; ``p_tokens``, ``p_qpos``, ``p_seq`` ``[pb]``;
+        ``p_last`` ``[B]``; ``table`` ``[B, Pm]``; ``att_lens`` ``[B]``."""
+        b, pm = self._max_slots, self.kv_cfg.max_pages_per_seq
+        return ((b, k1),) * 3 + ((pb,),) * 3 + ((b,), (b, pm), (b,))
+
+    def _tick_parts(self, packed, k1: int) -> List:
+        """The nine arrays as views of ``packed`` at static offsets
+        (``pb`` is what the buffer's length leaves): the host fills
+        these views of a NumPy buffer (``_assemble``), the compiled
+        step slices the traced one (``_step_fn``)."""
+        fixed = sum(math.prod(s) for s in self._tick_shapes(0, k1))
+        parts, off = [], 0
+        for shape in self._tick_shapes((packed.shape[0] - fixed) // 3, k1):
+            n = math.prod(shape)
+            parts.append(packed[off:off + n].reshape(shape))
+            off += n
+        return parts
+
+    def _empty_tick(self, pb: int, k1: int) -> np.ndarray:
+        """The packed buffer of a step that carries no row yet: every
+        table entry the null page, every prefill row padding."""
+        packed = np.zeros(sum(math.prod(s)
+                              for s in self._tick_shapes(pb, k1)), np.int32)
+        parts = self._tick_parts(packed, k1)
+        parts[4][:] = -1                        # p_qpos
+        parts[7][:] = NULL_PAGE                 # table
+        return packed
+
     def _assemble(self, running: List[Request], chunks, total_rows: int,
-                  drafts: Dict[int, Tuple]) -> Tuple[np.ndarray, ...]:
-        """The nine host arrays of one unified step, in the order
-        ``_step_fn`` takes them after the parameters and the pool."""
+                  drafts: Dict[int, Tuple]) -> np.ndarray:
+        """The host side of one unified step's inputs: ONE int32 buffer
+        (``_tick_shapes`` says what lies where), which ``_step_fn``
+        takes after the parameters and the pool."""
         b, k1 = self._max_slots, self._k1
         cfg = self.kv_cfg
-        d_tokens = np.zeros((b, k1), np.int32)
-        d_pos = np.zeros((b, k1), np.int32)
-        d_valid = np.zeros((b, k1), bool)
-        att_lens = np.zeros((b,), np.int32)
-        table = np.full((b, cfg.max_pages_per_seq), NULL_PAGE, np.int32)
+        pb = 0
+        if chunks:
+            pb = bucket_for(total_rows, self._buckets,
+                            max(cfg.max_seq_len, total_rows))
+            if self._ragged_kernel:  # whole blocks only (kernel packing)
+                pb = -(-pb // BLOCK_ROWS) * BLOCK_ROWS
+        packed = self._empty_tick(pb, k1)
+        (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last, table,
+         att_lens) = self._tick_parts(packed, k1)
         for req in running:
             s = req.slot
             dr = drafts.get(req.rid, ((), None))[0]
@@ -1916,19 +1969,9 @@ class ServingEngine:
             d_tokens[s, 0] = req.generated[-1]
             d_tokens[s, 1:n] = dr
             d_pos[s, :n] = req.cache_len + np.arange(n)
-            d_valid[s, :n] = True
+            d_valid[s, :n] = 1
             att_lens[s] = req.cache_len + n
             table[s, :len(req.pages)] = req.pages
-        pb = 0
-        if chunks:
-            pb = bucket_for(total_rows, self._buckets,
-                            max(cfg.max_seq_len, total_rows))
-            if self._ragged_kernel:  # whole blocks only (kernel packing)
-                pb = -(-pb // BLOCK_ROWS) * BLOCK_ROWS
-        p_tokens = np.zeros((pb,), np.int32)
-        p_qpos = np.full((pb,), -1, np.int32)
-        p_seq = np.zeros((pb,), np.int32)
-        p_last = np.zeros((b,), np.int32)
         off = 0
         for req, start, n, rows in chunks:
             s = req.slot
@@ -1944,8 +1987,7 @@ class ServingEngine:
             att_lens[s] = start + n
             table[s, :len(req.pages)] = req.pages
             off += rows
-        return (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
-                table, att_lens)
+        return packed
 
     def _walk_results(self, running: List[Request], chunks,
                       drafts: Dict[int, Tuple], d_logits: np.ndarray,

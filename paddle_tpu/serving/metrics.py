@@ -62,7 +62,7 @@ class ServingMetrics:
         #                               running slot per step)
         self.prefill_rows = 0         # prefill-chunk rows shipped (padded)
         self.prefill_pad_rows = 0     # of the bucket, padding/alignment
-        self.h2d_bytes = 0            # the steps' nine input arrays
+        self.h2d_bytes = 0            # the steps' packed input buffers
         self.d2h_bytes = 0            # the steps' two logits arrays
         # the ragged kernel's grid, on one chip (PR 27): a grid step
         # costs its fixed part whether its page is live or not, so the

@@ -3,28 +3,39 @@ for the profiler and, with an enabled tracer, for the ring.
 
 Marker ``obs``.  What is pinned: the obs-off path is the profiler's
 annotation and nothing else; the ring holds the phases of a tick in order
-under one tick number; a profiler session finds them in the host plane;
-the transfer counters equal a hand count; the compiled step carries the
-blocks' names.
+under one tick number, the dispatch inside the upload; a profiler session
+finds them in the host plane; a tick places one buffer, replicated over
+the engine's mesh, and dispatches once; the transfer counters equal a hand
+count; the compiled step carries the blocks' names and takes one integer
+operand, which it takes apart without a collective.
 """
 
 import glob
+import itertools
 import os
+import re
 
 import jax
+import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.analysis.retrace import auditor
 from paddle_tpu.obs import NULL_TRACER, Tracer, chrome_trace
 from paddle_tpu.obs import trace as obs_trace
+from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.platform.flags import FLAGS
 from paddle_tpu.serving import DecoderLM, ManualClock, ServingEngine
+from paddle_tpu.serving import engine as engine_mod
 
 pytestmark = pytest.mark.obs
 
-TICK_PHASES = ["pt:tick.schedule", "pt:tick.assemble", "pt:tick.upload",
-               "pt:tick.wait", "pt:tick.sample"]
+# as the ring holds them: a span is recorded when it ends, so the
+# dispatch comes before the upload that holds it
+TICK_PHASES = ["pt:tick.schedule", "pt:tick.assemble", "pt:tick.dispatch",
+               "pt:tick.upload", "pt:tick.wait", "pt:tick.sample"]
 V, SLOTS, PAGES_PER_SEQ = 64, 4, 8
+TP = 4
 
 
 @pytest.fixture(scope="module")
@@ -32,6 +43,19 @@ def small_model():
     model = DecoderLM(vocab_size=V, num_layers=1, num_heads=2, head_dim=8,
                       max_positions=128)
     return model, model.init_params(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def tp_model():
+    """Two blocks of four heads: splits over the 4-device CPU mesh the
+    tensor-parallel tests use."""
+    model = DecoderLM(vocab_size=V, num_layers=2, num_heads=TP, head_dim=8,
+                      max_positions=128)
+    return model, model.init_params(jax.random.PRNGKey(1))
+
+
+def tp_mesh():
+    return make_mesh((TP,), ("model",), jax.devices()[:TP])
 
 
 def make_engine(model, params, clock, **kw):
@@ -118,23 +142,32 @@ def test_phase_off_is_the_profilers_annotation_and_nothing_else(
 def test_ring_holds_a_ticks_phases_in_order(small_model):
     model, params = small_model
     clk = ManualClock(tick_s=0.01)
-    tracer = Tracer(time_fn=clk)
+    # the tracer's own clock moves at every reading, so that "inside"
+    # below is a statement about order and not about equal stamps
+    reads = itertools.count()
+    tracer = Tracer(time_fn=lambda: 1e-3 * next(reads))
     eng = make_engine(model, params, clk, tracer=tracer.scoped(replica=7))
     rid = eng.submit([2, 3, 4, 5], max_tokens=3)
     eng.run()
     eng.step()                                      # an idle tick
     assert eng.result(rid) is not None
-    by_tick = {}
+    by_tick, extent = {}, {}
     for e in tracer.ring:
         if e.name.startswith("pt:"):
             assert e.kind == "X" and e.replica == 7
             by_tick.setdefault(e.args["tick"], []).append(e.name)
+            extent[e.args["tick"], e.name] = (e.ts, e.ts + e.dur)
     busy = [t for t, names in by_tick.items() if "pt:tick.wait" in names]
     assert len(busy) == eng.metrics.step_dispatches >= 3
     for t in busy:
         # children first (a span is recorded when it ends), the closing
         # bookkeeping under the sample phase's name, the tick last
         assert by_tick[t] == TICK_PHASES + ["pt:tick.sample", "pt:tick"]
+        # the compiled step's call alone, strictly inside the upload:
+        # the placement comes before it
+        (d0, d1), (u0, u1) = (extent[t, "pt:tick." + n]
+                              for n in ("dispatch", "upload"))
+        assert u0 < d0 < d1 < u1
     idle = [t for t in by_tick if t not in busy]
     assert idle and all(by_tick[t] == ["pt:tick.schedule", "pt:tick.sample",
                                        "pt:tick"] for t in idle)
@@ -168,16 +201,97 @@ def test_profiler_session_finds_the_ticks_phases(small_model, tmp_path):
              if e.name.startswith("pt:")]
     ticks = [s for s in spans if s[0] == "pt:tick"]
     assert len(ticks) >= 3
-    for name in TICK_PHASES:
+    def inside(name, outer):
         mine = [s for s in spans if s[0] == name]
         assert len(mine) >= 3
-        assert all(any(t[1] <= s[1] and s[2] <= t[2] for t in ticks)
+        assert all(any(t[1] <= s[1] and s[2] <= t[2] for t in outer)
                    for s in mine)
+
+    for name in TICK_PHASES:
+        inside(name, ticks)
+    inside("pt:tick.dispatch", [s for s in spans
+                                if s[0] == "pt:tick.upload"])
 
 
 # ---------------------------------------------------------------------------
 # the transfer counters against a hand count
 # ---------------------------------------------------------------------------
+
+
+class Counting:
+    """Stands where ``jax.numpy`` was in the engine: counts the arrays
+    its host code makes."""
+
+    def __init__(self):
+        self.made = 0
+
+    def __getattr__(self, name):
+        if name in ("asarray", "array"):
+            self.made += 1
+        return getattr(jnp, name)
+
+
+@pytest.mark.parametrize("mesh,spec", [(False, 0), (False, 2), (True, 0),
+                                       (True, 2)],
+                         ids=["one-device", "one-device-k1=3", "mesh-of-4",
+                              "mesh-of-4-k1=3"])
+def test_a_tick_places_one_buffer_and_dispatches_once(
+        monkeypatch, small_model, tp_model, mesh, spec):
+    """With and without a prefill chunk, with and without drafted rows:
+    one ``jax.device_put`` of one int32 buffer, no other array made by
+    the engine's host code, one dispatch; and what the compiled step
+    receives is committed and fully replicated over the engine's mesh
+    (on the one device without one), so the call re-lays nothing."""
+    model, params = tp_model if mesh else small_model
+    kw = dict(mesh=tp_mesh()) if mesh else {}
+    if spec:
+        kw.update(spec_mode="ngram", spec_k=spec)
+    eng = make_engine(model, params, ManualClock(tick_s=0.01), **kw)
+    assert eng._k1 == 1 + spec
+    puts, got = [], []
+    real_put, real_step_fn = jax.device_put, eng._step_fn
+
+    def put(x, device=None, **kwargs):
+        puts.append(x)
+        return real_put(x, device, **kwargs)
+
+    def step_fn(pb, k1=1):
+        fn = real_step_fn(pb, k1)
+
+        def call(*args):
+            got.append((pb, args))
+            return fn(*args)
+        return call
+
+    eng.submit([2, 3, 4, 5], max_tokens=4)
+    eng.run()                                  # every program compiled
+    eng.submit([3, 4, 5, 6, 7], max_tokens=4)
+    counting = Counting()
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(engine_mod, "jnp", counting)
+    monkeypatch.setattr(eng, "_step_fn", step_fn)
+    for bucket in (True, False, False):        # the prompt, then decoding
+        before = eng.metrics.step_dispatches
+        eng.step()
+        assert eng.metrics.step_dispatches == before + 1
+        (packed,), ((pb, args),) = puts, got
+        assert (pb > 0) == bucket
+        assert counting.made == 0
+        assert packed.dtype == "int32" and packed.ndim == 1
+        assert packed.size == SLOTS * (3 * eng._k1 + 2 + PAGES_PER_SEQ) \
+            + 3 * pb
+        params_in, _kv_in, placed = args       # and nothing else
+        assert params_in is eng.params
+        assert placed.committed == mesh
+        assert placed.is_fully_replicated
+        if mesh:
+            assert placed.sharding == NamedSharding(eng.mesh, P())
+            assert len(placed.sharding.device_set) == TP
+            assert placed.sharding.device_set == \
+                eng._kv.k.sharding.device_set
+        assert (placed == packed).all()
+        puts.clear()
+        got.clear()
 
 
 def test_transfer_counters_equal_the_hand_count(small_model):
@@ -186,11 +300,10 @@ def test_transfer_counters_equal_the_hand_count(small_model):
     eng.submit([2, 3, 4, 5], max_tokens=4)
     eng.run()
     m = eng.metrics
-    # per dispatch, int32 unless said: d_tokens, d_pos [B, 1], d_valid
-    # [B, 1] bool, p_last, att_lens [B], table [B, pages]; a tick with
-    # prefill rows adds p_tokens, p_qpos, p_seq [bucket of 8]
-    decode_only = 4 * (SLOTS + SLOTS + SLOTS + SLOTS) + SLOTS \
-        + 4 * SLOTS * PAGES_PER_SEQ
+    # per dispatch, ONE int32 buffer: d_tokens, d_pos, d_valid [B, 1],
+    # p_last, att_lens [B], table [B, pages]; a tick with prefill rows
+    # adds p_tokens, p_qpos, p_seq [bucket of 8]
+    decode_only = 4 * (3 * SLOTS + 2 * SLOTS + SLOTS * PAGES_PER_SEQ)
     with_prefill = decode_only + 3 * 4 * 8
     assert m.prefill_rows > 0 and m.step_dispatches == 4
     assert m.h2d_bytes == with_prefill + 3 * decode_only
@@ -211,8 +324,38 @@ def test_serving_step_names_blocks_and_parts(small_model):
     scopes, which is what a device trace names its operations by."""
     model, params = small_model
     eng = make_engine(model, params, ManualClock(tick_s=0.01))
-    host = eng._assemble([], [], 0, {})
-    text = eng._step_fn(0, 1).lower(eng.params, eng._kv, *host).as_text(
+    packed = eng._assemble([], [], 0, {})
+    text = eng._step_fn(0, 1).lower(eng.params, eng._kv, packed).as_text(
         debug_info=True)
     for scope in ("l0/attn", "l0/ffn", "head"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("pb", [0, 8], ids=["decode-only", "bucket-of-8"])
+@pytest.mark.parametrize("spec", [0, 2], ids=["k1=1", "k1=3"])
+def test_serving_step_takes_one_integer_operand_and_gathers_nothing(
+        tp_model, pb, spec):
+    """On the 4-device mesh: besides the parameters and the pool the
+    step takes ONE operand, the tick's packed int32 buffer, replicated;
+    the compiled program holds the closed form's all-reduces (two a
+    block) and no other collective, so taking the buffer apart moves
+    nothing between chips."""
+    model, params = tp_model
+    kw = dict(spec_mode="ngram", spec_k=spec) if spec else {}
+    eng = make_engine(model, params, ManualClock(tick_s=0.01),
+                      mesh=tp_mesh(), **kw)
+    k1 = eng._k1
+    words = SLOTS * (3 * k1 + 2 + PAGES_PER_SEQ) + 3 * pb
+    lowered = eng._step_fn(pb, k1).lower(
+        eng.params, eng._kv, jax.device_put(eng._empty_tick(pb, k1),
+                                            eng._tick_sharding))
+    leaves = jax.tree.leaves(lowered.args_info)
+    ints = [a for a in leaves if jnp.issubdtype(a.dtype, jnp.integer)]
+    assert [(a.shape, str(a.dtype)) for a in ints] == [((words,), "int32")]
+    n_pool = len(jax.tree.leaves(eng._kv))
+    assert len(leaves) == len(params) + n_pool + 1
+    text = lowered.compile().as_text()
+    ops = re.findall(r"= \S+ (all-reduce|all-gather|all-to-all|"
+                     r"reduce-scatter|collective-permute|"
+                     r"collective-broadcast)(?:-start)?\(", text)
+    assert ops == ["all-reduce"] * (2 * model.num_layers), ops

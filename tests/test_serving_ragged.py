@@ -579,12 +579,9 @@ def test_engine_step_hands_the_kernel_the_pool_itself(kv_dtype):
                       head_dim=8, num_kv_heads=2, max_positions=128)
     eng = _engine(model, model.init_params(jax.random.PRNGKey(0)),
                   use_kernel=True, kv_dtype=kv_dtype, page_size=8)
-    pb, k1, b = 8, 1, eng._max_slots
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)   # noqa: E731
+    pb, k1 = 8, 1
     closed = jax.make_jaxpr(eng._step_fn(pb, k1))(
-        eng.params, eng._kv, i32(b, k1), i32(b, k1),
-        jnp.zeros((b, k1), bool), i32(pb), i32(pb) - 1, i32(pb), i32(b),
-        i32(b, eng.kv_cfg.max_pages_per_seq), i32(b))
+        eng.params, eng._kv, eng._empty_tick(pb, k1))
     calls = [e for e, _ in _eqns(closed.jaxpr, "jit")
              if e.params["name"] == "_ragged_call"]
     assert len(calls) == layers

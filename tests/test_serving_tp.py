@@ -325,7 +325,10 @@ def test_tp_step_audits_clean_comm_equals_closed_form():
         rec = auditor().sites["serving.step"]
         expected = 0.0
         for _sig, cap in rec.captured.items():
-            rows = cap.args[2].shape[0] + cap.args[5].shape[0]
+            # the packed buffer's length says the prefill bucket
+            p_tokens = eng._tick_parts(
+                np.empty(cap.args[2].shape, np.int32), eng._k1)[3]
+            rows = eng._max_slots * eng._k1 + p_tokens.shape[0]
             expected = max(expected, eng.tp_step_comm_bytes(rows))
         assert expected > 0.0
         assert reps["serving.step"].comm_bytes == expected
