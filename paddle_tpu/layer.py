@@ -2275,18 +2275,23 @@ def mla_attention(input, positions, *, num_heads: int, q_lora_rank: int,
 
 @_export
 def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
-                 expert_hidden: int, top_k: int, scaling: float = 1.0,
-                 shared_hidden: int = 0, name: Optional[str] = None,
+                 expert_hidden: int, top_k: int, routing: str = "sigmoid",
+                 scaling: float = 1.0, shared_hidden: int = 0,
+                 shared_gated: bool = False, name: Optional[str] = None,
                  param_attr=None) -> LayerOutput:
     """One rank's share of a DROPLESS expert layer (parallel/moe.py
-    moe_dropless): a bias-corrected sigmoid router over all ``n_routed``
-    experts, the ``held = (first, count)`` experts this rank holds
-    computed by grouped matrix products over the sorted (token, choice)
-    pairs, and a shared expert of width ``shared_hidden``; SwiGLU experts,
-    no capacity, nothing dropped, no auxiliary loss.  ``bias`` (the
-    router's correction bias) is a static parameter: it enters the choice
-    of experts only and the optimiser leaves it alone.  (The path with a
-    capacity and dropped tokens is :func:`moe_ffn`.)
+    moe_dropless): a router over all ``n_routed`` experts, the ``held =
+    (first, count)`` experts this rank holds computed by grouped matrix
+    products over the sorted (token, choice) pairs, and a shared expert of
+    width ``shared_hidden``; SwiGLU experts, no capacity, nothing dropped,
+    no auxiliary loss.  ``routing`` is ``"sigmoid"`` (bias-corrected
+    sigmoid scores, weights renormalised and times ``scaling``; ``bias``,
+    the router's correction bias, is a static parameter: it enters the
+    choice of experts only and the optimiser leaves it alone) or
+    ``"softmax"`` (a softmax over all the experts, the chosen weights
+    renormalised; no bias, no scaling).  ``shared_gated`` multiplies the
+    shared expert by ``sigmoid(x shared_mix)``, ``shared_mix`` [D, 1].
+    (The path with a capacity and dropped tokens is :func:`moe_ffn`.)
 
     Publishes per step, labelled ``layer=<name>``: ``moe_rows_total``,
     ``moe_rows_held_total``, ``moe_max_expert_rows``."""
@@ -2299,25 +2304,32 @@ def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
     enforce_that(0 <= first and first + count <= n_routed and count > 0,
                  f"held experts {held} are not among {n_routed}",
                  context="moe_dropless")
+    enforce_that(shared_hidden or not shared_gated,
+                 "shared_gated needs a shared expert (shared_hidden)",
+                 context="moe_dropless")
     params = {
         "router": ParamSpec((d, n_routed), attr),
-        "bias": ParamSpec((n_routed,), ParamAttr(
-            initializer=Constant(0.0), is_static=True)),
         "w_gate": ParamSpec((count, d, expert_hidden), attr),
         "w_up": ParamSpec((count, d, expert_hidden), attr),
         "w_down": ParamSpec((count, expert_hidden, d), attr),
     }
+    if routing == "sigmoid":
+        params["bias"] = ParamSpec((n_routed,), ParamAttr(
+            initializer=Constant(0.0), is_static=True))
     if shared_hidden:
         params.update({
             "shared_gate": ParamSpec((d, shared_hidden), attr),
             "shared_up": ParamSpec((d, shared_hidden), attr),
             "shared_down": ParamSpec((shared_hidden, d), attr)})
+    if shared_gated:
+        params["shared_mix"] = ParamSpec((d, 1), attr)
 
     def compute(ctx, p, ins):
         v = ins[0]
         valid = v.valid_mask if isinstance(v, SequenceBatch) else None
         y, stats = pmoe.moe_dropless(_data_of(v), p, top_k=top_k, held=held,
-                                     scaling=scaling, valid=valid)
+                                     routing=routing, scaling=scaling,
+                                     valid=valid)
         ctx.count("moe_rows_total", stats["rows_total"], layer=name)
         ctx.count("moe_rows_held_total", stats["rows_held"], layer=name)
         ctx.count("moe_max_expert_rows", stats["max_expert_rows"],
@@ -2329,6 +2341,91 @@ def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
     return LayerOutput(name=name, layer_type="moe_dropless", inputs=[inp],
                        fn=compute, params=params, size=d,
                        is_sequence=inp.is_sequence)
+
+
+@_export
+def gated_delta_net(input, *, num_k_heads: int, num_v_heads: int,
+                    head_k_dim: int, head_v_dim: int, conv_kernel: int = 4,
+                    epsilon: float = 1e-6, name: Optional[str] = None,
+                    param_attr=None) -> LayerOutput:
+    """Gated delta-rule mixing layer (linear attention) over packed
+    sequences (ops/gated_delta.py): one projection to ``[q | k | v | z]``
+    and one to the per-head gates ``[b | a]``, a causal depthwise
+    convolution of ``conv_kernel`` taps and SiLU over ``q | k | v``, the
+    delta-rule recurrence per value head in chunked form (state and
+    convolution start anew with each sequence), a gated RMSNorm per head
+    and the output projection.  ``a_log`` starts at 0 and ``dt_bias`` at
+    -4.6, a decay of about 0.99 a token.  No biases, no cache."""
+    from paddle_tpu.ops.gated_delta import gated_delta_net as gdn
+
+    _need_seq(input, "gated_delta_net")
+    name = name or unique_name("gated_delta_net")
+    attr = ParamAttr.to_attr(param_attr)
+    d = input.size
+    nq, nv = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+    const = lambda c: ParamAttr(initializer=Constant(c))  # noqa: E731
+    params = {
+        "w_qkvz": ParamSpec((d, 2 * nq + 2 * nv), attr),
+        "w_ba": ParamSpec((d, 2 * num_v_heads), attr),
+        "conv": ParamSpec((2 * nq + nv, conv_kernel), attr),
+        "a_log": ParamSpec((num_v_heads,), const(0.0)),
+        "dt_bias": ParamSpec((num_v_heads,), const(-4.6)),
+        "norm": ParamSpec((head_v_dim,), const(1.0)),
+        "wo": ParamSpec((nv, d), attr),
+    }
+
+    def compute(ctx, p, ins):
+        xs = ins[0]
+        y = gdn(xs.data, xs.segment_ids, p, num_k_heads=num_k_heads,
+                num_v_heads=num_v_heads, head_k_dim=head_k_dim,
+                head_v_dim=head_v_dim, eps=epsilon)
+        return xs.with_data(y.astype(pmath.dense_activation_dtype()))
+
+    return LayerOutput(name=name, layer_type="gated_delta_net",
+                       inputs=[input], fn=compute, params=params, size=d,
+                       is_sequence=True)
+
+
+@_export
+def gated_attention(input, positions, *, num_heads: int, num_kv_heads: int,
+                    head_dim: int, rotary_dim: int,
+                    rope_theta: float = 10000.0, epsilon: float = 1e-6,
+                    name: Optional[str] = None, param_attr=None
+                    ) -> LayerOutput:
+    """Causal grouped-query attention over packed sequences with a
+    weighted RMSNorm on each head's query and key, rotary positions on the
+    first ``rotary_dim`` of a head's ``head_dim`` and an output gate
+    ``sigmoid(g)`` that the query projection makes beside the query
+    (ops/gated_attention.py).  ``positions`` as :func:`mla_attention`'s.
+    No biases, no cache."""
+    from paddle_tpu.ops.gated_attention import gated_attention as gattn
+
+    _need_seq(input, "gated_attention")
+    _need_seq(positions, "gated_attention")
+    name = name or unique_name("gated_attention")
+    attr = ParamAttr.to_attr(param_attr)
+    gain = ParamAttr(initializer=Constant(1.0))
+    d = input.size
+    params = {
+        "wq": ParamSpec((d, num_heads * 2 * head_dim), attr),
+        "wk": ParamSpec((d, num_kv_heads * head_dim), attr),
+        "wv": ParamSpec((d, num_kv_heads * head_dim), attr),
+        "q_norm": ParamSpec((head_dim,), gain),
+        "k_norm": ParamSpec((head_dim,), gain),
+        "wo": ParamSpec((num_heads * head_dim, d), attr),
+    }
+
+    def compute(ctx, p, ins):
+        xs, pos = ins
+        y = gattn(xs.data, pos.data.reshape(-1), xs.segment_ids, p,
+                  num_heads=num_heads, num_kv_heads=num_kv_heads,
+                  head_dim=head_dim, rotary_dim=rotary_dim, eps=epsilon,
+                  theta=rope_theta, mesh=ctx.mesh)
+        return xs.with_data(y.astype(pmath.dense_activation_dtype()))
+
+    return LayerOutput(name=name, layer_type="gated_attention",
+                       inputs=[input, positions], fn=compute, params=params,
+                       size=d, is_sequence=True)
 
 
 @_export

@@ -13,6 +13,7 @@ from paddle_tpu.models import sequence_tagging
 from paddle_tpu.models import srl
 from paddle_tpu.models import transformer
 from paddle_tpu.models import glm_moe_lite
+from paddle_tpu.models import qwen3_next
 from paddle_tpu.models import quick_start
 from paddle_tpu.models import traffic_prediction
 from paddle_tpu.models import googlenet

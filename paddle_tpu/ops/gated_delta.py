@@ -1,0 +1,202 @@
+"""The gated delta-rule mixing layer (Gated DeltaNet linear attention).
+
+Per value head a state ``S`` in ``R^{d_k x d_v}`` starts from zero with
+each sequence and, token by token::
+
+    S <- exp(g_t) S;  r = v_t - S^T k_t;  S <- S + k_t (beta_t r)^T
+    o_t = S^T q_t
+
+That recurrence is the definition (``benchmarks/references/qwen3_next.py``
+and the tests run it).  Here it is computed in CHUNKED form: inside a chunk
+of ``CHUNK`` tokens the updates ``u_i = beta_i r_i`` solve the
+unit-lower-triangular system ``(I + A) u = beta v - (beta a k) S_0`` with
+``A_ij = beta_i D_ij (k_i . k_j)`` for ``j < i``, ``D_ij = exp(G_i - G_j)``,
+``G`` the running sum of ``g`` inside the chunk and ``a_i = exp(G_i)``;
+``(I + A)^{-1}`` is formed once a chunk by block forward substitution
+(``_unit_lower_inverse``) and applied to ``beta v`` and ``beta a k``, and
+the chunks are chained through ``S`` by ``lax.scan``.
+
+The tokens lie in one flat buffer with ``segment_ids``; a sequence may
+start anywhere in a chunk.  Three masks carry that: ``D`` is zero across
+segments, a token reads the incoming state only if its segment began
+before the chunk, and only the tokens of the chunk's last segment write
+the outgoing one.
+
+Float32 throughout: ``g``, ``G``, ``D``, the inverse and the carried ``S``.
+Under ``FLAGS.use_bf16`` the products take bfloat16 operands and accumulate
+in float32, except those that form the inverse, which stay float32 at the
+highest precision.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops import math as pmath
+from paddle_tpu.ops.norm import rms_norm
+
+CHUNK = 64
+
+
+def causal_conv(x: jax.Array, w: jax.Array, segment_ids: jax.Array
+                ) -> jax.Array:
+    """Causal depthwise convolution inside each segment of a flat buffer:
+    ``y_t = sum_j w[:, j] x_{t - (K - 1) + j}``, a row before its own
+    sequence's start counting as zero.  x: [T, C]; w: [C, K] (the last tap
+    meets the current token).  Float32."""
+    t, taps = x.shape[0], w.shape[1]
+    w = w.astype(jnp.float32)
+    # every tap reads a window of ONE padded copy, so the taps fuse into a
+    # single pass over it; rows of one sequence are contiguous, so the row
+    # s back belongs to this sequence exactly if it carries this row's id
+    xp = jnp.pad(x.astype(jnp.float32), ((taps - 1, 0), (0, 0)))
+    sp = jnp.pad(segment_ids, (taps - 1, 0), constant_values=-1)
+    y = xp[taps - 1:] * w[:, taps - 1]
+    for s in range(1, taps):
+        lo = taps - 1 - s
+        same = (sp[lo:lo + t] == segment_ids)[:, None]
+        y = y + jnp.where(same, xp[lo:lo + t], 0.0) * w[:, lo]
+    return y
+
+
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^{-1}`` for strictly lower-triangular ``a`` [..., C, C], ``C``
+    a power of two, by block forward substitution: the inverse of a
+    block-triangular ``[[M11, 0], [M21, M22]]`` is ``[[T11, 0], [-T22 M21
+    T11, T22]]``.  ``t`` starts as the inverse of the 1 x 1 diagonal blocks
+    (the identity) and each round joins neighbouring diagonal blocks of
+    size ``b`` into blocks of ``2 b``: ``t <- t - t (a * below_b) t``, where
+    ``below_b`` keeps ``a``'s ``M21`` blocks.  ``log2 C`` rounds of two
+    float32 products at the highest precision.  Every intermediate is a
+    block of the inverse of a leading part of ``I + a``, as benign as the
+    result; the shorter ``(I - a)(I + a^2)(I + a^4)...`` sums powers that
+    reach ``binomial(C, C / 2)`` when a chunk's keys align, and loses
+    everything to cancellation (it made a run diverge on the chip)."""
+    c = a.shape[-1]
+    assert c & (c - 1) == 0, c
+    mm = lambda x, y: jnp.matmul(  # noqa: E731
+        x, y, precision=jax.lax.Precision.HIGHEST)
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    t = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
+    b = 1
+    while b < c:
+        below = (i // (2 * b) == j // (2 * b)) & ((i // b) % 2 == 1) \
+            & ((j // b) % 2 == 0)
+        t = t - mm(mm(t, jnp.where(below, a, 0.0)), t)
+        b *= 2
+    return t
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, segment_ids: jax.Array) -> jax.Array:
+    """The recurrence above in chunked form.  q, k: [T, Hk, dk] (as they
+    enter the recurrence: normalised, q scaled); v: [T, Hv, dv]; g (log
+    decay, <= 0) and beta: [T, Hv]; ``Hv`` a multiple of ``Hk``, key head
+    ``h // (Hv / Hk)`` serving value head ``h``.  Returns o [T, Hv, dv]
+    float32.  ``T`` is padded to a multiple of ``CHUNK`` with rows of a
+    segment of their own."""
+    t, hk, dk = k.shape
+    hv, dv = v.shape[1], v.shape[2]
+    rep = hv // hk
+    ct = pmath.compute_dtype(v)
+    c = CHUNK
+    pad = -t % c
+    if pad:
+        rows = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
+        q, k, v, g, beta = (rows(a) for a in (q, k, v, g, beta))
+        segment_ids = jnp.pad(segment_ids, (0, pad),
+                              constant_values=jnp.iinfo(jnp.int32).max)
+    n = (t + pad) // c
+
+    def chunks(a):          # [T, H, ...] -> [n, H, c, ...]
+        return jnp.swapaxes(a.reshape((n, c) + a.shape[1:]), 1, 2)
+
+    def heads(a):           # a key head's array for each value head it serves
+        return jnp.repeat(a, rep, axis=1) if rep > 1 else a
+
+    def mm(x, y):           # bf16 operands under the policy, f32 result
+        return jnp.matmul(x.astype(ct), y.astype(ct),
+                          preferred_element_type=jnp.float32)
+
+    qc, kc, vc = chunks(q), chunks(k), chunks(v)
+    gc = chunks(g.astype(jnp.float32))                  # [n, Hv, c]
+    bc = chunks(beta.astype(jnp.float32))
+    seg = segment_ids.reshape(n, c)
+    before = jnp.concatenate([jnp.full((1,), -1, seg.dtype), seg[:-1, -1]])
+    same = (seg[:, :, None] == seg[:, None, :])[:, None]          # [n,1,c,c]
+    reads = (seg == before[:, None])[:, None].astype(jnp.float32)  # [n,1,c]
+    writes = (seg == seg[:, -1:])[:, None].astype(jnp.float32)
+
+    cum = jnp.cumsum(gc, axis=-1)
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(same & (i >= j),
+                              cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))                # D, diagonal kept
+    kt = jnp.swapaxes(kc, -1, -2)
+    kk, qk = heads(mm(kc, kt)), heads(mm(qc, kt))       # [n, Hv, c, c]
+    inv = _unit_lower_inverse(
+        jnp.where(i > j, bc[..., None] * kk * decay, 0.0))
+    a_in = jnp.exp(cum) * reads                         # a, [n, Hv, c]
+    kh, qh = heads(kc).astype(jnp.float32), heads(qc).astype(jnp.float32)
+    u = mm(inv, bc[..., None] * vc)                     # [n, Hv, c, dv]
+    w = mm(inv, (bc * a_in)[..., None] * kh)            # [n, Hv, c, dk]
+    q_in = qh * a_in[..., None]
+    k_out = kh * (jnp.exp(cum[..., -1:] - cum) * writes)[..., None]
+    keep = jnp.exp(cum[..., -1]) * reads[..., -1]       # [n, Hv]
+
+    def step(s, xs):
+        u_i, w_i, q_i, k_i, keep_i = xs
+        new = u_i - mm(w_i, s)                          # the chunk's updates
+        from_state = mm(q_i, s)
+        s = keep_i[:, None, None] * s + mm(jnp.swapaxes(k_i, -1, -2), new)
+        return s, (new, from_state)
+
+    _, (new, from_state) = jax.lax.scan(
+        step, jnp.zeros((hv, dk, dv), jnp.float32),
+        (u, w, q_in, k_out, keep))
+    o = from_state + mm(qk * decay, new)                # [n, Hv, c, dv]
+    return jnp.swapaxes(o, 1, 2).reshape(n * c, hv, dv)[:t]
+
+
+def gated_delta_net(x: jax.Array, segment_ids: jax.Array,
+                    p: Dict[str, jax.Array], *, num_k_heads: int,
+                    num_v_heads: int, head_k_dim: int, head_v_dim: int,
+                    eps: float = 1e-6) -> jax.Array:
+    """The whole mixing layer over one flat buffer.  x: [T, hidden];
+    segment_ids: [T].  ``p``: ``w_qkvz`` [hidden, 2 Hk dk + 2 Hv dv] (columns
+    ``[q | k | v | z]``), ``w_ba`` [hidden, 2 Hv] (``[b | a]``), ``conv``
+    [2 Hk dk + Hv dv, K], ``a_log``, ``dt_bias`` [Hv], ``norm`` [dv], ``wo``
+    [Hv dv, hidden].  No biases.  Returns [T, hidden] float32.
+
+    ``[q|k|v] <- silu(conv(q|k|v))``; q and k are L2-normalised per head and
+    q scaled by ``dk ** -0.5``; ``beta = sigmoid(b)``, ``g = -exp(a_log)
+    softplus(a + dt_bias)``; after the recurrence ``y = RMSNorm(o) * norm
+    * silu(z)`` per head, times ``wo``."""
+    t = x.shape[0]
+    hk, hv, dk, dv = num_k_heads, num_v_heads, head_k_dim, head_v_dim
+    nq, nv = hk * dk, hv * dv
+    with jax.named_scope("gdn"):
+        with jax.named_scope("gdn.proj"):
+            qkvz = pmath.matmul(x, p["w_qkvz"])
+            ba = pmath.matmul(x, p["w_ba"])
+        with jax.named_scope("gdn.conv"):
+            qkv = jax.nn.silu(causal_conv(qkvz[:, :2 * nq + nv], p["conv"],
+                                          segment_ids))
+            unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+            q = unit(qkv[:, :nq].reshape(t, hk, dk)) * float(dk) ** -0.5
+            k = unit(qkv[:, nq:2 * nq].reshape(t, hk, dk))
+            v = qkv[:, 2 * nq:].reshape(t, hv, dv)
+            beta = jax.nn.sigmoid(ba[:, :hv])
+            g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+                ba[:, hv:] + p["dt_bias"].astype(jnp.float32))
+        with jax.named_scope("gdn.scan"):
+            o = gated_delta_rule(q, k, v, g, beta, segment_ids)
+        with jax.named_scope("gdn.out"):
+            z = qkvz[:, 2 * nq + nv:].reshape(t, hv, dv)
+            y = rms_norm(o, p["norm"].astype(jnp.float32), eps) \
+                * jax.nn.silu(z)
+            return pmath.matmul(y.reshape(t, nv), p["wo"])

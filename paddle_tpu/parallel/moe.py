@@ -15,11 +15,12 @@ Two paths live here.  The CAPACITY path: ``moe_ffn_reference`` is the
 collectives-free dense formulation used for single-device runs and as
 the parity oracle; ``moe_ffn`` is the shard_map/all_to_all version.  The
 DROPLESS path (``moe_dropless``, further down): one rank's share of an
-expert-parallel layer with a bias-corrected sigmoid router over all the
-experts, the held experts computed by grouped matrix products over the
-sorted (token, choice) pairs (``ops/grouped_matmul.py``), a shared
-expert, no capacity and nothing dropped; it has no exchange yet and
-computes what its own experts give.
+expert-parallel layer with a router over all the experts (bias-corrected
+sigmoid scores or a softmax, as the caller names), the held experts
+computed by grouped matrix products over the sorted (token, choice) pairs
+(``ops/grouped_matmul.py``), a shared expert (gated or not), no capacity
+and nothing dropped; it has no exchange yet and computes what its own
+experts give.
 
 In the capacity path tokens over capacity are DROPPED (pass
 through as zeros — callers add the residual), the Switch convention.
@@ -380,6 +381,21 @@ def route_sigmoid_topk(x, router_w, bias, top_k: int, scaling: float = 1.0):
     return experts.astype(jnp.int32), g
 
 
+def route_softmax_topk(x, router_w, top_k: int):
+    """Softmax routing: ``p = softmax(x W_r)`` over all the experts in
+    float32 at the highest matmul precision; the ``top_k`` largest are
+    chosen and their weights renormalised, ``p[chosen] / sum(p[chosen])``
+    (``norm_topk_prob``).  No bias, no scaling.
+    Returns (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    g, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return experts.astype(jnp.int32), g / jnp.sum(g, axis=-1, keepdims=True)
+
+
+ROUTINGS = ("sigmoid", "softmax")
+
+
 def _take_rows(a, idx):
     """Rows of ``a`` at ``idx``; an index past the end gives a zero row."""
     return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
@@ -434,18 +450,22 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
-                 held: Tuple[int, int], scaling: float = 1.0,
-                 valid: Optional[jax.Array] = None, tile_m: int = 0):
+                 held: Tuple[int, int], routing: str = "sigmoid",
+                 scaling: float = 1.0, valid: Optional[jax.Array] = None,
+                 tile_m: int = 0):
     """What one rank of an expert-parallel group computes of a dropless
     expert layer: it routes over ALL the experts (``p["router"]`` is
-    [D, n_routed], ``p["bias"]`` the correction bias), holds the
+    [D, n_routed]) by the ``routing`` its caller names, ``"sigmoid"``
+    (:func:`route_sigmoid_topk`, with ``p["bias"]`` the correction bias and
+    ``scaling``) or ``"softmax"`` (:func:`route_softmax_topk`), holds the
     ``held = (first, count)`` of them whose gated feed-forward matrices it
     is given (``w_gate``, ``w_up`` [count, D, F]; ``w_down`` [count, F,
     D]), and returns its own experts' part of the result plus, where
     ``p`` has one, the shared expert (``shared_gate``, ``shared_up``,
-    ``shared_down``), which every rank computes alike.  A chosen expert
-    that is not held adds nothing here; no token is dropped and there is
-    no capacity.
+    ``shared_down``), which every rank computes alike, times
+    ``sigmoid(x shared_mix)`` where ``p`` has that [D, 1] gate.  A chosen
+    expert that is not held adds nothing here; no token is dropped and
+    there is no capacity.
 
     (token, choice) pairs on held experts are placed expert by expert
     into a buffer whose groups start at multiples of ``tile_m``
@@ -461,12 +481,18 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
     from paddle_tpu.ops import grouped_matmul as gm
     from paddle_tpu.ops import math as pmath
 
+    enforce_that(routing in ROUTINGS,
+                 f"routing {routing!r} is not one of {ROUTINGS}",
+                 context="moe_dropless")
     tile_m = tile_m or gm.TILE_M
     first, count = held
     t, _ = x.shape
     with jax.named_scope("moe.route"):
-        experts, g = route_sigmoid_topk(x, p["router"], p["bias"], top_k,
-                                        scaling)
+        if routing == "softmax":
+            experts, g = route_softmax_topk(x, p["router"], top_k)
+        else:
+            experts, g = route_sigmoid_topk(x, p["router"], p["bias"],
+                                            top_k, scaling)
         local = experts - first
         on = (local >= 0) & (local < count)
         if valid is not None:
@@ -498,8 +524,12 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
         out = _combine(y, g, dest, row_token, row_pair)
     if "shared_gate" in p:
         with jax.named_scope("moe.shared"):
-            out = out + pmath.swiglu(x, p["shared_gate"], p["shared_up"],
-                                     p["shared_down"])
+            shared = pmath.swiglu(x, p["shared_gate"], p["shared_up"],
+                                  p["shared_down"])
+            if "shared_mix" in p:
+                shared = shared * jax.nn.sigmoid(
+                    pmath.matmul(x, p["shared_mix"]))
+            out = out + shared
     n_valid = t if valid is None else jnp.sum(valid)
     stats = {"rows_total": jnp.asarray(n_valid * top_k, jnp.float32),
              "rows_held": jnp.sum(counts).astype(jnp.float32),

@@ -92,14 +92,17 @@ def dense(name, dim, n=4):
     return v, feed
 
 
-def make_seq(name, dim, lengths):
+def make_seq(name, dim, lengths, rng=None):
+    """``rng``: a generator of the case's own, for a layer whose numeric
+    gradient is input-dependent (the module's RNG depends on which cases a
+    worker ran before)."""
     v = layer.data(name=name,
                    type=paddle.data_type.dense_vector_sequence(dim))
     total = sum(lengths)
     seg = np.concatenate([np.full(L, i, np.int32)
                           for i, L in enumerate(lengths)])
     sb = SequenceBatch(
-        jnp.asarray(RNG.randn(total, dim).astype(np.float32)),
+        jnp.asarray((rng or RNG).randn(total, dim).astype(np.float32)),
         jnp.asarray(seg),
         jnp.asarray(np.asarray(lengths, np.int32)),
         max_len=max(lengths))

@@ -1187,6 +1187,38 @@ def _moe_dropless():
     check_layer_grad(out, {"x": fx}, delta=1e-4, rtol=8e-2)
 
 
+@case("moe_dropless_softmax")
+def _moe_dropless_softmax():
+    # the softmax routing with a gated shared expert (models/qwen3_next.py);
+    # no correction bias among its parameters
+    x, fx = dense("x", 6)
+    out = layer.moe_dropless(x, n_routed=4, held=(1, 2), expert_hidden=5,
+                             top_k=2, routing="softmax", shared_hidden=5,
+                             shared_gated=True)
+    assert "bias" not in out.params and "shared_mix" in out.params
+    check_layer_grad(out, {"x": fx}, delta=1e-4, rtol=8e-2)
+
+
+@case("gated_delta_net")
+def _gated_delta_net():
+    s, fs = make_seq("s", 8, [5, 3], rng=np.random.RandomState(1))
+    out = layer.gated_delta_net(s, num_k_heads=1, num_v_heads=2,
+                                head_k_dim=4, head_v_dim=4)
+    check_layer_grad(layer.pooling(out), {"s": fs}, delta=5e-3, rtol=8e-2)
+
+
+@case("gated_attention")
+def _gated_attention():
+    s, fs = make_seq("s", 8, [5, 3], rng=np.random.RandomState(2))
+    pos, _ = int_seq("pos", 8, [5, 3])
+    fpos = SequenceBatch(jnp.asarray([0, 1, 2, 3, 4, 0, 1, 2], jnp.int32),
+                         fs.segment_ids, fs.lengths, max_len=5)
+    out = layer.gated_attention(s, pos, num_heads=2, num_kv_heads=1,
+                                head_dim=8, rotary_dim=4)
+    check_layer_grad(layer.pooling(out), {"s": fs, "pos": fpos}, delta=5e-3,
+                     rtol=8e-2)
+
+
 @case("next_token_cost")
 def _next_token_cost():
     s, fs = make_seq("s", 6, [4, 3])

@@ -262,6 +262,57 @@ def test_grouped_matmul_compiles_for_v5e(topo, matrix, monkeypatch):
     assert _compile(jax.grad(loss, argnums=(0, 1)), *avals) == 2
 
 
+# ---- a whole train step of the delta-rule / gated-attention model ------------
+
+def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
+                                                                 monkeypatch):
+    """``models.qwen3_next.build`` under ``trainer.SGD`` at a small size
+    (heads of 128, one sequence of 256): the whole step, Adam included,
+    lowers and compiles for a described v5e; its text names the scopes the
+    benchmark's readers select by, and holds the flash kernels of the one
+    attention block and the grouped products of four expert layers."""
+    import paddle_tpu as paddle
+    from paddle_tpu import optimizer, trainer
+    from paddle_tpu.analysis import retrace
+    from paddle_tpu.models import qwen3_next
+    from paddle_tpu.ops import attention as pattn
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    # the code asks jax.default_backend() and sees the CPU
+    monkeypatch.setattr(pattn, "_interpret_default", lambda: False)
+    monkeypatch.setattr(gm, "interpret_default", lambda: False)
+    monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
+    paddle.topology.reset_name_scope()
+    *_, cost = qwen3_next.build(
+        vocab_size=512, hidden_size=256, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=128, linear_num_key_heads=2,
+        linear_num_value_heads=4, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, num_experts=8,
+        held_experts=(0, 4), num_experts_per_tok=2, max_len=256, remat=True)
+    params = paddle.Parameters.from_topology(
+        paddle.topology.Topology([cost]))
+    sgd = trainer.SGD(cost=cost, parameters=params,
+                      update_equation=optimizer.Adam(learning_rate=2e-4))
+    t = np.arange(257, dtype=np.int32)
+    feeds = sgd._make_feeder({"tokens": 0, "pos": 1, "target": 2}).feed(
+        [(t[:-1], t[:-1], t[1:])])
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    tree = lambda x: jax.tree.map(  # noqa: E731
+        lambda a: aval(np.shape(a), a.dtype), x)
+    compiled = sgd._build_step().lower(
+        tree(sgd.parameters.as_dict()), tree(sgd.opt_state),
+        tree(sgd.model_state), tree(jax.random.PRNGKey(0)),
+        tree(feeds)).compile()
+    text = compiled.as_text()
+    for scope in ("gdn/gdn.proj", "gdn/gdn.conv", "gdn/gdn.scan",
+                  "gdn/gdn.out", "gattn", "moe.route", "moe.experts",
+                  "moe.shared"):
+        assert scope + "/" in text, scope
+    # one attention block: forward twice (remat), dKV, dQ; four expert
+    # layers: 6 + 3 moe_gmm and 3 moe_tgmm each
+    assert text.count("tpu_custom_call") == 4 + 4 * 12
+
+
 # ---- names in the device trace ---------------------------------------------
 
 def _kernel_names(fn, *avals):
