@@ -12,9 +12,11 @@ of ``CHUNK`` tokens the updates ``u_i = beta_i r_i`` solve the
 unit-lower-triangular system ``(I + A) u = beta v - (beta a k) S_0`` with
 ``A_ij = beta_i D_ij (k_i . k_j)`` for ``j < i``, ``D_ij = exp(G_i - G_j)``,
 ``G`` the running sum of ``g`` inside the chunk and ``a_i = exp(G_i)``;
-``(I + A)^{-1}`` is formed once a chunk by block forward substitution
-(``_unit_lower_inverse``) and applied to ``beta v`` and ``beta a k``, and
-the chunks are chained through ``S`` by ``lax.scan``.
+``(I + A)^{-1}`` is formed once a chunk by block forward substitution and
+applied to ``beta v`` and ``beta a k``, and the chunks are chained through
+``S``.  All of that is two Pallas kernels, forward and backward
+(``ops/gdn_kernels.py``): a chunk's ``CHUNK x CHUNK`` tiles and the state
+live in VMEM; ``gated_delta_rule`` lays the buffer out for them.
 
 The tokens lie in one flat buffer with ``segment_ids``; a sequence may
 start anywhere in a chunk.  Three masks carry that: ``D`` is zero across
@@ -35,6 +37,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops import gdn_kernels
 from paddle_tpu.ops import math as pmath
 from paddle_tpu.ops.norm import rms_norm
 
@@ -62,34 +65,6 @@ def causal_conv(x: jax.Array, w: jax.Array, segment_ids: jax.Array
     return y
 
 
-def _unit_lower_inverse(a: jax.Array) -> jax.Array:
-    """``(I + a)^{-1}`` for strictly lower-triangular ``a`` [..., C, C], ``C``
-    a power of two, by block forward substitution: the inverse of a
-    block-triangular ``[[M11, 0], [M21, M22]]`` is ``[[T11, 0], [-T22 M21
-    T11, T22]]``.  ``t`` starts as the inverse of the 1 x 1 diagonal blocks
-    (the identity) and each round joins neighbouring diagonal blocks of
-    size ``b`` into blocks of ``2 b``: ``t <- t - t (a * below_b) t``, where
-    ``below_b`` keeps ``a``'s ``M21`` blocks.  ``log2 C`` rounds of two
-    float32 products at the highest precision.  Every intermediate is a
-    block of the inverse of a leading part of ``I + a``, as benign as the
-    result; the shorter ``(I - a)(I + a^2)(I + a^4)...`` sums powers that
-    reach ``binomial(C, C / 2)`` when a chunk's keys align, and loses
-    everything to cancellation (it made a run diverge on the chip)."""
-    c = a.shape[-1]
-    assert c & (c - 1) == 0, c
-    mm = lambda x, y: jnp.matmul(  # noqa: E731
-        x, y, precision=jax.lax.Precision.HIGHEST)
-    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    t = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
-    b = 1
-    while b < c:
-        below = (i // (2 * b) == j // (2 * b)) & ((i // b) % 2 == 1) \
-            & ((j // b) % 2 == 0)
-        t = t - mm(mm(t, jnp.where(below, a, 0.0)), t)
-        b *= 2
-    return t
-
-
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, segment_ids: jax.Array) -> jax.Array:
     """The recurrence above in chunked form.  q, k: [T, Hk, dk] (as they
@@ -98,7 +73,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     ``h // (Hv / Hk)`` serving value head ``h``.  Returns o [T, Hv, dv]
     float32.  ``T`` is padded to a multiple of ``CHUNK`` with rows of a
     segment of their own."""
-    t, hk, dk = k.shape
+    t, hk, _ = k.shape
     hv, dv = v.shape[1], v.shape[2]
     rep = hv // hk
     ct = pmath.compute_dtype(v)
@@ -111,54 +86,26 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                               constant_values=jnp.iinfo(jnp.int32).max)
     n = (t + pad) // c
 
-    def chunks(a):          # [T, H, ...] -> [n, H, c, ...]
-        return jnp.swapaxes(a.reshape((n, c) + a.shape[1:]), 1, 2)
+    def chunks(a):          # [T, Hv] -> [n, Hk, rep, c]
+        return jnp.swapaxes(a.astype(jnp.float32).reshape(n, c, hv), 1, 2
+                            ).reshape(n, hk, rep, c)
 
-    def heads(a):           # a key head's array for each value head it serves
-        return jnp.repeat(a, rep, axis=1) if rep > 1 else a
-
-    def mm(x, y):           # bf16 operands under the policy, f32 result
-        return jnp.matmul(x.astype(ct), y.astype(ct),
-                          preferred_element_type=jnp.float32)
-
-    qc, kc, vc = chunks(q), chunks(k), chunks(v)
-    gc = chunks(g.astype(jnp.float32))                  # [n, Hv, c]
-    bc = chunks(beta.astype(jnp.float32))
     seg = segment_ids.reshape(n, c)
     before = jnp.concatenate([jnp.full((1,), -1, seg.dtype), seg[:-1, -1]])
-    same = (seg[:, :, None] == seg[:, None, :])[:, None]          # [n,1,c,c]
-    reads = (seg == before[:, None])[:, None].astype(jnp.float32)  # [n,1,c]
-    writes = (seg == seg[:, -1:])[:, None].astype(jnp.float32)
+    reads = seg == before[:, None]                      # [n, c]
+    # a row's first row of the same segment: equal in a chunk where the
+    # ids are, and small enough to be exact in float32
+    first = jnp.argmax(seg[:, :, None] == seg[:, None, :], axis=-1)
+    marks = jnp.tile(jnp.stack([first, reads, seg == seg[:, -1:]], axis=1
+                               ).astype(jnp.float32), (1, 1, rep))
+    cum = jnp.cumsum(chunks(g), axis=-1)                # G, [n, Hk, rep, c]
+    # a key head's value heads side by side: [n, Hk, 2, rep c]
+    scalars = jnp.stack([cum, chunks(beta)], axis=2).reshape(
+        n, hk, 2, rep * c)
+    q2, k2, v2 = (a.reshape(n * c, -1) for a in (q, k, v))
 
-    cum = jnp.cumsum(gc, axis=-1)
-    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    decay = jnp.exp(jnp.where(same & (i >= j),
-                              cum[..., :, None] - cum[..., None, :],
-                              -jnp.inf))                # D, diagonal kept
-    kt = jnp.swapaxes(kc, -1, -2)
-    kk, qk = heads(mm(kc, kt)), heads(mm(qc, kt))       # [n, Hv, c, c]
-    inv = _unit_lower_inverse(
-        jnp.where(i > j, bc[..., None] * kk * decay, 0.0))
-    a_in = jnp.exp(cum) * reads                         # a, [n, Hv, c]
-    kh, qh = heads(kc).astype(jnp.float32), heads(qc).astype(jnp.float32)
-    u = mm(inv, bc[..., None] * vc)                     # [n, Hv, c, dv]
-    w = mm(inv, (bc * a_in)[..., None] * kh)            # [n, Hv, c, dk]
-    q_in = qh * a_in[..., None]
-    k_out = kh * (jnp.exp(cum[..., -1:] - cum) * writes)[..., None]
-    keep = jnp.exp(cum[..., -1]) * reads[..., -1]       # [n, Hv]
-
-    def step(s, xs):
-        u_i, w_i, q_i, k_i, keep_i = xs
-        new = u_i - mm(w_i, s)                          # the chunk's updates
-        from_state = mm(q_i, s)
-        s = keep_i[:, None, None] * s + mm(jnp.swapaxes(k_i, -1, -2), new)
-        return s, (new, from_state)
-
-    _, (new, from_state) = jax.lax.scan(
-        step, jnp.zeros((hv, dk, dv), jnp.float32),
-        (u, w, q_in, k_out, keep))
-    o = from_state + mm(qk * decay, new)                # [n, Hv, c, dv]
-    return jnp.swapaxes(o, 1, 2).reshape(n * c, hv, dv)[:t]
+    o = gdn_kernels.delta_rule_chunks(q2, k2, v2, scalars, marks, ct)
+    return o.reshape(n * c, hv, dv)[:t]
 
 
 def gated_delta_net(x: jax.Array, segment_ids: jax.Array,

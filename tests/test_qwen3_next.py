@@ -157,6 +157,74 @@ def test_a_later_segment_sees_nothing_of_an_earlier_one(f32_products):
     close(whole[70:], alone, rtol=1e-5)
 
 
+def rule_inputs(t, hk, hv):
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    return (unit(normal(1, t, hk, DK)) * DK ** -0.5,
+            unit(normal(2, t, hk, DK)), normal(3, t, hv, DV),
+            -uniform(4, 0.001, 0.1, t, hv), uniform(5, 0.1, 0.9, t, hv))
+
+
+def recurrence(seg, rep):
+    """The reference's per-token recurrence in float32, a key head's
+    arrays repeated for the value heads it serves."""
+    wide = lambda a: jnp.repeat(a, rep, axis=1)  # noqa: E731
+
+    def theirs(q, k, v, g, beta):
+        with jax.default_matmul_precision("highest"):
+            return REF.delta_rule(wide(q), wide(k), v, g, beta, seg, "f32",
+                                  block_rows=seg.shape[0])
+    return theirs
+
+
+def grads_of(f, args, probe):
+    return jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * probe),
+                            argnums=(0, 1, 2, 3, 4)))(*args)
+
+
+@pytest.mark.parametrize("lengths", [[100], [63, 130]],
+                         ids=["not-a-multiple", "starts-in-a-last-row"])
+@pytest.mark.parametrize("rep", [1, 2])
+def test_delta_rule_kernels_serve_one_or_two_value_heads_a_key_head(
+        f32_products, rep, lengths):
+    """A grid cell of the ``gdn_*`` kernels is a chunk of one key head and
+    its ``Hv / Hk`` value heads; a buffer that ends inside a chunk (its
+    padding a segment of its own), and a sequence whose first row is a
+    chunk's last (it reads no state there, and alone writes it)."""
+    t, hv = sum(lengths), 2 * rep
+    _, seg = segments(lengths)
+    args = rule_inputs(t, 2, hv)
+    ours = lambda *a: gd.gated_delta_rule(*a, seg)  # noqa: E731
+    theirs = recurrence(seg, rep)
+    want = jax.jit(theirs)(*args)
+    assert float(jnp.abs(want).max()) > 0.1
+    close(jax.jit(ours)(*args), want, rtol=1e-5)
+    probe = normal(6, t, hv, DV)
+    for a, b in zip(grads_of(ours, args, probe),
+                    grads_of(theirs, args, probe)):
+        close(a, b, rtol=2e-5)
+
+
+def test_delta_rule_with_bfloat16_operands_stays_near_the_recurrence():
+    """Under ``FLAGS.use_bf16`` (the default) the products take bfloat16
+    operands and accumulate in float32; the inverse, the decays and the
+    state stay float32.  Against the float32 recurrence the values read
+    5.0e-3 of their largest here and the five gradients 4.4e-3 to 6.6e-3
+    (the plain chunked form that the kernels replaced: 5.0e-3, and 4.8e-3
+    to 7.1e-3); the limits are twice that."""
+    assert FLAGS.use_bf16
+    lengths = [70, 130, 100]
+    t = sum(lengths)
+    _, seg = segments(lengths)
+    args = rule_inputs(t, HK, HV)
+    ours = lambda *a: gd.gated_delta_rule(*a, seg)  # noqa: E731
+    theirs = recurrence(seg, HV // HK)
+    close(jax.jit(ours)(*args), jax.jit(theirs)(*args), rtol=1e-2)
+    probe = normal(6, t, HV, DV)
+    for a, b in zip(grads_of(ours, args, probe),
+                    grads_of(theirs, args, probe)):
+        close(a, b, rtol=1.5e-2)
+
+
 E = 32
 
 

@@ -262,6 +262,31 @@ def test_grouped_matmul_compiles_for_v5e(topo, matrix, monkeypatch):
     assert _compile(jax.grad(loss, argnums=(0, 1)), *avals) == 2
 
 
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_delta_rule_kernels_compile_for_v5e_at_the_cell_size(topo, mode,
+                                                             monkeypatch):
+    """``gdn_chunk_fwd`` and, through the gradient, ``gdn_chunk_bwd`` at
+    the qwen3-next cell's size: 8192 tokens (128 chunks), 16 key and 32
+    value heads of 128; a cell's 128 x 128 tiles, the stacked q, k, v and
+    the two heads' state have to fit the kernel's VMEM."""
+    from paddle_tpu.ops import gated_delta, gdn_kernels
+
+    monkeypatch.setattr(gdn_kernels, "interpret_default", lambda: False)
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    t, hk, hv, d = 8192, 16, 32, 128
+    avals = (aval((t, hk, d), jnp.float32), aval((t, hk, d), jnp.float32),
+             aval((t, hv, d), jnp.float32), aval((t, hv), jnp.float32),
+             aval((t, hv), jnp.float32), aval((t,), jnp.int32))
+
+    def loss(*a):
+        o = gated_delta.gated_delta_rule(*a)
+        return jnp.sum(o * o)
+
+    n = _compile(gated_delta.gated_delta_rule if mode == "fwd"
+                 else jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *avals)
+    assert n == {"fwd": 1, "grad": 2}[mode]
+
+
 # ---- a whole train step of the delta-rule / gated-attention model ------------
 
 def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
@@ -270,17 +295,20 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
     (heads of 128, one sequence of 256): the whole step, Adam included,
     lowers and compiles for a described v5e; its text names the scopes the
     benchmark's readers select by, and holds the flash kernels of the one
-    attention block and the grouped products of four expert layers."""
+    attention block, the grouped products of four expert layers and the
+    delta rule's two kernels, whose 64 x 64 tiles never reach HBM."""
     import paddle_tpu as paddle
     from paddle_tpu import optimizer, trainer
     from paddle_tpu.analysis import retrace
     from paddle_tpu.models import qwen3_next
     from paddle_tpu.ops import attention as pattn
+    from paddle_tpu.ops import gdn_kernels
     from paddle_tpu.ops import grouped_matmul as gm
 
     # the code asks jax.default_backend() and sees the CPU
     monkeypatch.setattr(pattn, "_interpret_default", lambda: False)
     monkeypatch.setattr(gm, "interpret_default", lambda: False)
+    monkeypatch.setattr(gdn_kernels, "interpret_default", lambda: False)
     monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
     paddle.topology.reset_name_scope()
     *_, cost = qwen3_next.build(
@@ -309,8 +337,17 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
                   "moe.shared"):
         assert scope + "/" in text, scope
     # one attention block: forward twice (remat), dKV, dQ; four expert
-    # layers: 6 + 3 moe_gmm and 3 moe_tgmm each
-    assert text.count("tpu_custom_call") == 4 + 4 * 12
+    # layers: 6 + 3 moe_gmm and 3 moe_tgmm each; three delta-rule layers:
+    # gdn_chunk_fwd twice (remat), gdn_chunk_bwd once
+    assert text.count("tpu_custom_call") == 4 + 4 * 12 + 3 * 3
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "%gdn_" in line.split("=")[0]]
+    assert len(kernels) == 3 * 3
+    assert all("gdn/gdn.scan/" in line or "gdn.scan)" in line
+               for line in kernels), kernels
+    # [n, Hv, c, c]: 4 chunks of 64 rows, 4 value heads; no K K^T, Q K^T,
+    # decay, A or inverse of every chunk and head as an array in HBM
+    assert "f32[4,4,64,64]" not in text and "bf16[4,4,64,64]" not in text
 
 
 # ---- names in the device trace ---------------------------------------------
@@ -325,11 +362,28 @@ def _kernel_names(fn, *avals):
                   for line in text.splitlines() if "tpu_custom_call" in line)
 
 
-@pytest.mark.parametrize("which", ["flash", "ragged", "ragged_tp4"])
-def test_kernels_carry_stable_names(topo, which):
+@pytest.mark.parametrize("which", ["flash", "ragged", "ragged_tp4", "gdn"])
+def test_kernels_carry_stable_names(topo, which, monkeypatch):
     """``name=`` on the ``pallas_call`` sites the benchmark's cells run
     reaches the compiled program, alone and under ``shard_map``."""
     one = SingleDeviceSharding(topo.devices[0])
+    if which == "gdn":
+        from paddle_tpu.ops import gated_delta, gdn_kernels
+
+        monkeypatch.setattr(gdn_kernels, "interpret_default", lambda: False)
+        f32 = lambda *shape: _on(one)(shape, jnp.float32)  # noqa: E731
+
+        def loss(q, k, v, g, beta, seg):
+            o = gated_delta.gated_delta_rule(q, k, v, g, beta, seg)
+            return jnp.sum(o * o)
+
+        # the trace's readers select them by ``%gdn_``: no wrapped names
+        assert _kernel_names(
+            jax.grad(loss, argnums=(0, 1, 2, 3, 4)), f32(256, 2, 128),
+            f32(256, 2, 128), f32(256, 4, 128), f32(256, 4), f32(256, 4),
+            _on(one)((256,), jnp.int32)) == \
+            ["gdn_chunk_bwd", "gdn_chunk_fwd"]
+        return
     if which == "flash":
         q = _on(one)((4, 1024, 16, 128), jnp.bfloat16)
 
