@@ -452,7 +452,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
                  held: Tuple[int, int], routing: str = "sigmoid",
                  scaling: float = 1.0, valid: Optional[jax.Array] = None,
-                 tile_m: int = 0):
+                 tile_m: int = 0, operand_dtype=None):
     """What one rank of an expert-parallel group computes of a dropless
     expert layer: it routes over ALL the experts (``p["router"]`` is
     [D, n_routed]) by the ``routing`` its caller names, ``"sigmoid"``
@@ -473,11 +473,17 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
     rows, the worst case a step can meet (every choice of every token on
     a held expert), and only the tiles that hold rows are computed.
     ``valid`` [T] keeps padding rows of a packed buffer out.
+    ``operand_dtype`` is the type the rows take for the products (the
+    matrices are rounded to it): the global policy's where it is left out
+    (bfloat16 under ``FLAGS.use_bf16``); a caller that holds float32
+    matrices and wants no rounded copy of them names float32.
 
     x: [T, D].  Returns (y [T, D] float32, stats) with the step's
     ``rows_total`` (valid tokens x top_k), ``rows_held`` (pairs that
-    landed on held experts) and ``max_expert_rows`` (the fullest held
-    expert), as device scalars."""
+    landed on held experts), ``max_expert_rows`` (the fullest held
+    expert), ``live_experts`` (held experts with a row) and ``live_tiles``
+    (row tiles of ``tile_m`` the products compute: a held expert takes
+    one even with no row), as device scalars."""
     from paddle_tpu.ops import grouped_matmul as gm
     from paddle_tpu.ops import math as pmath
 
@@ -515,7 +521,7 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
         row_token = jnp.where(row_pair < t * top_k, row_pair // top_k, t)
         dest = dest.reshape(t, top_k)
     with jax.named_scope("moe.experts"):
-        ct = pmath.compute_dtype(x)
+        ct = operand_dtype or pmath.compute_dtype(x)
         xs = _dispatch(x.astype(ct), row_token, dest)
         h = gm.grouped_matmul(xs, p["w_gate"], tile_group, n_active, tile_m)
         u = gm.grouped_matmul(xs, p["w_up"], tile_group, n_active, tile_m)
@@ -533,7 +539,9 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
     n_valid = t if valid is None else jnp.sum(valid)
     stats = {"rows_total": jnp.asarray(n_valid * top_k, jnp.float32),
              "rows_held": jnp.sum(counts).astype(jnp.float32),
-             "max_expert_rows": jnp.max(counts).astype(jnp.float32)}
+             "max_expert_rows": jnp.max(counts).astype(jnp.float32),
+             "live_experts": jnp.sum(counts > 0).astype(jnp.float32),
+             "live_tiles": n_active[0].astype(jnp.float32)}
     return out, stats
 
 

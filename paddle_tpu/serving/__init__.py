@@ -17,6 +17,7 @@ from paddle_tpu.serving.control import (DEFAULT_CLASSES, AdmissionLedger,
                                         check_control_conservation)
 from paddle_tpu.serving.engine import (DecodeModel, DecoderLM, ServingEngine,
                                        greedy_decode_reference, validate_tp)
+from paddle_tpu.serving.block_moe_lm import BlockMoeLM
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
                                           SamplingParams, accept_tokens,
                                           next_token, warp_probs)
@@ -42,7 +43,8 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                           pack_prefill_chunks)
 
 __all__ = [
-    "ServingEngine", "DecodeModel", "DecoderLM", "greedy_decode_reference",
+    "ServingEngine", "DecodeModel", "DecoderLM", "BlockMoeLM",
+    "greedy_decode_reference",
     "ragged_paged_attention", "ragged_paged_attention_reference",
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",
     "validate_tp",

@@ -82,6 +82,42 @@ budget so ``python -m paddle_tpu.analysis sharding`` proves the decode
 hot path stays reduce-not-gather.  ``mesh=None`` keeps the exact
 replicated engine (and the exact PR 10 ``P()``/comm=0 contracts).
 
+Block models (generation by diffusion over blocks): a model that carries
+``block_length`` (``B``), ``denoise_steps`` (``S``) and ``mask_token_id``
+is served block by block.  The sequence is cut into blocks of ``B`` at
+absolute positions; a row sees every earlier block and its own block
+WHOLE (the one ``token <= position`` mask, with each row attending as
+its block's last position while its rotary position stays its own).  A
+prompt's whole blocks are prefilled (chunks end on block boundaries; no
+token comes of a prompt's last row); then a slot brings ``B`` rows a
+tick (the step's ``k1``): its current block, unfixed positions holding
+the mask token, rewritten in its page at every pass.  A denoising pass
+fixes the next ``B / S`` masked positions from the left to their best
+token, the mask token excluded (``on_token`` per fixed token, in
+position order, never past ``max_tokens``); when the block is full a
+committing pass writes its clean K/V and only then does ``cache_len``
+move past it.  Which pass a slot stands at follows from its request
+alone (``cache_len`` and the tokens it has), so a preempted or cancelled
+request needs no unwinding, and the step's shapes do not depend on the
+pass: it computes logits for ``[slots, B / S]`` selected rows and
+chooses each row's best unmasked token itself, so a slot's few tokens
+and a finite flag cross to the host, and the logits only for a request
+that samples.  That read back LAGS its dispatch by a tick: ``step()``
+dispatches tick ``k + 1`` and then reads what tick ``k`` came to, so the
+host's part of a tick (schedule, assemble, upload, walk) runs beside the
+device's and not between two of its steps.  What a pass will come to is
+known at its dispatch but for the tokens' values (how many it fixes,
+that a full block is committed, where a chunk ends), so the request
+moves on then (``cache_len``, ``Request.pending``) and the next step
+takes the pending tokens from the last step's words, on the device;
+``on_token`` runs when the words arrive, one ``step()`` call later.  A
+tick lands in its own call where a running request samples (its tokens
+are drawn on the host) or a fault plan is bound, and the flight in the
+air lands before the scheduler may preempt.
+Speculation, tensor parallelism, the host tier and chain migration
+refuse a block model at construction; the prefix cache serves it (whole
+pages of prefilled blocks).
+
 The model plugs in through the small :class:`DecodeModel` contract
 rather than a ``Topology``: serving needs per-layer access to Q/K/V
 *before* attention runs (the cache sits between them), which the opaque
@@ -93,10 +129,13 @@ exposing its projection weights.
 
 from __future__ import annotations
 
+import gc
 import math
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -156,6 +195,18 @@ class DecodeModel:
       (projection, residual, FFN — whatever the architecture does after
       attention)
     - ``logits(params, x) -> [..., vocab_size]``
+    - ``rotate(params, layer, q, k, positions) -> (q, k)`` (optional):
+      called on what ``qkv`` returned with the rows' absolute positions
+      ``[T]``, before K is written to its page — rotary positions.
+    - ``step_counters`` (optional, a tuple of names) with
+      ``attn_out_counted(params, layer, ctx, x, valid) -> (x, int32
+      [len(step_counters)])``: called in ``attn_out``'s place with the
+      rows' validity ``[T]`` (padding rows of the step are False); the
+      counts are summed over the step's layers, read back beside the
+      logits and added to ``ServingMetrics`` under those names.
+    - ``block_length``, ``denoise_steps``, ``mask_token_id`` (optional,
+      together): a BLOCK model, which generates by diffusion over blocks
+      of ``block_length`` positions (``ServingEngine``: "block models").
 
     Tensor-parallel serving (``ServingEngine(mesh=...)``) additionally
     needs:
@@ -399,6 +450,36 @@ def greedy_decode_reference(model: DecodeModel, params, prompt: List[int],
     return out
 
 
+@dataclass
+class _Flight:
+    """A block model's dispatched step whose words the host has not read
+    yet (``ServingEngine``: "block models")."""
+
+    # (request, slot, block start, tokens the block had, tokens the pass
+    # fixes: 0 is the committing pass) of every slot that rode the step
+    passes: List[Tuple[Request, int, int, int, int]]
+    chunks: list                   # as ``pack_prefill_chunks`` gave them
+    words: Any                     # the step's words, on the device
+    logits: Any                    # its [slots, B / S, V] logits, there too
+    rows: Tuple[int, int, int]     # block, prefill and padding rows
+    h2d_bytes: int
+    attn_cells: Tuple[int, int, int]
+
+
+def _settle_heap() -> None:
+    """Called when a step program has just been compiled and run.  What
+    the process built for it (jaxprs, HLO, the executable's metadata:
+    some hundred thousand objects that live as long as the engine) is
+    moved out of the garbage collector's generations, so that the full
+    collections Python starts now and then walk the young heap only.  A
+    tick makes almost nothing for them to free (its objects die by
+    reference count), but each walked the whole heap and stalled the
+    tick it fell in: 0.13 s, three to five times in a 45 s window on
+    the chip's host (PERF.md s6, PR 37)."""
+    gc.collect()
+    gc.freeze()
+
+
 def _parse_buckets(spec: str) -> Tuple[int, ...]:
     return tuple(sorted(int(t) for t in spec.split(",") if t.strip()))
 
@@ -454,6 +535,24 @@ class ServingEngine:
                      context="serving")
         page_size = int(page_size or FLAGS.serving_page_size)
         max_slots = int(max_slots or FLAGS.serving_max_slots)
+        if prefill_chunk is None:
+            prefill_chunk = int(FLAGS.serving_prefill_chunk)
+        # a block model (see the module doc): B rows a slot and tick,
+        # B / S tokens fixed a denoising pass.  None: one token a tick.
+        self._block: Optional[int] = None
+        self._ticks_per_token = 1.0
+        if hasattr(model, "block_length"):
+            self._block = int(model.block_length)
+            self._denoise_steps = int(model.denoise_steps)
+            self._mask_id = int(model.mask_token_id)
+            self._fix_rows = self._block // self._denoise_steps
+            self._ticks_per_token = (self._denoise_steps + 1) / self._block
+            self._refuse_for_block_model(
+                page_size, int(prefill_chunk), mesh,
+                spec_mode if spec_mode is not None
+                else FLAGS.serving_spec_mode,
+                host_tier_bytes if host_tier_bytes is not None
+                else FLAGS.serving_host_tier_bytes)
         # KV storage dtype: explicit kv_dtype > legacy dtype param >
         # FLAGS.serving_kv_dtype.  int8 turns on quantized pages.
         if kv_dtype is None:
@@ -560,8 +659,6 @@ class ServingEngine:
         self.pool = PagePool(num_pages)
         if prefix_cache is None:
             prefix_cache = bool(FLAGS.serving_prefix_cache)
-        if prefill_chunk is None:
-            prefill_chunk = int(FLAGS.serving_prefill_chunk)
         self._prefill_chunk = max(0, int(prefill_chunk))
         self.cache: Optional[PrefixCache] = None
         if prefix_cache:
@@ -595,9 +692,17 @@ class ServingEngine:
                 max_pages_per_seq=int(max_pages_per_seq),
                 max_queue=max_queue,
                 preempt_budget=preempt_budget if preempt_budget > 0
-                else None),
+                else None, block_length=self._block),
             cache=self.cache, time_fn=self._time)
-        self.metrics = ServingMetrics(pool_pages=self.pool.num_usable)
+        # counters the model's layers return from inside the step
+        self._counted: Tuple[str, ...] = tuple(
+            getattr(model, "step_counters", ()))
+        self.metrics = ServingMetrics(pool_pages=self.pool.num_usable,
+                                      model_counters=self._counted)
+        # a block model's step in the air, and the words a step takes
+        # where none is (no token of it is pending then)
+        self._flying: Optional[_Flight] = None
+        self._no_words = None
         # obs: tracer (FLAGS.obs_trace-gated at construction — a fleet
         # rebinds its shared, replica-scoped tracer via set_tracer) and
         # the unified metrics registry the per-stage latency histograms
@@ -675,8 +780,10 @@ class ServingEngine:
                 num_pages=int(draft_pool_pages or num_pages),
                 max_pages_per_seq=int(max_pages_per_seq),
                 max_slots=max_slots)
-        # verify rows per decode slot: 1 (plain decode) + spec_k drafts
-        self._k1 = 1 + (self.spec_k if self._proposer is not None else 0)
+        # rows per decode slot: 1 (plain decode) + spec_k drafts to
+        # verify, or a block model's whole block
+        self._k1 = self._block or \
+            1 + (self.spec_k if self._proposer is not None else 0)
         # donate the incoming KV pool: every call overwrites self._kv
         # with the returned pool, so XLA may update pages in place —
         # without this the decode tick copies the whole pool and peak
@@ -746,7 +853,7 @@ class ServingEngine:
             # dict entries against the pytree path) + the pool spec for
             # both the donated input and the aliased output
             step_in = (dict(self._shard_plan), kvspec, ())
-            step_out = ((), ()) + (kvspec,) * 4
+            step_out = ((), ()) + (kvspec,) * 4 + ((),) * bool(self._counted)
             kv_in = (kvspec, (), ())
             kv_out = (kvspec,) * 4
             mesh_axes = ((self.tp_axis, self.tp),)
@@ -818,6 +925,46 @@ class ServingEngine:
         self._prev_tick_busy = False
         self._tick_dur_ema = 0.0      # drives the unmeetable-deadline shed
         self._draining = False        # drain(): REJECT new submits
+
+    def _refuse_for_block_model(self, page_size: int, prefill_chunk: int,
+                                mesh, spec_mode: str,
+                                host_tier_bytes: int) -> None:
+        """What a block model cannot be built with, said at construction
+        (nothing takes a silent second path)."""
+        from paddle_tpu.platform.enforce import enforce_that
+
+        b, s = self._block, self._denoise_steps
+        ctx = "serving-block"
+        enforce_that(b >= 1 and s >= 1 and b % s == 0,
+                     f"denoise_steps ({s}) must divide block_length ({b}): "
+                     "every denoising pass fixes block_length / "
+                     "denoise_steps positions", context=ctx)
+        enforce_that(page_size % b == 0,
+                     f"page_size ({page_size}) must be a multiple of the "
+                     f"model's block_length ({b}): a block is rewritten in "
+                     "place in ONE page until it is committed", context=ctx)
+        enforce_that(prefill_chunk % b == 0,
+                     f"prefill_chunk ({prefill_chunk}) must be a multiple "
+                     f"of the model's block_length ({b}): a row sees its "
+                     "whole block, so a chunk ends on a block boundary",
+                     context=ctx)
+        enforce_that(str(spec_mode) == "off",
+                     "speculative decoding verifies one token a row; a "
+                     "block model (block_length on the model) fixes "
+                     "several positions a pass and is not speculated on: "
+                     "build it with spec_mode='off'", context=ctx)
+        enforce_that(mesh is None,
+                     "tensor-parallel serving (mesh=) of a block model is "
+                     "not built: its expert layer has no shard plan here; "
+                     "serve it with mesh=None", context=ctx)
+        enforce_that(int(host_tier_bytes) <= 0,
+                     "the host tier has not been driven with a block "
+                     "model: build it with host_tier_bytes=0", context=ctx)
+        enforce_that(self.role == "unified",
+                     f"role={self.role!r} hands requests over by chain "
+                     "migration, which cannot carry a block that is "
+                     "between passes: a block model serves as 'unified'",
+                     context=ctx)
 
     # ---- observability wiring -------------------------------------------
 
@@ -983,12 +1130,24 @@ class ServingEngine:
         model, cfg = self.model, self.kv_cfg
         b, page = self._max_slots, cfg.page_size
         bd = b * k1
+        blk = self._block
+        rotate = getattr(model, "rotate", None)
 
-        def raw(params, kv: KVPages, packed):
+        def raw(params, kv: KVPages, packed, *last):
             # packed: the tick's one int32 input buffer, replicated;
             # taken apart by static slices (a chip reads its own copy)
             (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
-             table, att_lens) = self._tick_parts(packed, k1)
+             table, att_lens, *d_sel) = self._tick_parts(packed, k1)
+            if blk is not None:
+                # last: the words of the step before (block models only).
+                # A token that step fixed has not reached the host when
+                # this one is assembled: d_tokens names it (-1 - j, the
+                # j-th pick of its slot) and it is taken from there
+                f = self._fix_rows
+                picked = last[0][:b * (f + 1)].reshape(b, f + 1)[:, :f]
+                d_tokens = jnp.where(d_tokens < 0, jnp.take_along_axis(
+                    picked, jnp.clip(-1 - d_tokens, 0, f - 1), axis=1),
+                    d_tokens)
             # d_tokens/d_pos/d_valid: [B, k1] — row 0 of a slot is the
             # plain decode token, rows 1..k its drafted lookahead
             # (invalid rows write the null page and produce garbage
@@ -998,7 +1157,8 @@ class ServingEngine:
             # p_last: [B] — row index of each slot's chunk-final row in
             # the packed stack (0 for slots not prefilling).  table:
             # [B, Pm]; att_lens: [B] — valid KV per slot AFTER this
-            # step's writes.
+            # step's writes.  d_sel (block models only): [B, B / S] —
+            # the rows of each slot's block that the step computes logits for.
             d_seq = jnp.repeat(jnp.arange(b), k1)
             dt = d_tokens.reshape(bd)
             dp = d_pos.reshape(bd)
@@ -1012,27 +1172,65 @@ class ServingEngine:
             p_pages = jnp.where(p_act, table[p_seq, pq // page], NULL_PAGE)
             pages = jnp.concatenate([d_pages, p_pages])
             offs = jnp.concatenate([dp % page, pq % page])
-            wmask = jnp.concatenate([dv, p_act])[:, None, None]
+            live = jnp.concatenate([dv, p_act])
+            wmask = live[:, None, None]
             row_seq = jnp.concatenate([d_seq, p_seq])
             qpos = jnp.concatenate([jnp.where(dv, dp, -1), p_qpos])
+            if blk is not None and blk > 1:
+                # a row sees its whole block: it attends as the block's
+                # last position (its rotary position stays its own)
+                qpos = jnp.where(qpos >= 0, (qpos // blk + 1) * blk - 1, -1)
+            counts = 0
             for l in range(cfg.num_layers):
                 # named_scope: blocks and their parts show up by name in
                 # xplane/profiler traces (as topology.forward's layers)
                 with jax.named_scope(f"l{l}"):
                     with jax.named_scope("attn"):
                         q, k, v = model.qkv(params, l, x)
+                        if rotate is not None:
+                            q, k = rotate(params, l, q, k, pos)
                         kv = append_token(kv, l, jnp.where(wmask, k, 0.0),
                                           jnp.where(wmask, v, 0.0), pages,
                                           offs)
                         ctx = self._attend(kv, l, q, table, att_lens,
                                            row_seq, qpos, k1=k1)
-                    x = model.attn_out(params, l, ctx, x)
+                    if self._counted:
+                        x, n = model.attn_out_counted(params, l, ctx, x,
+                                                      live)
+                        counts = counts + n
+                    else:
+                        x = model.attn_out(params, l, ctx, x)
+            more = (counts,) if self._counted else ()
+            if blk is not None:
+                # logits for the rows a denoising pass fixes only.  What
+                # crosses to the host is ONE small int32 vector (a read
+                # back costs its latency, not its bytes; taken apart by
+                # ``_block_words``): each row's best token with the mask
+                # token left out and, last in a slot's row, whether all
+                # its logits are finite (``picks`` [B, B / S + 1]); of a
+                # prompt's last row only whether it is finite (the chunk
+                # guard's reading; no token comes of it) [B]; the
+                # model's counts.  The logits themselves stay on the
+                # device, for a request that samples.
+                sel = (jnp.arange(b)[:, None] * k1 + d_sel[0]).reshape(-1)
+                logits = model.logits(params, x[sel])
+                token = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+                best = jnp.argmax(jnp.where(token == self._mask_id,
+                                            -jnp.inf, logits), axis=-1)
+                logits = logits.reshape(b, self._fix_rows, -1)
+                finite = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+                guard = jnp.isfinite(jnp.sum(x[p_last], axis=-1))
+                words = jnp.concatenate(
+                    [jnp.concatenate([best.reshape(b, -1), finite[:, None]],
+                                     axis=1).reshape(-1), guard, *more],
+                    dtype=jnp.int32)
+                return words, logits, self._tp_kv(kv)
             # logits only where the host will read them: the B*k1
             # decode/verify rows + each slot's chunk-final row
             sel = jnp.concatenate([jnp.arange(bd), p_last])
             logits = model.logits(params, x[sel])
             return (logits[:bd].reshape(b, k1, -1), logits[bd:],
-                    self._tp_kv(kv))
+                    self._tp_kv(kv)) + more
 
         fn = audit_jit(raw, site="serving.step",
                        donate_argnums=self._donate_kv,
@@ -1125,6 +1323,7 @@ class ServingEngine:
         else:
             self.scheduler.drop_queued(req, status)
         req.finished_at = now
+        req.pending = 0
         hook = self.metrics.on_shed if shed else {
             RequestStatus.COMPLETED: self.metrics.on_complete,
             RequestStatus.TIMED_OUT: self.metrics.on_timeout,
@@ -1187,7 +1386,7 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        return self.scheduler.has_work
+        return self.scheduler.has_work or self._flying is not None
 
     def step(self, now: Optional[float] = None) -> bool:
         """One engine tick: shed expired/unmeetable work, grow/preempt,
@@ -1199,13 +1398,21 @@ class ServingEngine:
         inside it, ``pt:tick.schedule`` here, ``.assemble``, ``.upload``
         (and inside that ``.dispatch``, the compiled step's call alone),
         ``.wait`` and ``.sample`` in :meth:`_do_step`, ``.sample`` again
-        for the bookkeeping that closes the tick)."""
+        for the bookkeeping that closes the tick).  A block model's
+        ``.wait`` and ``.sample`` (:meth:`_land`) belong to the step that
+        the call BEFORE this one dispatched."""
         tick, m = self._tick, self.metrics
         phase = self._tracer.phase
         with phase("tick", tick=tick):
+            if self._flying is not None and self._growth_may_preempt():
+                # a preempted request is re-prefilled from its tokens,
+                # which have to be on the host: the flight lands first
+                self._land(self._flying)
             with phase("tick.schedule", tick=tick):
                 running, chunks, total_rows, drafts, busy = \
                     self._schedule_tick(tick, now)
+            if not (running or chunks) and self._flying is not None:
+                self._land(self._flying)
             if running or chunks:
                 for req, start, n, _ in chunks:
                     self._tracer.instant("prefill_chunk", rid=req.rid,
@@ -1300,10 +1507,16 @@ class ServingEngine:
             key=lambda r: (r.last_progress_tick, r.slot))
         chunks, total_rows = pack_prefill_chunks(
             prefilling, self._prefill_chunk, self._row_align,
-            self._prefill_budget)
+            self._prefill_budget, block=self._block or 1)
+        # (a block model's first token comes of a block pass, not of the
+        # prompt's last row: its slots run with nothing generated yet)
         running = [r for r in sched.running_requests()
                    if r.status is RequestStatus.RUNNING
-                   and not r.prefilling and r.generated]
+                   and not r.prefilling
+                   and (r.generated or self._block is not None)
+                   # (every token of it fixed by the flight in the air:
+                   # it ends when that lands)
+                   and len(r.generated) + r.pending < r.max_tokens]
         # speculation: draft lookahead tokens per slot BEFORE the retry
         # loop (drafting mutates proposer state — it must run once per
         # tick, and the position-keyed RNG keeps it deterministic)
@@ -1397,6 +1610,8 @@ class ServingEngine:
         replica: still RUNNING, prefill fully materialized, and at
         least the first token emitted (so the destination starts with a
         decodable state — ``generated[-1]`` is the next step's input)."""
+        if self._block is not None:
+            return []     # a block between passes is not handed over
         return [r.rid for r in self.scheduler.running_requests()
                 if r.status is RequestStatus.RUNNING and not r.prefilling
                 and r.generated]
@@ -1703,15 +1918,16 @@ class ServingEngine:
                 self._finish(req, RequestStatus.TIMED_OUT, now)
                 continue
             # load shedding, on the WORST-CASE length assumption: at one
-            # token per tick (the engine's best rate), a request that
+            # token per tick (the engine's best rate; a block model's is
+            # B tokens in S + 1 ticks), a request that
             # runs to its full max_tokens cannot finish by its deadline.
             # An early EOS could beat the estimate — callers who rely on
             # early stopping should size max_tokens to what they
             # actually expect, since it is the only length signal the
             # engine has before decoding.
             if (req.deadline_at is not None and self._tick_dur_ema > 0.0
-                    and now + req.tokens_remaining * self._tick_dur_ema
-                    > req.deadline_at):
+                    and now + req.tokens_remaining * self._ticks_per_token
+                    * self._tick_dur_ema > req.deadline_at):
                 self._finish(req, RequestStatus.REJECTED, now, shed=True)
 
     def _propose_drafts(self, running: List[Request],
@@ -1799,6 +2015,11 @@ class ServingEngine:
 
     def _step_with_retry(self, running: List[Request], chunks, total_rows,
                          tick: int, drafts: Dict[int, Tuple]) -> None:
+        if self._block is not None and self.faults is None:
+            # nothing injects a failure, and a read back that lags its
+            # dispatch is not run again: what it raises reaches the caller
+            self._do_step(running, chunks, total_rows, drafts)
+            return
         attempt = 0
         while True:
             try:
@@ -1827,7 +2048,9 @@ class ServingEngine:
         the prefix-cache outcome, run the COW fork, and arm the chunked
         prefill (its first chunk runs this same tick)."""
         toks = req.cache_tokens
-        req.prefilling = True
+        # (a block model may have nothing to prefill: a prompt inside one
+        # block, or whole blocks that the cache covers)
+        req.prefilling = req.cache_len < self._prefill_target(req)
         req.chain_hash, req.chain_blocks = None, 0   # fresh insert cursor
         self.metrics.on_prefix(len(toks), req.cached_len)
         if req.cow_src is not None:
@@ -1865,13 +2088,17 @@ class ServingEngine:
         plus one bonus/corrected token, and a partial acceptance rolls
         the lookahead pages back (``scheduler.rollback_pages``) — the
         rejected rows' K/V beyond the new length is masked junk the
-        next real tokens overwrite."""
+        next real tokens overwrite.
+
+        A block model's step is dispatched here and walked a call later
+        (:meth:`_land`; the module doc says when in this one)."""
         k1, tick, phase = self._k1, self._tick, self._tracer.phase
         with phase("tick.assemble", tick=tick):
             packed = self._assemble(running, chunks, total_rows, drafts)
         parts = self._tick_parts(packed, k1)
         p_seq, att_lens = parts[5], parts[8]
         pb = p_seq.shape[0]                     # the prefill bucket
+        compiles = (pb, k1) not in self._step_fns
         with phase("tick.upload", tick=tick):
             # ONE placement, already in the layout the step wants
             # (replicated over the engine's mesh), so the call re-lays
@@ -1879,20 +2106,143 @@ class ServingEngine:
             step = self._step_fn(pb, k1)
             placed = jax.device_put(packed, self._tick_sharding)
             with phase("tick.dispatch", tick=tick):
-                d_logits, p_logits, self._kv = step(self.params, self._kv,
-                                                    placed)
+                d_logits, p_logits, self._kv, *more = step(
+                    self.params, self._kv, placed, *self._last_words())
+        if self._block is not None:
+            # the step's logits stay on the device and its one vector of
+            # words is read a tick later (the module doc): the requests
+            # move on now, by what the passes will come to
+            flight = _Flight(
+                self._passes(running), chunks, d_logits, p_logits,
+                (len(running) * k1, total_rows,
+                 pb - sum(c[2] for c in chunks)), packed.nbytes,
+                self._attn_cells(p_seq, att_lens))
+            self._advance(flight)
+            if compiles:
+                _settle_heap()        # (beside the device's first run)
+            before, self._flying = self._flying, flight
+            if before is not None:
+                self._land(before)
+            if self.faults is not None or any(
+                    r.sampling is not None and not r.sampling.greedy
+                    for r in running):
+                self._land(flight)
+            return
         with phase("tick.wait", tick=tick):
-            d_logits = np.asarray(d_logits)   # forces device sync; [B,k1,V]
-            p_logits = np.asarray(p_logits)
+            d_logits = np.asarray(d_logits)   # forces device sync;
+            p_logits = np.asarray(p_logits)   # [B,k1,V]
+            counts = [np.asarray(c) for c in more]
+            d2h = d_logits.nbytes + p_logits.nbytes \
+                + sum(c.nbytes for c in counts)
+        if compiles:
+            _settle_heap()
         with phase("tick.sample", tick=tick):
             self.metrics.on_step(
                 sum(1 + len(drafts.get(r.rid, ((),))[0]) for r in running),
                 total_rows, pb - sum(c[2] for c in chunks),
                 n_slots=len(running),
-                h2d_bytes=packed.nbytes,
-                d2h_bytes=d_logits.nbytes + p_logits.nbytes,
-                attn_cells=self._attn_cells(p_seq, att_lens))
+                h2d_bytes=packed.nbytes, d2h_bytes=d2h,
+                attn_cells=self._attn_cells(p_seq, att_lens),
+                model_counts=counts[0] if counts else ())
             self._walk_results(running, chunks, drafts, d_logits, p_logits)
+
+    # ---- a block model's lagged read back --------------------------------
+
+    def _last_words(self) -> tuple:
+        """What a block step takes after the tick's buffer: the words of
+        the step before it, still on the device (zeros where none is in
+        the air: no token is pending then).  Nothing for other models."""
+        if self._block is None:
+            return ()
+        if self._flying is not None:
+            return (self._flying.words,)
+        if self._no_words is None:
+            n = self._max_slots * (self._fix_rows + 2) + len(self._counted)
+            self._no_words = jax.device_put(np.zeros(n, np.int32),
+                                            self._tick_sharding)
+        return (self._no_words,)
+
+    def _growth_may_preempt(self) -> bool:
+        """Whether the pages that ``ensure_decode_pages`` is about to take
+        may not be there without a preemption."""
+        page = self.kv_cfg.page_size
+        need = sum(1 for r in self.scheduler.running.values()
+                   if r.cache_len >= len(r.pages) * page)
+        return need > self.pool.num_free + self.pool.num_reclaimable
+
+    def _passes(self, running: List[Request]):
+        """Which pass each running slot stands at: ``_Flight.passes``."""
+        blk, out = self._block, []
+        for req in running:
+            at, got = req.cache_len, len(req.generated) + req.pending
+            have = len(req.prompt) + got - at
+            n = 0 if have >= blk else min(self._fix_rows, blk - have,
+                                          req.max_tokens - got)
+            out.append((req, req.slot, at, have, n))
+        return out
+
+    def _advance(self, flight: _Flight) -> None:
+        """At its dispatch, what a step will come to but for its tokens'
+        values: chunks are prefilled, a full block is committed, a
+        denoising pass's tokens are pending."""
+        for req, start, n, _rows in flight.chunks:
+            self._advance_chunk(req, start, n)
+        for req, _slot, at, _have, n in flight.passes:
+            if n:
+                req.pending += n
+            else:
+                req.cache_len = at + self._block
+                # a block that a prompt's tail shares was still owed
+                self.scheduler.note_prefill_progress(req, at)
+
+    def _land(self, flight: _Flight) -> None:
+        """Read a dispatched step's words and walk them: chunk
+        bookkeeping first, the passes' tokens second.  A request that
+        ended meanwhile (cancelled, timed out, failed by an earlier
+        flight) is passed over: its slot and pages went back then, and
+        what the step wrote there lies before any later step's writes."""
+        tick, phase = self._tick, self._tracer.phase
+        if self._flying is flight:
+            self._flying = None
+        with phase("tick.wait", tick=tick):
+            # (no copy queued behind the call: a tick later it gains nothing)
+            words = np.asarray(flight.words)      # waits for the device
+            picks, guard, *counts = self._block_words(words)
+        with phase("tick.sample", tick=tick):
+            rows, prefill_rows, pad_rows = flight.rows
+            self.metrics.on_step(
+                rows, prefill_rows, pad_rows, n_slots=len(flight.passes),
+                h2d_bytes=flight.h2d_bytes, d2h_bytes=words.nbytes,
+                attn_cells=flight.attn_cells,
+                model_counts=counts[0] if counts else ())
+            # stamp AFTER the sync so TTFT includes the step compute
+            now = self._time()
+            for req, start, n, _rows in flight.chunks:
+                if req.status is RequestStatus.RUNNING:
+                    self._finish_chunk(req, start, n, guard[req.slot], now)
+            poisoned = self.faults.nan_rids if self.faults is not None \
+                else ()
+            for req, slot, at, have, n in flight.passes:
+                if req.status is not RequestStatus.RUNNING:
+                    continue
+                mine = picks[slot]
+                if req.rid in poisoned:
+                    mine = mine.copy()
+                    mine[-1] = 0                  # "not finite"
+                self._finish_block_pass(req, (at, have, n), mine,
+                                        flight.logits, now)
+
+    def _block_words(self, words: np.ndarray) -> List[np.ndarray]:
+        """A block step's one small output taken apart: ``picks`` ``[B,
+        B / S + 1]`` (:meth:`_finish_block_pass`), what the chunk guard
+        reads of each slot's last prompt row (0.0 or NaN) and, where the
+        model counts, its counts."""
+        b, f = self._max_slots, self._fix_rows + 1
+        parts = [words[:b * f].reshape(b, f),
+                 np.where(words[b * f:b * f + b] != 0, 0.0, np.nan)]
+        if self._counted:
+            parts.append(words[b * f + b:])
+        return parts
 
     def _attn_cells(self, p_seq: np.ndarray, att_lens: np.ndarray
                     ) -> Tuple[int, int, int]:
@@ -1916,15 +2266,21 @@ class ServingEngine:
                 calls * live * groups)
 
     def _tick_shapes(self, pb: int, k1: int) -> Tuple[Tuple[int, ...], ...]:
-        """A tick's nine input arrays in the order they lie in its one
+        """A tick's nine input arrays (ten for a block model) in the order
+        they lie in its one
         packed int32 buffer: ``d_tokens``, ``d_pos``, ``d_valid`` (0/1)
         ``[B, k1]``; ``p_tokens``, ``p_qpos``, ``p_seq`` ``[pb]``;
         ``p_last`` ``[B]``; ``table`` ``[B, Pm]``; ``att_lens`` ``[B]``."""
         b, pm = self._max_slots, self.kv_cfg.max_pages_per_seq
-        return ((b, k1),) * 3 + ((pb,),) * 3 + ((b,), (b, pm), (b,))
+        shapes = ((b, k1),) * 3 + ((pb,),) * 3 + ((b,), (b, pm), (b,))
+        if self._block is not None:
+            # a tenth, ``d_sel`` ``[B, B / S]``: the rows of each slot's
+            # block whose logits the step returns
+            shapes += ((b, self._fix_rows),)
+        return shapes
 
     def _tick_parts(self, packed, k1: int) -> List:
-        """The nine arrays as views of ``packed`` at static offsets
+        """Those arrays as views of ``packed`` at static offsets
         (``pb`` is what the buffer's length leaves): the host fills
         these views of a NumPy buffer (``_assemble``), the compiled
         step slices the traced one (``_step_fn``)."""
@@ -1961,9 +2317,30 @@ class ServingEngine:
                 pb = -(-pb // BLOCK_ROWS) * BLOCK_ROWS
         packed = self._empty_tick(pb, k1)
         (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last, table,
-         att_lens) = self._tick_parts(packed, k1)
+         att_lens, *d_sel) = self._tick_parts(packed, k1)
+        if self._block is not None:
+            block_rows, fix_rows = np.arange(k1), np.arange(self._fix_rows)
         for req in running:
             s = req.slot
+            table[s, :len(req.pages)] = req.pages
+            if self._block is not None:
+                # the slot's current block, whole: the tokens it has and
+                # the mask token where none is fixed yet
+                at, n_p = req.cache_len, len(req.prompt)
+                have = req.prompt[at:at + k1] + req.generated[
+                    max(0, at - n_p):max(0, at + k1 - n_p)]
+                d_tokens[s] = self._mask_id
+                d_tokens[s, :len(have)] = have
+                # and those the flight in the air is fixing: its j-th pick
+                n = len(have) + req.pending
+                d_tokens[s, len(have):n] = -1 - np.arange(req.pending)
+                d_pos[s] = at + block_rows
+                d_valid[s] = 1
+                att_lens[s] = at + k1
+                # the rows this pass fixes (a committing pass has none
+                # left: what it selects is read by no one)
+                d_sel[0][s] = np.minimum(n + fix_rows, k1 - 1)
+                continue
             dr = drafts.get(req.rid, ((), None))[0]
             n = 1 + len(dr)
             d_tokens[s, 0] = req.generated[-1]
@@ -1971,7 +2348,6 @@ class ServingEngine:
             d_pos[s, :n] = req.cache_len + np.arange(n)
             d_valid[s, :n] = 1
             att_lens[s] = req.cache_len + n
-            table[s, :len(req.pages)] = req.pages
         off = 0
         for req, start, n, rows in chunks:
             s = req.slot
@@ -2047,6 +2423,62 @@ class ServingEngine:
                 # rolls its own cache back to it (no-op for n-gram)
                 self._proposer.commit(req)
 
+    def _finish_block_pass(self, req: Request, stood: Tuple[int, int, int],
+                           picks: np.ndarray, logits, now: float) -> None:
+        """What one pass over a slot's block came to.  ``stood``: where
+        the block starts, how many tokens it had and how many the pass
+        was to fix, as the dispatch found them (``_Flight.passes``; the
+        request has moved on since).  ``picks``: the slot's row of the
+        step's words, the best unmasked token of each position the pass
+        was to fix and, last, whether their logits were all finite;
+        ``logits``: the step's ``[B, B / S, V]`` logits, on the device.
+        With nothing to fix it was the committing pass: the block's clean
+        K/V lie in its page (``cache_len`` moved past it at the
+        dispatch).  Else the next masked positions, from the left, take
+        their best token with the mask token left out (a request that
+        samples draws from the logits of its slot, fetched for it
+        alone), each emitted in position order; the answer may end
+        (``max_tokens``, EOS) inside the block."""
+        blk, m = self._block, self.metrics
+        _at, _have, n = stood
+        if not n:
+            req.last_progress_tick = self._tick
+            m.on_block_pass(blk)
+            return
+        req.pending -= n
+        if not picks[-1]:
+            # as a poisoned decode row: fail this request alone
+            self._finish(req, RequestStatus.FAILED, now)
+            return
+        if req.sampling is None or req.sampling.greedy:
+            toks = [int(t) for t in picks[:n]]
+        else:
+            rows = np.array(logits[req.slot, :n])
+            rows[:, self._mask_id] = -np.inf
+            at = len(req.generated)
+            toks = [next_token(row, req.sampling, at + i)
+                    for i, row in enumerate(rows)]
+        for fixed, tok in enumerate(toks, 1):
+            self._emit(req, tok, now)
+            if req.finished:
+                break
+        m.on_block_pass(blk, fixed)
+
+    def _prefill_target(self, req: Request) -> int:
+        """How many of ``cache_tokens`` are prefilled before decoding:
+        all of them, or a block model's whole blocks (the rest share
+        their block with the first tokens to come)."""
+        n = len(req.prompt) + len(req.generated)
+        return n if self._block is None else n // self._block * self._block
+
+    def _advance_chunk(self, req: Request, start: int, n: int) -> None:
+        """A prefill chunk's rows are in the cache: the materialized
+        length moves past them, and with the last chunk the request
+        leaves its prefill."""
+        req.cache_len = start + n
+        self.scheduler.note_prefill_progress(req, start)
+        req.prefilling = req.cache_len < self._prefill_target(req)
+
     def _finish_chunk(self, req: Request, start: int, n: int, logits,
                       now: float) -> None:
         """Post-dispatch bookkeeping for one prefill chunk that rode
@@ -2054,8 +2486,8 @@ class ServingEngine:
         the newly-completed full pages, and on the final chunk emit the
         first token from the chunk-final row's logits."""
         toks = req.cache_tokens
-        req.cache_len = start + n
-        self.scheduler.note_prefill_progress(req, start)
+        if self._block is None:     # (a block model's: at the dispatch)
+            self._advance_chunk(req, start, n)
         self.metrics.on_prefill(n)
         req.last_progress_tick = self._tick   # chunks are progress too
         if not np.isfinite(logits).all():
@@ -2077,12 +2509,13 @@ class ServingEngine:
             # prompt re-prefills cheaply.  The chain cursor makes each
             # chunk's insert O(chunk), not O(prefix-so-far).
             req.chain_hash, req.chain_blocks = self.cache.insert(
-                toks, req.pages, req.cache_len,
+                toks, req.pages, start + n,
                 from_block=req.chain_blocks, prev_hash=req.chain_hash,
                 tenant=req.tenant)
-        if req.cache_len < len(toks):
+        if req.prefilling:
             return                            # more chunks, later ticks
-        req.prefilling = False
+        if self._block is not None:
+            return         # no token comes of a block model's prompt rows
         # first token: greedy argmax unless the request samples (seeded
         # per-position draw — position 0 of its generated stream)
         self._emit(req, next_token(logits, req.sampling,

@@ -38,8 +38,13 @@ def _p95(xs: Sequence[float]) -> float:
 
 
 class ServingMetrics:
-    def __init__(self, pool_pages: int):
+    def __init__(self, pool_pages: int,
+                 model_counters: Sequence[str] = ()):
         self.pool_pages = max(1, pool_pages)
+        # what the model's layers count inside the compiled step (an
+        # expert layer's rows and tiles, say), under the model's names
+        self.model_counters: Dict[str, int] = dict.fromkeys(
+            model_counters, 0)
         self.submitted = 0
         self.rejected = 0
         self.completed = 0
@@ -70,6 +75,12 @@ class ServingMetrics:
         self.attn_kernel_calls = 0    # one per layer and step
         self.attn_grid_cells = 0      # grid steps those calls dispatched
         self.attn_live_cells = 0      # of them, steps whose page is live
+        # block models (generation by diffusion over blocks): a slot's
+        # rows, passes and tokens stand in no fixed ratio to its ticks
+        self.denoise_passes = 0       # slot participations that fixed tokens
+        self.commit_passes = 0        # ... that wrote a full block's K/V
+        self.block_rows = 0           # rows computed for blocks, both kinds
+        self.tokens_fixed = 0         # tokens the denoising passes fixed
         # speculative decoding (round 18)
         self.spec_ticks = 0           # verify ticks with >= 1 drafted token
         self.spec_tokens_proposed = 0  # drafted tokens shipped to verify
@@ -125,7 +136,8 @@ class ServingMetrics:
     def on_step(self, n_decode_rows: int, n_prefill_rows: int,
                 n_pad_rows: int, n_slots: Optional[int] = None,
                 h2d_bytes: int = 0, d2h_bytes: int = 0,
-                attn_cells: Tuple[int, int, int] = (0, 0, 0)) -> None:
+                attn_cells: Tuple[int, int, int] = (0, 0, 0),
+                model_counts: Sequence[int] = ()) -> None:
         """One unified-step dispatch: how many decode/verify rows and
         (padded) prefill rows rode it, and how much of the prefill
         bucket was padding.  ``n_slots`` is the running-slot
@@ -135,7 +147,9 @@ class ServingMetrics:
         dispatch moved between host and device: its input arrays up,
         its logits down.  ``attn_cells`` is the dispatch's (ragged
         kernel calls, grid steps of those calls, steps whose page is
-        live), zeros on the reference path."""
+        live), zeros on the reference path.  ``model_counts`` is what
+        the model's layers counted in the dispatch, in the order of the
+        names given at construction."""
         self.step_dispatches += 1
         self.decode_rows += n_decode_rows
         self.decode_slots += n_slots if n_slots is not None \
@@ -147,6 +161,18 @@ class ServingMetrics:
         self.attn_kernel_calls += attn_cells[0]
         self.attn_grid_cells += attn_cells[1]
         self.attn_live_cells += attn_cells[2]
+        for name, n in zip(self.model_counters, model_counts):
+            self.model_counters[name] += int(n)
+
+    def on_block_pass(self, rows: int, fixed: Optional[int] = None) -> None:
+        """One slot's pass over its block of ``rows`` rows: a denoising
+        pass that fixed ``fixed`` tokens, or (None) the committing one."""
+        self.block_rows += rows
+        if fixed is None:
+            self.commit_passes += 1
+        else:
+            self.denoise_passes += 1
+            self.tokens_fixed += fixed
 
     def on_prefix(self, requested: int, saved: int) -> None:
         """One admission's prefix-cache outcome: ``requested`` tokens
@@ -325,6 +351,11 @@ class ServingMetrics:
             "attn_kernel_calls": self.attn_kernel_calls,
             "attn_grid_cells": self.attn_grid_cells,
             "attn_live_cells": self.attn_live_cells,
+            "denoise_passes": self.denoise_passes,
+            "commit_passes": self.commit_passes,
+            "block_rows": self.block_rows,
+            "tokens_fixed": self.tokens_fixed,
+            **self.model_counters,
             "prefix_hit_rate": round(self.prefix_hit_rate(), 4),
             "spec_ticks": self.spec_ticks,
             "spec_tokens_proposed": self.spec_tokens_proposed,

@@ -140,6 +140,9 @@ def export_chain(engine, rid: int) -> MigrationBlob:
     export is a pure read and the caller decides when (if ever) to
     cancel the source copy."""
     req = engine._requests[rid]
+    enforce_that(engine._block is None,
+                 "a block model's chain is not handed over: its current "
+                 "block may stand between passes", context="serving-migrate")
     enforce_that(req.status is RequestStatus.RUNNING and
                  not req.prefilling and bool(req.generated),
                  f"rid {rid} is not migration-eligible "

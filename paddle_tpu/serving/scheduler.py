@@ -111,6 +111,10 @@ class Request:
     cow_src: Optional[int] = None   # shared page to COW-fork before prefill
     prefilling: bool = False        # admitted but chunks still
     #                                 materializing; False once decoding
+    # a block model's read back lags its dispatch by a tick: tokens that a
+    # dispatched pass is fixing and the host has not read yet (they follow
+    # ``generated``; the next step takes them from the device)
+    pending: int = 0
     # cache-insert chain cursor (engine-owned, reset per admission):
     # chunk j's insert resumes hashing where chunk j-1 stopped
     chain_hash: Optional[int] = None
@@ -144,6 +148,14 @@ class SchedulerConfig:
     max_pages_per_seq: int
     max_queue: Optional[int] = None     # None = unbounded queueing
     preempt_budget: Optional[int] = None  # None = unlimited re-prefills
+    # a block model's block length (None: one token a tick).  Its pages
+    # need no rule of their own: a block lies inside one page
+    # (page_size % block_length == 0, the engine's check), which is the
+    # page of the next position to be cached, so the page that
+    # ``admit`` / ``ensure_decode_pages`` take for ``cache_len`` is the
+    # whole block's, taken before its first pass and returned by
+    # preemption or release between passes like any other
+    block_length: Optional[int] = None
 
     @property
     def max_seq_len(self) -> int:
@@ -281,9 +293,12 @@ class ContinuousBatchingScheduler:
             cow_src = None
             if self.cache is not None:
                 hit_pages, hit_len = self.cache.lookup(toks)
-                if hit_pages and hit_len >= len(toks):
+                if hit_pages and hit_len >= len(toks) \
+                        and self.cfg.block_length is None:
                     # full cover: fork the last shared page, recompute
-                    # only the final token (its logits seed decoding)
+                    # only the final token (its logits seed decoding; a
+                    # block model's first token comes of a block pass
+                    # in a page of its own, so it shares every page)
                     cow_src = hit_pages[-1]
                     shared = hit_pages[:-1]
                     stitched = len(toks) - 1
@@ -507,8 +522,8 @@ class ContinuousBatchingScheduler:
 
 
 def pack_prefill_chunks(prefilling: List[Request], chunk: int, align: int,
-                        budget: int) -> Tuple[List[Tuple[Request, int, int,
-                                                         int]], int]:
+                        budget: int, block: int = 1
+                        ) -> Tuple[List[Tuple[Request, int, int, int]], int]:
     """Select which prefill chunks ride in THIS tick's unified step.
 
     Each prefilling request contributes one chunk of at most ``chunk``
@@ -520,6 +535,9 @@ def pack_prefill_chunks(prefilling: List[Request], chunk: int, align: int,
     per-tick prefill row count (hence the jit bucket) stays bounded.
     The FIRST chunk always packs even if it alone exceeds the budget
     (``bucket_for`` rounds the oversize up), so progress is guaranteed.
+    A block model (``block`` its block length, which divides ``chunk``)
+    prefills the whole blocks of its tokens only: chunks start and end
+    on block boundaries.
 
     Returns ``([(request, start, n_tokens, n_rows)], total_rows)``;
     this is scheduling policy, so it lives here with the rest of it.
@@ -527,7 +545,7 @@ def pack_prefill_chunks(prefilling: List[Request], chunk: int, align: int,
     out: List[Tuple[Request, int, int, int]] = []
     total = 0
     for req in prefilling:
-        remaining = len(req.cache_tokens) - req.cache_len
+        remaining = len(req.cache_tokens) // block * block - req.cache_len
         if remaining <= 0:
             continue
         n = remaining if chunk <= 0 else min(chunk, remaining)

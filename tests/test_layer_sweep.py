@@ -1166,7 +1166,10 @@ def _swiglu_ffn():
 
 @case("mla_attention")
 def _mla_attention():
-    s, fs = make_seq("s", 8, [5, 3])
+    # inputs of the case's own: with the module's generator, whose state
+    # depends on which cases a worker ran before, one draw in forty puts a
+    # sampled coordinate's numeric gradient outside the tolerance
+    s, fs = make_seq("s", 8, [5, 3], rng=np.random.RandomState(3))
     pos, _ = int_seq("pos", 8, [5, 3])
     fpos = SequenceBatch(jnp.asarray([0, 1, 2, 3, 4, 0, 1, 2], jnp.int32),
                          fs.segment_ids, fs.lengths, max_len=5)

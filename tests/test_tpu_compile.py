@@ -350,6 +350,73 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
     assert "f32[4,4,64,64]" not in text and "bf16[4,4,64,64]" not in text
 
 
+# ---- a serving step of the block-diffusion expert model -----------------------
+
+def test_block_moe_serving_step_compiles_for_v5e_at_published_widths(
+        topo, monkeypatch):
+    """``serving.BlockMoeLM`` behind ``ServingEngine`` at the cell's
+    widths (hidden 2048, 32 : 4 heads of 128, 128 experts of 768 top-8,
+    the whole vocabulary; 2 of its 4 layers), 32 slots of 4 rows: the
+    decode-only step lowers and compiles for a described v5e; the
+    experts' float32 matrices go into ``moe_gmm`` as they lie (no rounded
+    or re-laid copy), and what leaves the step for the host is a slot's
+    best tokens, not its logits."""
+    from paddle_tpu.analysis import retrace
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.serving import BlockMoeLM, ServingEngine
+    from paddle_tpu.serving import decode_attention as da
+    from paddle_tpu.serving import engine as eng_mod
+
+    monkeypatch.setattr(da, "_interpret_default", lambda: False)
+    monkeypatch.setattr(gm, "interpret_default", lambda: False)
+    monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
+    # the engine asks the backend which attention path compiles here, and
+    # makes a pool on it: the kernel path, and a pool of shapes alone
+    monkeypatch.setattr(eng_mod, "attention_path", lambda *a, **k: "kernel")
+    make_pool = eng_mod.init_kv_pages
+    monkeypatch.setattr(
+        eng_mod, "init_kv_pages",
+        lambda cfg, **kw: jax.eval_shape(lambda: make_pool(cfg, **kw)))
+    model = BlockMoeLM(
+        vocab_size=151936, num_layers=2, embed_dim=2048, num_heads=32,
+        num_kv_heads=4, head_dim=128, num_experts=128, experts_per_token=8,
+        expert_dim=768, block_length=4, denoise_steps=2,
+        mask_token_id=151669)
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    params = {k: aval(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init_params, jax.random.PRNGKey(0)).items()}
+    eng = ServingEngine(model, params, eos_id=model.vocab_size,
+                        page_size=128, max_slots=32, pool_bytes=1 << 29,
+                        max_pages_per_seq=10, buckets=(256,),
+                        prefill_chunk=256)
+    assert eng._ragged_kernel and eng._k1 == 4
+    buf = eng._empty_tick(0, 4)
+    # (and the words of the step before: the tokens it fixed are read there)
+    words = np.zeros(32 * 3 + 32 + 5, np.int32)
+    compiled = eng._step_fn(0, 4).lower(
+        params, jax.tree.map(lambda a: aval(a.shape, a.dtype), eng._kv),
+        aval(buf.shape, buf.dtype), aval(words.shape, words.dtype)).compile()
+    text = compiled.as_text()
+    # a layer: the ragged attention kernel and the three grouped products
+    assert text.count("tpu_custom_call") == 2 * 4
+    for scope in ("moe.route", "moe.experts"):
+        assert scope + "/" in text, scope
+    # the 805 MB of a layer's gate (or up, or down) matrices are read by
+    # the kernels alone: nothing else produces an array of their shape
+    for shape in ("[128,2048,768]", "[128,768,2048]"):
+        made = [line for line in text.splitlines() if " = " in line
+                and shape in line.split(" = ")[1].split("(")[0]
+                and "parameter(" not in line]
+        assert not made, made[:2]
+    out = jax.eval_shape(eng._step_fn(0, 4), params, eng._kv, buf, words)
+    # one small vector: picks [32, 3], the chunk guard's [32], five counts
+    assert out[0].shape == (32 * 3 + 32 + 5,) and out[0].dtype == jnp.int32
+    assert out[1].shape == (32, 2, 151936)       # the logits stay behind
+    # parameters 5.0 GB and the pool; a few tens of MB beside them
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 200e6
+
+
 # ---- names in the device trace ---------------------------------------------
 
 def _kernel_names(fn, *avals):
