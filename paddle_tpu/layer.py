@@ -1728,6 +1728,19 @@ def _per_example(fn_dense, value, *args):
     return fn_dense(value, *[_data_of(a) for a in args])
 
 
+def _count_flash_blocks(ctx, name: str, q_seg, kv_seg=None, *, causal: bool):
+    """Publish what one forward call of the flash kernel visits for these
+    segment ids (``flash_live_blocks_total``) beside what it would visit
+    were the buffer one sequence (``flash_tri_blocks_total``)."""
+    from paddle_tpu.ops.attention import flash_block_counts
+
+    live, whole = flash_block_counts(
+        q_seg[None, :], None if kv_seg is None else kv_seg[None, :],
+        causal=causal)
+    ctx.count("flash_live_blocks_total", live, layer=name)
+    ctx.count("flash_tri_blocks_total", whole, layer=name)
+
+
 @_export
 def multi_head_attention(query, key=None, value=None, *, num_heads: int,
                          size: int = None, causal: bool = False,
@@ -1798,6 +1811,8 @@ def multi_head_attention(query, key=None, value=None, *, num_heads: int,
                 causal=causal),
             ctx.mesh)(q, k, v, qs.segment_ids[None, :],
                       ks.segment_ids[None, :])
+        _count_flash_blocks(ctx, name, qs.segment_ids, ks.segment_ids,
+                            causal=causal)
         y = pmath.matmul(out.reshape(cap_q, size), p["wo"])
         y = qs.with_data(y.astype(pmath.dense_activation_dtype()))
         return _apply_extra(ctx, name, y, layer_attr)
@@ -2266,6 +2281,7 @@ def mla_attention(input, positions, *, num_heads: int, q_lora_rank: int,
                 num_heads=h, qk_nope_dim=qk_nope_head_dim,
                 qk_rope_dim=qk_rope_head_dim, v_dim=v_head_dim,
                 eps=epsilon, theta=rope_theta, mesh=ctx.mesh)
+        _count_flash_blocks(ctx, name, xs.segment_ids, causal=True)
         return xs.with_data(y.astype(pmath.dense_activation_dtype()))
 
     return LayerOutput(name=name, layer_type="mla_attention",
@@ -2421,6 +2437,7 @@ def gated_attention(input, positions, *, num_heads: int, num_kv_heads: int,
                   num_heads=num_heads, num_kv_heads=num_kv_heads,
                   head_dim=head_dim, rotary_dim=rotary_dim, eps=epsilon,
                   theta=rope_theta, mesh=ctx.mesh)
+        _count_flash_blocks(ctx, name, xs.segment_ids, causal=True)
         return xs.with_data(y.astype(pmath.dense_activation_dtype()))
 
     return LayerOutput(name=name, layer_type="gated_attention",

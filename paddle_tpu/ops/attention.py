@@ -11,34 +11,42 @@ back-to-back in one buffer and attention never crosses a segment boundary,
 so there is no padding waste.
 
 Design notes (TPU-first):
-  - forward is a pallas kernel: grid (batch, heads, q-blocks, k-blocks) with
-    the key axis STREAMED through the grid — only one (block_q x D) and one
-    (block_k x D) tile is ever resident in VMEM, with the online-softmax
-    carry (m, l, acc) held in VMEM scratch across the key axis.  VMEM use is
-    O(block^2) at ANY sequence length (the previous design kept full-seq K/V
-    resident per grid cell and hit the 16 MB scoped-vmem wall at 8192 packed
-    tokens).  Pallas double-buffers the streamed tiles, so the K/V DMA for
-    block j+1 overlaps the block-j matmuls; matmuls hit the MXU with
+  - the kernels visit LIVE blocks only.  A (q block, k block) pair is live
+    when the two blocks' segment-id ranges meet and the causal mask leaves a
+    pair in it (``block_live``).  ``block_schedule`` lists the live pairs in
+    XLA, once for all heads and all three kernels, sorted by the block that
+    stays resident; the kernels take the lists as scalar-prefetch operands
+    (``pltpu.PrefetchScalarGridSpec``) on a grid (batch, heads, visits)
+    whose last extent is the TRACED number of visits, and their index maps
+    read the block indices from them.  A packed buffer of 4 x 2048 tokens
+    takes 40 steps a head where the 16 x 16 square has 256; no step is spent
+    finding out that a block is dead, and no dead block's tiles are fetched.
+  - forward: q block resident, its live key blocks STREAMED past it — only
+    one (block_q x D) and one (block_k x D) tile is resident in VMEM, with
+    the online-softmax carry (m, l, acc) in VMEM scratch from the q block's
+    first visit to its last.  VMEM use is O(block^2) at ANY sequence length.
+    Pallas double-buffers the streamed tiles, so the K/V DMA of the next
+    visit overlaps this visit's matmuls; matmuls hit the MXU with
     block_q x head_dim x block_k shapes and fp32 accumulation.
-  - backward is TWO pallas kernels (dK/dV with the QUERY axis streamed
-    through the grid, dQ with the KEY axis streamed), each recomputing P
-    blockwise from (q, k, lse) — the S x S score matrix never exists in
-    either direction, and neither kernel holds a full sequence in VMEM.
-    There is no plain-JAX backward: ``jax.grad`` through ``mha_reference``
-    is the oracle the tests compare against.
-  - causal masking skips fully-masked blocks with pl.when AND clamps the
-    streamed-tile index maps, so the revisiting optimisation elides the DMA
-    for blocks that would be skipped (~half the grid for causal).
-  - on CPU (tests / 8-device virtual mesh) the kernels run in interpret mode.
+  - backward is TWO pallas kernels (dK/dV: k block resident, its live QUERY
+    blocks streamed; dQ: the forward's walk), each recomputing P blockwise
+    from (q, k, lse) — the S x S score matrix never exists in either
+    direction.  There is no plain-JAX backward: ``jax.grad`` through
+    ``mha_reference`` is the oracle the tests compare against.
+  - a resident block with no live pair (cross-attention over disjoint ids)
+    keeps one visit that computes nothing, so its output is written as zeros.
+  - on CPU (tests / 8-device virtual mesh) the same kernels run in interpret
+    mode.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -88,6 +96,134 @@ def mha_reference(q, k, v, segment_ids=None, kv_segment_ids=None,
 
 
 # ---------------------------------------------------------------------------
+# The block schedule: which blocks the kernels visit, made in XLA
+# ---------------------------------------------------------------------------
+
+# One visit of a kernel: the resident block (the one whose carry lives in
+# VMEM scratch), the streamed block, and what the step does with them.
+_FIRST, _LAST, _COMPUTE = 1, 2, 4
+
+
+class Visits(NamedTuple):
+    """A kernel's walk over the live blocks of each batch row, in order.
+
+    ``resident``, ``streamed``, ``flags`` are [B, extent] int32: visit ``t``
+    of row ``b`` holds block ``resident[b, t]`` in VMEM scratch and streams
+    block ``streamed[b, t]`` past it.  ``flags`` says whether the resident
+    block starts there (``_FIRST``: the carry is initialised), ends there
+    (``_LAST``: the output is written) and whether the pair is computed
+    (``_COMPUTE``).  ``count`` [B] is the number of visits of each row;
+    the slots behind it repeat the row's last visit with no flag set, so
+    they move no tile and do nothing (only a row shorter than the longest
+    of its batch has such steps: the grid's extent is ``count.max()``)."""
+    resident: jax.Array
+    streamed: jax.Array
+    flags: jax.Array
+    count: jax.Array
+
+
+def _causal_bound(nq: int, nk: int, block_q: int, block_k: int,
+                  causal: bool) -> np.ndarray:
+    """[nq, nk] bool, static: the blocks the causal mask leaves a pair in
+    (``j * block_k < (i + 1) * block_q``); all of them without ``causal``."""
+    if not causal:
+        return np.ones((nq, nk), bool)
+    i = np.arange(nq)[:, None]
+    j = np.arange(nk)[None, :]
+    return j * block_k < (i + 1) * block_q
+
+
+def block_live(q_seg, kv_seg, block_q: int, block_k: int, causal: bool):
+    """[B, nq, nk] bool: which (q block, k block) pairs the kernels compute.
+
+    Packed sequences give each block an id range; disjoint ranges mean no
+    ``q_seg == k_seg`` pair exists, so the whole block is dead.  The test is
+    conservative (overlapping ranges without an equal pair still compute),
+    hence correct for ANY id assignment.  The forward and both backward
+    kernels walk schedules made from this ONE array, so ``lse`` is never
+    consumed by a pair the forward skipped."""
+    batch, seq_q = q_seg.shape
+    nq, nk = seq_q // block_q, kv_seg.shape[1] // block_k
+    qb = q_seg.reshape(batch, nq, 1, block_q)
+    kb = kv_seg.reshape(batch, 1, nk, block_k)
+    meet = ((qb.max(-1) >= kb.min(-1)) & (qb.min(-1) <= kb.max(-1)))
+    return meet & _causal_bound(nq, nk, block_q, block_k, causal)
+
+
+def _visits(live, bound: np.ndarray) -> Visits:
+    """The walk over ``live`` [B, R, S] (resident blocks by streamed blocks)
+    sorted by resident block, then streamed block.  A resident block with no
+    live pair keeps one visit that computes nothing, so that its output is
+    still written (as zeros).  ``bound`` [R, S] is what ``live`` can be at
+    most: it gives the static extent of the arrays."""
+    batch, nr, ns = live.shape
+    extent = int(np.maximum(bound.sum(1), 1).sum())
+    lone = ~live.any(-1, keepdims=True) & (jnp.arange(ns) == 0)
+    visit = (live | lone).reshape(batch, nr * ns)
+    count = visit.sum(-1).astype(jnp.int32)
+    # a stable sort of "not visited" lists the visits first, in row-major
+    # order; the tail repeats the last of them
+    t = jnp.arange(extent, dtype=jnp.int32)
+    order = jnp.argsort(~visit, axis=-1, stable=True).astype(jnp.int32)
+    order = jnp.take_along_axis(
+        order, jnp.minimum(t, count[:, None] - 1), axis=-1)
+    resident, streamed = order // ns, order % ns
+    valid = t < count[:, None]
+    edge = jnp.ones((batch, 1), bool)
+    changes = resident[:, 1:] != resident[:, :-1]
+    first = jnp.concatenate([edge, changes], axis=1)
+    last = jnp.concatenate([changes, edge], axis=1) | (t == count[:, None] - 1)
+    compute = jnp.take_along_axis(live.reshape(batch, nr * ns), order, axis=-1)
+    flags = valid * (_FIRST * first + _LAST * last + _COMPUTE * compute)
+    return Visits(resident, streamed, flags.astype(jnp.int32), count)
+
+
+def block_schedule(q_seg, kv_seg, block_q: int, block_k: int, causal: bool):
+    """The kernels' walks, made in XLA from the segment ids, once for all
+    heads: ``by_q`` (sorted by q block, then k block) is the forward's and
+    dQ's, ``by_k`` (by k block, then q block) is dKV's."""
+    live = block_live(q_seg, kv_seg, block_q, block_k, causal)
+    bound = _causal_bound(live.shape[1], live.shape[2], block_q, block_k,
+                          causal)
+    return _visits(live, bound), _visits(live.transpose(0, 2, 1), bound.T)
+
+
+def _auto_block(seq: int) -> int:
+    """The default tile edge: the largest of the ladder that divides the
+    sequence.  Streaming keeps VMEM at O(block^2), so big tiles are free
+    memory-wise and each grid step amortises its fixed cost over 16x more
+    MXU work than a 128 tile."""
+    for edge in _BLOCK_LADDER:
+        if seq % edge == 0:
+            return edge
+    return 128  # small/ragged seqs: the caller clamps to seq
+
+
+def _blocks(seq_q: int, seq_k: int, block_q, block_k):
+    block_q = min(block_q or _auto_block(seq_q), seq_q)
+    block_k = min(block_k or _auto_block(seq_k), seq_k)
+    assert seq_q % block_q == 0 and seq_k % block_k == 0, (
+        f"sequence lengths ({seq_q},{seq_k}) must divide by blocks "
+        f"({block_q},{block_k}) — DataFeeder pads capacity to multiples")
+    return block_q, block_k
+
+
+def flash_block_counts(segment_ids, kv_segment_ids=None, *,
+                       causal: bool = False):
+    """(live, whole): the blocks one forward call of ``flash_attention``
+    visits for these segment ids at its default tiles, and the blocks it
+    would visit were each row one sequence (the causal triangle, or the
+    square without ``causal``).  For a layer's counters: under ``jit`` only
+    the live test and a sum of it are left of the schedule here."""
+    q_seg = segment_ids.astype(jnp.int32)
+    kv_seg = (q_seg if kv_segment_ids is None
+              else kv_segment_ids.astype(jnp.int32))
+    block_q, block_k = _blocks(q_seg.shape[1], kv_seg.shape[1], None, None)
+    by_q, _ = block_schedule(q_seg, kv_seg, block_q, block_k, causal)
+    return by_q.count.sum(), q_seg.shape[0] * by_q.resident.shape[1]
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
@@ -105,66 +241,35 @@ def _pv_operands(probs, other, pv_f32: bool):
     return probs.astype(other.dtype), other
 
 
-def _seg_live(qseg_ref, kseg_ref, b):
-    """Runtime block-skip predicate: packed sequences give each (q, k) block
-    an id range; disjoint ranges mean no q_seg == k_seg pair exists, so the
-    whole block is dead.  Conservative (overlapping ranges without an equal
-    pair still compute), hence correct for ANY id assignment.  Forward and
-    both backward kernels MUST use this same predicate so lse is never
-    consumed by a pair the forward skipped."""
-    q_sg = qseg_ref[b, :]
-    k_sg = kseg_ref[b, :]
-    return ((jnp.max(q_sg) >= jnp.min(k_sg)) &
-            (jnp.min(q_sg) <= jnp.max(k_sg)))
+def _step(res_ref, str_ref, flag_ref, extent: int):
+    """This grid step's visit, read from the scalar-prefetch refs: batch
+    row, resident block, streamed block and the three flags."""
+    b = pl.program_id(0)
+    at = b * extent + pl.program_id(2)
+    flags = flag_ref[at]
+    return (b, res_ref[at], str_ref[at], (flags & _FIRST) != 0,
+            (flags & _LAST) != 0, (flags & _COMPUTE) != 0)
 
 
-def _clamped_kv_maps(causal, block_q, block_k):
-    """Index maps for the streamed key-axis tiles on a (b, h, i, j) grid.
-    Under causal masking, clamp j to the last live key block for q block i
-    (`j*block_k < (i+1)*block_q` — the same bound the kernels' live
-    predicate uses), so skipped blocks repeat the previous index and the
-    revisiting optimisation elides their DMA entirely."""
-    if causal:
-        def kv_idx(b, h, i, j):
-            return (b, h, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k),
-                    0)
-
-        def kseg_idx(b, h, i, j):
-            return (0, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k))
-    else:
-        def kv_idx(b, h, i, j):
-            return (b, h, j, 0)
-
-        def kseg_idx(b, h, i, j):
-            return (0, j)
-    return kv_idx, kseg_idx
-
-
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref,
-                      lse_ref, m_scr, l_scr, acc_scr, *, sm_scale: float,
-                      causal: bool, num_kb: int, pv_f32: bool):
-    # q_ref: (1, 1, block_q, D); k_ref/v_ref: (1, 1, block_k, D) — the key
-    # axis is the LAST grid dim, streamed; carries (m, l, acc) persist in
-    # VMEM scratch across it.  qseg_ref: (B, block_q); kseg_ref: (B, block_k)
-    # — full batch dim because TPU block shapes must tile (8, 128) or span
-    # the whole array dim.
+def _flash_fwd_kernel(res_ref, str_ref, flag_ref, q_ref, k_ref, v_ref,
+                      qseg_ref, kseg_ref, o_ref, lse_ref, m_scr, l_scr,
+                      acc_scr, *, sm_scale: float, causal: bool, extent: int,
+                      pv_f32: bool):
+    # grid (B, H, visits): q block ``qi`` is resident, its live key blocks
+    # ``j`` are streamed past it; the online-softmax carries (m, l, acc)
+    # persist in VMEM scratch from the q block's first visit to its last.
+    # q_ref: (1, 1, block_q, D); k_ref/v_ref: (1, 1, block_k, D).
+    # qseg_ref: (B, block_q); kseg_ref: (B, block_k) — full batch dim
+    # because TPU block shapes must tile (8, 128) or span the whole dim.
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
-    b = pl.program_id(0)
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
+    b, qi, j, first, last, live = _step(res_ref, str_ref, flag_ref, extent)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # causal: key blocks strictly after this q block are fully masked;
-    # _seg_live skips cross-segment blocks at runtime
-    seg_live = _seg_live(qseg_ref, kseg_ref, b)
-    live = seg_live & (j * block_k < (qi + 1) * block_q) if causal \
-        else seg_live
 
     @pl.when(live)
     def _compute():
@@ -209,7 +314,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref,
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(j == num_kb - 1)
+    @pl.when(last)
     def _finalize():
         m = jnp.max(m_scr[...], axis=1, keepdims=True)
         l = jnp.max(l_scr[...], axis=1, keepdims=True)
@@ -219,50 +324,77 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref,
 
 
 def _dim_semantics(grid_ndim: int, interpret: bool):
-    """Grid (batch, heads, blocks, streamed): all parallel but the last —
-    only the streamed axis carries scratch state, so megacore may split any
-    earlier dim across cores."""
+    """All grid dims parallel but the last: only the streamed axis carries
+    scratch state, so megacore may split any earlier dim across cores."""
     if interpret:
         return None  # interpret mode ignores TPU compiler params
     sem = ("parallel",) * (grid_ndim - 1) + ("arbitrary",)
     return pltpu.CompilerParams(dimension_semantics=sem)
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-               interpret, pv_f32=False):
+def _visit_call(kernel, visits: Visits, batch: int, heads: int, *, in_specs,
+                out_specs, out_shape, scratch_shapes, interpret: bool,
+                name: str, **static):
+    """``pallas_call`` on the grid (batch, heads, visits): the schedule rides
+    as scalar-prefetch operands, the index maps take the blocks from it, and
+    the last extent is the traced number of visits (of the longest row)."""
+    extent = visits.resident.shape[1]
+    call = pl.pallas_call(
+        functools.partial(kernel, extent=extent, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch, heads, visits.count.max()),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape, compiler_params=_dim_semantics(3, interpret),
+        interpret=interpret,
+        name=name,
+    )
+    return functools.partial(call, visits.resident.reshape(-1),
+                             visits.streamed.reshape(-1),
+                             visits.flags.reshape(-1))
+
+
+def _tile_specs(extent: int, batch: int, block_r: int, block_s: int,
+                head_dim: int):
+    """BlockSpec makers of a (batch, heads, visits) grid: ``res(w)`` /
+    ``stream(w)`` give a [1, 1, block, w] tile of a [B, H, S, w] operand at
+    the visit's resident / streamed block, ``res_seg`` / ``stream_seg`` the
+    matching [B, block] tile of the segment ids."""
+    def at(b, t):
+        return b * extent + t
+
+    def res(width=head_dim):
+        return pl.BlockSpec(
+            (1, 1, block_r, width),
+            lambda b, h, t, r, s, f: (b, h, r[at(b, t)], 0))
+
+    def stream(width=head_dim):
+        return pl.BlockSpec(
+            (1, 1, block_s, width),
+            lambda b, h, t, r, s, f: (b, h, s[at(b, t)], 0))
+
+    res_seg = pl.BlockSpec((batch, block_r),
+                           lambda b, h, t, r, s, f: (0, r[at(b, t)]))
+    stream_seg = pl.BlockSpec((batch, block_s),
+                              lambda b, h, t, r, s, f: (0, s[at(b, t)]))
+    return res, stream, res_seg, stream_seg
+
+
+def _flash_fwd(q, k, v, q_seg, kv_seg, by_q: Visits, causal, sm_scale,
+               block_q, block_k, interpret, pv_f32=False):
     batch, seq_q, heads, head_dim = q.shape
-    seq_k = k.shape[1]
-    block_q = min(block_q, seq_q)
-    block_k = min(block_k, seq_k)
-    assert seq_q % block_q == 0 and seq_k % block_k == 0, (
-        f"sequence lengths ({seq_q},{seq_k}) must divide by blocks "
-        f"({block_q},{block_k}) — DataFeeder pads capacity to multiples")
     # (B, S, H, D) -> (B, H, S, D) for contiguous per-head blocks
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
 
-    num_kb = seq_k // block_k
-    kv_idx, kseg_idx = _clamped_kv_maps(causal, block_q, block_k)
-    grid = (batch, heads, seq_q // block_q, num_kb)
-    kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                               causal=causal, num_kb=num_kb, pv_f32=pv_f32)
-    out_t, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), kv_idx),
-            pl.BlockSpec((1, 1, block_k, head_dim), kv_idx),
-            pl.BlockSpec((batch, block_q), lambda b, h, i, j: (0, i)),
-            pl.BlockSpec((batch, block_k), kseg_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
-        ],
+    res, stream, res_seg, stream_seg = _tile_specs(
+        by_q.resident.shape[1], batch, block_q, block_k, head_dim)
+    out_t, lse = _visit_call(
+        _flash_fwd_kernel, by_q, batch, heads,
+        in_specs=[res(), stream(), stream(), res_seg, stream_seg],
+        out_specs=[res(), res(1)],
         out_shape=[
             jax.ShapeDtypeStruct((batch, heads, seq_q, head_dim), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, seq_q, 1), jnp.float32),
@@ -272,9 +404,8 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, head_dim), jnp.float32),
         ],
-        compiler_params=_dim_semantics(4, interpret),
-        interpret=interpret,
-        name="flash_fwd",
+        interpret=interpret, name="flash_fwd",
+        sm_scale=sm_scale, causal=causal, pv_f32=pv_f32,
     )(qt, kt, vt, q_seg, kv_seg)
     return out_t.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -288,30 +419,22 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
-def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
-                         lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                         *, sm_scale: float, causal: bool, num_qb: int,
-                         pv_f32: bool):
-    # grid (B, H, k-blocks, q-blocks): the QUERY axis is streamed through
-    # the last grid dim; dk/dv accumulate in VMEM scratch across it.
+def _flash_bwd_kv_kernel(res_ref, str_ref, flag_ref, q_ref, k_ref, v_ref,
+                         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
+                         dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
+                         causal: bool, extent: int, pv_f32: bool):
+    # grid (B, H, visits): k block ``kj`` is resident, its live QUERY blocks
+    # ``i`` are streamed past it; dk/dv accumulate in VMEM scratch.
     # k_ref/v_ref: (1, 1, block_k, D); q/do: (1, 1, block_q, D);
     # lse/delta: (1, 1, block_q, 1); qseg: (B, block_q); kseg: (B, block_k)
     block_k = k_ref.shape[2]
     block_q = q_ref.shape[2]
-    b = pl.program_id(0)
-    kj = pl.program_id(2)
-    i = pl.program_id(3)
+    b, kj, i, first, last, live = _step(res_ref, str_ref, flag_ref, extent)
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    # causal: q blocks whose last row precedes this k block are fully
-    # masked; _seg_live skips cross-segment blocks at runtime
-    seg_live = _seg_live(qseg_ref, kseg_ref, b)
-    live = seg_live & ((i + 1) * block_q > kj * block_k) if causal \
-        else seg_live
 
     @pl.when(live)
     def _compute():
@@ -346,31 +469,25 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
             dsb, qmm, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(i == num_qb - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
-                         lse_ref, delta_ref, dq_ref, dq_scr, *,
-                         sm_scale: float, causal: bool, num_kb: int,
-                         pv_f32: bool):
-    # grid (B, H, q-blocks, k-blocks): the KEY axis is streamed through the
-    # last grid dim; dq accumulates in VMEM scratch across it.
+def _flash_bwd_dq_kernel(res_ref, str_ref, flag_ref, q_ref, k_ref, v_ref,
+                         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, dq_scr, *, sm_scale: float, causal: bool,
+                         extent: int, pv_f32: bool):
+    # grid (B, H, visits), the forward's walk: q block ``qi`` is resident,
+    # its live KEY blocks ``j`` are streamed; dq accumulates in VMEM scratch.
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
-    b = pl.program_id(0)
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
+    b, qi, j, first, last, live = _step(res_ref, str_ref, flag_ref, extent)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    seg_live = _seg_live(qseg_ref, kseg_ref, b)
-    live = seg_live & (j * block_k < (qi + 1) * block_q) if causal \
-        else seg_live
 
     @pl.when(live)
     def _compute():
@@ -401,20 +518,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref,
             dsb, kmm, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == num_kb - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0, :, :] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
                       interpret, pv_f32=False):
-    q, k, v, q_seg, kv_seg, out, lse = res
+    q, k, v, q_seg, kv_seg, by_q, by_k, out, lse = res
     batch, seq_q, heads, head_dim = q.shape
     seq_k = k.shape[1]
-    block_q = min(block_q, seq_q)
-    block_k = min(block_k, seq_k)
-    num_qb = seq_q // block_q
-    num_kb = seq_k // block_k
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -424,51 +537,16 @@ def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
                     out.transpose(0, 2, 1, 3).astype(jnp.float32),
                     axis=-1, keepdims=True)               # (B, H, Sq, 1)
     lse_t = lse[..., None]                                # (B, H, Sq, 1)
+    static = dict(sm_scale=sm_scale, causal=causal, pv_f32=pv_f32)
 
-    # --- dK/dV: grid (B, H, k-blocks, q-blocks), query axis streamed ---
-    if causal:
-        # clamp the streamed q-tile index so fully-masked q blocks (strictly
-        # before the k block) don't re-DMA; pl.when skips their compute.
-        # The upper clamp to num_qb-1 covers causal cross-attention with
-        # seq_k > seq_q, where (kj*block_k)//block_q can exceed the last
-        # q block (the old code degraded to an out-of-range block index).
-        def q_idx(b, h, kj, i):
-            return (b, h, jnp.minimum(num_qb - 1,
-                                      jnp.maximum(i, (kj * block_k) // block_q)),
-                    0)
-
-        def qseg_idx(b, h, kj, i):
-            return (0, jnp.minimum(num_qb - 1,
-                                   jnp.maximum(i, (kj * block_k) // block_q)))
-    else:
-        def q_idx(b, h, kj, i):
-            return (b, h, i, 0)
-
-        def qseg_idx(b, h, kj, i):
-            return (0, i)
-
-    dk_t, dv_t = pl.pallas_call(
-        functools.partial(_flash_bwd_kv_kernel, sm_scale=sm_scale,
-                          causal=causal, num_qb=num_qb, pv_f32=pv_f32),
-        grid=(batch, heads, num_kb, num_qb),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), q_idx),
-            pl.BlockSpec((1, 1, block_k, head_dim),
-                         lambda b, h, kj, i: (b, h, kj, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim),
-                         lambda b, h, kj, i: (b, h, kj, 0)),
-            pl.BlockSpec((batch, block_q), qseg_idx),
-            pl.BlockSpec((batch, block_k), lambda b, h, kj, i: (0, kj)),
-            pl.BlockSpec((1, 1, block_q, head_dim), q_idx),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx),
-            pl.BlockSpec((1, 1, block_q, 1), q_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, head_dim),
-                         lambda b, h, kj, i: (b, h, kj, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim),
-                         lambda b, h, kj, i: (b, h, kj, 0)),
-        ],
+    # --- dK/dV: k block resident, its live q blocks streamed ---
+    kblk, qblk, kseg_blk, qseg_blk = _tile_specs(
+        by_k.resident.shape[1], batch, block_k, block_q, head_dim)
+    dk_t, dv_t = _visit_call(
+        _flash_bwd_kv_kernel, by_k, batch, heads,
+        in_specs=[qblk(), kblk(), kblk(), qseg_blk, kseg_blk, qblk(),
+                  qblk(1), qblk(1)],
+        out_specs=[kblk(), kblk()],
         out_shape=[
             jax.ShapeDtypeStruct((batch, heads, seq_k, head_dim), k.dtype),
             jax.ShapeDtypeStruct((batch, heads, seq_k, head_dim), v.dtype),
@@ -477,61 +555,44 @@ def _flash_bwd_pallas(res, do, *, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_k, head_dim), jnp.float32),
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
-        compiler_params=_dim_semantics(4, interpret),
-        interpret=interpret,
-        name="flash_bwd_dkv",
+        interpret=interpret, name="flash_bwd_dkv", **static,
     )(qt, kt, vt, q_seg, kv_seg, dot, lse_t, delta)
 
-    # --- dQ: grid (B, H, q-blocks, k-blocks), key axis streamed ---
-    kv_idx, kseg_idx = _clamped_kv_maps(causal, block_q, block_k)
-    blk_q = pl.BlockSpec((1, 1, block_q, head_dim),
-                         lambda b, h, i, j: (b, h, i, 0))
-    blk_q1 = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
-
-    dq_t = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, num_kb=num_kb, pv_f32=pv_f32),
-        grid=(batch, heads, num_qb, num_kb),
-        in_specs=[
-            blk_q,
-            pl.BlockSpec((1, 1, block_k, head_dim), kv_idx),
-            pl.BlockSpec((1, 1, block_k, head_dim), kv_idx),
-            pl.BlockSpec((batch, block_q), lambda b, h, i, j: (0, i)),
-            pl.BlockSpec((batch, block_k), kseg_idx),
-            blk_q,
-            blk_q1,
-            blk_q1,
-        ],
-        out_specs=blk_q,
+    # --- dQ: the forward's walk ---
+    qblk, kblk, qseg_blk, kseg_blk = _tile_specs(
+        by_q.resident.shape[1], batch, block_q, block_k, head_dim)
+    dq_t = _visit_call(
+        _flash_bwd_dq_kernel, by_q, batch, heads,
+        in_specs=[qblk(), kblk(), kblk(), qseg_blk, kseg_blk, qblk(),
+                  qblk(1), qblk(1)],
+        out_specs=qblk(),
         out_shape=jax.ShapeDtypeStruct((batch, heads, seq_q, head_dim),
                                        q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        compiler_params=_dim_semantics(4, interpret),
-        interpret=interpret,
-        name="flash_bwd_dq",
+        interpret=interpret, name="flash_bwd_dq", **static,
     )(qt, kt, vt, q_seg, kv_seg, dot, lse_t, delta)
 
     return (dq_t.transpose(0, 2, 1, 3), dk_t.transpose(0, 2, 1, 3),
-            dv_t.transpose(0, 2, 1, 3), None, None)
+            dv_t.transpose(0, 2, 1, 3), None, None, None, None)
 
 
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_attention(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q,
-                     block_k, interpret, pv_f32):
-    out, _ = _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q,
-                        block_k, interpret, pv_f32=pv_f32)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
+def _flash_attention(q, k, v, q_seg, kv_seg, by_q, by_k, causal, sm_scale,
+                     block_q, block_k, interpret, pv_f32):
+    out, _ = _flash_fwd(q, k, v, q_seg, kv_seg, by_q, causal, sm_scale,
+                        block_q, block_k, interpret, pv_f32=pv_f32)
     return out
 
 
-def _fwd_rule(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-              interpret, pv_f32):
-    out, lse = _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q,
-                          block_k, interpret, pv_f32=pv_f32)
-    return out, (q, k, v, q_seg, kv_seg, out, lse)
+def _fwd_rule(q, k, v, q_seg, kv_seg, by_q, by_k, causal, sm_scale, block_q,
+              block_k, interpret, pv_f32):
+    out, lse = _flash_fwd(q, k, v, q_seg, kv_seg, by_q, causal, sm_scale,
+                          block_q, block_k, interpret, pv_f32=pv_f32)
+    return out, (q, k, v, q_seg, kv_seg, by_q, by_k, out, lse)
 
 
 def _bwd_rule(causal, sm_scale, block_q, block_k, interpret, pv_f32, res, do):
@@ -558,25 +619,15 @@ def flash_attention(q, k, v, segment_ids=None, kv_segment_ids=None,
       kv_segment_ids: (B, Sk); defaults to segment_ids (self-attention).
       causal: lower-triangular masking (positions are absolute in the packed
         buffer — combine with segment ids for per-sequence causality).
+      block_q, block_k: tile edges; by default the largest of the ladder
+        that divides the sequence (call sites that chose their blocks —
+        ring/ulysses shard-sized tiles, tests — keep them).
     """
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
         interpret = _interpret_default()
     from paddle_tpu.platform.flags import FLAGS
-
-    # The default tile edge is the largest of the ladder that divides the
-    # sequence (call sites that chose their blocks — ring/ulysses
-    # shard-sized tiles, tests — keep them): streaming keeps VMEM at
-    # O(block^2), so big tiles are free memory-wise and each grid cell
-    # amortizes its fixed cost over 16x more MXU work than a 128 tile
-    # (measured: 128 tiles at seq 4096 = 32k grid cells of ~760ns overhead
-    # each, dwarfing the matmuls).
-    def _auto_block(seq):
-        for edge in _BLOCK_LADDER:
-            if seq % edge == 0:
-                return edge
-        return 128  # small/ragged seqs: min() below clamps to seq
 
     batch, seq_q = q.shape[0], q.shape[1]
     seq_k = k.shape[1]
@@ -587,10 +638,7 @@ def flash_attention(q, k, v, segment_ids=None, kv_segment_ids=None,
         k = k.astype(q.dtype)
     if v.dtype != q.dtype:
         v = v.astype(q.dtype)
-    if block_q is None:
-        block_q = _auto_block(seq_q)
-    if block_k is None:
-        block_k = _auto_block(seq_k)
+    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
     if segment_ids is None:
         q_seg = jnp.zeros((batch, seq_q), jnp.int32)
         kv_seg = jnp.zeros((batch, seq_k), jnp.int32)
@@ -598,6 +646,7 @@ def flash_attention(q, k, v, segment_ids=None, kv_segment_ids=None,
         q_seg = segment_ids.astype(jnp.int32)
         kv_seg = (q_seg if kv_segment_ids is None
                   else kv_segment_ids.astype(jnp.int32))
-    return _flash_attention(q, k, v, q_seg, kv_seg, bool(causal),
-                            float(sm_scale), int(block_q), int(block_k),
+    by_q, by_k = block_schedule(q_seg, kv_seg, block_q, block_k, bool(causal))
+    return _flash_attention(q, k, v, q_seg, kv_seg, by_q, by_k, bool(causal),
+                            float(sm_scale), block_q, block_k,
                             bool(interpret), bool(FLAGS.attn_pv_f32))

@@ -838,7 +838,11 @@ def _row_conv():
 
 @case("multi_head_attention")
 def _mha():
-    s, fs = make_seq("s", 8, [3, 2])
+    # its own inputs: with the module's generator the case's data, and so
+    # how far a central difference of 5e-3 lies from the softmax's slope,
+    # followed the cases a worker ran before (PR 38 saw one xdist run land
+    # 4% outside the tolerance, and twelve seeds pass at a quarter of it)
+    s, fs = make_seq("s", 8, [3, 2], rng=np.random.RandomState(4))
     out = layer.multi_head_attention(s, num_heads=2)
     check_layer_grad(layer.pooling(out), {"s": fs}, delta=5e-3, rtol=8e-2)
 

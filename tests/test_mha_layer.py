@@ -100,3 +100,53 @@ def test_mha_layer_trains(rng):
               event_handler=lambda ev: costs.append(float(ev.cost))
               if isinstance(ev, paddle.event.EndIteration) else None)
     assert np.mean(costs[-5:]) < np.mean(costs[:5]) / 2
+
+
+def test_mha_layer_counts_its_live_blocks(rng):
+    """The layer publishes what the flash kernel's schedule visits beside
+    the blocks a one-sequence buffer would take: after a ``SGD.train``
+    window the registry holds the schedule's counts times the steps."""
+    from paddle_tpu.obs import default_registry
+
+    dim, heads, vocab = 16, 2, 30
+    paddle.topology.reset_name_scope()
+    words = layer.data(name="w",
+                       type=paddle.data_type.integer_value_sequence(vocab))
+    y = layer.data(name="y", type=paddle.data_type.integer_value(2))
+    emb = layer.embedding(input=words, size=dim)
+    att = layer.multi_head_attention(emb, num_heads=heads, causal=True,
+                                     name="att")
+    pooled = layer.pooling(input=att,
+                           pooling_type=paddle.pooling.AvgPooling())
+    cost = layer.classification_cost(input=layer.fc(input=pooled, size=2),
+                                     label=y)
+    params = paddle.Parameters.from_topology(
+        paddle.topology.Topology([cost]), seed=0)
+    sgd = trainer.SGD(cost=cost, parameters=params,
+                      update_equation=optimizer.Adam(learning_rate=1e-3))
+    # each batch fills a 1024-token buffer (two blocks of 512: a triangle
+    # of 3).  256 + 256 | 200 + pad: the second block's ids do not meet
+    # the first's, 2 live.  300 + 300 (straddles) + 100: all 3 live.
+    steps = [[256, 256, 200], [300, 300, 100], [512, 40]]
+    live = [2, 3, 2]
+    for lengths, want in zip(steps, live):
+        seg = np.full(1024, len(lengths), np.int32)
+        seg[:sum(lengths)] = np.repeat(np.arange(len(lengths)), lengths)
+        got = pattn.flash_block_counts(jnp.asarray(seg)[None], causal=True)
+        assert (int(got[0]), int(got[1])) == (want, 3)
+
+    def reader():
+        for lengths in steps:
+            yield [([int(t) for t in rng.randint(0, vocab, size=n)], 1)
+                   for n in lengths]
+
+    before = default_registry().snapshot()
+    sgd.train(reader, num_passes=2, event_handler=lambda ev: None)
+    after = default_registry().snapshot()
+
+    def grown(name):
+        key = name + "{layer=att}"
+        return after[key] - before.get(key, 0)
+
+    assert grown("flash_live_blocks_total") == 2 * sum(live)
+    assert grown("flash_tri_blocks_total") == 2 * 3 * len(steps)

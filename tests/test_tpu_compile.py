@@ -241,6 +241,29 @@ def test_flash_attention_at_head_width_256_compiles_for_v5e(topo, mode):
     assert n == {"fwd": 1, "bwd_pallas": 3}[mode]
 
 
+@pytest.mark.parametrize("heads,width", [(16, 128), (20, 256), (16, 256)])
+def test_flash_kernels_walk_a_schedule_at_the_cells_shapes_for_v5e(
+        topo, heads, width):
+    """The train cells hand the kernels ONE row of 8192 tokens with segment
+    ids (1.3B: 16 x 128; glm: 20 x 256; qwen3-next: 16 x 256): the grid's
+    last extent is the traced number of live blocks and the index maps read
+    the blocks from scalar-prefetch operands, which Mosaic has to lower."""
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    q = aval((1, 8192, heads, width), jnp.bfloat16)
+    seg = aval((1, 8192), jnp.int32)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(flash_attention(q, k, v, segment_ids=seg, causal=True,
+                                       interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q, seg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text, name
+
+
 @pytest.mark.parametrize("matrix", ["up", "down"])
 def test_grouped_matmul_compiles_for_v5e(topo, matrix, monkeypatch):
     """``moe_gmm`` and, through the gradient, its transposed form and
