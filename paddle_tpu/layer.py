@@ -1798,24 +1798,29 @@ def multi_head_attention(query, key=None, value=None, *, num_heads: int,
         # (the kernel accumulates scores/output in f32). The projections
         # still ACCUMULATE in f32 (matmul's preferred_element_type) and
         # round once on the way out — the policy ops/math.py documents.
+        # two scopes, bound differently: the four products (the MXU) and
+        # the kernel with its layout changes
         qkv_t = pmath.compute_dtype(qs.data)
-        q = pmath.matmul(qs.data, p["wq"]).astype(qkv_t).reshape(
-            1, cap_q, num_heads, head_dim)
-        k = pmath.matmul(ks.data, p["wk"]).astype(qkv_t).reshape(
-            1, cap_k, num_heads, head_dim)
-        v = pmath.matmul(vs.data, p["wv"]).astype(qkv_t).reshape(
-            1, cap_k, num_heads, head_dim)
-        out = per_device(
-            lambda q, k, v, q_seg, k_seg: pattn.flash_attention(
-                q, k, v, segment_ids=q_seg, kv_segment_ids=k_seg,
-                causal=causal),
-            ctx.mesh)(q, k, v, qs.segment_ids[None, :],
-                      ks.segment_ids[None, :])
-        _count_flash_blocks(ctx, name, qs.segment_ids, ks.segment_ids,
-                            causal=causal)
-        y = pmath.matmul(out.reshape(cap_q, size), p["wo"])
-        y = qs.with_data(y.astype(pmath.dense_activation_dtype()))
-        return _apply_extra(ctx, name, y, layer_attr)
+        with jax.named_scope("attn.proj"):
+            q = pmath.matmul(qs.data, p["wq"]).astype(qkv_t)
+            k = pmath.matmul(ks.data, p["wk"]).astype(qkv_t)
+            v = pmath.matmul(vs.data, p["wv"]).astype(qkv_t)
+        with jax.named_scope("attn.core"):
+            out = per_device(
+                lambda q, k, v, q_seg, k_seg: pattn.flash_attention(
+                    q, k, v, segment_ids=q_seg, kv_segment_ids=k_seg,
+                    causal=causal),
+                ctx.mesh)(q.reshape(1, cap_q, num_heads, head_dim),
+                          k.reshape(1, cap_k, num_heads, head_dim),
+                          v.reshape(1, cap_k, num_heads, head_dim),
+                          qs.segment_ids[None, :], ks.segment_ids[None, :])
+            _count_flash_blocks(ctx, name, qs.segment_ids, ks.segment_ids,
+                                causal=causal)
+            out = out.reshape(cap_q, size)
+        with jax.named_scope("attn.proj"):
+            y = pmath.matmul(out, p["wo"])
+            y = y.astype(pmath.dense_activation_dtype())
+        return _apply_extra(ctx, name, qs.with_data(y), layer_attr)
 
     node = LayerOutput(name=name, layer_type="multi_head_attention",
                        inputs=[q_in, k_in, v_in], fn=compute, params=params,

@@ -271,7 +271,10 @@ def lm_head_xent(x, w, b, labels, block_v: int = 4096):
     logz. Matmuls ride the bf16/f32-accum policy (ops/math.py).
 
     x: [N, D] tokens; w: [D, V]; b: [V] or None; labels: [N] int.
-    Returns per-token loss [N] in f32.
+    Returns per-token loss [N] in f32.  The one loss that holds its own
+    head product, so it names its two pieces for a profiler trace:
+    ``head.logits`` (the products, forward and backward) and ``head.xent``
+    (the reductions and the softmax).
     """
     return _lm_head_xent(x, w, b if b is not None else jnp.zeros(
         (w.shape[1],), jnp.float32), labels.astype(jnp.int32), int(block_v))
@@ -285,12 +288,13 @@ def _lm_head_xent(x, w, b, labels, block_v):
 
 def _block_logits(x, w, b, j, bv):
     d = w.shape[0]
-    wj = jax.lax.dynamic_slice(w, (0, j * bv), (d, bv))
-    bj = jax.lax.dynamic_slice(b, (j * bv,), (bv,))
-    ct = _compute_dtype(x)
-    lg = jnp.matmul(x.astype(ct), wj.astype(ct),
-                    preferred_element_type=jnp.float32)
-    return lg + bj.astype(jnp.float32)
+    with jax.named_scope("head.logits"):
+        wj = jax.lax.dynamic_slice(w, (0, j * bv), (d, bv))
+        bj = jax.lax.dynamic_slice(b, (j * bv,), (bv,))
+        ct = _compute_dtype(x)
+        lg = jnp.matmul(x.astype(ct), wj.astype(ct),
+                        preferred_element_type=jnp.float32)
+        return lg + bj.astype(jnp.float32)
 
 
 def _lm_head_fwd_impl(x, w, b, labels, block_v):
@@ -302,22 +306,24 @@ def _lm_head_fwd_impl(x, w, b, labels, block_v):
     def body(carry, j):
         m, s, picked = carry
         lg = _block_logits(x, w, b, j, bv)               # [N, bv] f32
-        bm = jnp.max(lg, axis=-1)
-        new_m = jnp.maximum(m, bm)
-        s = s * jnp.exp(m - new_m) + jnp.sum(
-            jnp.exp(lg - new_m[:, None]), axis=-1)
-        in_blk = (labels >= j * bv) & (labels < (j + 1) * bv)
-        idx = jnp.clip(labels - j * bv, 0, bv - 1)
-        pick_j = jnp.take_along_axis(lg, idx[:, None], axis=-1)[:, 0]
-        picked = jnp.where(in_blk, pick_j, picked)
+        with jax.named_scope("head.xent"):
+            bm = jnp.max(lg, axis=-1)
+            new_m = jnp.maximum(m, bm)
+            s = s * jnp.exp(m - new_m) + jnp.sum(
+                jnp.exp(lg - new_m[:, None]), axis=-1)
+            in_blk = (labels >= j * bv) & (labels < (j + 1) * bv)
+            idx = jnp.clip(labels - j * bv, 0, bv - 1)
+            pick_j = jnp.take_along_axis(lg, idx[:, None], axis=-1)[:, 0]
+            picked = jnp.where(in_blk, pick_j, picked)
         return (new_m, s, picked), None
 
     init = (jnp.full((n,), neg), jnp.zeros((n,), jnp.float32),
             jnp.zeros((n,), jnp.float32))
     (m, s, picked), _ = jax.lax.scan(body, init,
                                      jnp.arange(nb, dtype=jnp.int32))
-    logz = m + jnp.log(s)
-    return logz - picked, logz
+    with jax.named_scope("head.xent"):
+        logz = m + jnp.log(s)
+        return logz - picked, logz
 
 
 def _lm_head_xent_fwd(x, w, b, labels, block_v):
@@ -335,21 +341,24 @@ def _lm_head_xent_bwd(block_v, res, g):
     def body(carry, j):
         dx, dw, db = carry
         lg = _block_logits(x, w, b, j, bv)
-        p = jnp.exp(lg - logz[:, None])                  # softmax block
-        in_blk = (labels >= j * bv) & (labels < (j + 1) * bv)
-        idx = jnp.clip(labels - j * bv, 0, bv - 1)
-        onehot = (jnp.arange(bv)[None, :] == idx[:, None]) & in_blk[:, None]
-        dlg = (p - onehot.astype(jnp.float32)) * gf[:, None]  # [N, bv]
-        wj = jax.lax.dynamic_slice(w, (0, j * bv), (d, bv))
-        ct = _compute_dtype(x)
-        dx = dx + jnp.matmul(dlg.astype(ct), wj.astype(ct).T,
+        with jax.named_scope("head.xent"):
+            p = jnp.exp(lg - logz[:, None])              # softmax block
+            in_blk = (labels >= j * bv) & (labels < (j + 1) * bv)
+            idx = jnp.clip(labels - j * bv, 0, bv - 1)
+            onehot = (jnp.arange(bv)[None, :] == idx[:, None]) \
+                & in_blk[:, None]
+            dlg = (p - onehot.astype(jnp.float32)) * gf[:, None]  # [N, bv]
+        with jax.named_scope("head.logits"):
+            wj = jax.lax.dynamic_slice(w, (0, j * bv), (d, bv))
+            ct = _compute_dtype(x)
+            dx = dx + jnp.matmul(dlg.astype(ct), wj.astype(ct).T,
+                                 preferred_element_type=jnp.float32)
+            dwj = jnp.matmul(x.astype(ct).T, dlg.astype(ct),
                              preferred_element_type=jnp.float32)
-        dwj = jnp.matmul(x.astype(ct).T, dlg.astype(ct),
-                         preferred_element_type=jnp.float32)
-        dw = jax.lax.dynamic_update_slice(
-            dw, dwj.astype(dw.dtype), (0, j * bv))
-        db = jax.lax.dynamic_update_slice(
-            db, jnp.sum(dlg, axis=0).astype(db.dtype), (j * bv,))
+            dw = jax.lax.dynamic_update_slice(
+                dw, dwj.astype(dw.dtype), (0, j * bv))
+            db = jax.lax.dynamic_update_slice(
+                db, jnp.sum(dlg, axis=0).astype(db.dtype), (j * bv,))
         return (dx, dw, db), None
 
     init = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w),

@@ -183,32 +183,69 @@ class Optimizer:
 
     def apply(self, params: Dict[str, jax.Array], grads: Dict[str, jax.Array],
               state: Dict[str, Any]) -> Tuple[Dict[str, jax.Array], Dict[str, Any]]:
+        """The whole update lies under the scope ``opt`` of the compiled
+        step (inside it ``opt.clip``, ``opt.update``, ``opt.average`` and,
+        for ZeRO, ``opt.shard`` / ``opt.gather``), so that a profiler
+        trace tells the update from the weight-gradient products XLA fuses
+        it with (``benchmarks/harness/step_parts.py``)."""
         plan = self._zero_plan
-        if plan is None:
-            return self._apply(params, grads, state)
-        # ZeRO-1 (arXiv 2004.13336): grads reduce-scatter into 1/N flat
-        # shards (GSPMD lowers psum + this constraint into psum_scatter),
-        # the whole update pipeline below runs on the shard views (slot
-        # state already lives flat-sharded), and the updated weights
-        # all-gather back to full replicated tensors.
-        new_flat, new_state = self._apply(plan.shard_tree(params),
-                                          plan.shard_tree(grads), state)
-        return plan.gather_tree(new_flat), new_state
+        with jax.named_scope("opt"):
+            if plan is None:
+                return self._apply(params, grads, state)
+            # ZeRO-1 (arXiv 2004.13336): grads reduce-scatter into 1/N
+            # flat shards (GSPMD lowers psum + this constraint into
+            # psum_scatter), the whole update pipeline below runs on the
+            # shard views (slot state already lives flat-sharded), and the
+            # updated weights all-gather back to full replicated tensors.
+            with jax.named_scope("opt.shard"):
+                flat_params = plan.shard_tree(params)
+                flat_grads = plan.shard_tree(grads)
+            new_flat, new_state = self._apply(flat_params, flat_grads, state)
+            with jax.named_scope("opt.gather"):
+                return plan.gather_tree(new_flat), new_state
 
     def _apply(self, params: Dict[str, jax.Array], grads: Dict[str, jax.Array],
                state: Dict[str, Any]) -> Tuple[Dict[str, jax.Array], Dict[str, Any]]:
         step = state["step"]
-        base_lr = self.learning_rate * self.schedule(step.astype(jnp.float32))
-        self._aux = self._pre_update(state, base_lr)
+        with jax.named_scope("opt.update"):
+            base_lr = self.learning_rate * self.schedule(
+                step.astype(jnp.float32))
+            self._aux = self._pre_update(state, base_lr)
 
         # global-norm clipping (reference: OptimizerWithGradientClipping used
         # per-parameter thresholds; pjit-era default is global norm, and
         # per-param thresholds from ParamAttr are applied below)
         if self.global_clip > 0.0:
-            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in grads.values()))
-            scale = jnp.minimum(1.0, self.global_clip / jnp.maximum(gnorm, 1e-12))
-            grads = {k: g * scale for k, g in grads.items()}
+            with jax.named_scope("opt.clip"):
+                gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                     for g in grads.values()))
+                scale = jnp.minimum(
+                    1.0, self.global_clip / jnp.maximum(gnorm, 1e-12))
+                grads = {k: g * scale for k, g in grads.items()}
 
+        with jax.named_scope("opt.update"):
+            new_params, new_slots = self._update_leaves(params, grads, state,
+                                                        base_lr, step)
+            new_state = {"step": step + 1, "slots": new_slots}
+            if "prune_masks" in state:
+                new_state["prune_masks"] = state["prune_masks"]
+            self._post_update(new_state, self._aux)
+        if self.model_average is not None:
+            with jax.named_scope("opt.average"):
+                w = self.model_average.average_window
+                decay = jnp.minimum(
+                    state["avg_count"] / (state["avg_count"] + 1.0),
+                    jnp.asarray(1.0 - 1.0 / max(1.0, w * 1000)))
+                new_state["avg"] = {
+                    k: decay * state["avg"][k] + (1 - decay) * new_params[k]
+                    for k in new_params
+                }
+                new_state["avg_count"] = state["avg_count"] + 1.0
+        return new_params, new_state
+
+    def _update_leaves(self, params, grads, state, base_lr, step):
+        """The per-leaf rule with its decay, clipping threshold and prune
+        masks: (new params, new slots)."""
         new_params: Dict[str, jax.Array] = {}
         new_slots = {s: {} for s in self.slot_names()}
         for name, p in params.items():
@@ -249,21 +286,7 @@ class Optimizer:
             new_params[name] = np_
             for s in self.slot_names():
                 new_slots[s][name] = ns[s]
-
-        new_state = {"step": step + 1, "slots": new_slots}
-        if "prune_masks" in state:
-            new_state["prune_masks"] = state["prune_masks"]
-        self._post_update(new_state, self._aux)
-        if self.model_average is not None:
-            w = self.model_average.average_window
-            decay = jnp.minimum(state["avg_count"] / (state["avg_count"] + 1.0),
-                                jnp.asarray(1.0 - 1.0 / max(1.0, w * 1000)))
-            new_state["avg"] = {
-                k: decay * state["avg"][k] + (1 - decay) * new_params[k]
-                for k in new_params
-            }
-            new_state["avg_count"] = state["avg_count"] + 1.0
-        return new_params, new_state
+        return new_params, new_slots
 
 
 # ---------------------------------------------------------------------------

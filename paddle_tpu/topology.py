@@ -20,6 +20,7 @@ the reference's per-layer ``backward()`` methods and Gen-2 AppendBackward
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -198,6 +199,19 @@ class LayerOutput:
         return f"LayerOutput({self.name!r}, type={self.layer_type!r}, size={self.size})"
 
 
+@contextlib.contextmanager
+def node_scope(node: LayerOutput):
+    """The scope a node computes under: its kind outside its name, so that
+    an ``op_name`` of the compiled step reads ``.../jvp(layer_norm)/jvp(
+    blk0_ln1)/...`` and a reader of a profiler trace can add up a kind
+    (``benchmarks/harness/step_parts.py``) as well as find an instance (the
+    REGISTER_TIMER-per-layer analog, NeuralNetwork.cpp:259).  No kind is
+    named as one of the scopes the layers open inside themselves
+    (``gdn.*``, ``moe.*``, ``attn.*``, ...; tests/test_step_scopes.py)."""
+    with jax.named_scope(node.layer_type), jax.named_scope(node.name):
+        yield
+
+
 def topological_order(outputs: Sequence[LayerOutput]) -> List[LayerOutput]:
     seen: Dict[str, LayerOutput] = {}
     order: List[LayerOutput] = []
@@ -327,10 +341,8 @@ class Topology:
             node_params = {p: params[self.param_key(node, p)] for p in node.params}
             ins = [values[i.name] for i in node.inputs]
             ctx._current = node.name
-            # named_scope: layer names show up in xplane/profiler traces
-            # (the REGISTER_TIMER-per-layer analog, NeuralNetwork.cpp:259)
             try:
-                with jax.named_scope(node.name):
+                with node_scope(node):
                     values[node.name] = node.fn(ctx, node_params, ins)
             except Exception as e:
                 # the CustomStackTrace analog (utils/CustomStackTrace.h,
@@ -397,7 +409,7 @@ class Topology:
                 ins = [local[i.name] for i in n.inputs]
                 sub._current = n.name
                 try:
-                    with jax.named_scope(n.name):
+                    with node_scope(n):
                         local[n.name] = n.fn(sub, node_params, ins)
                 except Exception as e:
                     e.add_note(

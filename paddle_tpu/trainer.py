@@ -390,17 +390,19 @@ class SGD:
                 outs, new_state = topo.forward(p, model_state, feeds,
                                                train=True, rng=rng, mesh=mesh,
                                                counters=counted)
-                cost_vals = [_reduce_cost(o) for o in outs[:n_costs]]
-                total = functools.reduce(jnp.add, cost_vals)
-                metric_vals = {name: _metric_scalar(o) for name, o in
-                               zip(metric_names, outs[n_costs:])}
-                if counted:
-                    # the layers' counters leave the step as ONE vector
-                    # beside the cost (its keys are fixed at trace time)
-                    # and are read with the costs, a log window late
-                    self._counter_keys = sorted(counted)
-                    metric_vals["__counters__"] = jnp.stack(
-                        [counted[k] for k in self._counter_keys])
+                with jax.named_scope("step.loss"):
+                    cost_vals = [_reduce_cost(o) for o in outs[:n_costs]]
+                    total = functools.reduce(jnp.add, cost_vals)
+                    metric_vals = {name: _metric_scalar(o) for name, o in
+                                   zip(metric_names, outs[n_costs:])}
+                    if counted:
+                        # the layers' counters leave the step as ONE
+                        # vector beside the cost (its keys are fixed at
+                        # trace time) and are read with the costs, a log
+                        # window late
+                        self._counter_keys = sorted(counted)
+                        metric_vals["__counters__"] = jnp.stack(
+                            [counted[k] for k in self._counter_keys])
                 return total, (new_state, metric_vals)
 
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -414,9 +416,10 @@ class SGD:
             if not stats_on:
                 return metric_vals
             metric_vals = dict(metric_vals)
-            metric_vals["__param_stats__"] = {
-                k: (jnp.mean(jnp.abs(g)), jnp.max(jnp.abs(g)))
-                for k, g in grads.items()}
+            with jax.named_scope("step.stats"):
+                metric_vals["__param_stats__"] = {
+                    k: (jnp.mean(jnp.abs(g)), jnp.max(jnp.abs(g)))
+                    for k, g in grads.items()}
             return metric_vals
 
         def step(params, opt_state, model_state, rng, feeds):
@@ -443,15 +446,18 @@ class SGD:
 
             (loss, (new_mstate, metric_vals)), grads = forward_backward(
                 params, model_state, rng, feeds)
-            grads, good, _ = screen_grads(grads, guard_state["inject"],
-                                          guard.max_norm)
+            with jax.named_scope("step.guard"):
+                grads, good, _ = screen_grads(grads, guard_state["inject"],
+                                              guard.max_norm)
             new_params, new_opt = optimizer.apply(params, grads, opt_state)
-            new_params = select_good(good, new_params, params)
-            new_opt = select_good(good, new_opt, opt_state)
-            new_mstate = select_good(good, new_mstate, model_state)
-            return (loss, new_params, new_opt, new_mstate,
-                    grad_stats(metric_vals, grads),
-                    guard_outputs(good, guard_state))
+            with jax.named_scope("step.guard"):
+                new_params = select_good(good, new_params, params)
+                new_opt = select_good(good, new_opt, opt_state)
+                new_mstate = select_good(good, new_mstate, model_state)
+            metric_vals = grad_stats(metric_vals, grads)
+            with jax.named_scope("step.guard"):
+                gout = guard_outputs(good, guard_state)
+            return loss, new_params, new_opt, new_mstate, metric_vals, gout
 
         # With mesh-sharded (NamedSharding) inputs, jit partitions the whole
         # step SPMD automatically — XLA inserts the grad psum (the
@@ -843,18 +849,32 @@ class SGD:
                 pending_counters: List = []
 
                 def flush():
-                    if pending:
-                        pass_costs.extend(
-                            np.asarray(jnp.stack(pending)).tolist())
-                        pending.clear()
-                    if pending_counters:
-                        self._publish_counters(pending_counters)
-                        pending_counters.clear()
-                    for k, buf in pending_metrics.items():
-                        if buf:
-                            pass_metrics[k].extend(
-                                np.asarray(jnp.stack(buf)).tolist())
-                            buf.clear()
+                    # the one place the loop waits for the device
+                    with self._tracer.phase("step.flush", cat="train"):
+                        if pending:
+                            pass_costs.extend(
+                                np.asarray(jnp.stack(pending)).tolist())
+                            pending.clear()
+                        if pending_counters:
+                            self._publish_counters(pending_counters)
+                            pending_counters.clear()
+                        for k, buf in pending_metrics.items():
+                            if buf:
+                                pass_metrics[k].extend(
+                                    np.asarray(jnp.stack(buf)).tolist())
+                                buf.clear()
+
+                place = self._shard_feeds
+                if prefetch > 0 and self.mesh is None:
+                    from paddle_tpu.reader.prefetch import device_put_feeds
+
+                    place = device_put_feeds
+
+                def feed(batch):
+                    # one batch's conversion and placement, on whichever
+                    # thread runs it
+                    with self._tracer.phase("step.feed", cat="train"):
+                        return place(feeder.feed(batch))
 
                 if prefetch > 0:
                     # device-resident double buffering: feed conversion +
@@ -862,13 +882,11 @@ class SGD:
                     # k's compute (the async DataProvider pool analog)
                     from paddle_tpu.reader.prefetch import device_prefetch
 
-                    feed_it = device_prefetch(
-                        raw_it, size=prefetch, transform=feeder.feed,
-                        place=self._shard_feeds if self.mesh is not None
-                        else None)
+                    feed_it = device_prefetch(raw_it, size=prefetch,
+                                              transform=feed,
+                                              place=lambda placed: placed)
                 else:
-                    feed_it = (self._shard_feeds(feeder.feed(b))
-                               for b in raw_it)
+                    feed_it = (feed(b) for b in raw_it)
                 for batch_id, feeds in enumerate(feed_it, start=skip):
                     if faults is not None:
                         # injected clock tick + scheduled death, BEFORE
@@ -877,20 +895,24 @@ class SGD:
                         faults.step_begin(self._global_step)
                     event_handler(v2_event.BeginIteration(pass_id, batch_id))
                     self._rng, key = jax.random.split(self._rng)
+                    # the timer (the reference's name) and the phase
+                    # inside it time the ASYNCHRONOUS dispatch of a step,
+                    # not a batch: the device runs on after the call
+                    # returns, and the loop waits for it in flush() alone
                     with stats.timer("trainOneBatch"):
+                        guarded = ()
                         if gstate is not None:
                             gstate["inject"] = np.float32(
                                 faults.grad_inject(self._global_step)
                                 if faults is not None else 0.0)
-                            (loss, params, opt_state, mstate, metric_vals,
-                             gout) = self._step_fn(params, opt_state,
-                                                   mstate, key, feeds,
-                                                   gstate)
-                            gstate = {"inject": gstate["inject"], **gout}
-                        else:
-                            loss, params, opt_state, mstate, metric_vals = \
-                                self._step_fn(params, opt_state, mstate,
-                                              key, feeds)
+                            guarded = (gstate,)
+                        with self._tracer.phase("step.dispatch",
+                                                cat="train"):
+                            out = self._step_fn(params, opt_state, mstate,
+                                                key, feeds, *guarded)
+                        loss, params, opt_state, mstate, metric_vals = out[:5]
+                        if gstate is not None:
+                            gstate = {"inject": gstate["inject"], **out[5]}
                     self._global_step += 1
                     pstats = metric_vals.pop("__param_stats__", None)
                     counted = metric_vals.pop("__counters__", None)
