@@ -114,7 +114,8 @@ def count_kernels_in_step(eng, pb: int) -> int:
     check(eng._ragged_kernel,
           "the engine chose the reference attention path, not the kernel")
     lowered = eng._step_fn(pb, eng._k1).lower(
-        eng.params, eng._kv, eng._empty_tick(pb, eng._k1))
+        eng.params, eng._kv, eng._empty_tick(pb, eng._k1),
+        eng._last_words())
     n = lowered.as_text().count("tpu_custom_call")
     check(n > 0, f"no tpu_custom_call in the lowered serving step (pb={pb})")
     return n
@@ -256,7 +257,8 @@ def run_engine(cfg: SmokeConfig, model, params, prompts, **engine_kw):
         m = eng.metrics
         before = (m.decode_rows, m.prefill_rows)
         t0 = time.perf_counter()
-        more = eng.step()              # returns after logits reached host
+        more = eng.step()              # returns after the words of the step
+        #                                before this one reached the host
         ticks.append(time.perf_counter() - t0)
         mixed[0] += m.decode_rows > before[0] and m.prefill_rows > before[1]
         return more

@@ -102,21 +102,44 @@ request needs no unwinding, and the step's shapes do not depend on the
 pass: it computes logits for ``[slots, B / S]`` selected rows and
 chooses each row's best unmasked token itself, so a slot's few tokens
 and a finite flag cross to the host, and the logits only for a request
-that samples.  That read back LAGS its dispatch by a tick: ``step()``
-dispatches tick ``k + 1`` and then reads what tick ``k`` came to, so the
-host's part of a tick (schedule, assemble, upload, walk) runs beside the
-device's and not between two of its steps.  What a pass will come to is
-known at its dispatch but for the tokens' values (how many it fixes,
-that a full block is committed, where a chunk ends), so the request
-moves on then (``cache_len``, ``Request.pending``) and the next step
-takes the pending tokens from the last step's words, on the device;
-``on_token`` runs when the words arrive, one ``step()`` call later.  A
-tick lands in its own call where a running request samples (its tokens
-are drawn on the host) or a fault plan is bound, and the flight in the
-air lands before the scheduler may preempt.
+that samples.
 Speculation, tensor parallelism, the host tier and chain migration
 refuse a block model at construction; the prefix cache serves it (whole
 pages of prefilled blocks).
+
+What lands when (both step kinds): a step chooses its tokens itself.
+A one-token tick takes, inside the compiled step, the first maximum of
+each decode row's and of each chunk-final row's logits and whether the
+row is all finite; a block step each fixed position's best unmasked
+token.  What leaves the step for the host is ONE int32 vector of words
+(the choices, the finite flags, the model's counts), replicated over the
+engine's mesh; the logits stay on the device.  The read of those words
+LAGS the dispatch by a call: ``step()`` dispatches tick ``k + 1`` and
+then reads what tick ``k`` came to, so the host's part of a tick
+(schedule, assemble, upload, walk) runs beside the device's and not
+between two of its steps.  What a step will come to is known at its
+dispatch but for the tokens' values (a decode row yields one token, a
+prompt's final chunk its first, a pass fixes so many, a full block is
+committed, a chunk ends there), so the request moves on then
+(``cache_len``, ``Request.pending``: :meth:`ServingEngine._advance`) and
+the next step takes the pending tokens from the last step's words, on
+the device; the finite guards, the prefix cache's inserts and
+``on_token`` run when the words arrive (:meth:`ServingEngine._land`),
+one ``step()`` call later, and ``has_work`` stays true while a step is
+in the air (``_flying``).  A request that ended meanwhile is passed
+over; an EOS that lands while a further row of its slot is in the air
+drops that row.  A step lands in its OWN call where the host needs its
+tokens or its logits before the next step is assembled, which the
+engine sees from its own state: a fault plan is bound; a proposer is
+bound (the verify walk reads the rows' logits and drafts from what it
+accepts); the engine's ``role`` hands requests over between calls; a
+request that rode the step samples (its slot's rows are fetched for it
+alone).  The step in the air lands before the scheduler where growth
+may preempt (a preempted request re-prefills from its tokens) and where
+nothing rides the next step; ``land()`` reads it for a caller that is
+about to export a running request's tokens (``migratable_rids``,
+``migrate.export_chain``).  ``ServingMetrics`` counts the steps read
+after the next dispatch (``steps_lagged``) beside ``step_dispatches``.
 
 The model plugs in through the small :class:`DecodeModel` contract
 rather than a ``Topology``: serving needs per-layer access to Q/K/V
@@ -452,18 +475,30 @@ def greedy_decode_reference(model: DecodeModel, params, prompt: List[int],
 
 @dataclass
 class _Flight:
-    """A block model's dispatched step whose words the host has not read
-    yet (``ServingEngine``: "block models")."""
+    """A dispatched step whose words the host has not read yet
+    (``ServingEngine``: "what lands when")."""
 
-    # (request, slot, block start, tokens the block had, tokens the pass
-    # fixes: 0 is the committing pass) of every slot that rode the step
-    passes: List[Tuple[Request, int, int, int, int]]
+    # every slot that rode the step: (request, slot) and then what its
+    # walk needs that the request will no longer say when the words
+    # arrive.  A block model's: block start, tokens the block had,
+    # tokens the pass fixes (0 is the committing pass).  Else: the
+    # drafts the slot's rows verify, and their proposal probabilities
+    passes: List[tuple]
     chunks: list                   # as ``pack_prefill_chunks`` gave them
     words: Any                     # the step's words, on the device
-    logits: Any                    # its [slots, B / S, V] logits, there too
-    rows: Tuple[int, int, int]     # block, prefill and padding rows
+    # its logits, there too: [slots, B / S, V] of a block model, else
+    # [slots * k1 + slots, V] (the decode / verify rows, then each
+    # slot's chunk-final row)
+    logits: Any
+    rows: Tuple[int, int, int]     # decode, prefill and padding rows
     h2d_bytes: int
     attn_cells: Tuple[int, int, int]
+
+
+def _samples(req: Request) -> bool:
+    """Whether the request draws its tokens on the host, from logits
+    (else they are the step's own choices)."""
+    return req.sampling is not None and not req.sampling.greedy
 
 
 def _settle_heap() -> None:
@@ -699,8 +734,8 @@ class ServingEngine:
             getattr(model, "step_counters", ()))
         self.metrics = ServingMetrics(pool_pages=self.pool.num_usable,
                                       model_counters=self._counted)
-        # a block model's step in the air, and the words a step takes
-        # where none is (no token of it is pending then)
+        # the step in the air, and the words a step takes where none is
+        # (no token is pending then)
         self._flying: Optional[_Flight] = None
         self._no_words = None
         # obs: tracer (FLAGS.obs_trace-gated at construction — a fleet
@@ -852,8 +887,10 @@ class ServingEngine:
             # per-leaf param specs (keyed by name: the auditor resolves
             # dict entries against the pytree path) + the pool spec for
             # both the donated input and the aliased output
-            step_in = (dict(self._shard_plan), kvspec, ())
-            step_out = ((), ()) + (kvspec,) * 4 + ((),) * bool(self._counted)
+            # (parameters, pool, the tick's buffer, the last step's
+            # words) -> (words, logits, pool)
+            step_in = (dict(self._shard_plan), kvspec, (), ())
+            step_out = ((), ()) + (kvspec,) * 4
             kv_in = (kvspec, (), ())
             kv_out = (kvspec,) * 4
             mesh_axes = ((self.tp_axis, self.tp),)
@@ -1118,12 +1155,15 @@ class ServingEngine:
         page (quantize-on-write on int8 pools; masked rows write ZEROS
         to the shared null page so computed junk can never leak into
         gathered fallback reads), runs one ragged paged attention over
-        the whole mixed batch per layer, and returns logits for ALL
+        the whole mixed batch per layer, and computes logits for ALL
         ``B * k1`` decode/verify rows plus each slot's chunk-final row
         — prior context, in-chunk causality AND in-verify causality
         (draft ``i`` sees drafts ``< i``) all come from the ONE
         ``token <= position`` mask, with no separate paths to keep in
-        sync."""
+        sync.  It returns ``(words, logits, pool)``: the int32 words the
+        host reads (each row's choice and finite flag), the logits left
+        on the device, the pool; and takes the words of the step before
+        it as its fourth argument (``_last_words``)."""
         fn = self._step_fns.get((pb, k1))
         if fn is not None:
             return fn
@@ -1138,16 +1178,22 @@ class ServingEngine:
             # taken apart by static slices (a chip reads its own copy)
             (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
              table, att_lens, *d_sel) = self._tick_parts(packed, k1)
+            # last: the words of the step before.  A token that step
+            # chose has not reached the host when this one is assembled:
+            # d_tokens names it and it is taken from there
             if blk is not None:
-                # last: the words of the step before (block models only).
-                # A token that step fixed has not reached the host when
-                # this one is assembled: d_tokens names it (-1 - j, the
-                # j-th pick of its slot) and it is taken from there
+                # (-1 - j: the j-th pick of the slot)
                 f = self._fix_rows
                 picked = last[0][:b * (f + 1)].reshape(b, f + 1)[:, :f]
                 d_tokens = jnp.where(d_tokens < 0, jnp.take_along_axis(
                     picked, jnp.clip(-1 - d_tokens, 0, f - 1), axis=1),
                     d_tokens)
+            else:
+                # (-1: the pick of the slot's decode row; -2: that of its
+                # chunk-final row, a prompt's first token)
+                slot = jnp.arange(b)[:, None]
+                d_tokens = jnp.where(d_tokens < 0, last[0][jnp.where(
+                    d_tokens == -2, bd + slot, slot * k1)], d_tokens)
             # d_tokens/d_pos/d_valid: [B, k1] — row 0 of a slot is the
             # plain decode token, rows 1..k its drafted lookahead
             # (invalid rows write the null page and produce garbage
@@ -1205,7 +1251,7 @@ class ServingEngine:
                 # logits for the rows a denoising pass fixes only.  What
                 # crosses to the host is ONE small int32 vector (a read
                 # back costs its latency, not its bytes; taken apart by
-                # ``_block_words``): each row's best token with the mask
+                # ``_walk_passes``): each row's best token with the mask
                 # token left out and, last in a slot's row, whether all
                 # its logits are finite (``picks`` [B, B / S + 1]); of a
                 # prompt's last row only whether it is finite (the chunk
@@ -1225,12 +1271,25 @@ class ServingEngine:
                                      axis=1).reshape(-1), guard, *more],
                     dtype=jnp.int32)
                 return words, logits, self._tp_kv(kv)
-            # logits only where the host will read them: the B*k1
-            # decode/verify rows + each slot's chunk-final row
+            # logits only where a token may come of them: the B*k1
+            # decode/verify rows + each slot's chunk-final row.  Of each
+            # the best token (the first maximum, as ``np.argmax``) and
+            # whether the row is all finite (the guards' reading) leave
+            # as int32 words ``[best | finite | counts]``
+            # (``_walk_rows``), replicated as the next step takes them;
+            # the logits stay on the device, for a request that samples
+            # and for the verify walk.  The head is replicated under
+            # tensor parallelism, so the choice adds no collective.
             sel = jnp.concatenate([jnp.arange(bd), p_last])
             logits = model.logits(params, x[sel])
-            return (logits[:bd].reshape(b, k1, -1), logits[bd:],
-                    self._tp_kv(kv)) + more
+            words = jnp.concatenate(
+                [jnp.argmax(logits, axis=-1),
+                 jnp.all(jnp.isfinite(logits), axis=-1), *more],
+                dtype=jnp.int32)
+            if self._tick_sharding is not None:
+                words = jax.lax.with_sharding_constraint(
+                    words, self._tick_sharding)
+            return words, logits, self._tp_kv(kv)
 
         fn = audit_jit(raw, site="serving.step",
                        donate_argnums=self._donate_kv,
@@ -1397,22 +1456,22 @@ class ServingEngine:
         The tick names its phases for the profiler (``pt:tick`` and,
         inside it, ``pt:tick.schedule`` here, ``.assemble``, ``.upload``
         (and inside that ``.dispatch``, the compiled step's call alone),
-        ``.wait`` and ``.sample`` in :meth:`_do_step`, ``.sample`` again
-        for the bookkeeping that closes the tick).  A block model's
-        ``.wait`` and ``.sample`` (:meth:`_land`) belong to the step that
-        the call BEFORE this one dispatched."""
+        ``.wait`` and ``.sample`` in :meth:`_land`, ``.sample`` again
+        for the bookkeeping that closes the tick).  ``.wait`` and
+        ``.sample`` belong to the step that the call BEFORE this one
+        dispatched, where that was left in the air (``_flying``)."""
         tick, m = self._tick, self.metrics
         phase = self._tracer.phase
         with phase("tick", tick=tick):
             if self._flying is not None and self._growth_may_preempt():
                 # a preempted request is re-prefilled from its tokens,
                 # which have to be on the host: the flight lands first
-                self._land(self._flying)
+                self.land()
             with phase("tick.schedule", tick=tick):
                 running, chunks, total_rows, drafts, busy = \
                     self._schedule_tick(tick, now)
-            if not (running or chunks) and self._flying is not None:
-                self._land(self._flying)
+            if not (running or chunks):
+                self.land()       # (nothing rides a next step to read behind)
             if running or chunks:
                 for req, start, n, _ in chunks:
                     self._tracer.instant("prefill_chunk", rid=req.rid,
@@ -1509,11 +1568,14 @@ class ServingEngine:
             prefilling, self._prefill_chunk, self._row_align,
             self._prefill_budget, block=self._block or 1)
         # (a block model's first token comes of a block pass, not of the
-        # prompt's last row: its slots run with nothing generated yet)
+        # prompt's last row: its slots run with nothing generated yet;
+        # else the first token may be the one the flight in the air is
+        # choosing)
         running = [r for r in sched.running_requests()
                    if r.status is RequestStatus.RUNNING
                    and not r.prefilling
-                   and (r.generated or self._block is not None)
+                   and (r.generated or r.pending
+                        or self._block is not None)
                    # (every token of it fixed by the flight in the air:
                    # it ends when that lands)
                    and len(r.generated) + r.pending < r.max_tokens]
@@ -1612,6 +1674,7 @@ class ServingEngine:
         decodable state — ``generated[-1]`` is the next step's input)."""
         if self._block is not None:
             return []     # a block between passes is not handed over
+        self.land()       # (the answer is about tokens the host has)
         return [r.rid for r in self.scheduler.running_requests()
                 if r.status is RequestStatus.RUNNING and not r.prefilling
                 and r.generated]
@@ -2015,7 +2078,7 @@ class ServingEngine:
 
     def _step_with_retry(self, running: List[Request], chunks, total_rows,
                          tick: int, drafts: Dict[int, Tuple]) -> None:
-        if self._block is not None and self.faults is None:
+        if self.faults is None:
             # nothing injects a failure, and a read back that lags its
             # dispatch is not run again: what it raises reaches the caller
             self._do_step(running, chunks, total_rows, drafts)
@@ -2069,18 +2132,12 @@ class ServingEngine:
 
     def _do_step(self, running: List[Request], chunks,
                  total_rows: int, drafts: Dict[int, Tuple]) -> None:
-        """Assemble and dispatch ONE unified step, then walk its
-        results: chunk bookkeeping first (cache inserts, finite guard,
-        final-chunk first-token emission — the v1 tick order),
-        decode/verify emissions second.
-
-        Every chunk's final-row logits go through the finite guard
-        BEFORE its full pages are indexed (those logits attend over
-        every K/V written so far, so finiteness transitively vouches
-        for the whole chain): without the per-chunk check, suspect K/V
-        from an overflowing prompt would be hittable for the whole
-        multi-tick prefill window, and a sharer admitted in that window
-        would stitch it before the final-chunk rollback ran.
+        """Assemble and dispatch ONE unified step, move the requests on
+        by what it will come to (:meth:`_advance`), and read the words
+        of the step BEFORE it (:meth:`_land`): the step just dispatched
+        stays in the air (``_flying``) until the next call, unless the
+        host needs its tokens or its logits now (:meth:`_lands_now`;
+        the module doc says when).
 
         With speculation, slot ``s`` ships ``1 + len(drafts[s])`` rows
         (the plain decode token plus the lookahead); the accept walk
@@ -2088,10 +2145,7 @@ class ServingEngine:
         plus one bonus/corrected token, and a partial acceptance rolls
         the lookahead pages back (``scheduler.rollback_pages``) — the
         rejected rows' K/V beyond the new length is masked junk the
-        next real tokens overwrite.
-
-        A block model's step is dispatched here and walked a call later
-        (:meth:`_land`; the module doc says when in this one)."""
+        next real tokens overwrite."""
         k1, tick, phase = self._k1, self._tick, self._tracer.phase
         with phase("tick.assemble", tick=tick):
             packed = self._assemble(running, chunks, total_rows, drafts)
@@ -2106,61 +2160,64 @@ class ServingEngine:
             step = self._step_fn(pb, k1)
             placed = jax.device_put(packed, self._tick_sharding)
             with phase("tick.dispatch", tick=tick):
-                d_logits, p_logits, self._kv, *more = step(
-                    self.params, self._kv, placed, *self._last_words())
+                words, logits, self._kv = step(
+                    self.params, self._kv, placed, self._last_words())
         if self._block is not None:
-            # the step's logits stay on the device and its one vector of
-            # words is read a tick later (the module doc): the requests
-            # move on now, by what the passes will come to
-            flight = _Flight(
-                self._passes(running), chunks, d_logits, p_logits,
-                (len(running) * k1, total_rows,
-                 pb - sum(c[2] for c in chunks)), packed.nbytes,
-                self._attn_cells(p_seq, att_lens))
-            self._advance(flight)
-            if compiles:
-                _settle_heap()        # (beside the device's first run)
-            before, self._flying = self._flying, flight
-            if before is not None:
-                self._land(before)
-            if self.faults is not None or any(
-                    r.sampling is not None and not r.sampling.greedy
-                    for r in running):
-                self._land(flight)
-            return
-        with phase("tick.wait", tick=tick):
-            d_logits = np.asarray(d_logits)   # forces device sync;
-            p_logits = np.asarray(p_logits)   # [B,k1,V]
-            counts = [np.asarray(c) for c in more]
-            d2h = d_logits.nbytes + p_logits.nbytes \
-                + sum(c.nbytes for c in counts)
+            passes, n_rows = self._passes(running), len(running) * k1
+        else:
+            passes = [(r, r.slot) + drafts.get(r.rid, ((), None))
+                      for r in running]
+            n_rows = sum(1 + len(p[2]) for p in passes)
+        flight = _Flight(
+            passes, chunks, words, logits,
+            (n_rows, total_rows, pb - sum(c[2] for c in chunks)),
+            packed.nbytes, self._attn_cells(p_seq, att_lens))
+        self._advance(flight)
         if compiles:
-            _settle_heap()
-        with phase("tick.sample", tick=tick):
-            self.metrics.on_step(
-                sum(1 + len(drafts.get(r.rid, ((),))[0]) for r in running),
-                total_rows, pb - sum(c[2] for c in chunks),
-                n_slots=len(running),
-                h2d_bytes=packed.nbytes, d2h_bytes=d2h,
-                attn_cells=self._attn_cells(p_seq, att_lens),
-                model_counts=counts[0] if counts else ())
-            self._walk_results(running, chunks, drafts, d_logits, p_logits)
+            _settle_heap()            # (beside the device's first run)
+        before, self._flying = self._flying, flight
+        if before is not None:
+            self._land(before, lagged=True)
+        if self._lands_now(flight):
+            self._land(flight)
 
-    # ---- a block model's lagged read back --------------------------------
+    # ---- the lagged read back --------------------------------------------
 
-    def _last_words(self) -> tuple:
-        """What a block step takes after the tick's buffer: the words of
-        the step before it, still on the device (zeros where none is in
-        the air: no token is pending then).  Nothing for other models."""
-        if self._block is None:
-            return ()
+    def _last_words(self):
+        """What a step takes after the tick's buffer: the words of the
+        step before it, still on the device (zeros where none is in the
+        air: no token is pending then)."""
         if self._flying is not None:
-            return (self._flying.words,)
+            return self._flying.words
         if self._no_words is None:
-            n = self._max_slots * (self._fix_rows + 2) + len(self._counted)
-            self._no_words = jax.device_put(np.zeros(n, np.int32),
-                                            self._tick_sharding)
-        return (self._no_words,)
+            b = self._max_slots
+            n = b * (self._fix_rows + 2) if self._block is not None \
+                else 2 * (b * self._k1 + b)
+            self._no_words = jax.device_put(
+                np.zeros(n + len(self._counted), np.int32),
+                self._tick_sharding)
+        return self._no_words
+
+    def _lands_now(self, flight: _Flight) -> bool:
+        """Whether the host needs a step's tokens or its logits before
+        the next step is assembled, by what the engine can see: a fault
+        plan is bound (it injects at the walk, tick by tick); a proposer
+        drafts from the tokens the verify walk accepts; a replica that
+        hands its requests over (``role``) exports their tokens between
+        two calls; a request that rode the step draws its token on the
+        host."""
+        return (self.faults is not None or self._proposer is not None
+                or self.role != "unified"
+                or any(_samples(p[0])
+                       for p in flight.passes + flight.chunks))
+
+    def land(self) -> None:
+        """Read the step in the air now, if one is: where the host needs
+        its tokens before the next dispatch, and for a caller that is
+        about to read running requests' tokens between two calls of
+        ``step`` (``migrate.export_chain``)."""
+        if self._flying is not None:
+            self._land(self._flying)
 
     def _growth_may_preempt(self) -> bool:
         """Whether the pages that ``ensure_decode_pages`` is about to take
@@ -2183,22 +2240,36 @@ class ServingEngine:
 
     def _advance(self, flight: _Flight) -> None:
         """At its dispatch, what a step will come to but for its tokens'
-        values: chunks are prefilled, a full block is committed, a
-        denoising pass's tokens are pending."""
+        values: chunks are prefilled (a prompt's last row yields its
+        first token, which is pending; not a block model's), a decode
+        row's token is pending and in the cache (what a verify walk
+        accepts beyond it is added when it lands), a full block is
+        committed, a denoising pass's tokens are pending."""
+        blk = self._block
         for req, start, n, _rows in flight.chunks:
             self._advance_chunk(req, start, n)
+            if blk is None and not req.prefilling:
+                req.pending += 1
+        if blk is None:
+            for req, *_ in flight.passes:
+                req.cache_len += 1
+                req.pending += 1
+            return
         for req, _slot, at, _have, n in flight.passes:
             if n:
                 req.pending += n
             else:
-                req.cache_len = at + self._block
+                req.cache_len = at + blk
                 # a block that a prompt's tail shares was still owed
                 self.scheduler.note_prefill_progress(req, at)
 
-    def _land(self, flight: _Flight) -> None:
+    def _land(self, flight: _Flight, lagged: bool = False) -> None:
         """Read a dispatched step's words and walk them: chunk
-        bookkeeping first, the passes' tokens second.  A request that
-        ended meanwhile (cancelled, timed out, failed by an earlier
+        bookkeeping first (finite guard, cache inserts, a final chunk's
+        first token: the v1 tick order), the slots' tokens second.
+        ``lagged``: the next step was dispatched first, so the device
+        does not wait for this.  A request that ended meanwhile
+        (cancelled, timed out, failed or completed by an earlier
         flight) is passed over: its slot and pages went back then, and
         what the step wrote there lies before any later step's writes."""
         tick, phase = self._tick, self._tracer.phase
@@ -2207,42 +2278,123 @@ class ServingEngine:
         with phase("tick.wait", tick=tick):
             # (no copy queued behind the call: a tick later it gains nothing)
             words = np.asarray(flight.words)      # waits for the device
-            picks, guard, *counts = self._block_words(words)
         with phase("tick.sample", tick=tick):
             rows, prefill_rows, pad_rows = flight.rows
             self.metrics.on_step(
                 rows, prefill_rows, pad_rows, n_slots=len(flight.passes),
                 h2d_bytes=flight.h2d_bytes, d2h_bytes=words.nbytes,
                 attn_cells=flight.attn_cells,
-                model_counts=counts[0] if counts else ())
+                # (the model's counts are the words' tail)
+                model_counts=words[words.size - len(self._counted):],
+                lagged=lagged)
             # stamp AFTER the sync so TTFT includes the step compute
             now = self._time()
-            for req, start, n, _rows in flight.chunks:
-                if req.status is RequestStatus.RUNNING:
-                    self._finish_chunk(req, start, n, guard[req.slot], now)
             poisoned = self.faults.nan_rids if self.faults is not None \
                 else ()
-            for req, slot, at, have, n in flight.passes:
-                if req.status is not RequestStatus.RUNNING:
-                    continue
-                mine = picks[slot]
-                if req.rid in poisoned:
-                    mine = mine.copy()
-                    mine[-1] = 0                  # "not finite"
-                self._finish_block_pass(req, (at, have, n), mine,
-                                        flight.logits, now)
+            walk = self._walk_rows if self._block is None \
+                else self._walk_passes
+            walk(flight, words, poisoned, now)
 
-    def _block_words(self, words: np.ndarray) -> List[np.ndarray]:
-        """A block step's one small output taken apart: ``picks`` ``[B,
-        B / S + 1]`` (:meth:`_finish_block_pass`), what the chunk guard
-        reads of each slot's last prompt row (0.0 or NaN) and, where the
-        model counts, its counts."""
+    def _walk_passes(self, flight: _Flight, words: np.ndarray, poisoned,
+                     now: float) -> None:
+        """What the host does with a block step's words: ``picks`` ``[B,
+        B / S + 1]`` (:meth:`_finish_block_pass`), then whether each
+        slot's last prompt row was finite (the chunk guard's reading)."""
         b, f = self._max_slots, self._fix_rows + 1
-        parts = [words[:b * f].reshape(b, f),
-                 np.where(words[b * f:b * f + b] != 0, 0.0, np.nan)]
-        if self._counted:
-            parts.append(words[b * f + b:])
-        return parts
+        picks = words[:b * f].reshape(b, f)
+        guard = words[b * f:b * f + b] != 0
+        for req, start, n, _rows in flight.chunks:
+            if req.status is RequestStatus.RUNNING:
+                self._finish_chunk(req, start, n, guard[req.slot], now)
+        for req, slot, at, have, n in flight.passes:
+            if req.status is not RequestStatus.RUNNING:
+                continue
+            mine = picks[slot]
+            if req.rid in poisoned:
+                mine = mine.copy()
+                mine[-1] = 0                  # "not finite"
+            self._finish_block_pass(req, (at, have, n), mine,
+                                    flight.logits, now)
+
+    def _walk_rows(self, flight: _Flight, words: np.ndarray, poisoned,
+                   now: float) -> None:
+        """What the host does with a one-token (or verify) step's words:
+        each row's best token, then whether each row's logits are all
+        finite, over the ``B * k1`` decode / verify rows and then each
+        slot's chunk-final row.  Greedy tokens are the step's own
+        choices; a request that samples draws from its rows of the
+        logits, fetched for it alone, and a proposer's verify walk reads
+        all of them, once."""
+        k1 = self._k1
+        bd = self._max_slots * k1
+        best, finite = words[:bd + self._max_slots], \
+            words[bd + self._max_slots:2 * (bd + self._max_slots)] != 0
+        host = None
+        if self._proposer is not None:
+            host = np.asarray(flight.logits)
+            self.metrics.on_fetch(host.nbytes)
+
+        def fetch(lo: int, n: int) -> np.ndarray:
+            if host is not None:
+                return host[lo:lo + n]
+            got = np.asarray(flight.logits[lo:lo + n])
+            self.metrics.on_fetch(got.nbytes)
+            return got
+
+        for req, start, n, _rows in flight.chunks:
+            if req.status is not RequestStatus.RUNNING:
+                continue    # cancelled from an earlier chunk's on_token
+            at = bd + req.slot
+            if not self._finish_chunk(req, start, n, finite[at], now):
+                continue
+            # first token: greedy argmax unless the request samples
+            # (seeded per-position draw — position 0 of its stream)
+            req.pending -= 1
+            self._emit(req, next_token(
+                fetch(at, 1)[0], req.sampling, len(req.generated))
+                if _samples(req) else int(best[at]), now)
+        for req, slot, dr, dprobs in flight.passes:
+            if req.status is not RequestStatus.RUNNING:
+                continue    # cancelled from another slot's on_token
+            lo, nrows = slot * k1, 1 + len(dr)
+            req.pending -= 1
+            if req.rid in poisoned or not finite[lo:lo + nrows].all():
+                # poisoned slot (possibly mid-verify): fail ONLY this
+                # request — its pages go back (uncached ones scrubbed
+                # by _finish), the fused batchmates keep decoding
+                # untouched and the proposer state is released
+                self._finish(req, RequestStatus.FAILED, now)
+                continue
+            if dr or _samples(req):
+                emitted, accepted = accept_tokens(
+                    fetch(lo, nrows), dr, dprobs, req.sampling,
+                    len(req.generated), self.eos_id)
+            else:
+                emitted, accepted = [int(best[lo])], 0
+            req.cache_len += accepted       # (the slot's own row: at
+            #                                 the dispatch)
+            if dr:
+                req.spec_proposed += len(dr)
+                req.spec_accepted += accepted
+                self.metrics.on_spec(len(dr), accepted)
+                self._tracer.instant("spec_accept", rid=req.rid,
+                                     proposed=len(dr), accepted=accepted)
+                if accepted < len(dr):
+                    # rejected branch: return the lookahead pages past
+                    # the accepted length (the rolled-back rows' K/V is
+                    # masked junk; a shared page was already COW-forked
+                    # before the write)
+                    self.scheduler.rollback_pages(req)
+                    self._tracer.instant("spec_rollback", rid=req.rid,
+                                         rejected=len(dr) - accepted)
+            for tok in emitted:
+                self._emit(req, tok, now)
+                if req.finished:
+                    break
+            if not req.finished and self._proposer is not None:
+                # accepted history is now truth: the draft proposer
+                # rolls its own cache back to it (no-op for n-gram)
+                self._proposer.commit(req)
 
     def _attn_cells(self, p_seq: np.ndarray, att_lens: np.ndarray
                     ) -> Tuple[int, int, int]:
@@ -2320,6 +2472,12 @@ class ServingEngine:
          att_lens, *d_sel) = self._tick_parts(packed, k1)
         if self._block is not None:
             block_rows, fix_rows = np.arange(k1), np.arange(self._fix_rows)
+        else:
+            # whose pending token is a prompt's first: the pick of the
+            # chunk-final row of the step in the air (-2), not of a
+            # decode row (-1)
+            first = {c[0].rid for c in self._flying.chunks} \
+                if self._flying is not None else ()
         for req in running:
             s = req.slot
             table[s, :len(req.pages)] = req.pages
@@ -2343,7 +2501,8 @@ class ServingEngine:
                 continue
             dr = drafts.get(req.rid, ((), None))[0]
             n = 1 + len(dr)
-            d_tokens[s, 0] = req.generated[-1]
+            d_tokens[s, 0] = req.generated[-1] if not req.pending \
+                else -2 if req.rid in first else -1
             d_tokens[s, 1:n] = dr
             d_pos[s, :n] = req.cache_len + np.arange(n)
             d_valid[s, :n] = 1
@@ -2364,64 +2523,6 @@ class ServingEngine:
             table[s, :len(req.pages)] = req.pages
             off += rows
         return packed
-
-    def _walk_results(self, running: List[Request], chunks,
-                      drafts: Dict[int, Tuple], d_logits: np.ndarray,
-                      p_logits: np.ndarray) -> None:
-        """What the host does with a step's logits: chunk bookkeeping
-        first, decode/verify emissions second (see :meth:`_do_step`)."""
-        # stamp AFTER the sync so TTFT includes the step compute
-        now = self._time()
-        for req, start, n, _rows in chunks:
-            if req.status is not RequestStatus.RUNNING:
-                continue    # cancelled from an earlier chunk's on_token
-            self._finish_chunk(req, start, n, p_logits[req.slot], now)
-        if self.faults is not None and self.faults.nan_rids:
-            poisoned = [r for r in running
-                        if r.rid in self.faults.nan_rids]
-            if poisoned:              # only then pay for a writable copy
-                d_logits = d_logits.copy()
-                for req in poisoned:
-                    d_logits[req.slot] = np.nan
-        for req in running:
-            if req.status is not RequestStatus.RUNNING:
-                continue    # cancelled from another slot's on_token
-            dr, dprobs = drafts.get(req.rid, ((), None))
-            nrows = 1 + len(dr)
-            rows = d_logits[req.slot, :nrows]
-            if not np.isfinite(rows).all():
-                # poisoned slot (possibly mid-verify): fail ONLY this
-                # request — its pages go back (uncached ones scrubbed
-                # by _finish), the fused batchmates keep decoding
-                # untouched and the proposer state is released
-                self._finish(req, RequestStatus.FAILED, now)
-                continue
-            emitted, accepted = accept_tokens(
-                rows, dr, dprobs, req.sampling, len(req.generated),
-                self.eos_id)
-            req.cache_len += accepted + 1
-            if dr:
-                req.spec_proposed += len(dr)
-                req.spec_accepted += accepted
-                self.metrics.on_spec(len(dr), accepted)
-                self._tracer.instant("spec_accept", rid=req.rid,
-                                     proposed=len(dr), accepted=accepted)
-                if accepted < len(dr):
-                    # rejected branch: return the lookahead pages past
-                    # the accepted length (the rolled-back rows' K/V is
-                    # masked junk; a shared page was already COW-forked
-                    # before the write)
-                    self.scheduler.rollback_pages(req)
-                    self._tracer.instant("spec_rollback", rid=req.rid,
-                                         rejected=len(dr) - accepted)
-            for tok in emitted:
-                self._emit(req, tok, now)
-                if req.finished:
-                    break
-            if not req.finished and self._proposer is not None:
-                # accepted history is now truth: the draft proposer
-                # rolls its own cache back to it (no-op for n-gram)
-                self._proposer.commit(req)
 
     def _finish_block_pass(self, req: Request, stood: Tuple[int, int, int],
                            picks: np.ndarray, logits, now: float) -> None:
@@ -2450,14 +2551,15 @@ class ServingEngine:
             # as a poisoned decode row: fail this request alone
             self._finish(req, RequestStatus.FAILED, now)
             return
-        if req.sampling is None or req.sampling.greedy:
-            toks = [int(t) for t in picks[:n]]
-        else:
+        if _samples(req):
             rows = np.array(logits[req.slot, :n])
+            m.on_fetch(rows.nbytes)
             rows[:, self._mask_id] = -np.inf
             at = len(req.generated)
             toks = [next_token(row, req.sampling, at + i)
                     for i, row in enumerate(rows)]
+        else:
+            toks = [int(t) for t in picks[:n]]
         for fixed, tok in enumerate(toks, 1):
             self._emit(req, tok, now)
             if req.finished:
@@ -2479,18 +2581,24 @@ class ServingEngine:
         self.scheduler.note_prefill_progress(req, start)
         req.prefilling = req.cache_len < self._prefill_target(req)
 
-    def _finish_chunk(self, req: Request, start: int, n: int, logits,
-                      now: float) -> None:
-        """Post-dispatch bookkeeping for one prefill chunk that rode
-        the unified step: advance the materialized length, guard, index
-        the newly-completed full pages, and on the final chunk emit the
-        first token from the chunk-final row's logits."""
+    def _finish_chunk(self, req: Request, start: int, n: int,
+                      finite: bool, now: float) -> bool:
+        """What one prefill chunk that rode a step came to, read when
+        its words arrive (the materialized length moved at the
+        dispatch): guard, then index the newly-completed full pages.
+        ``finite``: whether the chunk-final row's logits were.  They
+        attend over every K/V written so far, so finiteness transitively
+        vouches for the whole chain, and every chunk's goes through the
+        guard BEFORE its full pages are indexed: without the per-chunk
+        check, suspect K/V from an overflowing prompt would be hittable
+        for the whole multi-tick prefill window, and a sharer admitted
+        in that window would stitch it before the final-chunk rollback
+        ran.  True where the chunk was the prompt's last and its final
+        row yields the first token (never a block model's)."""
         toks = req.cache_tokens
-        if self._block is None:     # (a block model's: at the dispatch)
-            self._advance_chunk(req, start, n)
         self.metrics.on_prefill(n)
         req.last_progress_tick = self._tick   # chunks are progress too
-        if not np.isfinite(logits).all():
+        if not finite:
             if self.cache is not None:
                 # roll back entries ONLY for pages the FAILING chunk
                 # wrote (from the pre-chunk position onward): earlier
@@ -2502,7 +2610,7 @@ class ServingEngine:
                     req.pages[start // self.kv_cfg.page_size:])
             req.prefilling = False
             self._finish(req, RequestStatus.FAILED, now)
-            return
+            return False
         if self.cache is not None:
             # newly-completed FULL pages — now finite-vouched — become
             # hittable immediately, so even a preempted or mid-prefill
@@ -2512,14 +2620,10 @@ class ServingEngine:
                 toks, req.pages, start + n,
                 from_block=req.chain_blocks, prev_hash=req.chain_hash,
                 tenant=req.tenant)
-        if req.prefilling:
-            return                            # more chunks, later ticks
-        if self._block is not None:
-            return         # no token comes of a block model's prompt rows
-        # first token: greedy argmax unless the request samples (seeded
-        # per-position draw — position 0 of its generated stream)
-        self._emit(req, next_token(logits, req.sampling,
-                                   len(req.generated)), now)
+        # (a later chunk of this prompt may be in the air already:
+        # ``req.prefilling`` is the dispatch's, so ask the lengths)
+        return self._block is None and \
+            start + n >= self._prefill_target(req)
 
     def _emit(self, req: Request, tok: int, now: float) -> None:
         req.generated.append(tok)

@@ -61,6 +61,10 @@ class ServingMetrics:
         # whole point of the ragged kernel is fewer dispatches per unit
         # of work, so the benchmark's serve driver reads these directly
         self.step_dispatches = 0      # unified-step device dispatches
+        self.steps_lagged = 0         # of them, read after the NEXT step
+        #                               was dispatched (the host's walk
+        #                               ran beside the device, not
+        #                               between two of its steps)
         self.decode_rows = 0          # decode/verify rows shipped across
         #                               steps (k1 per speculating slot)
         self.decode_slots = 0         # slot participations (one per
@@ -68,7 +72,11 @@ class ServingMetrics:
         self.prefill_rows = 0         # prefill-chunk rows shipped (padded)
         self.prefill_pad_rows = 0     # of the bucket, padding/alignment
         self.h2d_bytes = 0            # the steps' packed input buffers
-        self.d2h_bytes = 0            # the steps' two logits arrays
+        self.d2h_bytes = 0            # the steps' int32 words (a row's
+        #                               choice and finite flag) and the
+        #                               rows of logits fetched for a
+        #                               request that samples or for a
+        #                               verify walk
         # the ragged kernel's grid, on one chip (PR 27): a grid step
         # costs its fixed part whether its page is live or not, so the
         # kernel's time follows the steps, not the tokens
@@ -137,20 +145,25 @@ class ServingMetrics:
                 n_pad_rows: int, n_slots: Optional[int] = None,
                 h2d_bytes: int = 0, d2h_bytes: int = 0,
                 attn_cells: Tuple[int, int, int] = (0, 0, 0),
-                model_counts: Sequence[int] = ()) -> None:
-        """One unified-step dispatch: how many decode/verify rows and
+                model_counts: Sequence[int] = (),
+                lagged: bool = False) -> None:
+        """One unified-step dispatch, counted when its words are read:
+        how many decode/verify rows and
         (padded) prefill rows rode it, and how much of the prefill
         bucket was padding.  ``n_slots`` is the running-slot
         participation count — equal to the row count without
         speculation, 1/k1 of it with (each speculating slot ships k1
         verify rows).  ``h2d_bytes``/``d2h_bytes`` are what the
         dispatch moved between host and device: its input arrays up,
-        its logits down.  ``attn_cells`` is the dispatch's (ragged
+        its words down (:meth:`on_fetch` adds what else is read of it).
+        ``lagged``: the words were read after the next step's dispatch.
+        ``attn_cells`` is the dispatch's (ragged
         kernel calls, grid steps of those calls, steps whose page is
         live), zeros on the reference path.  ``model_counts`` is what
         the model's layers counted in the dispatch, in the order of the
         names given at construction."""
         self.step_dispatches += 1
+        self.steps_lagged += bool(lagged)
         self.decode_rows += n_decode_rows
         self.decode_slots += n_slots if n_slots is not None \
             else n_decode_rows
@@ -163,6 +176,10 @@ class ServingMetrics:
         self.attn_live_cells += attn_cells[2]
         for name, n in zip(self.model_counters, model_counts):
             self.model_counters[name] += int(n)
+
+    def on_fetch(self, nbytes: int) -> None:
+        """Rows of a step's logits read to the host beside its words."""
+        self.d2h_bytes += nbytes
 
     def on_block_pass(self, rows: int, fixed: Optional[int] = None) -> None:
         """One slot's pass over its block of ``rows`` rows: a denoising
@@ -342,6 +359,7 @@ class ServingMetrics:
             "tokens_generated": self.tokens_generated,
             "prefill_tokens": self.prefill_tokens,
             "step_dispatches": self.step_dispatches,
+            "steps_lagged": self.steps_lagged,
             "decode_rows": self.decode_rows,
             "decode_slots": self.decode_slots,
             "prefill_rows": self.prefill_rows,

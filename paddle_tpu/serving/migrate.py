@@ -143,6 +143,7 @@ def export_chain(engine, rid: int) -> MigrationBlob:
     enforce_that(engine._block is None,
                  "a block model's chain is not handed over: its current "
                  "block may stand between passes", context="serving-migrate")
+    engine.land()    # the tokens of a step in the air belong to the chain
     enforce_that(req.status is RequestStatus.RUNNING and
                  not req.prefilling and bool(req.generated),
                  f"rid {rid} is not migration-eligible "

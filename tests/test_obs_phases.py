@@ -25,7 +25,8 @@ from paddle_tpu.obs import NULL_TRACER, Tracer, chrome_trace
 from paddle_tpu.obs import trace as obs_trace
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.platform.flags import FLAGS
-from paddle_tpu.serving import DecoderLM, ManualClock, ServingEngine
+from paddle_tpu.serving import (DecoderLM, FaultPlan, ManualClock,
+                                ServingEngine)
 from paddle_tpu.serving import engine as engine_mod
 
 pytestmark = pytest.mark.obs
@@ -90,7 +91,8 @@ def test_phase_off_is_the_profilers_annotation_and_nothing_else(
     ``TraceAnnotation``; no clock, lock, ring or registry is touched
     (``time`` and ``threading`` are taken away from ``obs.trace`` while
     an engine ticks), and the sealed steady state keeps its one compile
-    per bucket pair and its one dispatch and one readback a tick."""
+    per bucket pair and its one dispatch and one readback a tick (of the
+    step's int32 words, a call after its dispatch)."""
     # an enabled tracer stamps its own clock; the obs-off one must not
     with pytest.raises(AssertionError, match="read a clock"):
         with Tracer(time_fn=exploding_clock).phase("tick.schedule", tick=3):
@@ -117,7 +119,7 @@ def test_phase_off_is_the_profilers_annotation_and_nothing_else(
         assert pairs == len(eng._step_fns)
         auditor().seal()
         m = eng.metrics
-        before = (m.ticks, m.step_dispatches, m.d2h_bytes)
+        before = (m.ticks, m.step_dispatches, m.d2h_bytes, m.steps_lagged)
         rid2 = eng.submit([2, 3, 4, 5], max_tokens=4)
         eng.run()
         auditor().assert_budget("serving.step", pairs)
@@ -126,11 +128,14 @@ def test_phase_off_is_the_profilers_annotation_and_nothing_else(
         FLAGS.jit_audit = old
         auditor().reset()
     assert eng.result(rid) == eng.result(rid2)
-    # every tick of the second request was busy: one dispatch and one
-    # readback (the two logits arrays) each
-    ticks = m.ticks - before[0]
-    assert m.step_dispatches - before[1] == ticks
-    assert m.d2h_bytes - before[2] == ticks * 2 * SLOTS * V * 4
+    # every tick of the second request but the one that read the last
+    # step's words dispatched a step; what is read of a step is its
+    # words: a choice and a finite flag for each slot's decode row and
+    # chunk-final row
+    steps = m.ticks - before[0] - 1
+    assert m.step_dispatches - before[1] == steps
+    assert m.steps_lagged - before[3] == steps - 1
+    assert m.d2h_bytes - before[2] == steps * 2 * 2 * SLOTS * 4
     assert NULL_TRACER.events == [] and len(NULL_TRACER.ring) == 0
 
 
@@ -139,14 +144,21 @@ def test_phase_off_is_the_profilers_annotation_and_nothing_else(
 # ---------------------------------------------------------------------------
 
 
-def test_ring_holds_a_ticks_phases_in_order(small_model):
+@pytest.mark.parametrize("lands", ["a-call-later", "in-its-own-call"])
+def test_ring_holds_a_ticks_phases_in_order(small_model, lands):
+    """``wait`` and ``sample`` are the read of a step's words and their
+    walk: in the call after the step's dispatch, behind that call's own
+    dispatch, or (a fault plan bound) in the step's own call."""
     model, params = small_model
     clk = ManualClock(tick_s=0.01)
     # the tracer's own clock moves at every reading, so that "inside"
     # below is a statement about order and not about equal stamps
     reads = itertools.count()
     tracer = Tracer(time_fn=lambda: 1e-3 * next(reads))
-    eng = make_engine(model, params, clk, tracer=tracer.scoped(replica=7))
+    kw = dict(faults=FaultPlan(clock=clk)) if lands == "in-its-own-call" \
+        else {}
+    eng = make_engine(model, params, clk, tracer=tracer.scoped(replica=7),
+                      **kw)
     rid = eng.submit([2, 3, 4, 5], max_tokens=3)
     eng.run()
     eng.step()                                      # an idle tick
@@ -157,18 +169,33 @@ def test_ring_holds_a_ticks_phases_in_order(small_model):
             assert e.kind == "X" and e.replica == 7
             by_tick.setdefault(e.args["tick"], []).append(e.name)
             extent[e.args["tick"], e.name] = (e.ts, e.ts + e.dur)
-    busy = [t for t, names in by_tick.items() if "pt:tick.wait" in names]
-    assert len(busy) == eng.metrics.step_dispatches >= 3
+    landed = [t for t, names in by_tick.items() if "pt:tick.wait" in names]
+    busy = [t for t, names in by_tick.items() if "pt:tick.dispatch" in names]
+    assert len(landed) == len(busy) == eng.metrics.step_dispatches >= 3
+    # children first (a span is recorded when it ends), the closing
+    # bookkeeping under the sample phase's name, the tick last
+    whole = TICK_PHASES + ["pt:tick.sample", "pt:tick"]
+    if lands == "in-its-own-call":
+        assert landed == busy and eng.metrics.steps_lagged == 0
+    else:
+        # the first step had none before it to read; the last was read
+        # by the call after it, which had nothing to dispatch
+        assert landed == [t + 1 for t in busy]
+        assert by_tick[busy[0]] == [n for n in whole if n != "pt:tick.wait"
+                                    ][:4] + ["pt:tick.sample", "pt:tick"]
+        assert by_tick[landed[-1]] == ["pt:tick.schedule", "pt:tick.wait",
+                                       "pt:tick.sample", "pt:tick.sample",
+                                       "pt:tick"]
+        assert eng.metrics.steps_lagged == len(busy) - 1
     for t in busy:
-        # children first (a span is recorded when it ends), the closing
-        # bookkeeping under the sample phase's name, the tick last
-        assert by_tick[t] == TICK_PHASES + ["pt:tick.sample", "pt:tick"]
+        if t in landed:
+            assert by_tick[t] == whole
         # the compiled step's call alone, strictly inside the upload:
         # the placement comes before it
         (d0, d1), (u0, u1) = (extent[t, "pt:tick." + n]
                               for n in ("dispatch", "upload"))
         assert u0 < d0 < d1 < u1
-    idle = [t for t in by_tick if t not in busy]
+    idle = [t for t in by_tick if t not in busy and t not in landed]
     assert idle and all(by_tick[t] == ["pt:tick.schedule", "pt:tick.sample",
                                        "pt:tick"] for t in idle)
     # the historical span is still there, and the exporter takes phases
@@ -240,8 +267,10 @@ def test_a_tick_places_one_buffer_and_dispatches_once(
     """With and without a prefill chunk, with and without drafted rows:
     one ``jax.device_put`` of one int32 buffer, no other array made by
     the engine's host code, one dispatch; and what the compiled step
-    receives is committed and fully replicated over the engine's mesh
-    (on the one device without one), so the call re-lays nothing."""
+    receives (the buffer, and the words of the step before it, which
+    never left the device) is committed and fully replicated over the
+    engine's mesh (on the one device without one), so the call re-lays
+    nothing and no program is compiled a second time."""
     model, params = tp_model if mesh else small_model
     kw = dict(mesh=tp_mesh()) if mesh else {}
     if spec:
@@ -271,17 +300,22 @@ def test_a_tick_places_one_buffer_and_dispatches_once(
     monkeypatch.setattr(engine_mod, "jnp", counting)
     monkeypatch.setattr(eng, "_step_fn", step_fn)
     for bucket in (True, False, False):        # the prompt, then decoding
-        before = eng.metrics.step_dispatches
         eng.step()
-        assert eng.metrics.step_dispatches == before + 1
         (packed,), ((pb, args),) = puts, got
         assert (pb > 0) == bucket
         assert counting.made == 0
         assert packed.dtype == "int32" and packed.ndim == 1
         assert packed.size == SLOTS * (3 * eng._k1 + 2 + PAGES_PER_SEQ) \
             + 3 * pb
-        params_in, _kv_in, placed = args       # and nothing else
+        params_in, _kv_in, placed, last = args     # and nothing else
         assert params_in is eng.params
+        assert last.dtype == "int32" and last.is_fully_replicated
+        assert last.shape == (2 * (SLOTS * eng._k1 + SLOTS),)
+        if mesh:
+            assert last.committed and last.sharding.is_equivalent_to(
+                placed.sharding, 1)
+        # a plain engine's words are those of the step in the air
+        assert (eng._flying is None) == bool(spec)
         assert placed.committed == mesh
         assert placed.is_fully_replicated
         if mesh:
@@ -292,6 +326,7 @@ def test_a_tick_places_one_buffer_and_dispatches_once(
         assert (placed == packed).all()
         puts.clear()
         got.clear()
+    assert all(fn._cache_size() == 1 for fn in eng._step_fns.values())
 
 
 def test_transfer_counters_equal_the_hand_count(small_model):
@@ -307,8 +342,10 @@ def test_transfer_counters_equal_the_hand_count(small_model):
     with_prefill = decode_only + 3 * 4 * 8
     assert m.prefill_rows > 0 and m.step_dispatches == 4
     assert m.h2d_bytes == with_prefill + 3 * decode_only
-    # down: [B, 1, V] and [B, V] float32 logits, every dispatch
-    assert m.d2h_bytes == 4 * 2 * SLOTS * V * 4
+    # down: the step's int32 words, every dispatch: the choice and the
+    # finite flag of each slot's decode row and chunk-final row (the
+    # [2 B, V] float32 logits stay on the device)
+    assert m.d2h_bytes == 4 * 2 * 2 * SLOTS * 4
     snap = m.snapshot()
     assert snap["h2d_bytes"] == m.h2d_bytes
     assert snap["d2h_bytes"] == m.d2h_bytes
@@ -325,7 +362,8 @@ def test_serving_step_names_blocks_and_parts(small_model):
     model, params = small_model
     eng = make_engine(model, params, ManualClock(tick_s=0.01))
     packed = eng._assemble([], [], 0, {})
-    text = eng._step_fn(0, 1).lower(eng.params, eng._kv, packed).as_text(
+    text = eng._step_fn(0, 1).lower(eng.params, eng._kv, packed,
+                                    eng._last_words()).as_text(
         debug_info=True)
     for scope in ("l0/attn", "l0/ffn", "head"):
         assert scope in text, scope
@@ -336,10 +374,12 @@ def test_serving_step_names_blocks_and_parts(small_model):
 def test_serving_step_takes_one_integer_operand_and_gathers_nothing(
         tp_model, pb, spec):
     """On the 4-device mesh: besides the parameters and the pool the
-    step takes ONE operand, the tick's packed int32 buffer, replicated;
-    the compiled program holds the closed form's all-reduces (two a
-    block) and no other collective, so taking the buffer apart moves
-    nothing between chips."""
+    step takes ONE operand from the host, the tick's packed int32
+    buffer, replicated, and the int32 words of the step before it, which
+    it left replicated on the device; the compiled program holds the
+    closed form's all-reduces (two a block) and no other collective, so
+    taking the buffer apart and choosing each row's token move nothing
+    between chips, and the words it returns are replicated."""
     model, params = tp_model
     kw = dict(spec_mode="ngram", spec_k=spec) if spec else {}
     eng = make_engine(model, params, ManualClock(tick_s=0.01),
@@ -348,13 +388,20 @@ def test_serving_step_takes_one_integer_operand_and_gathers_nothing(
     words = SLOTS * (3 * k1 + 2 + PAGES_PER_SEQ) + 3 * pb
     lowered = eng._step_fn(pb, k1).lower(
         eng.params, eng._kv, jax.device_put(eng._empty_tick(pb, k1),
-                                            eng._tick_sharding))
+                                            eng._tick_sharding),
+        eng._last_words())
     leaves = jax.tree.leaves(lowered.args_info)
     ints = [a for a in leaves if jnp.issubdtype(a.dtype, jnp.integer)]
-    assert [(a.shape, str(a.dtype)) for a in ints] == [((words,), "int32")]
+    last = 2 * (SLOTS * k1 + SLOTS)
+    assert [(a.shape, str(a.dtype)) for a in ints] == [
+        ((words,), "int32"), ((last,), "int32")]
     n_pool = len(jax.tree.leaves(eng._kv))
-    assert len(leaves) == len(params) + n_pool + 1
-    text = lowered.compile().as_text()
+    assert len(leaves) == len(params) + n_pool + 2
+    compiled = lowered.compile()
+    assert compiled.output_shardings[0].is_fully_replicated
+    assert compiled.output_shardings[0].is_equivalent_to(
+        eng._tick_sharding, 1)
+    text = compiled.as_text()
     ops = re.findall(r"= \S+ (all-reduce|all-gather|all-to-all|"
                      r"reduce-scatter|collective-permute|"
                      r"collective-broadcast)(?:-start)?\(", text)

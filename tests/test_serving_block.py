@@ -474,8 +474,11 @@ def test_decoder_lm_serves_the_same_tokens_as_before(kv_heads):
             model, params, [t % 60 for t in p], 4, 60)
     m = eng.metrics.snapshot()
     assert m["block_rows"] == m["denoise_passes"] == m["tokens_fixed"] == 0
-    # the step returns what it returned: two logits arrays and the pool
+    # the step returns its words (each row's choice and finite flag, the
+    # three decode rows and then each slot's chunk-final row), the logits
+    # of those rows, which stay on the device, and the pool
     buf = eng._empty_tick(16, 1)
-    shapes = jax.eval_shape(eng._step_fn(16, 1), params, eng._kv, buf)
-    assert len(shapes) == 3 and shapes[0].shape == (3, 1, 61) \
-        and shapes[1].shape == (3, 61)
+    shapes = jax.eval_shape(eng._step_fn(16, 1), params, eng._kv, buf,
+                            eng._last_words())
+    assert len(shapes) == 3 and shapes[0].shape == (2 * 6,) \
+        and shapes[0].dtype == jnp.int32 and shapes[1].shape == (6, 61)

@@ -420,8 +420,9 @@ def test_sharer_of_mid_prefill_chunks_survives_late_poison(rng):
                   max_slots=2)
     clean8 = rng.randint(8, 50, size=8).tolist()
     a = eng.submit(clean8 + [7, 8], max_tokens=4)  # chunk 3 poisons
-    eng.step()                                      # A chunk 1 cached
-    eng.step()                                      # A chunk 2 cached
+    eng.step()                         # (a chunk's pages are indexed
+    eng.step()                         # when its guard's flag is read:
+    eng.land()                         # now, for the one in the air)
     assert len(eng.cache) == 2 and eng.status(a) is RequestStatus.RUNNING
     bprompt = clean8 + rng.randint(8, 50, size=3).tolist()
     b = eng.submit(bprompt, max_tokens=6)

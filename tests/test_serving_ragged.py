@@ -197,7 +197,8 @@ def test_cancel_from_chunk_callback_skips_batchmate_chunk(rng):
                    on_token=assassin)
     b = eng.submit(rng.randint(2, 50, size=6).tolist(), max_tokens=4)
     victim_rid["b"] = b
-    eng.step()
+    eng.step()                          # the chunks' step is dispatched
+    eng.step()                          # and walked, a call later
     assert eng.status(b) is RequestStatus.CANCELLED
     assert eng.status(a) is RequestStatus.RUNNING
     eng.run(max_ticks=100)
@@ -581,7 +582,7 @@ def test_engine_step_hands_the_kernel_the_pool_itself(kv_dtype):
                   use_kernel=True, kv_dtype=kv_dtype, page_size=8)
     pb, k1 = 8, 1
     closed = jax.make_jaxpr(eng._step_fn(pb, k1))(
-        eng.params, eng._kv, eng._empty_tick(pb, k1))
+        eng.params, eng._kv, eng._empty_tick(pb, k1), eng._last_words())
     calls = [e for e, _ in _eqns(closed.jaxpr, "jit")
              if e.params["name"] == "_ragged_call"]
     assert len(calls) == layers
@@ -875,6 +876,7 @@ def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
         m = eng.metrics
         before = (m.attn_kernel_calls, m.attn_grid_cells, m.attn_live_cells)
         eng.step()
+        eng.land()      # (a step is counted when its words are read)
         seen.append((m.attn_kernel_calls - before[0],
                      m.attn_grid_cells - before[1],
                      m.attn_live_cells - before[2]))
