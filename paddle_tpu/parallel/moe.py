@@ -474,9 +474,10 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
     a held expert), and only the tiles that hold rows are computed.
     ``valid`` [T] keeps padding rows of a packed buffer out.
     ``operand_dtype`` is the type the rows take for the products (the
-    matrices are rounded to it): the global policy's where it is left out
-    (bfloat16 under ``FLAGS.use_bf16``); a caller that holds float32
-    matrices and wants no rounded copy of them names float32.
+    matrices are rounded to it), the shared expert's products too: the
+    global policy's where it is left out (bfloat16 under
+    ``FLAGS.use_bf16``); a caller that holds float32 matrices and wants no
+    rounded copy of them names float32.
 
     x: [T, D].  Returns (y [T, D] float32, stats) with the step's
     ``rows_total`` (valid tokens x top_k), ``rows_held`` (pairs that
@@ -530,11 +531,18 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
         out = _combine(y, g, dest, row_token, row_pair)
     if "shared_gate" in p:
         with jax.named_scope("moe.shared"):
-            shared = pmath.swiglu(x, p["shared_gate"], p["shared_up"],
-                                  p["shared_down"])
+            if operand_dtype is None:
+                mm = pmath.matmul
+            else:
+                # the caller's type for the shared expert's products too:
+                # float32 matrices are multiplied where they lie
+                def mm(a, b):
+                    return jnp.matmul(a.astype(ct), b.astype(ct),
+                                      preferred_element_type=jnp.float32)
+            shared = mm(jax.nn.silu(mm(x, p["shared_gate"]))
+                        * mm(x, p["shared_up"]), p["shared_down"])
             if "shared_mix" in p:
-                shared = shared * jax.nn.sigmoid(
-                    pmath.matmul(x, p["shared_mix"]))
+                shared = shared * jax.nn.sigmoid(mm(x, p["shared_mix"]))
             out = out + shared
     n_valid = t if valid is None else jnp.sum(valid)
     stats = {"rows_total": jnp.asarray(n_valid * top_k, jnp.float32),

@@ -18,6 +18,7 @@ from paddle_tpu.serving.control import (DEFAULT_CLASSES, AdmissionLedger,
 from paddle_tpu.serving.engine import (DecodeModel, DecoderLM, ServingEngine,
                                        greedy_decode_reference, validate_tp)
 from paddle_tpu.serving.block_moe_lm import BlockMoeLM
+from paddle_tpu.serving.window_moe_lm import WindowMoeLM
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
                                           SamplingParams, accept_tokens,
                                           next_token, warp_probs)
@@ -44,6 +45,7 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
 
 __all__ = [
     "ServingEngine", "DecodeModel", "DecoderLM", "BlockMoeLM",
+    "WindowMoeLM",
     "greedy_decode_reference",
     "ragged_paged_attention", "ragged_paged_attention_reference",
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",

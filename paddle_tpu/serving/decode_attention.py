@@ -47,6 +47,15 @@ ragged invocation:
   for each head over its lane slice ``[:, h * D:(h + 1) * D]``.  Per
   head the arithmetic and its order over pages are those of ``hb = 1``,
   bit for bit; only the number of grid steps changes.
+- **Window layers.**  With a ``window`` (static, a layer's) a row at
+  position ``p`` sees token ``t`` iff ``p - window < t <= p``, and the
+  page table is read as a RING: page ``a`` of a sequence lives at entry
+  ``a mod width`` (``kv_cache.WindowRing``; a full-width table never
+  wraps).  The page axis of the grid then starts at the block's first
+  live page: two more scalar-prefetch operands give each row block its
+  first and last live page (from its rows' positions), the axis has only
+  as many steps as a block's rows can see pages, and a page below every
+  row's window is neither fetched nor a grid step.
 - **int8 pages, dequant in-register.**  Quantized pools ship per-token,
   per-kv-head f32 scales next to the int8 pages; the kernel (and the
   gather fallback — see ``kv_cache.dequantize_kv``, the ONE shared
@@ -83,7 +92,7 @@ from paddle_tpu.ops.kernel_util import interpret_default as _interpret_default
 from paddle_tpu.platform.enforce import enforce_that
 from paddle_tpu.serving.kv_cache import (KVPages, dequantize_kv,
                                          kv_pool_specs, layer_pages,
-                                         quantize_kv)
+                                         quantize_kv, window_pages)
 
 _LANES = 128     # lane width of the (rows, _LANES) m/l scratch carries
 BLOCK_ROWS = 8   # sublane row-block granularity of the sequence packing
@@ -166,7 +175,8 @@ def heads_per_cell(num_kv_heads: int, page_size: int, head_dim: int,
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      kv_lens, row_seq, qpos, *,
                                      k_scale=None, v_scale=None,
-                                     sm_scale: Optional[float] = None):
+                                     sm_scale: Optional[float] = None,
+                                     window: Optional[int] = None):
     """Gather-then-mask oracle for the ragged kernel.
 
     q: [T, H, D] — the sequence-packed row stack (decode rows AND
@@ -181,13 +191,25 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     — decode length masking, in-chunk causality and cached-prefix
     offsets are all this one inequality.  Padded rows return an
     arbitrary finite value (fully-masked softmax degenerates to
-    uniform); callers never read them."""
+    uniform); callers never read them.
+
+    ``window`` (a layer's, static): row r attends over tokens ``qpos[r] -
+    window + 1 .. qpos[r]`` only, and ``page_table`` is a ring (page
+    ``a`` of a sequence at entry ``a mod width``): a row gathers the few
+    pages its window can touch, from its first live one."""
     t, h, d = q.shape
     _, page, kvh, _ = k_pages.shape
     pm = page_table.shape[1]
     if sm_scale is None:
         sm_scale = float(d) ** -0.5
-    pt = page_table[row_seq]                       # [T, Pm]
+    if window is None:
+        pt = page_table[row_seq]                   # [T, Pm]
+    else:
+        # the row's own pages: from the first its window reaches
+        pm, ring = window_pages(window, 1, page, pm), pm
+        first = jnp.maximum(qpos - window + 1, 0) // page        # [T]
+        at = first[:, None] + jnp.arange(pm, dtype=jnp.int32)    # [T, pm]
+        pt = page_table[row_seq[:, None], at % ring]
     k = k_pages[pt]                                # [T, Pm, page, KVH, D]
     v = v_pages[pt]
     if k_scale is not None:
@@ -198,9 +220,15 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     if kvh != h:
         k = jnp.repeat(k, h // kvh, axis=2)        # GQA head replication
         v = jnp.repeat(v, h // kvh, axis=2)
-    tok = jnp.arange(pm * page, dtype=jnp.int32)
-    live = ((tok[None, :] <= qpos[:, None]) &
-            (tok[None, :] < kv_lens[row_seq][:, None]))
+    if window is None:
+        tok = jnp.arange(pm * page, dtype=jnp.int32)
+        live = ((tok[None, :] <= qpos[:, None]) &
+                (tok[None, :] < kv_lens[row_seq][:, None]))
+    else:
+        tok = (at[:, :, None] * page + jnp.arange(page, dtype=jnp.int32)
+               ).reshape(t, pm * page)
+        live = ((tok <= qpos[:, None]) & (tok > qpos[:, None] - window) &
+                (tok < kv_lens[row_seq][:, None]))
     s = jnp.einsum("thd,tkhd->thk", q.astype(jnp.float32), k) * sm_scale
     s = jnp.where(live[:, None, :], s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
@@ -212,9 +240,9 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
-                   k_ref, v_ref, *rest, page_size: int, num_pb: int, hb: int,
-                   sm_scale: float, quantized: bool):
+def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, *rest,
+                   page_size: int, num_pb: int, hb: int, sm_scale: float,
+                   quantized: bool, window: Optional[int] = None):
     # grid (row_blocks, kv_head_groups, pages-per-seq), ``hb`` KV heads
     # a group: the page axis is streamed; every head's (m, l, acc)
     # persist in VMEM scratch across it.  blk_seq/pt/len are the
@@ -231,6 +259,14 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
     # not a legal TPU tile).  Scratch: m/l (hb, RBG, LANES), acc
     # (hb, RBG, D).  The body tests the page's liveness and builds the
     # mask once, then runs the per-head update hb times, unrolled.
+    # With a ``window``, two more scalar-prefetch operands lead ``rest``:
+    # the block's first and last live page [NB] (its page axis starts at
+    # the first: step j is page ``first + j``), and the mask gains the
+    # window's lower bound.
+    first_ref = last_ref = None
+    if window is not None:
+        first_ref, last_ref, *rest = rest
+    qpos_ref, q_ref, k_ref, v_ref, *rest = rest
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -246,16 +282,23 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    n = len_ref[blk_seq_ref[ib]]
-    live = j * page_size < n
+    if window is None:
+        at = j
+        n = len_ref[blk_seq_ref[ib]]
+        live = j * page_size < n
+    else:
+        at = first_ref[ib] + j
+        live = at <= last_ref[ib]
 
     @pl.when(live)
     def _compute():
-        tok = j * page_size + jax.lax.broadcasted_iota(
+        tok = at * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rbg, page_size), 1)
         # ONE inequality is the whole mask: causal for prefill rows,
         # length for decode rows, everything for padded rows (qpos −1)
         seen = tok <= qpos_ref[0]
+        if window is not None:
+            seen = seen & (tok > qpos_ref[0] - window)
         if quantized:
             # in-register dequant: HBM traffic stays 1 byte/element.
             # A KV head's scale column is picked out of the
@@ -299,7 +342,8 @@ def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, qpos_ref, q_ref,
 
 
 def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
-                   kv_lens, row_seq, qpos, sm_scale, interpret: bool):
+                   kv_lens, row_seq, qpos, sm_scale, interpret: bool,
+                   window: Optional[int] = None):
     """Kernel-path entry, on the STORED pool (``[L, pages, page,
     KVH * D]``) and a layer index.  REQUIRES block-uniform packing: T a
     multiple of :data:`BLOCK_ROWS` and every aligned block of rows
@@ -308,7 +352,10 @@ def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
     The block map is read as ``row_seq[::BLOCK_ROWS]``; rows that
     violate uniformity would silently attend over the wrong pages, so
     the engine owns the packing and tests pin it against the reference
-    path."""
+    path.  With a ``window`` the live rows of a block lie within
+    :data:`BLOCK_ROWS` consecutive positions (as the engine packs them: a
+    chunk's rows follow each other, a slot's verify rows too), which is
+    what bounds the pages a block can see."""
     t, h, d = q.shape
     page, kvh = k_pool.shape[2], k_pool.shape[3] // d
     enforce_that(t % BLOCK_ROWS == 0,
@@ -323,17 +370,18 @@ def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
     return _ragged_call(q, k_pool, v_pool, k_scale, v_scale,
                         jnp.asarray(layer, jnp.int32).reshape(1), page_table,
                         kv_lens, row_seq, qpos, hb=hb, sm_scale=sm_scale,
-                        interpret=interpret)
+                        interpret=interpret, window=window)
 
 
 # jitted on its own so that a step program of L layers traces and lowers
 # the kernel once and calls it L times: the body is unrolled over the
 # cell's heads, and Pallas lowers in Python in every process, persistent
 # compile cache or not — per layer that is seconds of set-up
-@functools.partial(jax.jit, static_argnames=("hb", "sm_scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("hb", "sm_scale", "interpret",
+                                             "window"))
 def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
                  kv_lens, row_seq, qpos, *, hb: int, sm_scale: float,
-                 interpret: bool):
+                 interpret: bool, window: Optional[int] = None):
     t, h, d = q.shape
     _, pages, page, lanes = k_pool.shape
     kvh = lanes // d
@@ -362,21 +410,44 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
     pt = page_table.astype(jnp.int32)
     ln = kv_lens.astype(jnp.int32)
 
+    prefetch = [blk_seq, pt, ln, layer]
+    steps = pm
+    if window is not None:
+        # each block's first and last live page, from its rows' positions
+        # (a block with no live row: last < first, nothing is visited);
+        # the page axis starts at the first and is only as long as a
+        # block's rows can see pages
+        qb = qpos.astype(jnp.int32).reshape(nb, BLOCK_ROWS)
+        real = qb >= 0
+        lo = jnp.min(jnp.where(real, qb, jnp.iinfo(jnp.int32).max), axis=1)
+        hi = jnp.minimum(jnp.max(qb, axis=1), ln[blk_seq] - 1)
+        some = jnp.any(real, axis=1) & (hi >= 0)
+        first = jnp.where(some, jnp.maximum(lo - window + 1, 0) // page, 0)
+        last = jnp.where(some, hi // page, -1)
+        prefetch += [first.astype(jnp.int32), last.astype(jnp.int32)]
+        steps = window_pages(window, BLOCK_ROWS, page, pm)
+
     # TPU block shapes must end in (8k, 128k) or the array's own last
     # two dims — every spec below is written to that rule
 
-    def qpos_idx(ib, hg, j, blk_ref, pt_ref, len_ref, layer_ref):
+    def qpos_idx(ib, hg, j, *refs):
         return (ib, 0, 0)
 
-    def q_idx(ib, hg, j, blk_ref, pt_ref, len_ref, layer_ref):
+    def q_idx(ib, hg, j, *refs):
         return (hg, ib, 0, 0)
 
-    def live_row(ib, j, blk_ref, pt_ref, len_ref, layer_ref):
+    def live_row(ib, j, blk_ref, pt_ref, len_ref, layer_ref, *bounds):
         # clamp dead pages (j past the block's sequence's last live
         # page) to the last live one so their DMA is elided by
         # revisiting; pl.when skips their compute.  max(len-1, 0) keeps
         # length-0 sequences legal.
         seq = blk_ref[ib]
+        if bounds:
+            # the table is a ring: page ``a`` at entry ``a mod width``
+            first_ref, last_ref = bounds
+            at = jnp.minimum(first_ref[ib] + j,
+                             jnp.maximum(last_ref[ib], first_ref[ib]))
+            return layer_ref[0] * pages + pt_ref[seq, at % pm]
         last = jnp.maximum(len_ref[seq] - 1, 0) // page
         return layer_ref[0] * pages + pt_ref[seq, jnp.minimum(j, last)]
 
@@ -400,8 +471,8 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
                  v_scale.reshape(-1, page, kvh)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(nb, kvh // hb, pm),
+        num_scalar_prefetch=len(prefetch),
+        grid=(nb, kvh // hb, steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((hb, 1, rbg, d), q_idx),
         scratch_shapes=[
@@ -410,8 +481,9 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
             pltpu.VMEM((hb, rbg, d), jnp.float32),
         ],
     )
-    kernel = functools.partial(_ragged_kernel, page_size=page, num_pb=pm,
-                               hb=hb, sm_scale=sm_scale, quantized=quantized)
+    kernel = functools.partial(_ragged_kernel, page_size=page, num_pb=steps,
+                               hb=hb, sm_scale=sm_scale, quantized=quantized,
+                               window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -419,7 +491,7 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
         compiler_params=_dim_semantics(3, interpret),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(blk_seq, pt, ln, layer, *args)
+    )(*prefetch, *args)
     out = out.reshape(kvh, nb, BLOCK_ROWS, g, d).transpose(1, 2, 0, 3, 4)
     return out.reshape(t, h, d)
 
@@ -429,13 +501,13 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
 # ---------------------------------------------------------------------------
 
 def _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer, *rest,
-                        sm_scale):
+                        sm_scale, window=None):
     """The (row-blocked) reference path on one layer of a stored pool."""
     k, v, ks, vs = layer_pages(
         KVPages(k_pool, v_pool, k_scale, v_scale, head_dim=q.shape[-1]),
         layer)
     return _ragged_reference_blocked(q, k, v, *rest, k_scale=ks, v_scale=vs,
-                                     sm_scale=sm_scale)
+                                     sm_scale=sm_scale, window=window)
 
 
 def _pool_dims(q, k_pool, v_pool, k_scale):
@@ -463,7 +535,8 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
                            row_seq, qpos, *, layer, k_scale=None,
                            v_scale=None, sm_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None):
     """Ragged paged attention over a sequence-packed mixed batch (see
     :func:`ragged_paged_attention_reference` for the semantics).
 
@@ -477,7 +550,12 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
     ``use_kernel=None`` auto-selects through :func:`attention_path`; the
     kernel additionally requires block-uniform :data:`BLOCK_ROWS`
     packing (the engine's packer guarantees it).  A kernel that was
-    chosen runs or raises — it never degrades to the reference path."""
+    chosen runs or raises — it never degrades to the reference path.
+
+    ``window`` (static; a window layer's): a row sees the last ``window``
+    tokens up to its own, ``page_table`` is read as a ring, and pages
+    below a block's windows are neither fetched nor visited (the module
+    doc)."""
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
@@ -492,10 +570,11 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
                               kv_lens.astype(jnp.int32),
                               row_seq.astype(jnp.int32),
                               qpos.astype(jnp.int32),
-                              float(sm_scale), bool(interpret))
+                              float(sm_scale), bool(interpret),
+                              window=None if window is None else int(window))
     return _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer,
                                page_table, kv_lens, row_seq, qpos,
-                               sm_scale=sm_scale)
+                               sm_scale=sm_scale, window=window)
 
 
 def ragged_paged_attention_tp(mesh, axis, q, k_pool, v_pool, page_table,
@@ -569,7 +648,8 @@ _REF_ROW_BLOCK = 64   # fallback row-block: bounds the per-row K/V gather
 
 def _ragged_reference_blocked(q, k_pages, v_pages, page_table, kv_lens,
                               row_seq, qpos, k_scale=None, v_scale=None,
-                              sm_scale=None, block: int = _REF_ROW_BLOCK):
+                              sm_scale=None, block: int = _REF_ROW_BLOCK,
+                              window: Optional[int] = None):
     """The reference path evaluated in row blocks.  The dumb oracle
     gathers each row's whole page chain ([T, Pm, page, H_kv, D]) — fine
     for tests, but as the ENGINE's fallback a 256-row prefill chunk
@@ -582,7 +662,8 @@ def _ragged_reference_blocked(q, k_pages, v_pages, page_table, kv_lens,
     if t <= block:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, kv_lens, row_seq, qpos,
-            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
+            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
+            window=window)
     pad = (-t) % block
     qp_ = jnp.concatenate([q, jnp.zeros((pad,) + q.shape[1:], q.dtype)]) \
         if pad else q
@@ -595,7 +676,8 @@ def _ragged_reference_blocked(q, k_pages, v_pages, page_table, kv_lens,
         qb, rb, pb = args
         return ragged_paged_attention_reference(
             qb, k_pages, v_pages, page_table, kv_lens, rb, pb,
-            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
+            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
+            window=window)
 
     h, d = q.shape[1], q.shape[2]
     out = jax.lax.map(body, (qp_.reshape(-1, block, h, d),

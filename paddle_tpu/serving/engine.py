@@ -107,6 +107,26 @@ Speculation, tensor parallelism, the host tier and chain migration
 refuse a block model at construction; the prefix cache serves it (whole
 pages of prefilled blocks).
 
+Window layers: a model that says ``layer_window(l)`` has layers of more
+than one KIND (``kv_cache.layer_kinds``).  The full-attention layers keep
+what every model's layers kept: pages from the free list, a page table a
+slot, admission, growth and preemption by pages; the pool's arrays then
+hold those layers only.  Each window kind keeps a ring of pages a slot
+(``kv_cache.WindowRing``: the window, the rows a slot brings in a step,
+page rounding), in arrays of its own, bound to the slot: what falls out
+of the window is overwritten by the same sequence's later positions while
+it lives, and the ring goes back with the slot.  One ``pool_bytes`` is
+divided between the kinds (the rings take what their bound needs, the
+free list the rest); ``free_bytes`` and ``check_page_conservation`` count
+both.  The compiled step writes a row's K/V to the page of its layer's
+kind and attends with the layer's window (``ragged_paged_attention(...,
+window=)``: pages below it are neither fetched nor visited); a model
+without windows has the one kind and builds the pool, the tick buffer and
+the step it always built.  The prefix cache is not built for such a model
+(a hit would stitch pages whose window layers' K/V are gone), and
+speculation, tensor parallelism, int8 pages, the host tier, chain
+migration and a block model refuse it at construction.
+
 What lands when (both step kinds): a step chooses its tokens itself.
 A one-token tick takes, inside the compiled step, the first maximum of
 each decode row's and of each chunk-final row's logits and whether the
@@ -152,6 +172,7 @@ exposing its projection weights.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import math
 import time
@@ -177,12 +198,14 @@ from paddle_tpu.serving.faults import (FaultPlan, InjectedDeviceError,
                                        PageLeakError)
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
                                          KVPages, PagedKVConfig, PagePool,
-                                         PrefixCache, append_token,
+                                         PrefixCache, WindowRing, append_token,
                                          dequantize_kv, fork_page,
                                          init_kv_pages, kv_pool_specs,
-                                         layer_pages, pages_for_budget,
+                                         layer_kinds, layer_pages,
+                                         make_window_ring, pages_for_budget,
                                          pages_spanned,
                                          read_pages, resolve_kv_dtype,
+                                         split_pool_bytes, window_pages,
                                          write_pages, zero_pages)
 from paddle_tpu.serving.metrics import ServingMetrics
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
@@ -230,6 +253,12 @@ class DecodeModel:
     - ``block_length``, ``denoise_steps``, ``mask_token_id`` (optional,
       together): a BLOCK model, which generates by diffusion over blocks
       of ``block_length`` positions (``ServingEngine``: "block models").
+    - ``layer_window(layer) -> Optional[int]`` (optional): the number of
+      most recent tokens the layer attends over, None for a layer that
+      attends over everything (``ServingEngine``: "window layers").  The
+      query heads a layer brings are its ``q``'s (``q.shape[-2]``; any
+      multiple of ``num_kv_heads``); ``num_heads`` is the most any layer
+      has.
 
     Tensor-parallel serving (``ServingEngine(mesh=...)``) additionally
     needs:
@@ -493,6 +522,8 @@ class _Flight:
     rows: Tuple[int, int, int]     # decode, prefill and padding rows
     h2d_bytes: int
     attn_cells: Tuple[int, int, int]
+    # what the host counted of the kinds' state (``_kind_counts``)
+    kind_counts: Tuple[int, ...] = ()
 
 
 def _samples(req: Request) -> bool:
@@ -595,6 +626,33 @@ class ServingEngine:
         kv_dtype = resolve_kv_dtype(kv_dtype)
         num_kv_heads = int(getattr(model, "num_kv_heads", 0)
                            or model.num_heads)
+        # the model's layers by the state they keep (the module doc:
+        # "window layers"): full-attention pages first, then a ring a
+        # slot for each window.  One kind for a model without windows
+        kinds = layer_kinds(model)
+        if len(kinds) > 1:
+            self._refuse_for_window_model(
+                kinds, mesh, kv_dtype, prefix_cache,
+                spec_mode if spec_mode is not None
+                else FLAGS.serving_spec_mode,
+                host_tier_bytes if host_tier_bytes is not None
+                else FLAGS.serving_host_tier_bytes)
+            prefix_cache = False
+        rows = int(prefill_chunk) if int(prefill_chunk) > 0 else 1 << 30
+        self._rings: Tuple[WindowRing, ...] = tuple(
+            make_window_ring(
+                kind, slots=max_slots,
+                rows=-(-rows // BLOCK_ROWS) * BLOCK_ROWS,
+                num_heads=model.num_heads, num_kv_heads=num_kv_heads,
+                head_dim=model.head_dim, page_size=page_size,
+                max_pages_per_seq=int(max_pages_per_seq or 1 << 30),
+                dtype=kv_dtype)
+            for kind in kinds[1:])
+        # layer -> (its kind: 0 the pages, i the i-th ring; its index
+        # among the kind's layers, which is its layer in the kind's arrays)
+        self._layer_state = {l: (i, j) for i, kind in enumerate(kinds)
+                             for j, l in enumerate(kind.layers)}
+        full_layers = len(kinds[0].layers)
         # tensor-parallel placement (ROADMAP item 1): with a mesh, the
         # megatron shard_plan places attention heads + FFN columns over
         # the `model` axis, the paged pool shards its KV-head dim the
@@ -652,8 +710,8 @@ class ServingEngine:
             # heads).  The scheduler charges admission in pages, so both
             # multipliers flow straight into admissible concurrency.
             num_pages = pages_for_budget(
-                pool_bytes, model.num_layers, model.num_heads,
-                model.head_dim, page_size, kv_dtype,
+                split_pool_bytes(pool_bytes, self._rings), full_layers,
+                model.num_heads, model.head_dim, page_size, kv_dtype,
                 num_kv_heads=num_kv_heads, tp=self.tp)
         num_pages = int(num_pages or FLAGS.serving_max_pages)
         if max_pages_per_seq is None:
@@ -685,12 +743,14 @@ class ServingEngine:
         else:
             self._time = time_fn or time.monotonic
         self.kv_cfg = PagedKVConfig(
-            num_layers=model.num_layers, num_heads=model.num_heads,
+            num_layers=full_layers, num_heads=model.num_heads,
             head_dim=model.head_dim, page_size=page_size,
             num_pages=num_pages, max_pages_per_seq=int(max_pages_per_seq),
             dtype=kv_dtype, num_kv_heads=num_kv_heads, tp=self.tp)
         self._kv: KVPages = init_kv_pages(self.kv_cfg, mesh=self.mesh,
                                           axis=self.tp_axis)
+        self._ring_kv: Tuple[KVPages, ...] = tuple(
+            init_kv_pages(ring.cfg) for ring in self._rings)
         self.pool = PagePool(num_pages)
         if prefix_cache is None:
             prefix_cache = bool(FLAGS.serving_prefix_cache)
@@ -732,8 +792,16 @@ class ServingEngine:
         # counters the model's layers return from inside the step
         self._counted: Tuple[str, ...] = tuple(
             getattr(model, "step_counters", ()))
-        self.metrics = ServingMetrics(pool_pages=self.pool.num_usable,
-                                      model_counters=self._counted)
+        # and what the host counts of the kinds' state a step
+        # (``_kind_counts``), under these names beside them
+        self._kind_counted: Tuple[str, ...] = (
+            "full_kv_tokens_held", "window_kv_tokens_held",
+            "window_kv_tokens_live", "window_pages_released",
+            "window_kernel_calls",
+            "window_grid_cells", "window_live_cells") if self._rings else ()
+        self.metrics = ServingMetrics(
+            pool_pages=self.pool.num_usable,
+            model_counters=self._counted + self._kind_counted)
         # the step in the air, and the words a step takes where none is
         # (no token is pending then)
         self._flying: Optional[_Flight] = None
@@ -828,7 +896,7 @@ class ServingEngine:
         # run still declares — and the jaxpr auditor still verifies —
         # the TPU donation contract.  The old per-backend gate here left
         # the contract invisible (and untested) on CPU.
-        self._donate_kv = (1,)
+        self._donate_kv = (1,) + tuple(range(4, 4 + len(self._rings)))
         # compiled-path contracts, declared next to the jit sites they
         # bind (checked by `python -m paddle_tpu.analysis xla`): the KV
         # pool must be donated and alias back out, per-tick sites must
@@ -853,7 +921,8 @@ class ServingEngine:
         # auditor's live-set estimator sums full aval bytes and cannot
         # see GSPMD's per-chip split — so scale the per-chip pool bytes
         # back up by tp (healthz keeps reporting the per-chip number)
-        kv_bytes = self.kv_cfg.kv_bytes() * self.tp
+        kv_bytes = self.kv_cfg.kv_bytes() * self.tp + \
+            sum(ring.kv_bytes() for ring in self._rings)
         act_bytes = 4 * rows * (8 * e * model.num_layers
                                 + model.vocab_size)
         kv_name = jnp.dtype(self.kv_cfg.dtype).name
@@ -896,7 +965,8 @@ class ServingEngine:
             mesh_axes = ((self.tp_axis, self.tp),)
             expect = (0, 1)      # params and pool must arrive sharded
         self._step_contract = SiteContract(
-            per_tick=True, donate=(1,), allow_upcast=allow_upcast,
+            per_tick=True, donate=self._donate_kv,
+            allow_upcast=allow_upcast,
             peak_bytes=xla_peak_bytes if xla_peak_bytes is not None else
             2 * kv_bytes + 8 * param_bytes + 16 * act_bytes + (1 << 26),
             flops=xla_flops if xla_flops is not None else
@@ -1003,6 +1073,58 @@ class ServingEngine:
                      "between passes: a block model serves as 'unified'",
                      context=ctx)
 
+    def _refuse_for_window_model(self, kinds, mesh, kv_dtype, prefix_cache,
+                                 spec_mode: str,
+                                 host_tier_bytes: int) -> None:
+        """What a model with window layers cannot be built with, said at
+        construction, each by its mechanism (nothing takes a silent
+        second path).  The prefix cache left to its default is simply
+        not built."""
+        from paddle_tpu.platform.enforce import enforce_that
+
+        ctx = "serving-window"
+        enforce_that(len(kinds[0].layers) >= 1,
+                     "every layer of the model has a window: the page "
+                     "table, admission and preemption are the "
+                     "full-attention layers', and a model without one is "
+                     "not built", context=ctx)
+        enforce_that(self._block is None,
+                     "a block model's rows attend as their block's last "
+                     "position; that under a window is not built: serve a "
+                     "block model without layer_window", context=ctx)
+        enforce_that(not prefix_cache,
+                     "the prefix cache stitches full-attention pages; the "
+                     "window layers' K/V of a cached prefix are gone once "
+                     "its sequence moved on, so a hit would read what is "
+                     "no longer there: build the engine with "
+                     "prefix_cache=False (the default builds none for a "
+                     "model with window layers)", context=ctx)
+        enforce_that(str(spec_mode) == "off",
+                     "speculative decoding rolls rejected rows back by "
+                     "page; a window layer's ring has no pages to give "
+                     "back and a rejected row has overwritten what fell "
+                     "out of the window: build it with spec_mode='off'",
+                     context=ctx)
+        enforce_that(mesh is None,
+                     "tensor-parallel serving (mesh=) of a model with "
+                     "window layers is not built: the rings have no "
+                     "placement over a mesh; serve it with mesh=None",
+                     context=ctx)
+        enforce_that(jnp.dtype(kv_dtype) != jnp.int8,
+                     "int8 pages have not been driven through a window "
+                     "layer's ring: serve it with a float kv_dtype",
+                     context=ctx)
+        enforce_that(int(host_tier_bytes) <= 0,
+                     "the host tier spills cached prefix pages, which a "
+                     "model with window layers has none of: build it with "
+                     "host_tier_bytes=0", context=ctx)
+        enforce_that(self.role == "unified",
+                     f"role={self.role!r} hands requests over by chain "
+                     "migration, which exports full-attention pages only "
+                     "and would leave the window layers' rings behind: a "
+                     "model with window layers serves as 'unified'",
+                     context=ctx)
+
     # ---- observability wiring -------------------------------------------
 
     def set_tracer(self, tracer) -> None:
@@ -1100,7 +1222,7 @@ class ServingEngine:
             ctx, NamedSharding(self.mesh, P(None, self.tp_axis, None)))
 
     def _attend(self, kv: KVPages, layer: int, q, table, att_lens,
-                row_seq, qpos, k1: int = 1):
+                row_seq, qpos, k1: int = 1, window: Optional[int] = None):
         """One ragged paged attention over the tick's mixed row stack.
         The reference path consumes the compact ``[B * k1 + pb]`` rows
         as-is; the kernel path expands each slot's ``k1`` decode/verify
@@ -1111,14 +1233,16 @@ class ServingEngine:
         itself.  Under TP the kernel rides a ``shard_map`` over the
         model axis (heads are attention-local, so each chip runs the
         unchanged kernel on its head shard) and both paths re-assert
-        the head sharding on the context."""
+        the head sharding on the context.  ``kv``, ``layer`` and ``table``
+        are the layer's KIND's (a window layer's ring and its ``window``
+        with them)."""
         if not self._ragged_kernel:
             # row-blocked fallback: identical math to the oracle, with
             # the per-row K/V gather bounded to one block of rows
             k, v, ks, vs = layer_pages(kv, layer)
             return self._tp_ctx(_ragged_reference_blocked(
                 q, k, v, table, att_lens, row_seq, qpos, k_scale=ks,
-                v_scale=vs))
+                v_scale=vs, window=window))
         b, rb = self._max_slots, BLOCK_ROWS
         bd = b * k1                      # compact decode/verify rows
         rbk = -(-k1 // rb) * rb          # padded rows per slot
@@ -1136,6 +1260,8 @@ class ServingEngine:
         # no slice of the layer, no re-tiling, no copy of either
         pool = dict(layer=layer, k_scale=kv.k_scale, v_scale=kv.v_scale,
                     use_kernel=True)
+        if window is not None:
+            pool["window"] = window
         if self.mesh is not None and self.tp > 1:
             ctx = ragged_paged_attention_tp(
                 self.mesh, self.tp_axis, qe, kv.k, kv.v, table, att_lens,
@@ -1173,7 +1299,17 @@ class ServingEngine:
         blk = self._block
         rotate = getattr(model, "rotate", None)
 
+        rings = self._rings
+        ring_tables = [ring.table() for ring in rings]
+        windows = (None,) + tuple(ring.window for ring in rings)
+        # (a model of one kind names no kind: its step is what it was)
+        kind_scope = (lambda i: jax.named_scope(
+            "attn.full" if windows[i] is None else "attn.window")) \
+            if rings else (lambda i: contextlib.nullcontext())
+
         def raw(params, kv: KVPages, packed, *last):
+            # (behind the last step's words: each ring's arrays)
+            last, ring_kv = last[:1], last[1:]
             # packed: the tick's one int32 input buffer, replicated;
             # taken apart by static slices (a chip reads its own copy)
             (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
@@ -1227,25 +1363,36 @@ class ServingEngine:
                 # last position (its rotary position stays its own)
                 qpos = jnp.where(qpos >= 0, (qpos // blk + 1) * blk - 1, -1)
             counts = 0
-            for l in range(cfg.num_layers):
+            # every kind's arrays, page table and the page each row's K/V
+            # goes to: the pool's as above, a ring's by its rule (page
+            # ``a`` of a slot's sequence at entry ``a mod R`` of its ring)
+            state = [kv, *ring_kv]
+            tables = [table, *(jnp.asarray(t) for t in ring_tables)]
+            row_pages = [pages] + [
+                jnp.where(live, t[row_seq, (pos // page) % t.shape[1]],
+                          NULL_PAGE) for t in tables[1:]]
+            for l in range(model.num_layers):
                 # named_scope: blocks and their parts show up by name in
                 # xplane/profiler traces (as topology.forward's layers)
+                i, li = self._layer_state[l]
                 with jax.named_scope(f"l{l}"):
-                    with jax.named_scope("attn"):
+                    with jax.named_scope("attn"), kind_scope(i):
                         q, k, v = model.qkv(params, l, x)
                         if rotate is not None:
                             q, k = rotate(params, l, q, k, pos)
-                        kv = append_token(kv, l, jnp.where(wmask, k, 0.0),
-                                          jnp.where(wmask, v, 0.0), pages,
-                                          offs)
-                        ctx = self._attend(kv, l, q, table, att_lens,
-                                           row_seq, qpos, k1=k1)
+                        state[i] = append_token(
+                            state[i], li, jnp.where(wmask, k, 0.0),
+                            jnp.where(wmask, v, 0.0), row_pages[i], offs)
+                        ctx = self._attend(state[i], li, q, tables[i],
+                                           att_lens, row_seq, qpos, k1=k1,
+                                           window=windows[i])
                     if self._counted:
                         x, n = model.attn_out_counted(params, l, ctx, x,
                                                       live)
                         counts = counts + n
                     else:
                         x = model.attn_out(params, l, ctx, x)
+            kv, ring_kv = state[0], tuple(state[1:])
             more = (counts,) if self._counted else ()
             if blk is not None:
                 # logits for the rows a denoising pass fixes only.  What
@@ -1289,7 +1436,7 @@ class ServingEngine:
             if self._tick_sharding is not None:
                 words = jax.lax.with_sharding_constraint(
                     words, self._tick_sharding)
-            return words, logits, self._tp_kv(kv)
+            return (words, logits, self._tp_kv(kv)) + ring_kv
 
         fn = audit_jit(raw, site="serving.step",
                        donate_argnums=self._donate_kv,
@@ -1373,6 +1520,12 @@ class ServingEngine:
             if suspect:
                 self._kv = self._zero_fn(self._kv,
                                          jnp.asarray(suspect, jnp.int32))
+            if req.slot is not None:
+                # and its slot's ring of every window kind: the next
+                # sequence there reads the ring's pages under its mask
+                self._ring_kv = tuple(
+                    self._zero_fn(kv, jnp.asarray(ring.table()[req.slot]))
+                    for ring, kv in zip(self._rings, self._ring_kv))
         if self._proposer is not None:
             # drop any draft-model cache state (its pages return to the
             # draft pool); a no-op for the n-gram proposer
@@ -1651,6 +1804,17 @@ class ServingEngine:
                 f"REF-LEAK: held={held} refs={pool.total_refs} "
                 f"cached={pool.num_cached} free={pool.num_free} "
                 f"usable={pool.num_usable}")
+        # a window kind's rings go with the slots: every slot is either
+        # free or a running request's, so no ring is lost with one
+        slots = len(self.scheduler.running) + \
+            len(self.scheduler._free_slots)
+        if self._rings and slots != self._max_slots:
+            self._dump_postmortem("RING-LEAK")
+            raise PageLeakError(
+                f"RING-LEAK: {len(self.scheduler.running)} running and "
+                f"{len(self.scheduler._free_slots)} free slots of "
+                f"{self._max_slots}: a slot's window rings are held by "
+                "no one")
         if self._proposer is not None:
             # the draft-model pool obeys the same conservation law:
             # pages held by live draft states == draft-pool refcounts
@@ -1665,6 +1829,14 @@ class ServingEngine:
                 self._dump_postmortem("HOSTTIER-LEAK")
                 raise
 
+    def free_bytes(self) -> int:
+        """What of ``pool_bytes`` no live sequence holds, over every kind
+        of layer state: the free list's pages and the rings of the free
+        slots (per chip)."""
+        return self.pool.num_free * self.kv_cfg.bytes_per_page() + \
+            len(self.scheduler._free_slots) * sum(
+                ring.bytes_per_slot() for ring in self._rings)
+
     # ---- page-migration plane (round 16) --------------------------------
 
     def migratable_rids(self) -> List[int]:
@@ -1672,8 +1844,9 @@ class ServingEngine:
         replica: still RUNNING, prefill fully materialized, and at
         least the first token emitted (so the destination starts with a
         decodable state — ``generated[-1]`` is the next step's input)."""
-        if self._block is not None:
-            return []     # a block between passes is not handed over
+        if self._block is not None or self._rings:
+            return []     # a block between passes is not handed over,
+            #               nor a window layer's ring
         self.land()       # (the answer is about tokens the host has)
         return [r.rid for r in self.scheduler.running_requests()
                 if r.status is RequestStatus.RUNNING and not r.prefilling
@@ -2160,8 +2333,10 @@ class ServingEngine:
             step = self._step_fn(pb, k1)
             placed = jax.device_put(packed, self._tick_sharding)
             with phase("tick.dispatch", tick=tick):
-                words, logits, self._kv = step(
-                    self.params, self._kv, placed, self._last_words())
+                words, logits, self._kv, *ring_kv = step(
+                    self.params, self._kv, placed, self._last_words(),
+                    *self._ring_kv)
+                self._ring_kv = tuple(ring_kv)
         if self._block is not None:
             passes, n_rows = self._passes(running), len(running) * k1
         else:
@@ -2171,7 +2346,8 @@ class ServingEngine:
         flight = _Flight(
             passes, chunks, words, logits,
             (n_rows, total_rows, pb - sum(c[2] for c in chunks)),
-            packed.nbytes, self._attn_cells(p_seq, att_lens))
+            packed.nbytes, self._attn_cells(p_seq, att_lens),
+            self._kind_counts(parts, chunks))
         self._advance(flight)
         if compiles:
             _settle_heap()            # (beside the device's first run)
@@ -2285,8 +2461,9 @@ class ServingEngine:
                 h2d_bytes=flight.h2d_bytes, d2h_bytes=words.nbytes,
                 attn_cells=flight.attn_cells,
                 # (the model's counts are the words' tail)
-                model_counts=words[words.size - len(self._counted):],
-                lagged=lagged)
+                model_counts=tuple(
+                    words[words.size - len(self._counted):])
+                + flight.kind_counts, lagged=lagged)
             # stamp AFTER the sync so TTFT includes the step compute
             now = self._time()
             poisoned = self.faults.nan_rids if self.faults is not None \
@@ -2416,6 +2593,67 @@ class ServingEngine:
         calls = cfg.num_layers
         return (calls, calls * nb * groups * cfg.max_pages_per_seq,
                 calls * live * groups)
+
+    def _kind_counts(self, parts, chunks) -> Tuple[int, ...]:
+        """What one step holds and visits of the kinds' state, counted on
+        the host from the tick's arrays under ``_kind_counted``'s names:
+        the tokens the full-attention layers hold of the step's sequences
+        (a layer), those a window layer still holds and those of them its
+        rows' windows reach (both summed over the window kinds), the
+        pages that fell out of a window with this step's rows, and the
+        window layers' kernel calls, grid steps and live grid steps
+        (:meth:`_window_cells`).  Empty for a model without window
+        layers."""
+        if not self._rings:
+            return ()
+        d_valid, att_lens = parts[2], parts[8]
+        rows = d_valid.sum(axis=1)         # a slot's rows of this step
+        for req, _start, n, _rows in chunks:
+            rows[req.slot] = n
+        held = seen = released = 0
+        for ring in self._rings:
+            held += int(ring.tokens_held(att_lens).sum())
+            seen += int(np.minimum(att_lens, ring.window + rows - 1).sum())
+            released += int((ring.released(att_lens)
+                             - ring.released(att_lens - rows)).sum())
+        return (int(att_lens.sum()), held, seen, released) + \
+            self._window_cells(parts)
+
+    def _window_cells(self, parts) -> Tuple[int, int, int]:
+        """(kernel calls, grid steps, live grid steps) of the window
+        layers in one step, as ``_ragged_call`` lays a windowed grid out:
+        ``(row blocks, KV-head groups, window_pages)`` a layer, a block's
+        steps live from the first page its rows' windows reach to the
+        page of its last row.  Zeros on the reference path, as
+        :meth:`_attn_cells`."""
+        if not self._ragged_kernel:
+            return (0, 0, 0)
+        d_pos, d_valid, p_qpos, p_seq, att_lens = (
+            parts[1], parts[2], parts[4], parts[5], parts[8])
+        page = self.kv_cfg.page_size
+        # each row block's lowest and highest live position: a slot's
+        # decode rows (one block), then the chunks' blocks
+        n_valid = d_valid.sum(axis=1)
+        qb = p_qpos.reshape(-1, BLOCK_ROWS)
+        lo = np.concatenate([
+            np.where(n_valid > 0, d_pos[:, 0], -1),
+            np.where(qb >= 0, qb, np.iinfo(np.int32).max).min(axis=1)])
+        hi = np.concatenate([
+            np.where(n_valid > 0, d_pos[:, 0] + n_valid - 1, -1),
+            qb.max(axis=1)])
+        seq = np.concatenate([np.arange(self._max_slots),
+                              p_seq[::BLOCK_ROWS]])
+        hi = np.minimum(hi, att_lens[seq] - 1)
+        calls = grid = live = 0
+        for ring in self._rings:
+            first = np.maximum(lo - ring.window + 1, 0) // page
+            n = ring.cfg.num_layers * self._attn_head_groups
+            calls += ring.cfg.num_layers
+            grid += n * len(lo) * window_pages(
+                ring.window, BLOCK_ROWS, page, ring.ring_pages)
+            live += n * int(np.where(hi >= 0, hi // page - first + 1,
+                                     0).sum())
+        return (calls, grid, live)
 
     def _tick_shapes(self, pb: int, k1: int) -> Tuple[Tuple[int, ...], ...]:
         """A tick's nine input arrays (ten for a block model) in the order

@@ -37,6 +37,31 @@ inactive decode slots) are steered to it instead of being predicated
 out, which keeps every scatter dense and shape-stable under jit.  No
 live sequence is ever granted page 0.
 
+**Kinds of layer state.**  What a layer keeps of a live sequence is
+its KIND's business (:func:`layer_kinds` reads it off the model).  The
+first kind is the full-attention pages described above: every token of
+the sequence, in pages from the one free list, for as long as the
+sequence lives; a model whose layers all attend over everything has this
+kind alone and builds exactly the pool it always built (the pool then
+holds every layer).  A layer with a WINDOW (``model.layer_window(l)``)
+attends over the last ``window`` tokens only, so its kind keeps no more
+than those, the chunk in flight and page rounding: a :class:`WindowRing`
+gives every slot a ring of ``R = ceil((window + rows - 1) / page) + 1``
+pages of its own (``rows`` the most rows a slot brings in one step:
+:func:`window_pages`).  THE
+RULE: page ``a`` of a sequence (positions ``[a * page, (a + 1) * page)``)
+lives at entry ``a mod R`` of its slot's ring; when the sequence writes
+position ``p`` it overwrites what page ``p // page - R`` left there, which
+by then lies below every window that can still be asked for.  So a page
+that falls out of the window is released WHILE the sequence lives and
+used again by the same slot's later positions; nothing is allocated at
+admission, growth cannot fail, and preemption, cancellation and
+completion return the ring with the slot.  Each window kind has device
+arrays of its own (``[layers of the kind, 1 + slots * R, page, H_kv *
+D]``: its layers only), and ONE byte budget is divided between the kinds
+(:func:`split_pool_bytes`): the rings take what their bound needs, the
+free list gets the rest.
+
 Automatic prefix caching (round 9): pages are **refcounted** — a page
 shared by N sequences is freed only when the last holder unrefs it —
 and a host-side :class:`PrefixCache` indexes *full* pages by chained
@@ -84,8 +109,12 @@ def resolve_kv_dtype(name):
 
 @dataclass(frozen=True)
 class PagedKVConfig:
-    """Static geometry of the paged pool (one pool shared by all layers:
-    page id ``p`` addresses layer ``l``'s slice ``k[l, p]`` for every l).
+    """Static geometry of one kind's paged pool (shared by all the
+    kind's layers: page id ``p`` addresses the kind's ``l``-th layer's
+    slice ``k[l, p]`` for every l; a model with no window layer has the
+    one kind, and ``num_layers`` is the model's).  ``num_heads`` is what
+    the GQA check below reads: the fewest query heads any layer brings
+    (a layer's own count is its ``q``'s).
 
     ``num_kv_heads`` (None = ``num_heads``) is the GQA knob: the pool
     stores K/V for the KV heads only, and the ragged attention kernel
@@ -190,6 +219,115 @@ def pages_for_budget(pool_bytes: int, num_layers: int, num_heads: int,
                           dtype=resolve_kv_dtype(dtype),
                           num_kv_heads=num_kv_heads, tp=int(tp))
     return max(2, int(pool_bytes) // probe.bytes_per_page())
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """The layers of a model that keep the same state of a live sequence:
+    ``window`` None for full attention (pages from the free list, the
+    first kind), else the number of most recent tokens a layer of the
+    kind attends over (a :class:`WindowRing`).  ``layers`` are the
+    model's layer indices, ascending: the kind's ``i``-th layer is layer
+    ``i`` of its device arrays."""
+
+    window: Optional[int]
+    layers: Tuple[int, ...]
+
+
+def layer_kinds(model) -> Tuple[LayerKind, ...]:
+    """The model's layers by kind, the full-attention kind first (with
+    every layer, for a model that has no ``layer_window``), then one kind
+    a distinct window, ascending."""
+    n = int(model.num_layers)
+    window_of = getattr(model, "layer_window", None)
+    windows = [None if window_of is None else window_of(l) for l in range(n)]
+    for w in windows:
+        enforce_that(w is None or int(w) >= 1,
+                     f"a layer's window must be positive, got {w!r}",
+                     context="serving")
+    kinds = [LayerKind(None, tuple(l for l in range(n)
+                                   if windows[l] is None))]
+    for w in sorted({int(w) for w in windows if w is not None}):
+        kinds.append(LayerKind(w, tuple(l for l in range(n)
+                                        if windows[l] == w)))
+    return tuple(kinds)
+
+
+def window_pages(window: int, rows: int, page: int, width: int) -> int:
+    """How many pages ``rows`` consecutive positions can see between them
+    under ``window``: those that positions ``[p - window + 1, p + rows)``
+    can touch, for any ``p`` (never more than the table's ``width``).
+    With a step's most rows of one sequence it is the length of a
+    :class:`WindowRing`, with a row block's the page axis of the windowed
+    kernel's grid."""
+    return min(int(width), (int(window) + max(1, int(rows)) - 2) // page + 2)
+
+
+@dataclass(frozen=True)
+class WindowRing:
+    """The state of one window kind (the module doc has the rule): every
+    slot owns ``ring_pages`` pages for good, ``table[s]`` lists them, and
+    page ``a`` of the slot's sequence lives at ``table[s, a mod
+    ring_pages]``.  ``cfg`` is the geometry of the kind's device arrays
+    (its layers only; ``max_pages_per_seq`` is the ring's length)."""
+
+    window: int
+    slots: int
+    cfg: PagedKVConfig
+
+    @property
+    def ring_pages(self) -> int:
+        return self.cfg.max_pages_per_seq
+
+    def table(self) -> np.ndarray:
+        """``[slots, ring_pages]`` int32, fixed for the engine's life
+        (page 0 stays the null page)."""
+        r = self.ring_pages
+        return (1 + np.arange(self.slots * r, dtype=np.int32)
+                ).reshape(self.slots, r)
+
+    def released(self, n_tokens) -> "np.ndarray":
+        """How many of its first pages a sequence of ``n_tokens`` cached
+        tokens no longer needs in this kind: those wholly below the window
+        of the next position (``n_tokens``), and of every later one."""
+        return np.maximum(0, np.asarray(n_tokens) - self.window + 1) \
+            // self.cfg.page_size
+
+    def tokens_held(self, n_tokens) -> "np.ndarray":
+        """Tokens of a sequence that the kind still keeps, a layer."""
+        n = np.asarray(n_tokens)
+        return n - self.released(n) * self.cfg.page_size
+
+    def bytes_per_slot(self) -> int:
+        return self.ring_pages * self.cfg.bytes_per_page()
+
+    def kv_bytes(self) -> int:
+        """The kind's device arrays, the null page included."""
+        return self.cfg.kv_bytes()
+
+
+
+def make_window_ring(kind: LayerKind, *, slots: int, rows: int,
+                     num_heads: int, num_kv_heads: int, head_dim: int,
+                     page_size: int, max_pages_per_seq: int, dtype
+                     ) -> WindowRing:
+    r = window_pages(kind.window, rows, page_size, max_pages_per_seq)
+    return WindowRing(int(kind.window), int(slots), PagedKVConfig(
+        num_layers=len(kind.layers), num_heads=num_heads, head_dim=head_dim,
+        page_size=page_size, num_pages=1 + slots * r, max_pages_per_seq=r,
+        dtype=resolve_kv_dtype(dtype), num_kv_heads=num_kv_heads))
+
+
+def split_pool_bytes(pool_bytes: int, rings: Sequence[WindowRing]) -> int:
+    """ONE byte budget over the kinds: the rings take what their bound
+    needs (they cannot run with less), the full-attention free list gets
+    what is left, which is returned."""
+    left = int(pool_bytes) - sum(r.kv_bytes() for r in rings)
+    enforce_that(left > 0,
+                 f"pool_bytes ({pool_bytes}) does not hold the window "
+                 f"layers' rings ({[r.kv_bytes() for r in rings]} bytes): "
+                 "give the pool more, or fewer slots", context="serving")
+    return left
 
 
 @functools.partial(jax.tree_util.register_dataclass,

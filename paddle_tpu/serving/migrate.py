@@ -143,6 +143,10 @@ def export_chain(engine, rid: int) -> MigrationBlob:
     enforce_that(engine._block is None,
                  "a block model's chain is not handed over: its current "
                  "block may stand between passes", context="serving-migrate")
+    enforce_that(not engine._rings,
+                 "a model with window layers is not handed over: the chain "
+                 "holds full-attention pages only and would leave the "
+                 "window layers' rings behind", context="serving-migrate")
     engine.land()    # the tokens of a step in the air belong to the chain
     enforce_that(req.status is RequestStatus.RUNNING and
                  not req.prefilling and bool(req.generated),
@@ -178,6 +182,10 @@ def import_chain(engine, blob: MigrationBlob, *, on_token=None,
     tick decodes it — no prefill, no queue wait."""
     _check_geometry(engine, blob)
     enforce_that(blob.kind == "chain", "import_chain needs a chain blob",
+                 context="serving-migrate")
+    enforce_that(not engine._rings,
+                 "a chain carries full-attention pages only: an engine "
+                 "whose model has window layers cannot take one in",
                  context="serving-migrate")
     now = engine._time() if now is None else now
     sched = engine.scheduler

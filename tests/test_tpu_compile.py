@@ -440,6 +440,86 @@ def test_block_moe_serving_step_compiles_for_v5e_at_published_widths(
     assert mem.temp_size_in_bytes < 200e6
 
 
+# ---- a serving step of the window-and-full expert model ----------------------
+
+@pytest.mark.parametrize("pb", [0, 1024])
+def test_window_moe_serving_step_compiles_for_v5e_at_published_widths(
+        topo, monkeypatch, pb):
+    """``serving.WindowMoeLM`` behind ``ServingEngine`` at the cell's
+    widths (hidden 2048, 48 and 64 query heads on 8 KV heads of 128, a
+    512-token window, 128 of 256 experts of 512 top-8 with a shared one,
+    the whole vocabulary; the dense layer, two window layers and a full
+    one), 32 slots, the decode-only step and the one with the 1024-row
+    prefill bucket: both lower and compile for a described v5e with the
+    ragged kernel at GQA groups of 6 and of 8, the window layers' calls
+    on their rings; the experts' float32 matrices go into ``moe_gmm`` as
+    they lie."""
+    from paddle_tpu.analysis import retrace
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.serving import ServingEngine, WindowMoeLM
+    from paddle_tpu.serving import decode_attention as da
+    from paddle_tpu.serving import engine as eng_mod
+
+    monkeypatch.setattr(da, "_interpret_default", lambda: False)
+    monkeypatch.setattr(gm, "interpret_default", lambda: False)
+    monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
+    monkeypatch.setattr(eng_mod, "attention_path", lambda *a, **k: "kernel")
+    make_pool = eng_mod.init_kv_pages
+    monkeypatch.setattr(
+        eng_mod, "init_kv_pages",
+        lambda cfg, **kw: jax.eval_shape(lambda: make_pool(cfg, **kw)))
+    yarn = {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 4096, "beta_slow": 1,
+            "beta_fast": 64, "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5}
+    model = WindowMoeLM(
+        vocab_size=100352, embed_dim=2048, layer_heads=[48, 64, 64, 48],
+        layer_windows=[None, 512, 512, None],
+        layer_sparse=[False, True, True, True], num_kv_heads=8, head_dim=128,
+        dense_dim=8192, num_experts=256, held=(0, 128), experts_per_token=8,
+        expert_dim=512, shared_dim=512, routed_scaling=2.5, rope_full=yarn,
+        rope_window={"rope_type": "default", "rope_theta": 10000,
+                     "partial_rotary_factor": 1})
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    params = {k: aval(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init_params, jax.random.PRNGKey(0)).items()}
+    eng = ServingEngine(model, params, eos_id=model.vocab_size,
+                        page_size=128, max_slots=32, pool_bytes=1 << 31,
+                        max_pages_per_seq=130, buckets=(1024,),
+                        prefill_chunk=512)
+    (ring,) = eng._rings
+    assert eng._ragged_kernel and eng._k1 == 1 and ring.ring_pages == 9
+    assert eng.kv_cfg.num_layers == 2 and ring.cfg.num_layers == 2
+    buf = eng._empty_tick(pb, 1)
+    words = np.zeros(2 * (32 + 32) + 6, np.int32)
+    pools = [jax.tree.map(lambda a: aval(a.shape, a.dtype), kv)
+             for kv in (eng._kv,) + eng._ring_kv]
+    compiled = eng._step_fn(pb, 1).lower(
+        params, pools[0], aval(buf.shape, buf.dtype),
+        aval(words.shape, words.dtype), *pools[1:]).compile()
+    text = compiled.as_text()
+    # a layer: the ragged attention kernel, and the three grouped products
+    # of the three expert layers
+    assert text.count("tpu_custom_call") == 4 + 3 * 3
+    for scope in ("attn.full", "attn.window", "moe.route", "moe.experts",
+                  "moe.shared", "ffn.dense"):
+        assert scope + "/" in text, scope
+    for shape in ("[128,2048,512]", "[128,512,2048]"):
+        made = [line for line in text.splitlines() if " = " in line
+                and shape in line.split(" = ")[1].split("(")[0]
+                and "parameter(" not in line]
+        assert not made, made[:2]
+    out = jax.eval_shape(eng._step_fn(pb, 1), params, eng._kv, buf, words,
+                         *eng._ring_kv)
+    assert out[0].shape == (2 * 64 + 6,) and out[0].dtype == jnp.int32
+    assert out[1].shape == (64, 100352)          # the logits stay behind
+    assert len(out) == 4                         # ... the pool and the ring
+    mem = compiled.memory_analysis()
+    print("window moe step", pb, "temp bytes", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 300e6
+
+
 # ---- names in the device trace ---------------------------------------------
 
 def _kernel_names(fn, *avals):
