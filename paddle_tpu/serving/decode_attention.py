@@ -15,24 +15,38 @@ ragged invocation:
   padding) drive ONE causal/offset mask — ``token t is visible to the
   row at position p iff t <= p`` — which subsumes decode length masking,
   in-chunk causality, and cached-prefix offsets.
-- **Scalar-prefetched page tables.**  For the pallas path the rows are
-  packed into blocks of :data:`BLOCK_ROWS` with one sequence per block;
-  the per-block sequence id, the page tables, and the KV lengths ride in
-  as scalar-prefetch operands so the K/V BlockSpec index maps chase the
-  ragged page chain and DMA exactly the pages each block's sequence
-  owns, page j+1's fetch overlapping page j's compute.  Dead pages are
-  skipped with ``pl.when`` AND their index maps clamp to the last live
-  page, so the revisiting optimisation elides the dead DMAs.
+- **A walk of live visits.**  For the pallas path the rows are packed so
+  that every aligned :data:`BLOCK_ROWS` rows belong to one sequence, and
+  stand in RESIDENT blocks of two heights in the one call: a decoding
+  slot's rows in a short block of ``BLOCK_ROWS`` (its few rows ride along
+  while its context's pages stream past: the fetch sets the pace), the
+  rows of the prefill bucket in TALL blocks of :func:`tall_block_rows`
+  (64 rows at GQA groups of 6 and 8, 128 at group 1), so that
+  a page's K/V slab is fetched once for all the rows a chunk has in the
+  block and the two products run with hundreds of rows on the MXU.  The
+  grid's streamed axis is not a rectangle of pages but a SCHEDULE of
+  visits (:func:`visit_schedule`), made in XLA from the tick's own arrays
+  (``row_seq``, ``qpos``, ``kv_lens``, the page table) and
+  scalar-prefetched: a visit is (resident block, page of one sequence,
+  first / last flags), a block lists the pages its live rows can see and
+  no other (none past a sequence's length, none above a chunk block's
+  last row), and the axis' extent is the traced number of visits.  The
+  index maps take the block and the pool page from the schedule, page
+  j+1's fetch overlapping page j's compute; the kind of block a visit
+  does not work on stands still, so nothing is fetched for it.  A tall
+  block may hold rows of several sequences (chunks start at any multiple
+  of ``BLOCK_ROWS``): it works on one sequence a visit, the other rows
+  masked and left as they are.
 - **GQA head-group packing.**  The grid's head axis runs over KV heads,
-  not query heads: a block of ``BLOCK_ROWS * group`` query rows (group =
-  ``num_heads // num_kv_heads``) is packed against each K/V page load,
+  not query heads: a resident block's rows times ``group`` query heads
+  (``num_heads // num_kv_heads``) are packed against each K/V page load,
   so K/V HBM traffic drops by the group factor — the pool stores KV
   heads only.
 - **The pool where it lies.**  The kernel's K and V operands are the
   pool's own leaves, stored ``[L, pages, page, KVH * D]``
   (``kv_cache.KVPages``) and addressed as ``[L * pages, page,
   KVH * D]`` — a merge of leading, untiled dims, free on a TPU — with
-  the layer as a fourth scalar-prefetch operand: the index maps add
+  the layer riding with the scalar-prefetch operands: the index maps add
   ``layer * pages`` to the page id.  So the compiled serving step
   neither slices a layer out of the pool nor re-tiles it; the layer is
   a traced operand, so one lowering of the kernel serves all ``L``
@@ -42,8 +56,8 @@ ragged invocation:
   ``(page, KVH * D)`` slab is contiguous in the pool, so a cell takes
   ``hb`` KV heads at once (:func:`heads_per_cell`: as many as fit the
   VMEM budget — all of them at the widths served so far): ONE DMA of
-  the slab where there were ``hb`` strided ones, the page's liveness
-  and the mask evaluated once, then the per-head online-softmax update
+  the slab where there were ``hb`` strided ones, the mask evaluated
+  once, then the per-head online-softmax update
   for each head over its lane slice ``[:, h * D:(h + 1) * D]``.  Per
   head the arithmetic and its order over pages are those of ``hb = 1``,
   bit for bit; only the number of grid steps changes.
@@ -51,11 +65,9 @@ ragged invocation:
   position ``p`` sees token ``t`` iff ``p - window < t <= p``, and the
   page table is read as a RING: page ``a`` of a sequence lives at entry
   ``a mod width`` (``kv_cache.WindowRing``; a full-width table never
-  wraps).  The page axis of the grid then starts at the block's first
-  live page: two more scalar-prefetch operands give each row block its
-  first and last live page (from its rows' positions), the axis has only
-  as many steps as a block's rows can see pages, and a page below every
-  row's window is neither fetched nor a grid step.
+  wraps).  A block's visits of a sequence then start at the first page
+  the window of its lowest row reaches: a page below every row's window
+  is neither fetched nor a grid step.
 - **int8 pages, dequant in-register.**  Quantized pools ship per-token,
   per-kv-head f32 scales next to the int8 pages; the kernel (and the
   gather fallback — see ``kv_cache.dequantize_kv``, the ONE shared
@@ -64,9 +76,9 @@ ragged invocation:
 Two paths with identical semantics, selected by :func:`attention_path`
 — the single dispatch gate every paged-attention call routes through:
 
-- **Pallas kernel**: grid ``(row_blocks, kv_heads / hb, pages)``,
-  online-softmax carry (m, l, acc) per head in VMEM scratch across the
-  page axis.
+- **Pallas kernel**: grid ``(kv_heads / hb, visits)``, online-softmax
+  carry (m, l, acc) per head in VMEM scratch from a resident block's
+  first visit to its last.
 - **Reference path** (CPU/interpreter fallback and the parity oracle):
   page-table gather + masked softmax in f32 — no new math to trust,
   reading the SAME stored (possibly quantized) values.
@@ -80,14 +92,15 @@ dispatch.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.attention import DEFAULT_MASK_VALUE, _dim_semantics
+from paddle_tpu.ops.attention import DEFAULT_MASK_VALUE
 from paddle_tpu.ops.kernel_util import interpret_default as _interpret_default
 from paddle_tpu.platform.enforce import enforce_that
 from paddle_tpu.serving.kv_cache import (KVPages, dequantize_kv,
@@ -237,168 +250,498 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 
 # ---------------------------------------------------------------------------
+# The walk: a schedule of live (resident row block, page) visits
+# ---------------------------------------------------------------------------
+
+# a visit's flags (``Visits.flags``): it is its resident block's first
+# (the carries start), its last (the block is written), it computes (a
+# block nobody's row can see a page of keeps ONE visit that does not, so
+# that its output is still written, as zeros), its block is a tall one
+_FIRST, _LAST, _COMPUTE, _TALL = 1, 2, 4, 8
+
+
+class Visits(NamedTuple):
+    """The kernel's walk, one entry a grid step (int32 ``[extent]``; the
+    first ``count`` are steps, the tail is never run).  ``block``: the
+    resident block the step works on, the short blocks numbered before the
+    tall ones (the index maps hold the kind it does not work on still:
+    nothing is fetched for it); ``at``: which page of its sequence the
+    step streams (the tokens' positions; the index maps look the pool's
+    page up in the page table); ``seq``: the sequence (a tall block may
+    hold rows of several, and works on one a visit)."""
+
+    flags: jax.Array
+    block: jax.Array
+    at: jax.Array
+    seq: jax.Array
+    count: jax.Array               # int32 scalar: the steps of the walk
+
+
+class Walk(NamedTuple):
+    """All a kernel call needs of a tick's rows: the schedule of visits
+    and, as sublane columns (``[blocks, rows * group, 1]``: a score row's
+    value beside its (rows, page) scores without a layout change), the
+    positions of the short blocks' rows, and the positions and sequences
+    of the tall blocks' (a kind the call has no block of: no rows)."""
+
+    visits: Visits
+    short_pos: jax.Array
+    tall_pos: jax.Array
+    tall_seq: jax.Array
+
+
+def _most_pages(rows: int, page: int, width: int,
+                window: Optional[int]) -> int:
+    """The most pages one sequence's ``rows`` consecutive positions in a
+    resident block can see: the table's ``width``, or what their windows
+    reach."""
+    return width if window is None else window_pages(window, rows, page,
+                                                     width)
+
+
+def visit_extent(short_blocks: int, tall_blocks: int, tall_rows: int,
+                 page: int, width: int, window: Optional[int]) -> int:
+    """The static length of a walk's arrays: the most visits its blocks
+    can have, a short block one sequence's, a tall block those of up to
+    ``tall_rows / BLOCK_ROWS`` sequences."""
+    return (short_blocks * _most_pages(BLOCK_ROWS, page, width, window) +
+            tall_blocks * (tall_rows // BLOCK_ROWS) *
+            _most_pages(tall_rows, page, width, window))
+
+
+def _runs(xp, row_seq, qpos, kv_lens, *, decode_rows: int, tall_rows: int,
+          page: int, width: int, window: Optional[int]):
+    """The runs of a walk (:func:`visit_schedule` has the rule), one for
+    each ``BLOCK_ROWS`` rows: ``(seq, first, n, lone, block)``, the run's
+    sequence, its first page, how many pages it visits (0 for rows that
+    ride in the run their block's leading rows make), whether it is the
+    one step of a block that visits none, and the resident block it
+    belongs to (static).  ``xp``: ``jnp`` on the device, ``numpy`` for the
+    host's count of the same walk (:func:`visit_counts`)."""
+    t = qpos.shape[0]
+    nb, nd = t // BLOCK_ROWS, decode_rows // BLOCK_ROWS
+    per_tall = tall_rows // BLOCK_ROWS
+    nc = (nb - nd) // per_tall
+    big = np.iinfo(np.int32).max
+    qb = qpos.reshape(nb, BLOCK_ROWS)
+    seq = row_seq.reshape(nb, BLOCK_ROWS)[:, 0]
+    lo = xp.min(xp.where(qb >= 0, qb, big), axis=1)
+    hi = xp.max(qb, axis=1)
+    leads = np.ones((nd,), bool)
+    at = np.arange(per_tall)
+    if nc:
+        # inside a tall block: the rows of one sequence are one run, led
+        # by the first BLOCK_ROWS of them
+        sq = seq[nd:].reshape(nc, per_tall)
+        same = sq[:, :, None] == sq[:, None, :]
+        lo = xp.concatenate([lo[:nd], xp.min(xp.where(
+            same, lo[nd:].reshape(nc, 1, per_tall), big), axis=2).ravel()])
+        hi = xp.concatenate([hi[:nd], xp.max(xp.where(
+            same, hi[nd:].reshape(nc, 1, per_tall), -1), axis=2).ravel()])
+        leads = xp.concatenate([leads, ~xp.any(
+            same & (at[None, :] < at[:, None]), axis=2).ravel()])
+    # (a table of a few entries is looked up by comparison: a gather out
+    # of it costs the TPU more than the whole walk's arithmetic)
+    slot = np.arange(kv_lens.shape[0])
+    hi = xp.minimum(hi, xp.sum(xp.where(
+        seq[:, None] == slot, kv_lens[None, :], 0), axis=1) - 1)
+    first = xp.zeros((nb,), np.int32) if window is None \
+        else xp.maximum(lo - window + 1, 0) // page
+    most = np.concatenate([
+        np.full(nd, _most_pages(BLOCK_ROWS, page, width, window)),
+        np.full(nb - nd, _most_pages(tall_rows, page, width, window))]
+    ).astype(np.int32)
+    n = xp.where(leads & (hi >= 0) & (lo <= hi),
+                 xp.minimum(hi // page - first + 1, most), 0)
+    first = xp.where(n > 0, first, 0)
+    # the one visit of a block that has none
+    lone = n[:nd] == 0
+    if nc:
+        idle = xp.sum(n[nd:].reshape(nc, per_tall), axis=1) == 0
+        lone = xp.concatenate([lone, (idle[:, None] & (at == 0)).ravel()])
+    block = np.concatenate([np.arange(nd), nd + np.arange(nb - nd)
+                            // per_tall]).astype(np.int32)
+    return seq, first, n, lone, block
+
+
+def visit_schedule(row_seq, qpos, kv_lens, *, decode_rows: int,
+                   tall_rows: int, page: int, width: int,
+                   window: Optional[int] = None) -> Visits:
+    """The walk of one call, made in XLA from the tick's own arrays (once
+    for every head group and, the arrays being a tick's, for every layer
+    of a kind).
+
+    Rows ``[0, decode_rows)`` stand in SHORT resident blocks of
+    :data:`BLOCK_ROWS` rows (a decoding slot's), the rows behind them in
+    TALL blocks of ``tall_rows`` (both multiples of ``BLOCK_ROWS``; every
+    aligned ``BLOCK_ROWS`` rows belong to one sequence).  A RUN is what
+    one sequence has in one resident block: a short block is one run, a
+    tall block has one a sequence with rows in it.  A run visits the
+    pages its live rows can see and no other: from page 0 (under a
+    ``window``: from the first page its lowest row's window reaches) to
+    the page of its highest row, the causal bound of THAT run, never past
+    the sequence's length.  Runs follow each other by block; a block with
+    no visit of its own gets one that computes nothing."""
+    nb, nd = qpos.shape[0] // BLOCK_ROWS, decode_rows // BLOCK_ROWS
+    seq, first, n, lone, block = _runs(
+        jnp, row_seq, qpos, kv_lens, decode_rows=decode_rows,
+        tall_rows=tall_rows, page=page, width=width, window=window)
+    steps = n + lone
+    # where each run's steps end (a running sum, as a triangle of sums:
+    # one pass), and what a visit takes from its run, found by comparison
+    # and not by a gather: runs of no step are passed over
+    run = np.arange(nb)
+    end = jnp.sum(jnp.where(run[None, :] <= run[:, None], steps[None, :], 0),
+                  axis=1)
+    start, count = end - steps, end[-1].astype(jnp.int32)
+    extent = visit_extent(nd, (nb - nd) * BLOCK_ROWS // tall_rows, tall_rows,
+                          page, width, window)
+    tt = jnp.arange(extent, dtype=jnp.int32)
+    mine = (start[None, :] <= tt[:, None]) & (tt[:, None] < end[None, :])
+    seq, page_at, res, computes = jnp.sum(jnp.where(
+        mine[:, :, None], jnp.stack(
+            [seq, first - start, jnp.asarray(block), n > 0], axis=1)[None],
+        0), axis=1).T
+    edge = jnp.ones((1,), bool)
+    turns = res[1:] != res[:-1]
+    flags = (_FIRST * jnp.concatenate([edge, turns]) +
+             _LAST * (jnp.concatenate([turns, edge]) | (tt == count - 1)) +
+             _COMPUTE * computes + _TALL * (res >= nd))
+    return Visits(flags, res, page_at + tt, seq, count)
+
+
+def _tall_padded(xp, row_seq, qpos, decode_rows: int, tall: int):
+    """``row_seq`` and ``qpos`` with the rows behind the short blocks
+    padded to whole tall blocks (rows that see nothing)."""
+    pad = -(qpos.shape[0] - decode_rows) % tall
+    if not pad:
+        return row_seq, qpos
+    return (xp.concatenate([row_seq, xp.zeros((pad,), np.int32)]),
+            xp.concatenate([qpos, xp.full((pad,), -1, np.int32)]))
+
+
+def visit_counts(row_seq, qpos, kv_lens, *, decode_rows: int, tall_rows: int,
+                 page: int, width: int, window: Optional[int] = None):
+    """The host's count of the walk :func:`visit_schedule` makes of the
+    same (numpy) arrays, for one KV-head group of one layer: ``(visits,
+    computing, pages)``: the grid steps, those of them that fetch a page
+    and compute, and the distinct (sequence, page) pairs among them, which
+    is what a walk that read every page once would fetch.  The rows behind
+    ``decode_rows`` are padded to whole tall blocks as the call pads
+    them."""
+    row_seq, qpos = _tall_padded(np, row_seq, qpos, decode_rows, tall_rows)
+    seq, first, n, lone, _ = _runs(
+        np, row_seq, qpos, kv_lens, decode_rows=decode_rows,
+        tall_rows=tall_rows, page=page, width=width, window=window)
+    # a sequence's pages: the union of its runs' [first, first + n)
+    edges = np.zeros((len(kv_lens), int((first + n).max()) + 1), np.int32)
+    np.add.at(edges, (seq, first), n > 0)
+    np.subtract.at(edges, (seq, first + n), n > 0)
+    pages = int((np.cumsum(edges, axis=1) > 0).sum())
+    return int((n + lone).sum()), int(n.sum()), pages
+
+
+# ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel(blk_seq_ref, pt_ref, len_ref, layer_ref, *rest,
-                   page_size: int, num_pb: int, hb: int, sm_scale: float,
-                   quantized: bool, window: Optional[int] = None):
-    # grid (row_blocks, kv_head_groups, pages-per-seq), ``hb`` KV heads
-    # a group: the page axis is streamed; every head's (m, l, acc)
-    # persist in VMEM scratch across it.  blk_seq/pt/len are the
-    # scalar-prefetched block→sequence map [NB], page table [S, Pm] and
-    # KV lengths [S] (SMEM); layer_ref [1] rides with them for the
-    # index maps alone (the body never reads it).  qpos_ref:
-    # (1, RBG, 1) — per-score-row absolute positions, already
-    # group-expanded, as a sublane column so the mask broadcasts over
-    # the (RBG, page) scores without a layout change.  q_ref/o_ref: (hb, 1, RBG, D); k_ref/v_ref:
-    # (1, page, hb * D) — the group's lane slab of one page, contiguous
-    # in the pool (the whole page when hb is all heads), head h of the
-    # group at lanes [h * D, (h + 1) * D); quantized adds ks/vs
-    # (1, page, KVH) scale blocks (all KV heads: a (page, 1) block is
-    # not a legal TPU tile).  Scratch: m/l (hb, RBG, LANES), acc
-    # (hb, RBG, D).  The body tests the page's liveness and builds the
-    # mask once, then runs the per-head update hb times, unrolled.
-    # With a ``window``, two more scalar-prefetch operands lead ``rest``:
-    # the block's first and last live page [NB] (its page axis starts at
-    # the first: step j is page ``first + j``), and the mask gains the
-    # window's lower bound.
-    first_ref = last_ref = None
-    if window is not None:
-        first_ref, last_ref, *rest = rest
-    qpos_ref, q_ref, k_ref, v_ref, *rest = rest
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    ib = pl.program_id(0)
-    hg = pl.program_id(1)
-    j = pl.program_id(2)
-    rbg, d = q_ref.shape[2:]
+def _ragged_kernel(flag_ref, block_ref, at_ref, seq_ref, pt_ref, layer_ref,
+                   *rest, page_size: int, hb: int,
+                   sm_scale: float, quantized: bool, shorts: bool,
+                   talls: bool, window: Optional[int] = None):
+    # grid (kv_head_groups, visits), ``hb`` KV heads a group: a step is one
+    # visit of the walk (``Visits``, scalar-prefetched with the page table
+    # and the layer: the body reads the flags, the page's place in its
+    # sequence and the sequence; the index maps the rest).  A resident block's (m, l, acc)
+    # persist in VMEM scratch from its first visit to its last.
+    # Operands, each kind only where the call has blocks of it: for the
+    # SHORT blocks qpos (1, RS, 1), the score rows' absolute positions,
+    # group-expanded, as a sublane column so that the mask broadcasts
+    # over the (rows, page) scores without a layout change, and q
+    # (hb, 1, RS, D), RS = BLOCK_ROWS * group; for the TALL blocks qpos
+    # and the rows' sequences (1, RT, 1) and q (hb, 1, RT, D), RT =
+    # tall_rows * group; then k/v (1, page, hb * D), the group's lane slab
+    # of one page, contiguous in the pool, head h at lanes [h * D,
+    # (h + 1) * D); quantized adds ks/vs (1, page, KVH) scale blocks (all
+    # KV heads: a (page, 1) block is not a legal TPU tile); one output a
+    # kind, as its q.  Scratch: m/l (hb, R, LANES), acc (hb, R, D) at the
+    # taller kind's rows; a short block uses the first RS.
+    args = list(rest)
+    short = [args.pop(0) for _ in range(2)] if shorts else None
+    tall = [args.pop(0) for _ in range(3)] if talls else None
+    k_ref, v_ref = args.pop(0), args.pop(0)
+    ks_ref, vs_ref = (args.pop(0), args.pop(0)) if quantized else (None,) * 2
+    if shorts:
+        short.append(args.pop(0))
+    if talls:
+        tall.append(args.pop(0))
+    m_scr, l_scr, acc_scr = args
+    hg = pl.program_id(0)
+    step = pl.program_id(1)
+    flags = flag_ref[step]
+    d = acc_scr.shape[2]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def visit(rows, qpos_of, q_ref, o_ref, shared: bool = False):
+        # one visit of a resident block of ``rows`` score rows, whose
+        # positions ``qpos_of()`` gives ((rows, 1); -1: the row sees
+        # nothing).  ``shared`` (tall blocks): a row the page is not for
+        # keeps what it has
+        @pl.when((flags & _FIRST) != 0)
+        def _init():
+            m_scr[:, :rows] = jnp.full((hb, rows, _LANES), -jnp.inf,
+                                       jnp.float32)
+            l_scr[:, :rows] = jnp.zeros((hb, rows, _LANES), jnp.float32)
+            acc_scr[:, :rows] = jnp.zeros((hb, rows, d), jnp.float32)
 
-    if window is None:
-        at = j
-        n = len_ref[blk_seq_ref[ib]]
-        live = j * page_size < n
-    else:
-        at = first_ref[ib] + j
-        live = at <= last_ref[ib]
-
-    @pl.when(live)
-    def _compute():
-        tok = at * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rbg, page_size), 1)
-        # ONE inequality is the whole mask: causal for prefill rows,
-        # length for decode rows, everything for padded rows (qpos −1)
-        seen = tok <= qpos_ref[0]
-        if window is not None:
-            seen = seen & (tok > qpos_ref[0] - window)
-        if quantized:
-            # in-register dequant: HBM traffic stays 1 byte/element.
-            # A KV head's scale column is picked out of the
-            # (page, KVH) block with a masked lane reduction.
-            ksc, vsc = ks_ref[0], vs_ref[0]
-            lane = jax.lax.broadcasted_iota(jnp.int32, ksc.shape, 1)
-
-            def head_scale(sc, head):
-                return jnp.sum(jnp.where(lane == head, sc, 0.0),
-                               axis=1, keepdims=True)      # (page, 1)
-        for h in range(hb):
-            q = q_ref[h, 0]                        # (RBG, D)
-            kb = k_ref[0, :, h * d:(h + 1) * d]    # (page, D)
-            vb = v_ref[0, :, h * d:(h + 1) * d]
+        @pl.when((flags & _COMPUTE) != 0)
+        def _compute():
+            qpos = qpos_of()
+            tok = at_ref[step] * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page_size), 1)
+            # ONE inequality is the whole mask: causal for prefill rows,
+            # length for decode rows, everything for padded rows (qpos −1)
+            seen = tok <= qpos
+            if window is not None:
+                seen = seen & (tok > qpos - window)
+            if shared:
+                own = jnp.broadcast_to(qpos, (rows, d)) >= 0
             if quantized:
-                kb = kb.astype(jnp.float32) * head_scale(ksc, hg * hb + h)
-                vb = vb.astype(jnp.float32) * head_scale(vsc, hg * hb + h)
-            s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = s * sm_scale                       # (RBG, page)
-            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+                # in-register dequant: HBM traffic stays 1 byte/element.
+                # A KV head's scale column is picked out of the
+                # (page, KVH) block with a masked lane reduction.
+                ksc, vsc = ks_ref[0], vs_ref[0]
+                lane = jax.lax.broadcasted_iota(jnp.int32, ksc.shape, 1)
 
-            m_prev = jnp.max(m_scr[h], axis=1, keepdims=True)
-            l_prev = jnp.max(l_scr[h], axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+                def head_scale(sc, head):
+                    return jnp.sum(jnp.where(lane == head, sc, 0.0),
+                                   axis=1, keepdims=True)      # (page, 1)
+            for h in range(hb):
+                q = q_ref[h, 0]                        # (rows, D)
+                kb = k_ref[0, :, h * d:(h + 1) * d]    # (page, D)
+                vb = v_ref[0, :, h * d:(h + 1) * d]
+                if quantized:
+                    kb = kb.astype(jnp.float32) * head_scale(ksc,
+                                                             hg * hb + h)
+                    vb = vb.astype(jnp.float32) * head_scale(vsc,
+                                                             hg * hb + h)
+                s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                s = s * sm_scale                       # (rows, page)
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
 
-    @pl.when(j == num_pb - 1)
-    def _finalize():
-        for h in range(hb):
-            l = jnp.max(l_scr[h], axis=1, keepdims=True)
-            l = jnp.where(l == 0.0, 1.0, l)  # length-0 rows -> zeros, not NaN
-            o_ref[h, 0] = (acc_scr[h] / l).astype(o_ref.dtype)
+                m_prev = jnp.max(m_scr[h, :rows], axis=1, keepdims=True)
+                l_prev = jnp.max(l_scr[h, :rows], axis=1, keepdims=True)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc_scr[h, :rows] * alpha + jax.lax.dot_general(
+                    p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if shared:
+                    # (another sequence's page leaves no trace in a row,
+                    # not even a NaN times zero)
+                    acc = jnp.where(own, acc, acc_scr[h, :rows])
+                acc_scr[h, :rows] = acc
+                m_scr[h, :rows] = jnp.broadcast_to(m_new, (rows, _LANES))
+                l_scr[h, :rows] = jnp.broadcast_to(l_new, (rows, _LANES))
+
+        @pl.when((flags & _LAST) != 0)
+        def _finalize():
+            for h in range(hb):
+                l = jnp.max(l_scr[h, :rows], axis=1, keepdims=True)
+                l = jnp.where(l == 0.0, 1.0, l)  # no page seen: zeros, not NaN
+                o_ref[h, 0] = (acc_scr[h, :rows] / l).astype(o_ref.dtype)
+
+    if shorts:
+        qpos_ref, q_ref, o_ref = short
+        pl.when((flags & _TALL) == 0)(functools.partial(
+            visit, q_ref.shape[2], lambda: qpos_ref[0], q_ref, o_ref))
+    if talls:
+        tpos_ref, tseq_ref, tq_ref, to_ref = tall
+        # a tall block works on ONE sequence a visit: the rows of the
+        # others stand as rows that see nothing
+        pl.when((flags & _TALL) != 0)(functools.partial(
+            visit, tq_ref.shape[2],
+            lambda: jnp.where(tseq_ref[0] == seq_ref[step], tpos_ref[0], -1),
+            tq_ref, to_ref, shared=True))
+
+
+# What a call's resident blocks and K/V slabs may take of a core's VMEM
+# (a TPU v5e's is 128 MiB; a Mosaic kernel is scoped to 16 MiB of it
+# unless it asks), and a tall block's height: the score rows (rows x group)
+# it aims at and the most rows it takes.  A visit's products grow with the
+# block's rows and its fetch does not: from some 128 score rows a KV head
+# up the products hide the fetch (PERF.md s6, PR 42: a visit of 384 score
+# rows x 8 heads takes 4.0 us, a page's 1 MB 1.3 us), and up to 384-512
+# the MXU and the step's fixed cost are still better used; beyond, a block
+# only wastes more on the rows of the sequences a visit is not for.
+_VMEM_LIMIT = 48 << 20
+_TALL_SCORE_ROWS = 512
+_TALL_ROWS_MOST = 128
+
+
+def tall_block_rows(group: int, hb: int, head_dim: int) -> int:
+    """``C``: the rows of a tall resident block, a power of two times
+    :data:`BLOCK_ROWS` (chunks and buckets are, so blocks and chunks end
+    together) and a pure function of shapes as :func:`heads_per_cell` is:
+    ``_TALL_SCORE_ROWS / group`` score rows a KV head, at most
+    ``_TALL_ROWS_MOST`` rows (128 rows at group 1 and 2, 64 at groups of
+    6 and 8), fewer where q and the output (double-buffered), the three
+    carries and the two row columns of ``hb`` heads would not fit beside
+    the K/V slabs' budget and the body's scores (q counted at four bytes
+    an element, whatever it has)."""
+    room = _VMEM_LIMIT - _KV_VMEM_BUDGET - (8 << 20)
+    per_row = group * (hb * (2 * 2 * head_dim * 4 +
+                             (2 * _LANES + head_dim) * 4) +
+                       2 * 2 * _LANES * 4)
+    rows = min(_TALL_ROWS_MOST, _TALL_SCORE_ROWS // group, room // per_row)
+    return max(BLOCK_ROWS, 1 << (int(rows).bit_length() - 1))
 
 
 def _ragged_pallas(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
                    kv_lens, row_seq, qpos, sm_scale, interpret: bool,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None, decode_rows: int = 0,
+                   walk: Optional[Walk] = None):
     """Kernel-path entry, on the STORED pool (``[L, pages, page,
     KVH * D]``) and a layer index.  REQUIRES block-uniform packing: T a
     multiple of :data:`BLOCK_ROWS` and every aligned block of rows
     belonging to ONE sequence (callers pad each sequence's rows to the
-    block size — decode slots to one block, chunks to whole blocks).
-    The block map is read as ``row_seq[::BLOCK_ROWS]``; rows that
-    violate uniformity would silently attend over the wrong pages, so
-    the engine owns the packing and tests pin it against the reference
-    path.  With a ``window`` the live rows of a block lie within
-    :data:`BLOCK_ROWS` consecutive positions (as the engine packs them: a
-    chunk's rows follow each other, a slot's verify rows too), which is
-    what bounds the pages a block can see."""
+    block size — decode slots to one block, chunks to whole blocks);
+    rows that violate uniformity would silently attend over the wrong
+    pages, so the engine owns the packing and tests pin it against the
+    reference path.  The first ``decode_rows`` rows (static; a multiple
+    of ``BLOCK_ROWS``) are walked in resident blocks of ``BLOCK_ROWS``
+    (a decoding slot's few rows against its whole context: the page
+    fetch sets the pace and the rows ride along); the rows behind them
+    in TALL blocks of :func:`tall_block_rows` rows, each page of a
+    sequence fetched once for all the rows the sequence has in the
+    block (a prefill chunk's: the products set the pace).  A tall block
+    may hold rows of several sequences and works on one a visit.  With
+    a ``window`` the live rows one sequence has in a block lie within
+    as many consecutive positions as the block has rows (as the engine
+    packs them: a chunk's rows follow each other, a slot's verify rows
+    too), which is what bounds the pages a visit list can hold.  ``walk``:
+    the call's walk where the caller has made it (:func:`ragged_walk`)."""
     t, h, d = q.shape
     page, kvh = k_pool.shape[2], k_pool.shape[3] // d
-    enforce_that(t % BLOCK_ROWS == 0,
-                 f"ragged kernel rows ({t}) must pack to BLOCK_ROWS "
-                 f"({BLOCK_ROWS})", context="serving")
+    enforce_that(t % BLOCK_ROWS == 0 and decode_rows % BLOCK_ROWS == 0
+                 and 0 <= decode_rows <= t,
+                 f"ragged kernel rows ({t}, of them {decode_rows} in short "
+                 f"blocks) must pack to BLOCK_ROWS ({BLOCK_ROWS})",
+                 context="serving")
     enforce_that(h % kvh == 0, f"num_heads ({h}) must be a multiple of "
                  f"num_kv_heads ({kvh})", context="serving")
-    hb = heads_per_cell(kvh, page, d, k_pool.dtype.itemsize,
-                        k_scale is not None)
+    hb, tall = _cell_shape(t - decode_rows, h, kvh, d, page,
+                           k_pool.dtype.itemsize, k_scale is not None)
+    if walk is None:
+        walk = _walk(kv_lens, row_seq, qpos, tall=tall, group=h // kvh,
+                     page=page, width=page_table.shape[1],
+                     decode_rows=decode_rows, window=window)
     # the layer is an OPERAND, never a static argument: one trace and
     # one lowering of the kernel then serve every layer of a step
     return _ragged_call(q, k_pool, v_pool, k_scale, v_scale,
-                        jnp.asarray(layer, jnp.int32).reshape(1), page_table,
-                        kv_lens, row_seq, qpos, hb=hb, sm_scale=sm_scale,
-                        interpret=interpret, window=window)
+                        jnp.asarray(layer, jnp.int32).reshape(1),
+                        page_table.astype(jnp.int32), walk, hb=hb,
+                        sm_scale=sm_scale, interpret=interpret, window=window,
+                        decode_rows=decode_rows, tall=tall)
+
+
+def tall_rows_for(region: int, group: int, hb: int, head_dim: int) -> int:
+    """The rows of a call's tall blocks: :func:`tall_block_rows`, and never
+    more than the ``region`` behind the short blocks holds (a call with no
+    such rows has no tall block, whatever this says)."""
+    return max(BLOCK_ROWS, min(tall_block_rows(group, hb, head_dim), region))
+
+
+def _cell_shape(tall_region: int, num_heads: int, num_kv_heads: int,
+                head_dim: int, page: int, kv_itemsize: int, quantized: bool):
+    """``(hb, tall)``: the KV heads a grid cell takes and the rows of a
+    tall block, for a call whose rows behind the short blocks are
+    ``tall_region``."""
+    hb = heads_per_cell(num_kv_heads, page, head_dim, kv_itemsize, quantized)
+    return hb, tall_rows_for(tall_region, num_heads // num_kv_heads, hb,
+                             head_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("tall", "group", "page", "width",
+                                             "decode_rows", "window"))
+def _walk(kv_lens, row_seq, qpos, *, tall: int, group: int, page: int,
+          width: int, decode_rows: int, window: Optional[int]) -> Walk:
+    row_seq, qpos = _tall_padded(jnp, row_seq.astype(jnp.int32),
+                                 qpos.astype(jnp.int32), decode_rows, tall)
+    visits = visit_schedule(row_seq, qpos, kv_lens.astype(jnp.int32),
+                            decode_rows=decode_rows, tall_rows=tall,
+                            page=page, width=width, window=window)
+
+    def columns(x, rows):
+        return jnp.repeat(x.reshape(-1, rows), group, axis=1)[..., None]
+
+    return Walk(visits, columns(qpos[:decode_rows], BLOCK_ROWS),
+                columns(qpos[decode_rows:], tall),
+                columns(row_seq[decode_rows:], tall))
+
+
+def ragged_walk(page_table, kv_lens, row_seq, qpos, *, num_heads: int,
+                num_kv_heads: int, head_dim: int, page_size: int,
+                kv_itemsize: int, quantized: bool = False,
+                decode_rows: int = 0,
+                window: Optional[int] = None) -> Walk:
+    """The walk a kernel call at these shapes makes of these rows
+    (:func:`visit_schedule`, and the rows' columns), for a caller with
+    several calls over the same rows: a step's layers of one kind share
+    the tick's arrays, so the engine makes the walk once and hands it to
+    each call (``ragged_paged_attention(walk=)``).  The head counts are
+    ONE chip's (under tensor parallelism a shard's: every shard walks the
+    same)."""
+    _, tall = _cell_shape(qpos.shape[0] - decode_rows, num_heads,
+                          num_kv_heads, head_dim, page_size, kv_itemsize,
+                          quantized)
+    return _walk(kv_lens, row_seq, qpos, tall=tall,
+                 group=num_heads // num_kv_heads, page=page_size,
+                 width=page_table.shape[1], decode_rows=decode_rows,
+                 window=window)
+
+
+def _score_rows(x, blocks: int, rows: int, kvh: int, g: int):
+    """``[blocks * rows, kvh * g, ...]`` -> ``[kvh, blocks, rows * g,
+    ...]``: a block's rows with the ``g`` query heads of a KV head next to
+    each other, so that one K/V page load feeds the whole head group."""
+    x = x.reshape(blocks, rows, kvh, g, *x.shape[2:])
+    x = jnp.moveaxis(x, 2, 0)
+    return x.reshape(kvh, blocks, rows * g, *x.shape[4:])
+
+
+def _rows_back(o, blocks: int, rows: int, kvh: int, g: int):
+    """The inverse of :func:`_score_rows`: ``[blocks * rows, kvh * g, D]``."""
+    o = o.reshape(kvh, blocks, rows, g, o.shape[-1])
+    return jnp.moveaxis(o, 0, 2).reshape(blocks * rows, kvh * g, o.shape[-1])
 
 
 # jitted on its own so that a step program of L layers traces and lowers
 # the kernel once and calls it L times: the body is unrolled over the
 # cell's heads, and Pallas lowers in Python in every process, persistent
 # compile cache or not — per layer that is seconds of set-up
-@functools.partial(jax.jit, static_argnames=("hb", "sm_scale", "interpret",
-                                             "window"))
+@functools.partial(jax.jit, static_argnames=(
+    "hb", "sm_scale", "interpret", "window", "decode_rows", "tall"))
 def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
-                 kv_lens, row_seq, qpos, *, hb: int, sm_scale: float,
-                 interpret: bool, window: Optional[int] = None):
+                 walk: Walk, *, hb: int, sm_scale: float, interpret: bool,
+                 window: Optional[int] = None, decode_rows: int = 0,
+                 tall: int = BLOCK_ROWS):
     t, h, d = q.shape
     _, pages, page, lanes = k_pool.shape
     kvh = lanes // d
-    pm = page_table.shape[1]
     g = h // kvh
-    nb = t // BLOCK_ROWS
-    rbg = BLOCK_ROWS * g
     quantized = k_scale is not None
+    nd, nc = walk.short_pos.shape[0], walk.tall_pos.shape[0]
+    # the rows behind the short blocks, to whole tall blocks
+    pad = decode_rows + nc * tall - t
+    if pad:
+        q = jnp.concatenate([q, jnp.zeros((pad, h, d), q.dtype)])
+    pm = page_table.shape[1]
+    prefetch = [*walk.visits[:4], page_table, layer]
 
-    blk_seq = row_seq.reshape(nb, BLOCK_ROWS)[:, 0].astype(jnp.int32)
-    qpos_rows = jnp.repeat(qpos.astype(jnp.int32).reshape(nb, BLOCK_ROWS),
-                           g, axis=1)[..., None]          # (NB, RBG, 1)
-    # [T, H, D] -> [KVH, NB, RB*G, D]: each block packs its G query
-    # heads per KV head next to each other, so one K/V page load feeds
-    # the whole head group
-    q5 = q.reshape(nb, BLOCK_ROWS, kvh, g, d).transpose(2, 0, 1, 3, 4)
-    q5 = q5.reshape(kvh, nb, rbg, d)
     # the pool [L, P, page, KVH*D] addressed as [L*P, page, KVH*D]: the
     # two tiled dims stay as they are, so this is no copy on a TPU, and
     # page p of the layer is row ``layer * P + p``.  The KV heads of
@@ -407,93 +750,93 @@ def _ragged_call(q, k_pool, v_pool, k_scale, v_scale, layer, page_table,
     # (row, 0, hg) with a legal (page, hb*D) tile and no transpose.
     kt = k_pool.reshape(-1, page, lanes)
     vt = v_pool.reshape(-1, page, lanes)
-    pt = page_table.astype(jnp.int32)
-    ln = kv_lens.astype(jnp.int32)
-
-    prefetch = [blk_seq, pt, ln, layer]
-    steps = pm
-    if window is not None:
-        # each block's first and last live page, from its rows' positions
-        # (a block with no live row: last < first, nothing is visited);
-        # the page axis starts at the first and is only as long as a
-        # block's rows can see pages
-        qb = qpos.astype(jnp.int32).reshape(nb, BLOCK_ROWS)
-        real = qb >= 0
-        lo = jnp.min(jnp.where(real, qb, jnp.iinfo(jnp.int32).max), axis=1)
-        hi = jnp.minimum(jnp.max(qb, axis=1), ln[blk_seq] - 1)
-        some = jnp.any(real, axis=1) & (hi >= 0)
-        first = jnp.where(some, jnp.maximum(lo - window + 1, 0) // page, 0)
-        last = jnp.where(some, hi // page, -1)
-        prefetch += [first.astype(jnp.int32), last.astype(jnp.int32)]
-        steps = window_pages(window, BLOCK_ROWS, page, pm)
 
     # TPU block shapes must end in (8k, 128k) or the array's own last
-    # two dims — every spec below is written to that rule
+    # two dims — every spec below is written to that rule.  An index
+    # map's arguments: head group, step, then the prefetched refs
 
-    def qpos_idx(ib, hg, j, *refs):
-        return (ib, 0, 0)
+    def short_at(s, block):
+        # (while the tall blocks are worked on: the last short one, still)
+        return jnp.minimum(block[s], nd - 1)
 
-    def q_idx(ib, hg, j, *refs):
-        return (hg, ib, 0, 0)
+    def tall_at(s, block):
+        # (while the short blocks are worked on: the first tall one)
+        return jnp.maximum(block[s] - nd, 0)
 
-    def live_row(ib, j, blk_ref, pt_ref, len_ref, layer_ref, *bounds):
-        # clamp dead pages (j past the block's sequence's last live
-        # page) to the last live one so their DMA is elided by
-        # revisiting; pl.when skips their compute.  max(len-1, 0) keeps
-        # length-0 sequences legal.
-        seq = blk_ref[ib]
-        if bounds:
-            # the table is a ring: page ``a`` at entry ``a mod width``
-            first_ref, last_ref = bounds
-            at = jnp.minimum(first_ref[ib] + j,
-                             jnp.maximum(last_ref[ib], first_ref[ib]))
-            return layer_ref[0] * pages + pt_ref[seq, at % pm]
-        last = jnp.maximum(len_ref[seq] - 1, 0) // page
-        return layer_ref[0] * pages + pt_ref[seq, jnp.minimum(j, last)]
+    def short_col(hg, s, flags, block, *refs):
+        return (short_at(s, block), 0, 0)
 
-    def kv_idx(ib, hg, j, *refs):
-        return (live_row(ib, j, *refs), 0, hg)
+    def short_blk(hg, s, flags, block, *refs):
+        return (hg, short_at(s, block), 0, 0)
 
-    def scale_idx(ib, hg, j, *refs):
-        return (live_row(ib, j, *refs), 0, 0)
+    def tall_col(hg, s, flags, block, *refs):
+        return (tall_at(s, block), 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, rbg, 1), qpos_idx),
-        pl.BlockSpec((hb, 1, rbg, d), q_idx),
-        pl.BlockSpec((1, page, hb * d), kv_idx),
-        pl.BlockSpec((1, page, hb * d), kv_idx),
-    ]
-    args = [qpos_rows, q5, kt, vt]
+    def tall_blk(hg, s, flags, block, *refs):
+        return (hg, tall_at(s, block), 0, 0)
+
+    def pool_row(s, flags, block, at, seq, table, layer_):
+        # the table is a ring under a window: page ``a`` at ``a mod width``
+        return layer_[0] * pages + table[seq[s], at[s] % pm]
+
+    def kv_idx(hg, s, *refs):
+        return (pool_row(s, *refs), 0, hg)
+
+    def scale_idx(hg, s, *refs):
+        return (pool_row(s, *refs), 0, 0)
+
+    in_specs, args, out_specs, out_shape = [], [], [], []
+    rows = 0
+    for blocks, height, col, blk, columns, at in (
+            (nd, BLOCK_ROWS, short_col, short_blk, [walk.short_pos],
+             slice(0, decode_rows)),
+            (nc, tall, tall_col, tall_blk, [walk.tall_pos, walk.tall_seq],
+             slice(decode_rows, None))):
+        if not blocks:
+            continue
+        rows = max(rows, height * g)
+        in_specs += [pl.BlockSpec((1, height * g, 1), col)] * len(columns)
+        args += columns
+        in_specs.append(pl.BlockSpec((hb, 1, height * g, d), blk))
+        args.append(_score_rows(q[at], blocks, height, kvh, g))
+        out_specs.append(pl.BlockSpec((hb, 1, height * g, d), blk))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (kvh, blocks, height * g, d), q.dtype))
+    in_specs += [pl.BlockSpec((1, page, hb * d), kv_idx)] * 2
+    args += [kt, vt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page, kvh), scale_idx),
-                     pl.BlockSpec((1, page, kvh), scale_idx)]
+        in_specs += [pl.BlockSpec((1, page, kvh), scale_idx)] * 2
         args += [k_scale.reshape(-1, page, kvh),
                  v_scale.reshape(-1, page, kvh)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(nb, kvh // hb, steps),
+        grid=(kvh // hb, walk.visits.count),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((hb, 1, rbg, d), q_idx),
+        out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((hb, rbg, _LANES), jnp.float32),
-            pltpu.VMEM((hb, rbg, _LANES), jnp.float32),
-            pltpu.VMEM((hb, rbg, d), jnp.float32),
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rows, d), jnp.float32),
         ],
     )
-    kernel = functools.partial(_ragged_kernel, page_size=page, num_pb=steps,
-                               hb=hb, sm_scale=sm_scale, quantized=quantized,
-                               window=window)
-    out = pl.pallas_call(
+    kernel = functools.partial(_ragged_kernel, page_size=page, hb=hb,
+                               sm_scale=sm_scale, quantized=quantized,
+                               shorts=nd > 0, talls=nc > 0, window=window)
+    outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kvh, nb, rbg, d), q.dtype),
-        compiler_params=_dim_semantics(3, interpret),
+        out_shape=out_shape,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="ragged_paged_attention",
     )(*prefetch, *args)
-    out = out.reshape(kvh, nb, BLOCK_ROWS, g, d).transpose(1, 2, 0, 3, 4)
-    return out.reshape(t, h, d)
+    back = [_rows_back(o, blocks, height, kvh, g)
+            for o, (blocks, height) in zip(
+                outs, [bh for bh in ((nd, BLOCK_ROWS), (nc, tall)) if bh[0]])]
+    return jnp.concatenate(back)[:t] if len(back) > 1 else back[0][:t]
 
 
 # ---------------------------------------------------------------------------
@@ -536,7 +879,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
                            v_scale=None, sm_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None,
+                           decode_rows: int = 0,
+                           walk: Optional[Walk] = None):
     """Ragged paged attention over a sequence-packed mixed batch (see
     :func:`ragged_paged_attention_reference` for the semantics).
 
@@ -555,7 +900,16 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
     ``window`` (static; a window layer's): a row sees the last ``window``
     tokens up to its own, ``page_table`` is read as a ring, and pages
     below a block's windows are neither fetched nor visited (the module
-    doc)."""
+    doc).
+
+    ``decode_rows`` (static; the kernel's): how many leading rows are
+    decoding slots' and stand in short resident blocks; the rows behind
+    them, a prefill bucket's, are walked in tall blocks (0: all of them
+    are; the result is the same, a decode row's visit then costs a tall
+    block's products).  ``walk``: the call's schedule of visits where the
+    caller has made it for several calls over the same rows
+    (:func:`ragged_walk`, at these shapes and this ``decode_rows`` and
+    ``window``)."""
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     if interpret is None:
@@ -571,7 +925,8 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, kv_lens,
                               row_seq.astype(jnp.int32),
                               qpos.astype(jnp.int32),
                               float(sm_scale), bool(interpret),
-                              window=None if window is None else int(window))
+                              window=None if window is None else int(window),
+                              decode_rows=int(decode_rows), walk=walk)
     return _reference_on_layer(q, k_pool, v_pool, k_scale, v_scale, layer,
                                page_table, kv_lens, row_seq, qpos,
                                sm_scale=sm_scale, window=window)
@@ -582,7 +937,9 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pool, v_pool, page_table,
                               k_scale=None, v_scale=None,
                               sm_scale: Optional[float] = None,
                               use_kernel: Optional[bool] = None,
-                              interpret: Optional[bool] = None):
+                              interpret: Optional[bool] = None,
+                              decode_rows: int = 0,
+                              walk: Optional[Walk] = None):
     """Tensor-parallel ragged attention: the pallas kernel wrapped in a
     ``shard_map`` over the ``axis`` (``model``) mesh dim.
 
@@ -628,10 +985,17 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pool, v_pool, page_table,
     if k_scale is not None:
         in_specs += [pool, pool]
 
-    def local(qs, ks, vs, lyr, pt, ln, rs, qp, *scales):
-        kss, vss = scales if scales else (None, None)
+    if walk is not None:
+        in_specs.append(jax.tree.map(lambda _: repl, walk))
+
+    def local(qs, ks, vs, lyr, pt, ln, rs, qp, *rest):
+        rest = list(rest)
+        kss, vss = (rest.pop(0), rest.pop(0)) if k_scale is not None \
+            else (None, None)
         return _ragged_pallas(qs, ks, vs, kss, vss, lyr, pt, ln, rs, qp,
-                              float(sm_scale), bool(interpret))
+                              float(sm_scale), bool(interpret),
+                              decode_rows=int(decode_rows),
+                              walk=rest[0] if rest else None)
 
     fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=head, check_vma=False)
@@ -640,6 +1004,8 @@ def ragged_paged_attention_tp(mesh, axis, q, k_pool, v_pool, page_table,
             row_seq.astype(jnp.int32), qpos.astype(jnp.int32)]
     if k_scale is not None:
         args += [k_scale, v_scale]
+    if walk is not None:
+        args.append(walk)
     return fn(*args)
 
 

@@ -172,6 +172,7 @@ exposing its projection weights.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
 import math
@@ -193,7 +194,7 @@ from paddle_tpu.platform.flags import FLAGS
 from paddle_tpu.serving.decode_attention import (
     BLOCK_ROWS, _ragged_reference_blocked, attention_path,
     expand_decode_rows, heads_per_cell, ragged_paged_attention,
-    ragged_paged_attention_tp)
+    ragged_paged_attention_tp, ragged_walk, tall_rows_for, visit_counts)
 from paddle_tpu.serving.faults import (FaultPlan, InjectedDeviceError,
                                        PageLeakError)
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
@@ -205,7 +206,7 @@ from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
                                          make_window_ring, pages_for_budget,
                                          pages_spanned,
                                          read_pages, resolve_kv_dtype,
-                                         split_pool_bytes, window_pages,
+                                         split_pool_bytes,
                                          write_pages, zero_pages)
 from paddle_tpu.serving.metrics import ServingMetrics
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
@@ -258,7 +259,9 @@ class DecodeModel:
       attends over everything (``ServingEngine``: "window layers").  The
       query heads a layer brings are its ``q``'s (``q.shape[-2]``; any
       multiple of ``num_kv_heads``); ``num_heads`` is the most any layer
-      has.
+      has, and ``layer_heads`` (optional; a count a layer) says each
+      layer's where they differ: the host's count of the kernel's
+      visits lays the tall blocks out by it.
 
     Tensor-parallel serving (``ServingEngine(mesh=...)``) additionally
     needs:
@@ -521,7 +524,7 @@ class _Flight:
     logits: Any
     rows: Tuple[int, int, int]     # decode, prefill and padding rows
     h2d_bytes: int
-    attn_cells: Tuple[int, int, int]
+    attn_cells: Tuple[int, int, int, int]
     # what the host counted of the kinds' state (``_kind_counts``)
     kind_counts: Tuple[int, ...] = ()
 
@@ -831,9 +834,20 @@ class ServingEngine:
         # KV-head groups of the kernel's grid on one chip (its middle
         # axis), for the grid counters of ``_attn_cells``
         kvh = self.kv_cfg.kv_heads // self.tp
-        self._attn_head_groups = kvh // heads_per_cell(
+        self._attn_cell_heads = heads_per_cell(
             kvh, self.kv_cfg.page_size, self.kv_cfg.head_dim,
             jnp.dtype(self.kv_cfg.dtype).itemsize, self.kv_cfg.quantized)
+        self._attn_head_groups = kvh // self._attn_cell_heads
+        # and, for each kind of layer state, its layers by their GQA
+        # group (query heads a KV head: a tall block's height follows
+        # it): {group: layers}.  ``layer_heads`` is the model's where
+        # its layers differ (the DecodeModel contract)
+        heads = getattr(model, "layer_heads", None) or \
+            (model.num_heads,) * int(model.num_layers)
+        self._kind_groups = tuple(
+            collections.Counter(int(heads[l]) // self.kv_cfg.kv_heads
+                                for l in kind.layers)
+            for kind in kinds)
         self._buckets = tuple(sorted(int(b) for b in buckets)) if buckets \
             else _parse_buckets(FLAGS.serving_prefill_buckets)
         self._max_slots = max_slots
@@ -1222,20 +1236,26 @@ class ServingEngine:
             ctx, NamedSharding(self.mesh, P(None, self.tp_axis, None)))
 
     def _attend(self, kv: KVPages, layer: int, q, table, att_lens,
-                row_seq, qpos, k1: int = 1, window: Optional[int] = None):
+                row_seq, qpos, k1: int = 1, window: Optional[int] = None,
+                walks: Optional[dict] = None):
         """One ragged paged attention over the tick's mixed row stack.
         The reference path consumes the compact ``[B * k1 + pb]`` rows
         as-is; the kernel path expands each slot's ``k1`` decode/verify
-        rows to whole BLOCK_ROWS blocks (the one-sequence-per-block
-        packing contract) — prefill rows are already block-aligned by
-        the packer — and slices the context back out.  The expansion
-        touches [B*k1, H, D]-sized data, noise next to the attention
-        itself.  Under TP the kernel rides a ``shard_map`` over the
-        model axis (heads are attention-local, so each chip runs the
-        unchanged kernel on its head shard) and both paths re-assert
-        the head sharding on the context.  ``kv``, ``layer`` and ``table``
-        are the layer's KIND's (a window layer's ring and its ``window``
-        with them)."""
+        rows to whole BLOCK_ROWS blocks (every aligned BLOCK_ROWS rows
+        belong to one sequence: the kernel's packing contract) — prefill
+        rows are already block-aligned by the packer — tells the kernel
+        where the decode region ends (its rows stand in short resident
+        blocks, the bucket's in tall ones), and slices the context back
+        out.  The expansion touches [B*k1, H, D]-sized data, noise next
+        to the attention itself.  Under TP the kernel rides a
+        ``shard_map`` over the model axis (heads are attention-local, so
+        each chip runs the unchanged kernel on its head shard) and both
+        paths re-assert the head sharding on the context.  ``kv``,
+        ``layer`` and ``table`` are the layer's KIND's (a window layer's
+        ring and its ``window`` with them).  ``walks``: the step's cache of the kernel's walks:
+        the layers of one kind (and head count) attend over the same
+        rows, so the first of them makes the schedule of visits
+        (``decode_attention.ragged_walk``) and the others take it."""
         if not self._ragged_kernel:
             # row-blocked fallback: identical math to the oracle, with
             # the per-row K/V gather bounded to one block of rows
@@ -1249,7 +1269,7 @@ class ServingEngine:
         td = b * rbk                     # expanded decode/verify rows
         h, d = q.shape[1], q.shape[2]
         # decode rows expand through THE shared packing helper (one copy
-        # of the one-sequence-per-block contract); prefill rows are
+        # of the packing contract); prefill rows are
         # already block-aligned by the packer and concatenate behind
         qd, rsd, qpd = expand_decode_rows(q[:bd], qpos[:bd],
                                           rows_per_seq=k1)
@@ -1259,9 +1279,19 @@ class ServingEngine:
         # the kernel takes the pool's own leaves and the layer's index:
         # no slice of the layer, no re-tiling, no copy of either
         pool = dict(layer=layer, k_scale=kv.k_scale, v_scale=kv.v_scale,
-                    use_kernel=True)
+                    use_kernel=True, decode_rows=td)
         if window is not None:
             pool["window"] = window
+        if walks is not None:
+            if (window, h) not in walks:
+                walks[window, h] = ragged_walk(
+                    table, att_lens, rs, qp, num_heads=h // self.tp,
+                    num_kv_heads=kv.k.shape[3] // d // self.tp, head_dim=d,
+                    page_size=kv.k.shape[2],
+                    kv_itemsize=kv.k.dtype.itemsize,
+                    quantized=kv.k_scale is not None, decode_rows=td,
+                    window=window)
+            pool["walk"] = walks[window, h]
         if self.mesh is not None and self.tp > 1:
             ctx = ragged_paged_attention_tp(
                 self.mesh, self.tp_axis, qe, kv.k, kv.v, table, att_lens,
@@ -1371,6 +1401,7 @@ class ServingEngine:
             row_pages = [pages] + [
                 jnp.where(live, t[row_seq, (pos // page) % t.shape[1]],
                           NULL_PAGE) for t in tables[1:]]
+            walks = {}      # the kernel's walks, by kind and head count
             for l in range(model.num_layers):
                 # named_scope: blocks and their parts show up by name in
                 # xplane/profiler traces (as topology.forward's layers)
@@ -1385,7 +1416,7 @@ class ServingEngine:
                             jnp.where(wmask, v, 0.0), row_pages[i], offs)
                         ctx = self._attend(state[i], li, q, tables[i],
                                            att_lens, row_seq, qpos, k1=k1,
-                                           window=windows[i])
+                                           window=windows[i], walks=walks)
                     if self._counted:
                         x, n = model.attn_out_counted(params, l, ctx, x,
                                                       live)
@@ -2346,7 +2377,7 @@ class ServingEngine:
         flight = _Flight(
             passes, chunks, words, logits,
             (n_rows, total_rows, pb - sum(c[2] for c in chunks)),
-            packed.nbytes, self._attn_cells(p_seq, att_lens),
+            packed.nbytes, self._attn_cells(parts),
             self._kind_counts(parts, chunks))
         self._advance(flight)
         if compiles:
@@ -2573,26 +2604,52 @@ class ServingEngine:
                 # rolls its own cache back to it (no-op for n-gram)
                 self._proposer.commit(req)
 
-    def _attn_cells(self, p_seq: np.ndarray, att_lens: np.ndarray
-                    ) -> Tuple[int, int, int]:
-        """(kernel calls, grid steps, live grid steps) of one step on
-        one chip, counted as ``_ragged_pallas`` lays its grid out:
-        ``(row blocks, KV-head groups, max_pages_per_seq)`` per layer,
-        a step live where its page holds a token of its block's
-        sequence (``j * page < att_lens[seq]`` — the kernel's own test,
-        so the decode block of a prefilling slot counts, masked rows
-        and all).  Zeros on the reference path."""
+    def _walk_counts(self, parts, kind: int) -> Tuple[int, int, int, int]:
+        """(kernel calls, grid steps, those of them that compute, pages
+        needed) of one kind's layers in one step on one chip, counted as
+        ``_ragged_call`` lays its walk out (``decode_attention.
+        visit_counts`` runs the schedule's own rule over the tick's rows
+        as ``_attend`` hands them over): a step is a visit of a resident
+        row block to one page of one of its sequences, a slot's decode
+        rows one short block, the bucket's rows tall blocks of
+        ``tall_block_rows``; a block nobody's row sees a page of has one
+        step that computes nothing.  ``pages needed``: the distinct
+        (sequence, page) pairs among the visits, what a walk that read
+        each page once would fetch (the visits over it: the re-read
+        factor).  All times layers and KV-head groups."""
+        d_pos, d_valid, p_qpos, p_seq, att_lens = (
+            parts[1], parts[2], parts[4], parts[5], parts[8])
+        b, k1, cfg = self._max_slots, self._k1, self.kv_cfg
+        rbk = -(-k1 // BLOCK_ROWS) * BLOCK_ROWS
+        qpos = np.full((b, rbk), -1, np.int32)
+        qpos[:, :k1] = np.where(d_valid != 0, d_pos, -1)
+        qpos = np.concatenate([qpos.ravel(), p_qpos])
+        if self._block is not None:
+            # (a row attends as its block's last position)
+            qpos = np.where(qpos >= 0, (qpos // self._block + 1)
+                            * self._block - 1, -1)
+        row_seq = np.concatenate([np.repeat(np.arange(b, dtype=np.int32),
+                                            rbk), p_seq])
+        window, width = (None, cfg.max_pages_per_seq) if kind == 0 else (
+            self._rings[kind - 1].window, self._rings[kind - 1].ring_pages)
+        calls = visits = computing = pages = 0
+        for group, layers in self._kind_groups[kind].items():
+            tall = tall_rows_for(len(p_qpos), group, self._attn_cell_heads,
+                                 cfg.head_dim)
+            v, c, p = visit_counts(
+                row_seq, qpos, att_lens, decode_rows=b * rbk, tall_rows=tall,
+                page=cfg.page_size, width=width, window=window)
+            n = layers * self._attn_head_groups
+            calls, visits = calls + layers, visits + n * v
+            computing, pages = computing + n * c, pages + n * p
+        return calls, visits, computing, pages
+
+    def _attn_cells(self, parts) -> Tuple[int, int, int, int]:
+        """What the full-attention layers' kernel calls of one step walk
+        (:meth:`_walk_counts`).  Zeros on the reference path."""
         if not self._ragged_kernel:
-            return (0, 0, 0)
-        cfg, groups = self.kv_cfg, self._attn_head_groups
-        blocks_per_slot = -(-self._k1 // BLOCK_ROWS)
-        nb = self._max_slots * blocks_per_slot + len(p_seq) // BLOCK_ROWS
-        pages = -(-att_lens // cfg.page_size)        # live pages per slot
-        live = blocks_per_slot * int(pages.sum()) + \
-            int(pages[p_seq[::BLOCK_ROWS]].sum())
-        calls = cfg.num_layers
-        return (calls, calls * nb * groups * cfg.max_pages_per_seq,
-                calls * live * groups)
+            return (0, 0, 0, 0)
+        return self._walk_counts(parts, 0)
 
     def _kind_counts(self, parts, chunks) -> Tuple[int, ...]:
         """What one step holds and visits of the kinds' state, counted on
@@ -2620,40 +2677,16 @@ class ServingEngine:
             self._window_cells(parts)
 
     def _window_cells(self, parts) -> Tuple[int, int, int]:
-        """(kernel calls, grid steps, live grid steps) of the window
-        layers in one step, as ``_ragged_call`` lays a windowed grid out:
-        ``(row blocks, KV-head groups, window_pages)`` a layer, a block's
-        steps live from the first page its rows' windows reach to the
-        page of its last row.  Zeros on the reference path, as
+        """(kernel calls, grid steps, those that compute) of the window
+        layers in one step (:meth:`_walk_counts` over the rings: a run's
+        visits go from the first page its rows' windows reach to the
+        page of its last row).  Zeros on the reference path, as
         :meth:`_attn_cells`."""
         if not self._ragged_kernel:
             return (0, 0, 0)
-        d_pos, d_valid, p_qpos, p_seq, att_lens = (
-            parts[1], parts[2], parts[4], parts[5], parts[8])
-        page = self.kv_cfg.page_size
-        # each row block's lowest and highest live position: a slot's
-        # decode rows (one block), then the chunks' blocks
-        n_valid = d_valid.sum(axis=1)
-        qb = p_qpos.reshape(-1, BLOCK_ROWS)
-        lo = np.concatenate([
-            np.where(n_valid > 0, d_pos[:, 0], -1),
-            np.where(qb >= 0, qb, np.iinfo(np.int32).max).min(axis=1)])
-        hi = np.concatenate([
-            np.where(n_valid > 0, d_pos[:, 0] + n_valid - 1, -1),
-            qb.max(axis=1)])
-        seq = np.concatenate([np.arange(self._max_slots),
-                              p_seq[::BLOCK_ROWS]])
-        hi = np.minimum(hi, att_lens[seq] - 1)
-        calls = grid = live = 0
-        for ring in self._rings:
-            first = np.maximum(lo - ring.window + 1, 0) // page
-            n = ring.cfg.num_layers * self._attn_head_groups
-            calls += ring.cfg.num_layers
-            grid += n * len(lo) * window_pages(
-                ring.window, BLOCK_ROWS, page, ring.ring_pages)
-            live += n * int(np.where(hi >= 0, hi // page - first + 1,
-                                     0).sum())
-        return (calls, grid, live)
+        counts = [self._walk_counts(parts, 1 + i)[:3]
+                  for i in range(len(self._rings))]
+        return tuple(int(n) for n in np.sum(counts, axis=0))
 
     def _tick_shapes(self, pb: int, k1: int) -> Tuple[Tuple[int, ...], ...]:
         """A tick's nine input arrays (ten for a block model) in the order

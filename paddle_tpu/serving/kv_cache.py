@@ -258,8 +258,8 @@ def window_pages(window: int, rows: int, page: int, width: int) -> int:
     under ``window``: those that positions ``[p - window + 1, p + rows)``
     can touch, for any ``p`` (never more than the table's ``width``).
     With a step's most rows of one sequence it is the length of a
-    :class:`WindowRing`, with a row block's the page axis of the windowed
-    kernel's grid."""
+    :class:`WindowRing`, with a resident row block's the most visits one
+    sequence can have in it in the windowed kernel's walk."""
     return min(int(width), (int(window) + max(1, int(rows)) - 2) // page + 2)
 
 

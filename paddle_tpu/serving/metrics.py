@@ -77,12 +77,17 @@ class ServingMetrics:
         #                               rows of logits fetched for a
         #                               request that samples or for a
         #                               verify walk
-        # the ragged kernel's grid, on one chip (PR 27): a grid step
-        # costs its fixed part whether its page is live or not, so the
-        # kernel's time follows the steps, not the tokens
+        # the ragged kernel's walk, on one chip (the full-attention
+        # layers'): a grid step is a visit of a resident row block to
+        # one page, so the kernel's time follows the visits
         self.attn_kernel_calls = 0    # one per layer and step
-        self.attn_grid_cells = 0      # grid steps those calls dispatched
-        self.attn_live_cells = 0      # of them, steps whose page is live
+        self.attn_grid_cells = 0      # grid steps (visits) of those calls
+        self.attn_live_cells = 0      # of them, steps that read a page
+        #                               (all but the one step of a block
+        #                               whose rows see nothing)
+        self.attn_pages_needed = 0    # distinct (sequence, page) pairs
+        #                               among them: grid / needed is how
+        #                               often a page is read over
         # block models (generation by diffusion over blocks): a slot's
         # rows, passes and tokens stand in no fixed ratio to its ticks
         self.denoise_passes = 0       # slot participations that fixed tokens
@@ -144,7 +149,7 @@ class ServingMetrics:
     def on_step(self, n_decode_rows: int, n_prefill_rows: int,
                 n_pad_rows: int, n_slots: Optional[int] = None,
                 h2d_bytes: int = 0, d2h_bytes: int = 0,
-                attn_cells: Tuple[int, int, int] = (0, 0, 0),
+                attn_cells: Tuple[int, int, int, int] = (0, 0, 0, 0),
                 model_counts: Sequence[int] = (),
                 lagged: bool = False) -> None:
         """One unified-step dispatch, counted when its words are read:
@@ -158,10 +163,10 @@ class ServingMetrics:
         its words down (:meth:`on_fetch` adds what else is read of it).
         ``lagged``: the words were read after the next step's dispatch.
         ``attn_cells`` is the dispatch's (ragged
-        kernel calls, grid steps of those calls, steps whose page is
-        live), zeros on the reference path.  ``model_counts`` is what
-        the model's layers counted in the dispatch, in the order of the
-        names given at construction."""
+        kernel calls, grid steps of those calls, steps that read a
+        page, distinct pages among them), zeros on the reference path.
+        ``model_counts`` is what the model's layers counted in the
+        dispatch, in the order of the names given at construction."""
         self.step_dispatches += 1
         self.steps_lagged += bool(lagged)
         self.decode_rows += n_decode_rows
@@ -174,6 +179,7 @@ class ServingMetrics:
         self.attn_kernel_calls += attn_cells[0]
         self.attn_grid_cells += attn_cells[1]
         self.attn_live_cells += attn_cells[2]
+        self.attn_pages_needed += attn_cells[3]
         for name, n in zip(self.model_counters, model_counts):
             self.model_counters[name] += int(n)
 
@@ -369,6 +375,7 @@ class ServingMetrics:
             "attn_kernel_calls": self.attn_kernel_calls,
             "attn_grid_cells": self.attn_grid_cells,
             "attn_live_cells": self.attn_live_cells,
+            "attn_pages_needed": self.attn_pages_needed,
             "denoise_passes": self.denoise_passes,
             "commit_passes": self.commit_passes,
             "block_rows": self.block_rows,

@@ -127,3 +127,76 @@ def stored_pool(*pages):
         return a.reshape(1, *a.shape[:2], -1) if a.ndim == 4 else a[None]
 
     return tuple(one(a) for a in pages)
+
+
+def walk_batch(rng, decode, chunks, *, k1=1, kvh=2, group=1, page=8, d=16,
+               bucket=0, pool="float32", width=None, layers=2):
+    """A tick's rows as ``ServingEngine._attend`` hands them to the ragged
+    kernel, over a random stored pool: every slot's ``k1`` decode rows
+    padded to whole ``BLOCK_ROWS`` blocks (``decode``: the decoding slots'
+    lengths, 0 an idle slot; a prefilling slot's decode rows are padding),
+    then the chunks ``(rows, start)`` of the slots behind them, each padded
+    to ``BLOCK_ROWS``, then padding up to ``bucket`` rows.  ``width``: the
+    page table's (a ring's, where a window layer reads it; the longest
+    sequence's pages by default).  Returns the arguments of
+    ``ragged_paged_attention`` as a dict (``decode_rows`` among them)."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving import BLOCK_ROWS, quantize_kv
+
+    rbk = -(-k1 // BLOCK_ROWS) * BLOCK_ROWS
+    slots = len(decode) + len(chunks)
+    lens = list(decode) + [start + n for n, start in chunks]
+    rows = []
+    for s, n in enumerate(decode):
+        pos = list(range(max(n - k1, 0), n))
+        rows += [(s, p) for p in pos] + [(s, -1)] * (rbk - len(pos))
+    rows += [(len(decode) + j, -1) for j in range(len(chunks))
+             for _ in range(rbk)]
+    td = len(rows)
+    for j, (n, start) in enumerate(chunks):
+        rows += [(len(decode) + j, start + i) for i in range(n)] + \
+            [(len(decode) + j, -1)] * (-n % BLOCK_ROWS)
+    rows += [(0, -1)] * max(0, bucket - (len(rows) - td))
+    width = width or max(lens) // page + 1
+    shape = (layers, 1 + slots * width, page, kvh, d)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    scales = {}
+    if pool == "int8":
+        k, scales["k_scale"] = quantize_kv(k)
+        v, scales["v_scale"] = quantize_kv(v)
+    return dict(
+        q=jnp.asarray(rng.standard_normal((len(rows), kvh * group, d)),
+                      jnp.float32),
+        k_pool=k.reshape(*shape[:3], kvh * d),
+        v_pool=v.reshape(*shape[:3], kvh * d),
+        page_table=jnp.asarray(1 + np.arange(slots * width).reshape(
+            slots, width), jnp.int32),
+        kv_lens=jnp.asarray(lens, jnp.int32),
+        row_seq=jnp.asarray([r[0] for r in rows], jnp.int32),
+        qpos=jnp.asarray([r[1] for r in rows], jnp.int32),
+        layer=layers - 1, decode_rows=td, **scales)
+
+
+# chunk mixes that stress the regrouping of a bucket's rows into tall
+# blocks: (decoding slots' lengths, chunks (rows, start), bucket rows,
+# rows of a tall block; None: what the shapes give)
+WALK_MIXES = {
+    # one long chunk at a context of many pages: every tall block walks
+    # them once, up to its own last row
+    "long_chunk": ([13, 30], [(512, 256)], 512, 128),
+    "three_chunks": ([13], [(8, 40), (72, 16), (136, 0)], 216, 16),
+    # 20 rows pad to 24: the chunk ends inside the second tall block, and
+    # another sequence's rows share it
+    "ends_inside_a_block": ([9], [(20, 12), (8, 0)], 32, 16),
+    "empty_bucket": ([13, 30, 7], [], 16, 16),
+    "a_sequence_of_length_0": ([0, 21, 0], [(16, 8)], 16, 16),
+}
+
+# every mix at every group (1, 6, 8), the pool's type and the rows a slot
+# (1: decode; 4: a block model's, or speculation's) turning with them so
+# that each pair of values meets
+WALK_CASES = [(mix, group, ("float32", "int8")[(i + j) % 2],
+               (1, 4)[(i + j // 2) % 2])
+              for i, mix in enumerate(sorted(WALK_MIXES))
+              for j, group in enumerate((1, 6, 8))]

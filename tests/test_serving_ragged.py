@@ -36,6 +36,7 @@ from paddle_tpu.ops.attention import mha_reference
 
 from conftest import assert_serving_drained as assert_drained  # noqa: E402
 from conftest import stored_pool  # noqa: E402
+from conftest import WALK_CASES, WALK_MIXES, walk_batch  # noqa: E402
 
 ragged = pytest.mark.ragged
 serving = pytest.mark.serving
@@ -414,8 +415,140 @@ def test_ragged_kernel_heads_per_cell_parity(rng, monkeypatch, case, kvh, h,
     else:
         want = _oracle(q, kc, vc, kv_lens, row_seq, qpos, h)
     np.testing.assert_allclose(ker[real], want[real], rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(ker, np.asarray(
-        _parent_ragged_pallas(*args)))
+    # (a padded row sees nothing, and what it holds is read by nobody: the
+    # former kernel walked its sequence's every page past it, this one
+    # only the pages a live row of its block can see)
+    np.testing.assert_array_equal(ker[real], np.asarray(
+        _parent_ragged_pallas(*args))[real])
+
+
+# ---------------------------------------------------------------------------
+# the walk (PR 42): short blocks for decode rows, tall ones for a bucket's
+# ---------------------------------------------------------------------------
+
+
+def _kernel_and_reference(batch, **kw):
+    """The kernel's and the reference path's outputs for ``walk_batch``'s
+    arguments, and which rows are real."""
+    batch = dict(batch)
+    q = batch.pop("q")
+    rest = [batch.pop(n) for n in ("k_pool", "v_pool", "page_table",
+                                   "kv_lens", "row_seq", "qpos")]
+    td = batch.pop("decode_rows")
+    got = np.asarray(ragged_paged_attention(
+        q, *rest, **batch, **kw, use_kernel=True, interpret=True,
+        decode_rows=td))
+    want = np.asarray(ragged_paged_attention(q, *rest, **batch, **kw,
+                                             use_kernel=False))
+    return got, want, np.asarray(rest[-1]) >= 0
+
+
+def _tall(monkeypatch, rows, group):
+    """Make a tall block ``rows`` rows high (None: what the shapes give)."""
+    if rows is not None:
+        monkeypatch.setattr(decode_attention, "_TALL_SCORE_ROWS",
+                            rows * group)
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("mix,group,pool,k1", WALK_CASES)
+def test_the_walk_over_a_mixed_tick_matches_the_reference(
+        rng, monkeypatch, mix, group, pool, k1):
+    """Decode rows in short blocks and the bucket's rows regrouped into
+    tall ones, in one call: every real row is the reference's, whatever
+    the chunks' lengths and wherever they end in a tall block; every row
+    of the output is written (a block that visits nothing, a padded row),
+    and finite."""
+    decode, chunks, bucket, tall = WALK_MIXES[mix]
+    _tall(monkeypatch, tall, group)
+    batch = walk_batch(rng, decode, chunks, k1=k1, group=group, pool=pool,
+                       bucket=bucket)
+    got, want, real = _kernel_and_reference(batch)
+    np.testing.assert_allclose(got[real], want[real], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all()
+
+
+@ragged
+@serving
+@pytest.mark.parametrize("mix,group", [
+    (mix, (6, 1, 8)[i % 3]) for i, mix in enumerate(sorted(WALK_MIXES))])
+def test_the_walk_at_the_height_the_shapes_give(rng, mix, group):
+    """The same mixes with tall blocks as high as ``tall_block_rows`` makes
+    them (128, 64 and 64 rows at these groups, or the whole bucket): blocks
+    that hold several sequences' rows and work on one a visit.  And told
+    nothing of the decode region (``decode_rows`` 0: every row in tall
+    blocks), the kernel computes the same rows."""
+    decode, chunks, bucket, _ = WALK_MIXES[mix]
+    batch = walk_batch(rng, decode, chunks, group=group, bucket=bucket)
+    got, want, real = _kernel_and_reference(batch)
+    np.testing.assert_allclose(got[real], want[real], rtol=2e-5, atol=2e-5)
+    batch["decode_rows"] = 0
+    alone, _, _ = _kernel_and_reference(batch)
+    np.testing.assert_allclose(alone[real], want[real], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all() and np.isfinite(alone).all()
+
+
+@ragged
+@serving
+def test_a_tall_block_keeps_its_sequences_apart(rng, monkeypatch):
+    """A page of one sequence that holds a NaN reaches that sequence's rows
+    and no other's, though they share a tall block."""
+    _tall(monkeypatch, 16, 2)
+    batch = walk_batch(rng, [9], [(8, 0), (8, 8)], group=2, bucket=16)
+    table = np.asarray(batch["page_table"])
+    batch["v_pool"] = batch["v_pool"].at[:, table[1, 0]].set(np.nan)
+    got, want, real = _kernel_and_reference(batch)
+    seq = np.asarray(batch["row_seq"])
+    assert np.isnan(got[real & (seq == 1)]).all()
+    np.testing.assert_allclose(got[real & (seq != 1)],
+                               want[real & (seq != 1)], rtol=2e-5, atol=2e-5)
+
+
+@ragged
+@serving
+def test_visit_schedule_against_a_count_by_hand():
+    """Page 4, two decode blocks and a bucket of 32 rows in tall blocks of
+    16: slot 0 decodes at length 13 (4 pages), slot 1 is idle (one step
+    that computes nothing); slot 2's chunk of 20 rows at positions 4-23
+    fills the first tall block (positions 4-19: pages 0-4) and half of the
+    second (20-23: pages 0-5), which slot 3's chunk of 8 rows at 0-7 shares
+    (pages 0-1)."""
+    from paddle_tpu.serving.decode_attention import (_COMPUTE, _FIRST, _LAST,
+                                                     _TALL, visit_counts,
+                                                     visit_schedule)
+    rows = [(0, 12)] + [(0, -1)] * 7 + [(1, -1)] * 8 + [(2, -1)] * 8 + \
+        [(3, -1)] * 8
+    rows += [(2, 4 + i) for i in range(20)] + [(2, -1)] * 4 + \
+        [(3, i) for i in range(8)]
+    row_seq = np.asarray([r[0] for r in rows], np.int32)
+    qpos = np.asarray([r[1] for r in rows], np.int32)
+    lens = np.asarray([13, 0, 24, 8], np.int32)
+    kw = dict(decode_rows=32, tall_rows=16, page=4)
+    assert visit_counts(row_seq, qpos, lens, width=6, **kw) == (
+        4 + 1 + 1 + 1 + 5 + 6 + 2, 4 + 5 + 6 + 2, 4 + 6 + 2)
+    walk = jax.jit(functools.partial(visit_schedule, width=6, **kw))(
+        row_seq, qpos, lens)
+    n = int(walk.count)
+    assert n == 20 and walk.flags.shape == (4 * 6 + 4 * 6,)
+    flags, at, seq = (np.asarray(a)[:n] for a in (walk.flags, walk.at,
+                                                  walk.seq))
+    #            slot 0's pages  idle x3    block 0       block 1: slot 2, 3
+    assert list(seq) == [0] * 4 + [1, 2, 3] + [2] * 5 + [2] * 6 + [3] * 2
+    assert list(at) == [0, 1, 2, 3, 0, 0, 0, 0, 1, 2, 3, 4,
+                        0, 1, 2, 3, 4, 5, 0, 1]
+    first = [0, 4, 5, 6, 7, 12]
+    last = [3, 4, 5, 6, 11, 19]
+    assert [i for i in range(n) if flags[i] & _FIRST] == first
+    assert [i for i in range(n) if flags[i] & _LAST] == last
+    assert [i for i in range(n) if not flags[i] & _COMPUTE] == [4, 5, 6]
+    assert [i for i in range(n) if flags[i] & _TALL] == list(range(7, 20))
+    # the resident blocks, the four short ones numbered before the tall
+    assert list(np.asarray(walk.block)[:n]) == [0] * 4 + [1, 2, 3] + \
+        [4] * 5 + [5] * 8
+    # under a window of 6 a run starts at the first page its rows can see
+    assert visit_counts(row_seq, qpos, lens, width=6, window=6, **kw)[:2] == (
+        3 + 1 + 1 + 1 + 5 + 3 + 2, 3 + 5 + 3 + 2)
 
 
 # ---------------------------------------------------------------------------
@@ -588,6 +721,10 @@ def test_engine_step_hands_the_kernel_the_pool_itself(kv_dtype):
     assert len(calls) == layers
     # one trace of the kernel, shared by the L call sites
     assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    # and ONE walk for them all: the layers attend over the tick's rows,
+    # so the schedule of visits is made once a step, not once a layer
+    assert len([e for e, _ in _eqns(closed.jaxpr, "jit")
+                if e.params["name"] == "_walk"]) == 1
     pool_shapes = {"k": eng._kv.k.shape, "s": None if eng._kv.k_scale is None
                    else eng._kv.k_scale.shape}
     n_pool = 2 if kv_dtype == "float32" else 4
@@ -601,9 +738,9 @@ def test_engine_step_hands_the_kernel_the_pool_itself(kv_dtype):
     assert len(pallas) == 1          # inside the one shared trace
     (eqn, inner), = pallas.values()
     made_by = {v: q for q in inner.eqns for v in q.outvars}
-    # scalar prefetch (blk_seq, table, lens, layer), qpos, q, then K, V
-    # (and the scales)
-    for v in eqn.invars[6:6 + n_pool]:
+    # scalar prefetch (the walk and the layer), each kind's row columns
+    # and q, then K, V (and the scales) last
+    for v in eqn.invars[-n_pool:]:
         src = made_by[v]
         assert src.primitive.name == "reshape", src
         (pool,) = src.invars
@@ -648,6 +785,32 @@ def test_heads_per_cell_chooser(monkeypatch):
             for item in (1, 2, 4):
                 hb = heads_per_cell(kvh, page, 128, item, item == 1)
                 assert 1 <= hb <= kvh and kvh % hb == 0
+
+
+@ragged
+@serving
+def test_tall_block_rows_chooser(monkeypatch):
+    """A tall block's height, from shapes alone: the score rows a KV head
+    it aims at over the GQA group, never above the most rows, a power of
+    two times ``BLOCK_ROWS``, and less where VMEM has no room."""
+    from paddle_tpu.serving.decode_attention import tall_block_rows
+    assert tall_block_rows(1, 8, 128) == 128      # the 6.7B's shard
+    assert tall_block_rows(6, 8, 128) == 64       # laguna's full layers
+    assert tall_block_rows(8, 8, 128) == 64       # its window layers
+    assert tall_block_rows(8, 4, 128) == 64       # sdar
+    assert tall_block_rows(16, 8, 256) == 32
+    assert tall_block_rows(512, 1, 128) == BLOCK_ROWS   # never under 8
+    # aimed at 64 rows, 8 heads of 256 a cell at group 16 would hold 50
+    # MiB of q, output and carries: 32 rows fit, and 16 at 16 heads
+    monkeypatch.setattr(decode_attention, "_TALL_SCORE_ROWS", 1024)
+    assert tall_block_rows(16, 8, 256) == 32
+    assert tall_block_rows(16, 16, 256) == 16
+    assert tall_block_rows(16, 2, 128) == 64
+    for group in (1, 2, 3, 4, 6, 8, 12, 16):
+        for hb in (1, 2, 8, 32):
+            rows = tall_block_rows(group, hb, 128)
+            assert rows % BLOCK_ROWS == 0 and rows & (rows - 1) == 0
+            assert BLOCK_ROWS <= rows <= 128
 
 
 # ---------------------------------------------------------------------------
@@ -856,12 +1019,14 @@ def test_engine_kernel_fallback_parity_mixed(rng):
 @serving
 @pytest.mark.parametrize("hb", [4, 2])
 def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
-    """``attn_kernel_calls`` / ``attn_grid_cells`` / ``attn_live_cells``:
-    a call's grid is ``nb x (KVH / hb) x max_pages_per_seq`` for both
-    compiled buckets (decode-only: one block a slot; with the 8-row
-    prefill bucket: one more), and a step is live where its page holds
-    a token of its block's sequence — counted here by hand from the
-    slots' lengths, tick by tick."""
+    """``attn_kernel_calls`` / ``attn_grid_cells`` / ``attn_live_cells`` /
+    ``attn_pages_needed``: a call's grid is ``(KVH / hb, visits)``, a
+    visit one page of one sequence before one resident row block: each of
+    the four slots' decode blocks walks the pages its decode row can see
+    (an idle or prefilling slot's block: one step that computes nothing),
+    the 8-row bucket is one tall block that walks its chunk's pages up to
+    the chunk's last row; the pages needed are the distinct (slot, page)
+    pairs among the visits — counted here by hand, tick by tick."""
     model = DecoderLM(vocab_size=50, num_layers=2, num_heads=4, head_dim=8,
                       max_positions=128)
     params = model.init_params(jax.random.PRNGKey(0))
@@ -869,17 +1034,19 @@ def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
     monkeypatch.setattr(decode_attention, "_KV_VMEM_BUDGET",
                         _budget_for(hb, 4, 4, 8, 4, False))
     eng = _engine(model, params, use_kernel=True, eos_id=50)
-    layers, groups, pm = 2, 4 // hb, 10
+    layers, groups = 2, 4 // hb
     seen = []
 
     def step():
         m = eng.metrics
-        before = (m.attn_kernel_calls, m.attn_grid_cells, m.attn_live_cells)
+        before = (m.attn_kernel_calls, m.attn_grid_cells, m.attn_live_cells,
+                  m.attn_pages_needed)
         eng.step()
         eng.land()      # (a step is counted when its words are read)
         seen.append((m.attn_kernel_calls - before[0],
                      m.attn_grid_cells - before[1],
-                     m.attn_live_cells - before[2]))
+                     m.attn_live_cells - before[2],
+                     m.attn_pages_needed - before[3]))
 
     eng.submit(rng.randint(2, 50, size=5).tolist(), max_tokens=20)
     step()      # A prefills 5 rows in one 8-row bucket: length 5
@@ -892,21 +1059,25 @@ def test_attn_cell_counters_match_a_count_by_hand(rng, monkeypatch, hb):
     def pages(n):
         return -(-n // 4)
 
-    want_live = [
-        pages(5) + pages(5),              # A's decode block, A's chunk
-        pages(6),
-        pages(7) + pages(8) + pages(8),   # A; B's decode block and chunk
-        pages(8) + pages(11) + pages(11),
-        pages(9) + pages(12),
+    # (visits that read a page, blocks that see nothing, pages needed)
+    want = [
+        (pages(5), 4, pages(5)),            # A's chunk; no decode row yet
+        (pages(6), 3, pages(6)),
+        (pages(7) + pages(8), 3, pages(7) + pages(8)),   # A; B's chunk
+        # B's chunk block reads B's pages once, B's decode block nothing
+        (pages(8) + pages(11), 3, pages(8) + pages(11)),
+        (pages(9) + pages(12), 2, pages(9) + pages(12)),
     ]
-    want_blocks = [4 + 1, 4, 4 + 1, 4 + 1, 4]
-    assert seen == [(layers, layers * nb * groups * pm,
-                     layers * live * groups)
-                    for nb, live in zip(want_blocks, want_live)]
+    assert seen == [(layers, layers * groups * (live + idle),
+                     layers * groups * live, layers * groups * needed)
+                    for live, idle, needed in want]
     snap = eng.metrics.snapshot()
     assert snap["attn_kernel_calls"] == 5 * layers
-    assert snap["attn_grid_cells"] == sum(c for _, c, _ in seen)
-    assert snap["attn_live_cells"] == sum(c for _, _, c in seen)
+    assert snap["attn_grid_cells"] == sum(c for _, c, _, _ in seen)
+    assert snap["attn_live_cells"] == sum(c for _, _, c, _ in seen)
+    assert snap["attn_pages_needed"] == sum(c for _, _, _, c in seen)
+    # every page is read once: the kernel's re-read factor is 1 here
+    assert snap["attn_live_cells"] == snap["attn_pages_needed"]
     # the reference path dispatches no kernel: the counters stay at zero
     ref = _engine(model, params, use_kernel=False, eos_id=50)
     ref.submit([3, 4, 5], max_tokens=2)

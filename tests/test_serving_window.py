@@ -34,6 +34,8 @@ if BENCH not in sys.path:
 
 from harness import cells, weights  # noqa: E402
 
+from conftest import WALK_CASES, WALK_MIXES, walk_batch  # noqa: E402
+
 REF = cells.load_module(os.path.join(BENCH, "references", "laguna.py"))
 FAMILY = cells.load_module(os.path.join(BENCH, "families", "laguna.py"))
 TINY = cells.load_json(os.path.join(BENCH, "tests", "configs",
@@ -189,6 +191,40 @@ def test_a_long_sequence_holds_no_more_than_the_rings_bound(made):
     eng.check_page_conservation()
 
 
+def test_the_kinds_visit_counters_against_a_count_by_hand(made):
+    """One request through the kernel path (4 slots, pages of 4, a window
+    of 12): a tick's visits are, in the 2 full layers, the pages up to its
+    decode row's (or its chunk's last row's) and one step for each of the
+    blocks that see nothing; in the 3 window layers the same from the
+    first page the window of its lowest row reaches."""
+    eng = engine(made, use_kernel=True)
+    eng.submit(prompt_of(3, 5), 24)
+    m = eng.metrics
+    names = ("window_kernel_calls", "window_grid_cells", "window_live_cells")
+    seen = []
+    for _ in range(20):
+        before = [m.attn_grid_cells, m.attn_live_cells, m.attn_pages_needed,
+                  *(m.model_counters[n] for n in names)]
+        eng.step()
+        eng.land()
+        after = [m.attn_grid_cells, m.attn_live_cells, m.attn_pages_needed,
+                 *(m.model_counters[n] for n in names)]
+        seen.append(tuple(b - a for a, b in zip(before, after)))
+    # tick 0: the chunk of 5 rows (positions 0-4) in the bucket's tall
+    # block, 4 decode blocks that see nothing; then a decode row at
+    # position 5, 6, ...: 3 idle blocks
+    rows = [(0, 4, 4)] + [(p, p, 3) for p in range(5, 24)]
+    want = []
+    for lo, hi, idle in rows:
+        full = hi // PAGE + 1
+        win = hi // PAGE - max(lo - WINDOW + 1, 0) // PAGE + 1
+        want.append((2 * (full + idle), 2 * full, 2 * full,
+                     3, 3 * (win + idle), 3 * win))
+    assert seen == want
+    assert seen[-1][0] > seen[-1][4] * 2 // 3      # the window cuts visits
+    eng.run()
+
+
 def test_one_budget_is_divided_between_the_kinds(made):
     prog = FAMILY.serve_program(TINY, [None])
     params = {name: made[ref] for name, ref in prog["names"].items()}
@@ -333,6 +369,38 @@ def test_kernel_with_a_window_against_its_reference(group, ring):
         p = jax.nn.softmax(jnp.where(live[:, None], s, -1e30), axis=-1)
         hand = np.asarray(jnp.einsum("thk,tkhd->thd", p, vv))[real]
         np.testing.assert_allclose(outs[0], hand, atol=2e-6)
+
+
+@pytest.mark.parametrize("mix,group,pool,k1", WALK_CASES)
+def test_the_walk_under_a_window_matches_the_reference(monkeypatch, mix,
+                                                       group, pool, k1):
+    """The mixes of ``test_serving_ragged`` (decode rows in short blocks, the
+    bucket's rows regrouped into tall ones) in a window layer: the table is
+    a ring as long as the step's most rows of one sequence need, a run
+    starts at the first page its lowest row's window reaches, and every
+    real row is the reference's."""
+    from paddle_tpu.serving import decode_attention
+
+    decode, chunks, bucket, tall = WALK_MIXES[mix]
+    window, page = 20, 8
+    monkeypatch.setattr(decode_attention, "_TALL_SCORE_ROWS", tall * group)
+    ring = window_pages(window, max([k1] + [n for n, _ in chunks]), page,
+                        1 << 20)
+    batch = walk_batch(np.random.default_rng(group), decode, chunks, k1=k1,
+                       group=group, pool=pool, bucket=bucket, page=page,
+                       width=ring)
+    q = batch.pop("q")
+    rest = [batch.pop(n) for n in ("k_pool", "v_pool", "page_table",
+                                   "kv_lens", "row_seq", "qpos")]
+    td = batch.pop("decode_rows")
+    got = np.asarray(ragged_paged_attention(
+        q, *rest, **batch, window=window, use_kernel=True, interpret=True,
+        decode_rows=td))
+    want = np.asarray(ragged_paged_attention(
+        q, *rest, **batch, window=window, use_kernel=False))
+    real = np.asarray(rest[-1]) >= 0
+    np.testing.assert_allclose(got[real], want[real], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got).all()
 
 
 def test_a_window_wider_than_the_sequence_is_the_full_mask():

@@ -221,6 +221,65 @@ def test_ragged_kernel_on_the_whole_pool_compiles_for_v5e(topo, chips, layers,
     assert not moved, moved
 
 
+# ---- the walk (PR 42): short and tall blocks at the serve cells' shapes ------
+
+WALK_CELLS = [
+    # rows in short blocks, bucket, heads and KV heads a chip, window,
+    # slots, the page table's width, chips
+    ("laguna_full", 256, 1024, 48, 8, None, 32, 130, 1),
+    ("laguna_full_decode", 256, 0, 48, 8, None, 32, 130, 1),
+    ("laguna_window", 256, 1024, 64, 8, 512, 32, 9, 1),
+    ("laguna_window_decode", 256, 0, 64, 8, 512, 32, 9, 1),
+    ("6.7b_tp4", 256, 256, 32, 32, None, 32, 10, 4),
+    ("6.7b_tp4_decode", 256, 0, 32, 32, None, 32, 10, 4),
+    ("sdar_block4", 256, 256, 32, 4, None, 32, 12, 1),   # 4 rows a slot
+    ("sdar_block4_decode", 256, 0, 32, 4, None, 32, 12, 1),
+]
+
+
+@pytest.mark.parametrize("td,pb,h,kvh,window,slots,pm,chips",
+                         [c[1:] for c in WALK_CELLS],
+                         ids=[c[0] for c in WALK_CELLS])
+def test_the_walk_compiles_for_v5e_at_the_serve_cells_shapes(
+        topo, td, pb, h, kvh, window, slots, pm, chips):
+    """One Mosaic kernel for the decode rows' short blocks and the bucket's
+    tall ones together (laguna: 48 and 64 heads over 8 KV heads, 64-row
+    tall blocks, with a window of 512 and without, a 130-page table whose
+    walk of up to 20,800 visits rides in SMEM; 6.7B under TP 4: 8 heads a
+    chip, the 256-row bucket one tall block; sdar: group 8), the walk made
+    by the caller and handed in as the engine does.  A block shape or a
+    scalar-prefetch size Mosaic refuses is met here, on the CPU."""
+    from paddle_tpu.serving.decode_attention import ragged_walk
+
+    d, page, t = 128, 128, td + pb
+    if chips == 1:
+        mesh = None
+        place = lambda spec: _on(SingleDeviceSharding(topo.devices[0]))  # noqa: E731,E501
+    else:
+        mesh = Mesh(np.asarray(topo.devices), ("model",))
+        place = lambda spec: _on(NamedSharding(mesh, spec))  # noqa: E731
+    q = place(P(None, "model", None))((t, h, d), jnp.float32)
+    pool = place(P(None, None, None, "model"))((2, 64, page, kvh * d),
+                                               jnp.float32)
+    i32 = lambda *shape: place(P())(shape, jnp.int32)  # noqa: E731
+
+    def fn(q, k, v, layer, table, lens, row_seq, qpos):
+        walk = ragged_walk(table, lens, row_seq, qpos, num_heads=h // chips,
+                           num_kv_heads=kvh // chips, head_dim=d,
+                           page_size=page, kv_itemsize=4, decode_rows=td,
+                           window=window)
+        kw = dict(layer=layer, use_kernel=True, interpret=False,
+                  decode_rows=td, walk=walk)
+        if mesh is None:
+            return ragged_paged_attention(q, k, v, table, lens, row_seq,
+                                          qpos, window=window, **kw)
+        return ragged_paged_attention_tp(mesh, "model", q, k, v, table, lens,
+                                         row_seq, qpos, **kw)
+
+    assert _compile(fn, q, pool, pool, i32(), i32(slots, pm), i32(slots),
+                    i32(t), i32(t)) == 1
+
+
 # ---- the latent-attention and expert-layer kernels at their cell's widths ---
 
 @pytest.mark.parametrize("mode", ["fwd", "bwd_pallas"])
