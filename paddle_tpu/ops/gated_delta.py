@@ -32,7 +32,7 @@ highest precision.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,25 +44,61 @@ from paddle_tpu.ops.norm import rms_norm
 CHUNK = 64
 
 
-def causal_conv(x: jax.Array, w: jax.Array, segment_ids: jax.Array
-                ) -> jax.Array:
+def causal_conv(x: jax.Array, w: jax.Array, segment_ids: jax.Array,
+                carry: Optional[jax.Array] = None,
+                bias: Optional[jax.Array] = None):
     """Causal depthwise convolution inside each segment of a flat buffer:
-    ``y_t = sum_j w[:, j] x_{t - (K - 1) + j}``, a row before its own
-    sequence's start counting as zero.  x: [T, C]; w: [C, K] (the last tap
-    meets the current token).  Float32."""
+    ``y_t = sum_j w[:, j] x_{t - (K - 1) + j} (+ bias)``, a row before its
+    own sequence's start counting as zero.  x: [T, C]; w: [C, K] (the last
+    tap meets the current token); bias: [C].  Float32.
+
+    ``carry`` ``[segments, K - 1, C]`` hands in the rows BEFORE each
+    segment's first (a sequence that continues from an earlier call): a
+    row's id is then its segment's index there, a row with a negative id
+    belongs to none, and ``(y, carry)`` comes back, the new carry holding
+    the last ``K - 1`` rows of every segment (the old ones moved up where
+    a segment brought fewer; a segment with no row keeps its own)."""
     t, taps = x.shape[0], w.shape[1]
     w = w.astype(jnp.float32)
     # every tap reads a window of ONE padded copy, so the taps fuse into a
     # single pass over it; rows of one sequence are contiguous, so the row
     # s back belongs to this sequence exactly if it carries this row's id
     xp = jnp.pad(x.astype(jnp.float32), ((taps - 1, 0), (0, 0)))
-    sp = jnp.pad(segment_ids, (taps - 1, 0), constant_values=-1)
     y = xp[taps - 1:] * w[:, taps - 1]
+    if carry is None:
+        sp = jnp.pad(segment_ids, (taps - 1, 0), constant_values=-1)
+        for s in range(1, taps):
+            lo = taps - 1 - s
+            same = (sp[lo:lo + t] == segment_ids)[:, None]
+            y = y + jnp.where(same, xp[lo:lo + t], 0.0) * w[:, lo]
+        return y if bias is None else y + bias.astype(jnp.float32)
+    k1, n_seg = taps - 1, carry.shape[0]
+    carry = carry.astype(jnp.float32)
+    idx = jnp.arange(t, dtype=jnp.int32)
+    seg = segment_ids.astype(jnp.int32)
+    begins = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
+    # a row's place in its segment, and its segment's carry (any for a row
+    # of none)
+    place = idx - jax.lax.cummax(jnp.where(begins, idx, 0))
+    own = jnp.clip(seg, 0, n_seg - 1)
     for s in range(1, taps):
-        lo = taps - 1 - s
-        same = (sp[lo:lo + t] == segment_ids)[:, None]
-        y = y + jnp.where(same, xp[lo:lo + t], 0.0) * w[:, lo]
-    return y
+        lo = k1 - s
+        # the row s back: of this buffer, or (before the segment's first)
+        # entry ``place - s`` from the carry's end
+        before = carry[own, jnp.clip(k1 - s + place, 0, k1 - 1)]
+        y = y + jnp.where((place >= s)[:, None], xp[lo:lo + t],
+                          before) * w[:, lo]
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    # the last K - 1 rows of [carry | the segment's rows], a segment
+    where = jnp.where(seg >= 0, seg, n_seg)             # (none: dropped)
+    count = jnp.zeros((n_seg,), jnp.int32).at[where].add(1, mode="drop")
+    first = jnp.full((n_seg,), t, jnp.int32).at[where].min(idx, mode="drop")
+    at = count[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :]
+    old = jnp.take_along_axis(carry, jnp.clip(at, 0, k1 - 1)[:, :, None],
+                              axis=1)
+    new = x.astype(jnp.float32)[jnp.clip(first[:, None] + at - k1, 0, t - 1)]
+    return y, jnp.where((at < k1)[:, :, None], old, new)
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,

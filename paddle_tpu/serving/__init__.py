@@ -19,6 +19,7 @@ from paddle_tpu.serving.engine import (DecodeModel, DecoderLM, ServingEngine,
                                        greedy_decode_reference, validate_tp)
 from paddle_tpu.serving.block_moe_lm import BlockMoeLM
 from paddle_tpu.serving.window_moe_lm import WindowMoeLM
+from paddle_tpu.serving.hybrid_ssm_lm import HybridSsmLM
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
                                           SamplingParams, accept_tokens,
                                           next_token, warp_probs)
@@ -27,12 +28,13 @@ from paddle_tpu.serving.faults import (FaultPlan, FleetFaultPlan,
                                        PageLeakError)
 from paddle_tpu.serving.fleet import FleetRouter, Replica, ReplicaState
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, KVPages, PagedKVConfig,
-                                         PagePool, PrefixCache, append_token,
+                                         PagePool, PrefixCache, RecurrentState,
+                                         append_token,
                                          dequantize_kv, fork_page, gather_kv,
                                          init_kv_pages, layer_pages,
                                          pages_for_budget,
                                          prefix_chain_hashes, quantize_kv,
-                                         resolve_kv_dtype)
+                                         recurrent_state, resolve_kv_dtype)
 from paddle_tpu.serving.metrics import FleetMetrics, ServingMetrics
 from paddle_tpu.serving.migrate import (MigrationBlob,
                                         check_migration_conservation,
@@ -45,7 +47,7 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
 
 __all__ = [
     "ServingEngine", "DecodeModel", "DecoderLM", "BlockMoeLM",
-    "WindowMoeLM",
+    "WindowMoeLM", "HybridSsmLM",
     "greedy_decode_reference",
     "ragged_paged_attention", "ragged_paged_attention_reference",
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",
@@ -54,7 +56,8 @@ __all__ = [
     "init_kv_pages", "layer_pages", "append_token",
     "gather_kv", "fork_page", "prefix_chain_hashes", "quantize_kv",
     "dequantize_kv",
-    "pages_for_budget", "resolve_kv_dtype",
+    "pages_for_budget", "resolve_kv_dtype", "RecurrentState",
+    "recurrent_state",
     "ContinuousBatchingScheduler", "Request", "RequestStatus",
     "SchedulerConfig", "bucket_for", "pack_prefill_chunks",
     "ServingMetrics", "FleetMetrics",

@@ -127,6 +127,26 @@ the step it always built.  The prefix cache is not built for such a model
 speculation, tensor parallelism, int8 pages, the host tier, chain
 migration and a block model refuse it at construction.
 
+Recurrent state: a model that says ``layer_state(l)`` keeps, in those
+layers, a constant-size state a slot BESIDE the layer's pages or ring
+(``kv_cache.RecurrentState``: a state-space branch's state and its
+convolution's last inputs; arrays ``[slots, ...]``, one a layer and leaf,
+under the same ``pool_bytes``, counted by ``free_bytes`` and
+``check_page_conservation``).  The compiled step hands ``model.mix`` the
+layer's arrays and the tick's row layout and takes them back; they enter
+and leave the step behind the rings' arrays, donated, and never cross to
+the host.  The rules (``mix`` keeps them, the tests pin them): a slot
+decodes OR prefills in a step and its state is written once; a chunk that
+starts at position 0 reads zeros whatever the slot held (admission,
+re-prefill after preemption: nothing is cleared on the host); a chunk at
+a later position reads what the sequence's last row wrote; padding rows,
+invalid decode rows and idle slots leave the state bit for bit.  What
+cannot hold over such a state refuses the model at construction, each by
+its mechanism (``_refuse_for_recurrent_model``): the prefix cache,
+speculation, the host tier, chain migration, tensor parallelism, a block
+model.  Preemption works: the pages go back, the state is overwritten
+from zero at the re-prefill.
+
 What lands when (both step kinds): a step chooses its tokens itself.
 A one-token tick takes, inside the compiled step, the first maximum of
 each decode row's and of each chunk-final row's logits and whether the
@@ -199,13 +219,15 @@ from paddle_tpu.serving.faults import (FaultPlan, InjectedDeviceError,
                                        PageLeakError)
 from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
                                          KVPages, PagedKVConfig, PagePool,
-                                         PrefixCache, WindowRing, append_token,
+                                         PrefixCache, RecurrentState,
+                                         WindowRing, append_token,
                                          dequantize_kv, fork_page,
                                          init_kv_pages, kv_pool_specs,
                                          layer_kinds, layer_pages,
                                          make_window_ring, pages_for_budget,
                                          pages_spanned,
-                                         read_pages, resolve_kv_dtype,
+                                         read_pages, recurrent_state,
+                                         resolve_kv_dtype,
                                          split_pool_bytes,
                                          write_pages, zero_pages)
 from paddle_tpu.serving.metrics import ServingMetrics
@@ -262,6 +284,19 @@ class DecodeModel:
       has, and ``layer_heads`` (optional; a count a layer) says each
       layer's where they differ: the host's count of the kernel's
       visits lays the tall blocks out by it.
+    - ``layer_state(layer) -> Optional[{leaf: (shape, dtype)}]`` with
+      ``mix(params, layer, x, state, rows) -> (mixed, state)`` (optional,
+      together): a layer that keeps a constant-size RECURRENT state a
+      slot beside its K/V (``ServingEngine``: "recurrent state").
+      ``mix`` is called in the layer's scope beside ``qkv``, on the same
+      block input ``x [T, E]``, with the layer's arrays ``{leaf: [slots,
+      ...]}`` and the tick's row layout ``rows``: ``row_seq [T]`` (a row's
+      slot), ``pos [T]``, ``live [T]`` (False: padding or an invalid row)
+      and ``decode_rows`` (static: the first so many rows are the slots'
+      decode rows, row ``s`` slot ``s``'s; the rest the bucket's chunks, a
+      chunk's rows contiguous).  ``mixed`` is the model's own (the
+      branch's rows, its counts) and reaches ``attn_out`` /
+      ``attn_out_counted`` as a further last argument.
 
     Tensor-parallel serving (``ServingEngine(mesh=...)``) additionally
     needs:
@@ -604,6 +639,11 @@ class ServingEngine:
                      context="serving")
         page_size = int(page_size or FLAGS.serving_page_size)
         max_slots = int(max_slots or FLAGS.serving_max_slots)
+        # (as asked for, for what refuses a kind of model below)
+        spec_asked = spec_mode if spec_mode is not None \
+            else FLAGS.serving_spec_mode
+        tier_asked = host_tier_bytes if host_tier_bytes is not None \
+            else FLAGS.serving_host_tier_bytes
         if prefill_chunk is None:
             prefill_chunk = int(FLAGS.serving_prefill_chunk)
         # a block model (see the module doc): B rows a slot and tick,
@@ -617,11 +657,7 @@ class ServingEngine:
             self._fix_rows = self._block // self._denoise_steps
             self._ticks_per_token = (self._denoise_steps + 1) / self._block
             self._refuse_for_block_model(
-                page_size, int(prefill_chunk), mesh,
-                spec_mode if spec_mode is not None
-                else FLAGS.serving_spec_mode,
-                host_tier_bytes if host_tier_bytes is not None
-                else FLAGS.serving_host_tier_bytes)
+                page_size, int(prefill_chunk), mesh, spec_asked, tier_asked)
         # KV storage dtype: explicit kv_dtype > legacy dtype param >
         # FLAGS.serving_kv_dtype.  int8 turns on quantized pages.
         if kv_dtype is None:
@@ -635,11 +671,15 @@ class ServingEngine:
         kinds = layer_kinds(model)
         if len(kinds) > 1:
             self._refuse_for_window_model(
-                kinds, mesh, kv_dtype, prefix_cache,
-                spec_mode if spec_mode is not None
-                else FLAGS.serving_spec_mode,
-                host_tier_bytes if host_tier_bytes is not None
-                else FLAGS.serving_host_tier_bytes)
+                kinds, mesh, kv_dtype, prefix_cache, spec_asked, tier_asked)
+            prefix_cache = False
+        # and what a layer keeps BESIDE them: a constant-size state a slot
+        # (the module doc: "recurrent state"); None for a model without
+        self._recurrent: Optional[RecurrentState] = recurrent_state(
+            model, max_slots)
+        if self._recurrent is not None:
+            self._refuse_for_recurrent_model(
+                mesh, prefix_cache, spec_asked, tier_asked)
             prefix_cache = False
         rows = int(prefill_chunk) if int(prefill_chunk) > 0 else 1 << 30
         self._rings: Tuple[WindowRing, ...] = tuple(
@@ -713,7 +753,8 @@ class ServingEngine:
             # heads).  The scheduler charges admission in pages, so both
             # multipliers flow straight into admissible concurrency.
             num_pages = pages_for_budget(
-                split_pool_bytes(pool_bytes, self._rings), full_layers,
+                split_pool_bytes(pool_bytes, self._rings, self._recurrent),
+                full_layers,
                 model.num_heads, model.head_dim, page_size, kv_dtype,
                 num_kv_heads=num_kv_heads, tp=self.tp)
         num_pages = int(num_pages or FLAGS.serving_max_pages)
@@ -754,6 +795,12 @@ class ServingEngine:
                                           axis=self.tp_axis)
         self._ring_kv: Tuple[KVPages, ...] = tuple(
             init_kv_pages(ring.cfg) for ring in self._rings)
+        # the recurrent kind's arrays ({leaf: [slots, ...]} a layer of the
+        # kind) and, by model layer, which of them is the layer's
+        self._rec_kv: Tuple[Dict[str, jax.Array], ...] = \
+            self._recurrent.init() if self._recurrent is not None else ()
+        self._rec_layer = {l: j for j, l in enumerate(
+            self._recurrent.layers if self._recurrent is not None else ())}
         self.pool = PagePool(num_pages)
         if prefix_cache is None:
             prefix_cache = bool(FLAGS.serving_prefix_cache)
@@ -802,6 +849,10 @@ class ServingEngine:
             "window_kv_tokens_live", "window_pages_released",
             "window_kernel_calls",
             "window_grid_cells", "window_live_cells") if self._rings else ()
+        if self._recurrent is not None:
+            self._kind_counted = (self._kind_counted
+                                  or ("full_kv_tokens_held",)) + (
+                "state_slots_live", "state_bytes_live")
         self.metrics = ServingMetrics(
             pool_pages=self.pool.num_usable,
             model_counters=self._counted + self._kind_counted)
@@ -910,7 +961,9 @@ class ServingEngine:
         # run still declares — and the jaxpr auditor still verifies —
         # the TPU donation contract.  The old per-backend gate here left
         # the contract invisible (and untested) on CPU.
-        self._donate_kv = (1,) + tuple(range(4, 4 + len(self._rings)))
+        # (behind the rings' arrays: the recurrent kind's, one argument)
+        self._donate_kv = (1,) + tuple(range(
+            4, 4 + len(self._rings) + (self._recurrent is not None)))
         # compiled-path contracts, declared next to the jit sites they
         # bind (checked by `python -m paddle_tpu.analysis xla`): the KV
         # pool must be donated and alias back out, per-tick sites must
@@ -936,7 +989,9 @@ class ServingEngine:
         # see GSPMD's per-chip split — so scale the per-chip pool bytes
         # back up by tp (healthz keeps reporting the per-chip number)
         kv_bytes = self.kv_cfg.kv_bytes() * self.tp + \
-            sum(ring.kv_bytes() for ring in self._rings)
+            sum(ring.kv_bytes() for ring in self._rings) + \
+            (self._recurrent.kv_bytes() if self._recurrent is not None
+             else 0)
         act_bytes = 4 * rows * (8 * e * model.num_layers
                                 + model.vocab_size)
         kv_name = jnp.dtype(self.kv_cfg.dtype).name
@@ -1139,6 +1194,48 @@ class ServingEngine:
                      "model with window layers serves as 'unified'",
                      context=ctx)
 
+    def _refuse_for_recurrent_model(self, mesh, prefix_cache,
+                                    spec_mode: str,
+                                    host_tier_bytes: int) -> None:
+        """What a model with a recurrent state cannot be built with, said
+        at construction, each by its mechanism (nothing takes a silent
+        second path).  The prefix cache left to its default is simply
+        not built."""
+        from paddle_tpu.platform.enforce import enforce_that
+
+        ctx = "serving-recurrent"
+        enforce_that(self._block is None,
+                     "a block model rewrites its current block at every "
+                     "pass; a recurrent state has advanced past a row once "
+                     "it has seen it: serve a block model without "
+                     "layer_state", context=ctx)
+        enforce_that(not prefix_cache,
+                     "the prefix cache stitches pages; a hit would also "
+                     "need the recurrent state at the prefix's end, and a "
+                     "snapshot of it at page boundaries is not built: "
+                     "build the engine with prefix_cache=False (the "
+                     "default builds none for a model with a recurrent "
+                     "state)", context=ctx)
+        enforce_that(str(spec_mode) == "off",
+                     "speculative decoding rolls rejected rows back by "
+                     "page; a rejected row has already advanced the "
+                     "recurrent state, which keeps no earlier copy: build "
+                     "it with spec_mode='off'", context=ctx)
+        enforce_that(mesh is None,
+                     "tensor-parallel serving (mesh=) of a model with a "
+                     "recurrent state is not built: the state has no "
+                     "placement over a mesh; serve it with mesh=None",
+                     context=ctx)
+        enforce_that(int(host_tier_bytes) <= 0,
+                     "the host tier spills cached prefix pages, which a "
+                     "model with a recurrent state has none of: build it "
+                     "with host_tier_bytes=0", context=ctx)
+        enforce_that(self.role == "unified",
+                     f"role={self.role!r} hands requests over by chain "
+                     "migration, which exports pages only and would leave "
+                     "the recurrent state behind: a model with a recurrent "
+                     "state serves as 'unified'", context=ctx)
+
     # ---- observability wiring -------------------------------------------
 
     def set_tracer(self, tracer) -> None:
@@ -1337,9 +1434,15 @@ class ServingEngine:
             "attn.full" if windows[i] is None else "attn.window")) \
             if rings else (lambda i: contextlib.nullcontext())
 
+        rec_layer = self._rec_layer
+        mix = getattr(model, "mix", None)
+
         def raw(params, kv: KVPages, packed, *last):
-            # (behind the last step's words: each ring's arrays)
-            last, ring_kv = last[:1], last[1:]
+            # (behind the last step's words: each ring's arrays, then the
+            # recurrent kind's)
+            last, ring_kv, rec_kv = (last[:1], last[1:1 + len(rings)],
+                                     last[1 + len(rings):])
+            rec = list(rec_kv[0]) if rec_kv else []
             # packed: the tick's one int32 input buffer, replicated;
             # taken apart by static slices (a chip reads its own copy)
             (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last,
@@ -1402,6 +1505,8 @@ class ServingEngine:
                 jnp.where(live, t[row_seq, (pos // page) % t.shape[1]],
                           NULL_PAGE) for t in tables[1:]]
             walks = {}      # the kernel's walks, by kind and head count
+            # the tick's row layout, for a layer that keeps a state a slot
+            rows = dict(row_seq=row_seq, pos=pos, live=live, decode_rows=bd)
             for l in range(model.num_layers):
                 # named_scope: blocks and their parts show up by name in
                 # xplane/profiler traces (as topology.forward's layers)
@@ -1417,13 +1522,21 @@ class ServingEngine:
                         ctx = self._attend(state[i], li, q, tables[i],
                                            att_lens, row_seq, qpos, k1=k1,
                                            window=windows[i], walks=walks)
+                    more = ()
+                    if l in rec_layer:
+                        # the layer's recurrent branch, on the same input
+                        mixed, rec[rec_layer[l]] = mix(
+                            params, l, x, rec[rec_layer[l]], rows)
+                        more = (mixed,)
                     if self._counted:
                         x, n = model.attn_out_counted(params, l, ctx, x,
-                                                      live)
+                                                      live, *more)
                         counts = counts + n
                     else:
-                        x = model.attn_out(params, l, ctx, x)
+                        x = model.attn_out(params, l, ctx, x, *more)
             kv, ring_kv = state[0], tuple(state[1:])
+            if rec_kv:
+                ring_kv += (tuple(rec),)
             more = (counts,) if self._counted else ()
             if blk is not None:
                 # logits for the rows a denoising pass fixes only.  What
@@ -1839,13 +1952,15 @@ class ServingEngine:
         # free or a running request's, so no ring is lost with one
         slots = len(self.scheduler.running) + \
             len(self.scheduler._free_slots)
-        if self._rings and slots != self._max_slots:
-            self._dump_postmortem("RING-LEAK")
+        if (self._rings or self._recurrent is not None) \
+                and slots != self._max_slots:
+            token = "RING-LEAK" if self._rings else "STATE-LEAK"
+            self._dump_postmortem(token)
             raise PageLeakError(
-                f"RING-LEAK: {len(self.scheduler.running)} running and "
+                f"{token}: {len(self.scheduler.running)} running and "
                 f"{len(self.scheduler._free_slots)} free slots of "
-                f"{self._max_slots}: a slot's window rings are held by "
-                "no one")
+                f"{self._max_slots}: a slot's window rings or recurrent "
+                "state are held by no one")
         if self._proposer is not None:
             # the draft-model pool obeys the same conservation law:
             # pages held by live draft states == draft-pool refcounts
@@ -1862,11 +1977,13 @@ class ServingEngine:
 
     def free_bytes(self) -> int:
         """What of ``pool_bytes`` no live sequence holds, over every kind
-        of layer state: the free list's pages and the rings of the free
-        slots (per chip)."""
+        of layer state: the free list's pages and the rings and recurrent
+        states of the free slots (per chip)."""
+        per_slot = sum(ring.bytes_per_slot() for ring in self._rings) + (
+            self._recurrent.bytes_per_slot() if self._recurrent is not None
+            else 0)
         return self.pool.num_free * self.kv_cfg.bytes_per_page() + \
-            len(self.scheduler._free_slots) * sum(
-                ring.bytes_per_slot() for ring in self._rings)
+            len(self.scheduler._free_slots) * per_slot
 
     # ---- page-migration plane (round 16) --------------------------------
 
@@ -1875,9 +1992,10 @@ class ServingEngine:
         replica: still RUNNING, prefill fully materialized, and at
         least the first token emitted (so the destination starts with a
         decodable state — ``generated[-1]`` is the next step's input)."""
-        if self._block is not None or self._rings:
+        if self._block is not None or self._rings \
+                or self._recurrent is not None:
             return []     # a block between passes is not handed over,
-            #               nor a window layer's ring
+            #               nor a window layer's ring, nor a slot's state
         self.land()       # (the answer is about tokens the host has)
         return [r.rid for r in self.scheduler.running_requests()
                 if r.status is RequestStatus.RUNNING and not r.prefilling
@@ -2364,10 +2482,12 @@ class ServingEngine:
             step = self._step_fn(pb, k1)
             placed = jax.device_put(packed, self._tick_sharding)
             with phase("tick.dispatch", tick=tick):
-                words, logits, self._kv, *ring_kv = step(
+                words, logits, self._kv, *kinds = step(
                     self.params, self._kv, placed, self._last_words(),
-                    *self._ring_kv)
-                self._ring_kv = tuple(ring_kv)
+                    *self._kind_kv())
+                self._ring_kv = tuple(kinds[:len(self._rings)])
+                if self._recurrent is not None:
+                    self._rec_kv = kinds[-1]
         if self._block is not None:
             passes, n_rows = self._passes(running), len(running) * k1
         else:
@@ -2387,6 +2507,13 @@ class ServingEngine:
             self._land(before, lagged=True)
         if self._lands_now(flight):
             self._land(flight)
+
+    def _kind_kv(self) -> Tuple:
+        """What a step takes behind the last step's words, and returns
+        behind the pool: each ring's arrays, then (one argument) the
+        recurrent kind's."""
+        return self._ring_kv + ((self._rec_kv,) if self._recurrent
+                                is not None else ())
 
     # ---- the lagged read back --------------------------------------------
 
@@ -2659,11 +2786,20 @@ class ServingEngine:
         rows' windows reach (both summed over the window kinds), the
         pages that fell out of a window with this step's rows, and the
         window layers' kernel calls, grid steps and live grid steps
-        (:meth:`_window_cells`).  Empty for a model without window
-        layers."""
-        if not self._rings:
+        (:meth:`_window_cells`); for a model with a recurrent state the
+        slots that hold a sequence and their states' bytes over its
+        layers.  Empty for a model with neither."""
+        if not self._kind_counted:
             return ()
         d_valid, att_lens = parts[2], parts[8]
+        state = ()
+        if self._recurrent is not None:
+            # the slots that hold a sequence (which its state belongs to,
+            # with or without a row in this step), and their states' bytes
+            live = len(self.scheduler.running)
+            state = (live, live * self._recurrent.bytes_per_slot())
+        if not self._rings:
+            return (int(att_lens.sum()),) + state
         rows = d_valid.sum(axis=1)         # a slot's rows of this step
         for req, _start, n, _rows in chunks:
             rows[req.slot] = n
@@ -2674,7 +2810,7 @@ class ServingEngine:
             released += int((ring.released(att_lens)
                              - ring.released(att_lens - rows)).sum())
         return (int(att_lens.sum()), held, seen, released) + \
-            self._window_cells(parts)
+            self._window_cells(parts) + state
 
     def _window_cells(self, parts) -> Tuple[int, int, int]:
         """(kernel calls, grid steps, those that compute) of the window

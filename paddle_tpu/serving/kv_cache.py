@@ -38,29 +38,44 @@ out, which keeps every scatter dense and shape-stable under jit.  No
 live sequence is ever granted page 0.
 
 **Kinds of layer state.**  What a layer keeps of a live sequence is
-its KIND's business (:func:`layer_kinds` reads it off the model).  The
-first kind is the full-attention pages described above: every token of
-the sequence, in pages from the one free list, for as long as the
-sequence lives; a model whose layers all attend over everything has this
-kind alone and builds exactly the pool it always built (the pool then
-holds every layer).  A layer with a WINDOW (``model.layer_window(l)``)
-attends over the last ``window`` tokens only, so its kind keeps no more
-than those, the chunk in flight and page rounding: a :class:`WindowRing`
-gives every slot a ring of ``R = ceil((window + rows - 1) / page) + 1``
-pages of its own (``rows`` the most rows a slot brings in one step:
-:func:`window_pages`).  THE
-RULE: page ``a`` of a sequence (positions ``[a * page, (a + 1) * page)``)
-lives at entry ``a mod R`` of its slot's ring; when the sequence writes
-position ``p`` it overwrites what page ``p // page - R`` left there, which
-by then lies below every window that can still be asked for.  So a page
-that falls out of the window is released WHILE the sequence lives and
-used again by the same slot's later positions; nothing is allocated at
-admission, growth cannot fail, and preemption, cancellation and
-completion return the ring with the slot.  Each window kind has device
-arrays of its own (``[layers of the kind, 1 + slots * R, page, H_kv *
-D]``: its layers only), and ONE byte budget is divided between the kinds
-(:func:`split_pool_bytes`): the rings take what their bound needs, the
-free list gets the rest.
+its KIND's business; there are three, read off the model
+(:func:`layer_kinds`, :func:`recurrent_state`), under ONE byte budget
+(:func:`split_pool_bytes`: the kinds bound to a slot take what their
+bound needs, the free list gets the rest):
+
+1. PAGES (every model): every token of the sequence, in pages from the
+   one free list, granted at admission and growth, freed (or shared,
+   forked, cached, rolled back, migrated) by page, for as long as the
+   sequence lives.  A model whose layers all attend over everything and
+   keep nothing else has this kind alone and builds exactly the pool it
+   always built (the pool then holds every layer).
+2. A RING of pages a slot (``model.layer_window(l)``; exclusive with 1 in
+   a layer): a layer with a WINDOW attends over the last ``window`` tokens
+   only, so its kind keeps no more than those, the chunk in flight and
+   page rounding: a :class:`WindowRing` gives every slot a ring of ``R =
+   ceil((window + rows - 1) / page) + 1`` pages of its own (``rows`` the
+   most rows a slot brings in one step: :func:`window_pages`).  THE RULE:
+   page ``a`` of a sequence (positions ``[a * page, (a + 1) * page)``)
+   lives at entry ``a mod R`` of its slot's ring; when the sequence writes
+   position ``p`` it overwrites what page ``p // page - R`` left there,
+   which by then lies below every window that can still be asked for.  So
+   a page that falls out of the window is released WHILE the sequence
+   lives and used again by the same slot's later positions; nothing is
+   allocated at admission, growth cannot fail, and preemption,
+   cancellation and completion return the ring with the slot.  Each
+   window kind has device arrays of its own (``[layers of the kind, 1 +
+   slots * R, page, H_kv * D]``: its layers only).
+3. A RECURRENT STATE a slot (``model.layer_state(l)``; BESIDE 1 or 2 in
+   the same layer): a constant-size state whatever the sequence's length
+   (a state-space branch's ``[heads, lanes, state]`` and its
+   convolution's last inputs), one array a layer and leaf, ``[slots,
+   ...]`` (:class:`RecurrentState`).  Its lifetime is the slot's: the
+   compiled step starts a sequence's first row (position 0: admission, or
+   re-prefill after preemption) from zeros whatever the slot held, every
+   later row reads what the sequence's last row wrote, and a slot
+   without a row in a step keeps its state bit for bit.  Nothing is
+   allocated, freed, forked or rolled back, and nothing is cleared on the
+   host.
 
 Automatic prefix caching (round 9): pages are **refcounted** — a page
 shared by N sequences is freed only when the last holder unrefs it —
@@ -318,13 +333,70 @@ def make_window_ring(kind: LayerKind, *, slots: int, rows: int,
         dtype=resolve_kv_dtype(dtype), num_kv_heads=num_kv_heads))
 
 
-def split_pool_bytes(pool_bytes: int, rings: Sequence[WindowRing]) -> int:
-    """ONE byte budget over the kinds: the rings take what their bound
-    needs (they cannot run with less), the full-attention free list gets
-    what is left, which is returned."""
-    left = int(pool_bytes) - sum(r.kv_bytes() for r in rings)
+@dataclass(frozen=True)
+class RecurrentState:
+    """The recurrent kind (the module doc): ``layers`` are the model's
+    layers that keep one, ``leaves`` ``((name, shape, dtype name), ...)``
+    what ONE slot keeps in ONE of them, the same in each."""
+
+    layers: Tuple[int, ...]
+    slots: int
+    leaves: Tuple[Tuple[str, Tuple[int, ...], str], ...]
+
+    def bytes_per_slot(self) -> int:
+        """A slot's state over all the kind's layers."""
+        return len(self.layers) * sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for _, shape, dtype in self.leaves)
+
+    def kv_bytes(self) -> int:
+        """The kind's device arrays."""
+        return self.slots * self.bytes_per_slot()
+
+    def init(self) -> Tuple[Dict[str, jax.Array], ...]:
+        """Zeros: for each of the kind's layers ``{leaf: [slots, ...]}``.
+        One array a layer and leaf, not one stacked over the layers: the
+        step reads and writes a layer's WHOLE array, which as a slab of a
+        stacked one would be copied out and back."""
+        return tuple({name: jnp.zeros((self.slots,) + shape, dtype)
+                      for name, shape, dtype in self.leaves}
+                     for _ in self.layers)
+
+
+def recurrent_state(model, slots: int) -> Optional[RecurrentState]:
+    """The model's recurrent kind: the layers whose ``layer_state(l)`` is
+    not None, ascending.  None for a model without the member (or without
+    such a layer)."""
+    state_of = getattr(model, "layer_state", None)
+    if state_of is None:
+        return None
+    found = {l: state_of(l) for l in range(int(model.num_layers))}
+    layers = tuple(l for l, leaves in found.items() if leaves is not None)
+    if not layers:
+        return None
+    norm = lambda leaves: tuple(  # noqa: E731
+        (str(name), tuple(int(n) for n in shape), jnp.dtype(dtype).name)
+        for name, (shape, dtype) in sorted(leaves.items()))
+    leaves = norm(found[layers[0]])
+    for l in layers[1:]:
+        enforce_that(norm(found[l]) == leaves,
+                     f"layer {l} keeps another recurrent state than layer "
+                     f"{layers[0]}: one kind holds one shape of state",
+                     context="serving")
+    return RecurrentState(layers, int(slots), leaves)
+
+
+def split_pool_bytes(pool_bytes: int, rings: Sequence[WindowRing],
+                     recurrent: Optional[RecurrentState] = None) -> int:
+    """ONE byte budget over the kinds: the recurrent states and the rings
+    take what their bound needs (they cannot run with less), the
+    full-attention free list gets what is left, which is returned."""
+    state = recurrent.kv_bytes() if recurrent is not None else 0
+    left = int(pool_bytes) - state - sum(r.kv_bytes() for r in rings)
+    held = (f"the {recurrent.slots} slots' recurrent states ({state} "
+            "bytes) and " if recurrent is not None else "")
     enforce_that(left > 0,
-                 f"pool_bytes ({pool_bytes}) does not hold the window "
+                 f"pool_bytes ({pool_bytes}) does not hold {held}the window "
                  f"layers' rings ({[r.kv_bytes() for r in rings]} bytes): "
                  "give the pool more, or fewer slots", context="serving")
     return left

@@ -147,6 +147,10 @@ def export_chain(engine, rid: int) -> MigrationBlob:
                  "a model with window layers is not handed over: the chain "
                  "holds full-attention pages only and would leave the "
                  "window layers' rings behind", context="serving-migrate")
+    enforce_that(engine._recurrent is None,
+                 "a model with a recurrent state is not handed over: the "
+                 "chain holds pages only and would leave the slot's state "
+                 "behind", context="serving-migrate")
     engine.land()    # the tokens of a step in the air belong to the chain
     enforce_that(req.status is RequestStatus.RUNNING and
                  not req.prefilling and bool(req.generated),
@@ -186,6 +190,10 @@ def import_chain(engine, blob: MigrationBlob, *, on_token=None,
     enforce_that(not engine._rings,
                  "a chain carries full-attention pages only: an engine "
                  "whose model has window layers cannot take one in",
+                 context="serving-migrate")
+    enforce_that(engine._recurrent is None,
+                 "a chain carries pages only: an engine whose model keeps "
+                 "a recurrent state cannot take one in",
                  context="serving-migrate")
     now = engine._time() if now is None else now
     sched = engine.scheduler

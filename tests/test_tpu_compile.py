@@ -234,6 +234,10 @@ WALK_CELLS = [
     ("6.7b_tp4_decode", 256, 0, 32, 32, None, 32, 10, 4),
     ("sdar_block4", 256, 256, 32, 4, None, 32, 12, 1),   # 4 rows a slot
     ("sdar_block4_decode", 256, 0, 32, 4, None, 32, 12, 1),
+    # falcon-h1: 20 query heads on 4 KV heads (a GQA group of 5, no power
+    # of two), 64 slots, a 38-page table
+    ("falconh1_group5", 512, 1024, 20, 4, None, 64, 38, 1),
+    ("falconh1_group5_decode", 512, 0, 20, 4, None, 64, 38, 1),
 ]
 
 
@@ -577,6 +581,96 @@ def test_window_moe_serving_step_compiles_for_v5e_at_published_widths(
     print("window moe step", pb, "temp bytes", mem.temp_size_in_bytes,
           "args", mem.argument_size_in_bytes)
     assert mem.temp_size_in_bytes < 300e6
+
+
+# ---- a serving step of the hybrid state-space model -------------------------
+
+def test_a_gqa_group_of_five_lays_its_blocks_out():
+    from paddle_tpu.serving.decode_attention import (heads_per_cell,
+                                                     tall_rows_for)
+
+    # all 4 KV heads of 128 in one grid cell; 512 // 5 = 102 score rows a
+    # head: the power of two below
+    cell = heads_per_cell(4, 128, 128, 4, False)
+    assert cell == 4
+    assert tall_rows_for(1024, 5, cell, 128) == 64
+
+
+@pytest.mark.parametrize("pb", [0, 1024])
+def test_hybrid_ssm_serving_step_compiles_for_v5e_at_published_widths(
+        topo, monkeypatch, pb):
+    """``serving.HybridSsmLM`` behind ``ServingEngine`` at the cell's
+    widths (hidden 5120, 20 query heads on 4 KV heads of 128, 32
+    state-space heads of 128 with state 256 in 2 groups, 4 taps, SwiGLU
+    21,504, an eighth of the vocabulary; 4 blocks), 64 slots under the
+    cell's 3.5 GiB pool, the decode-only step and the one with the
+    1024-row prefill bucket: both lower and compile for a described v5e
+    with the ragged kernel at a GQA group of 5; the slots' states enter
+    donated and alias back out, no copy of a layer's [64, 32, 128, 256]
+    is made, and the step holds under the chip's 16 GB."""
+    from paddle_tpu.analysis import retrace
+    from paddle_tpu.serving import HybridSsmLM, ServingEngine
+    from paddle_tpu.serving import decode_attention as da
+    from paddle_tpu.serving import engine as eng_mod
+    from paddle_tpu.serving import kv_cache
+
+    monkeypatch.setattr(da, "_interpret_default", lambda: False)
+    monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
+    monkeypatch.setattr(eng_mod, "attention_path", lambda *a, **k: "kernel")
+    make_pool, make_states = eng_mod.init_kv_pages, \
+        kv_cache.RecurrentState.init
+    monkeypatch.setattr(
+        eng_mod, "init_kv_pages",
+        lambda cfg, **kw: jax.eval_shape(lambda: make_pool(cfg, **kw)))
+    monkeypatch.setattr(kv_cache.RecurrentState, "init",
+                        lambda self: jax.eval_shape(
+                            lambda: make_states(self)))
+    model = HybridSsmLM(
+        vocab_size=32640, embed_dim=5120, num_layers=4, num_heads=20,
+        num_kv_heads=4, head_dim=128, ffn_dim=21504, ssm_heads=32,
+        ssm_head_dim=128, ssm_state=256, ssm_groups=2, conv_taps=4,
+        chunk=128, rope_theta=1e11, norm_eps=1e-5,
+        multipliers={"embedding_multiplier": 5.656854249492381,
+                     "key_multiplier": 0.011048543456039804,
+                     "ssm_multipliers": (0.3535533905932738, 0.25,
+                                         0.1767766952966369, 0.5,
+                                         0.3535533905932738)})
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    params = {k: aval(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init_params, jax.random.PRNGKey(0)).items()}
+    eng = ServingEngine(model, params, eos_id=model.vocab_size,
+                        page_size=128, max_slots=64, pool_bytes=3758096384,
+                        max_pages_per_seq=38, buckets=(1024,),
+                        prefill_chunk=512)
+    assert eng._ragged_kernel and eng._k1 == 1 and eng.cache is None
+    assert eng.kv_cfg.num_pages == 1272
+    assert eng._recurrent.kv_bytes() == 1_089_470_464
+    buf = eng._empty_tick(pb, 1)
+    words = np.zeros(2 * (64 + 64) + 4, np.int32)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: aval(a.shape, a.dtype), tree)
+    compiled = eng._step_fn(pb, 1).lower(
+        params, on_chip(eng._kv), aval(buf.shape, buf.dtype),
+        aval(words.shape, words.dtype), on_chip(eng._rec_kv)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4       # a layer: the kernel
+    for scope in ("ssm.proj", "ssm.conv", "ssm.scan/ssd_step", "ssm.out",
+                  "attn", "ffn", "head"):
+        assert scope + "/" in text, scope
+    assert ("ssm.scan/ssd_chunks/while" in text) == (pb > 0)
+    # a layer's states are updated where they lie
+    copies = [line for line in text.splitlines()
+              if "f32[64,32,128,256]" in line.split(" = ")[-1].split("(")[0]
+              and " copy(" in line]
+    assert not copies, copies[:2]
+    mem = compiled.memory_analysis()
+    print("hybrid ssm step", pb, "temp bytes", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes, "aliased",
+          mem.alias_size_in_bytes)
+    # the pool and the 8 state arrays alias their outputs
+    assert mem.alias_size_in_bytes > 3.7e9
+    assert mem.temp_size_in_bytes < 400e6
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
 
 
 # ---- names in the device trace ---------------------------------------------
