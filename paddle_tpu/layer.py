@@ -2377,6 +2377,7 @@ def gated_delta_net(input, *, num_k_heads: int, num_v_heads: int,
     convolution start anew with each sequence), a gated RMSNorm per head
     and the output projection.  ``a_log`` starts at 0 and ``dt_bias`` at
     -4.6, a decay of about 0.99 a token.  No biases, no cache."""
+    from paddle_tpu.ops.gated_delta import conv_is_fused
     from paddle_tpu.ops.gated_delta import gated_delta_net as gdn
 
     _need_seq(input, "gated_delta_net")
@@ -2395,11 +2396,16 @@ def gated_delta_net(input, *, num_k_heads: int, num_v_heads: int,
         "wo": ParamSpec((nv, d), attr),
     }
 
+    heads = dict(num_k_heads=num_k_heads, num_v_heads=num_v_heads,
+                 head_k_dim=head_k_dim, head_v_dim=head_v_dim)
+
     def compute(ctx, p, ins):
         xs = ins[0]
-        y = gdn(xs.data, xs.segment_ids, p, num_k_heads=num_k_heads,
-                num_v_heads=num_v_heads, head_k_dim=head_k_dim,
-                head_v_dim=head_v_dim, eps=epsilon)
+        y = gdn(xs.data, xs.segment_ids, p, eps=epsilon, **heads)
+        if conv_is_fused(**heads):
+            # rows the fused convolution kernels took from the projection
+            ctx.count("gdn_conv_fused_rows_total", xs.data.shape[0],
+                      layer=name)
         return xs.with_data(y.astype(pmath.dense_activation_dtype()))
 
     return LayerOutput(name=name, layer_type="gated_delta_net",

@@ -37,7 +37,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops import gdn_kernels
+from paddle_tpu.ops import gdn_conv_kernels, gdn_kernels
 from paddle_tpu.ops import math as pmath
 from paddle_tpu.ops.norm import rms_norm
 
@@ -144,6 +144,29 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return o.reshape(n * c, hv, dv)[:t]
 
 
+def conv_is_fused(num_k_heads: int, num_v_heads: int, head_k_dim: int,
+                  head_v_dim: int) -> bool:
+    """Whether ``gated_delta_net`` at these widths takes q, k, v from the
+    fused kernels: told from the shapes alone."""
+    return gdn_conv_kernels.lane_block(gdn_conv_kernels.Dims(
+        num_k_heads, num_v_heads, head_k_dim, head_v_dim)) is not None
+
+
+def qkv_conv_xla(qkvz: jax.Array, w: jax.Array, segment_ids: jax.Array,
+                 dims: gdn_conv_kernels.Dims):
+    """``gdn_conv_kernels.qkv_conv`` composed from ``causal_conv``: what
+    the layer runs at widths the kernels do not take, and what the tests
+    hold the kernels to."""
+    t, nq = qkvz.shape[0], dims.nq
+    qkv = jax.nn.silu(causal_conv(qkvz[:, :dims.channels], w, segment_ids))
+    unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+    q = unit(qkv[:, :nq].reshape(t, dims.hk, dims.dk)) \
+        * float(dims.dk) ** -0.5
+    k = unit(qkv[:, nq:2 * nq].reshape(t, dims.hk, dims.dk))
+    return q.reshape(t, nq), k.reshape(t, nq), qkv[:, 2 * nq:]
+
+
 def gated_delta_net(x: jax.Array, segment_ids: jax.Array,
                     p: Dict[str, jax.Array], *, num_k_heads: int,
                     num_v_heads: int, head_k_dim: int, head_v_dim: int,
@@ -157,29 +180,31 @@ def gated_delta_net(x: jax.Array, segment_ids: jax.Array,
     ``[q|k|v] <- silu(conv(q|k|v))``; q and k are L2-normalised per head and
     q scaled by ``dk ** -0.5``; ``beta = sigmoid(b)``, ``g = -exp(a_log)
     softplus(a + dt_bias)``; after the recurrence ``y = RMSNorm(o) * norm
-    * silu(z)`` per head, times ``wo``."""
+    * silu(z)`` per head, times ``wo``.
+
+    From ``qkvz`` to q, k, v is one Pallas kernel each way
+    (``ops/gdn_conv_kernels.py``) where the heads fill whole 128-lane tiles
+    (``conv_is_fused``); at other widths the same steps stay with XLA."""
     t = x.shape[0]
     hk, hv, dk, dv = num_k_heads, num_v_heads, head_k_dim, head_v_dim
-    nq, nv = hk * dk, hv * dv
+    nv = hv * dv
     with jax.named_scope("gdn"):
         with jax.named_scope("gdn.proj"):
             qkvz = pmath.matmul(x, p["w_qkvz"])
             ba = pmath.matmul(x, p["w_ba"])
         with jax.named_scope("gdn.conv"):
-            qkv = jax.nn.silu(causal_conv(qkvz[:, :2 * nq + nv], p["conv"],
-                                          segment_ids))
-            unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
-                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
-            q = unit(qkv[:, :nq].reshape(t, hk, dk)) * float(dk) ** -0.5
-            k = unit(qkv[:, nq:2 * nq].reshape(t, hk, dk))
-            v = qkv[:, 2 * nq:].reshape(t, hv, dv)
+            dims = gdn_conv_kernels.Dims(hk, hv, dk, dv)
+            prologue = gdn_conv_kernels.qkv_conv \
+                if conv_is_fused(*dims) else qkv_conv_xla
+            q, k, v = (a.reshape(t, -1, d) for a, d in zip(
+                prologue(qkvz, p["conv"], segment_ids, dims), (dk, dk, dv)))
             beta = jax.nn.sigmoid(ba[:, :hv])
             g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
                 ba[:, hv:] + p["dt_bias"].astype(jnp.float32))
         with jax.named_scope("gdn.scan"):
             o = gated_delta_rule(q, k, v, g, beta, segment_ids)
         with jax.named_scope("gdn.out"):
-            z = qkvz[:, 2 * nq + nv:].reshape(t, hv, dv)
+            z = qkvz[:, dims.channels:].reshape(t, hv, dv)
             y = rms_norm(o, p["norm"].astype(jnp.float32), eps) \
                 * jax.nn.silu(z)
             return pmath.matmul(y.reshape(t, nv), p["wo"])

@@ -16,6 +16,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import event, optimizer, trainer
 from paddle_tpu.ops import gated_delta as gd
+from paddle_tpu.ops import gdn_conv_kernels as gck
 from paddle_tpu.ops.gated_attention import gated_attention
 from paddle_tpu.parallel import moe as pmoe
 from paddle_tpu.platform.flags import FLAGS
@@ -271,6 +272,152 @@ def test_delta_rule_layer_forward_and_gradients_with_packed_segments(
     close(g_ours[0], g_ref[0], rtol=1e-3)
     for k, g in g_ref[1].items():
         close(g_ours[1][k.replace("norm_g", "norm")], g, rtol=1e-3)
+
+
+# ---- the fused prologue: convolution, SiLU, q/k normalisation -------------------
+
+WIDE = gck.Dims(2, 4, 128, 128)       # two heads a column block
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Row tiles of 16 and chunks of 8, so that a buffer of some tens of
+    rows has several tiles (the calls are jitted on their shapes)."""
+    monkeypatch.setattr(gck, "ROWS", 16)
+    monkeypatch.setattr(gck, "CHUNK_ROWS", 8)
+    gck._conv_fwd.clear_cache()
+    gck._conv_bwd.clear_cache()
+    yield
+    gck._conv_fwd.clear_cache()
+    gck._conv_bwd.clear_cache()
+
+
+@pytest.mark.parametrize("lengths", [
+    [16, 32], [15, 33], [14, 34], [13, 1, 2, 3, 29], [20, 21], [5]],
+    ids=["begins-at-a-tiles-first-row", "begins-at-a-tiles-last-row",
+         "begins-inside-the-halo", "shorter-than-the-taps",
+         "not-a-multiple-of-the-tile", "under-a-sublane-tile"])
+def test_fused_prologue_is_the_xla_composition(small_tiles, lengths):
+    """``qkv_conv`` against ``silu(causal_conv(...))`` and the
+    normalisation (``qkv_conv_xla``), forward and through ``jax.grad`` for the projection's
+    columns (the ``z`` columns' share is zero) and the taps."""
+    t = sum(lengths)
+    _, seg = segments(lengths)
+    qkvz = normal(51, t, WIDE.channels + WIDE.nv)
+    w = normal(52, WIDE.channels, 4, std=0.5)
+    want = gd.qkv_conv_xla(qkvz, w, seg, WIDE)
+    got = gck.qkv_conv(qkvz, w, seg, WIDE)
+    for a, b in zip(got, want):
+        close(a, b, rtol=1e-5)
+    probes = [normal(53 + i, *a.shape) for i, a in enumerate(want)]
+
+    def loss(f):
+        return lambda x, w: sum(jnp.sum(o * p) for o, p in
+                                zip(f(x, w, seg, WIDE), probes))
+
+    g_got = jax.grad(loss(gck.qkv_conv), argnums=(0, 1))(qkvz, w)
+    g_want = jax.grad(loss(gd.qkv_conv_xla), argnums=(0, 1))(qkvz, w)
+    close(g_got[0], g_want[0], rtol=1e-5)
+    close(g_got[1], g_want[1], rtol=1e-5)
+    assert not np.asarray(g_got[0][:, WIDE.channels:]).any()
+
+
+def test_fused_prologue_at_the_tile_the_chip_runs():
+    """The row tile and chunk as they stand, one whole tile and a part."""
+    dims = gck.Dims(1, 2, 128, 128)
+    _, seg = segments([200, 3, 97])
+    qkvz, w = normal(56, 300, dims.channels + dims.nv), normal(57, 512, 4)
+    for a, b in zip(gck.qkv_conv(qkvz, w, seg, dims),
+                    gd.qkv_conv_xla(qkvz, w, seg, dims)):
+        close(a, b, rtol=1e-5)
+
+
+@pytest.mark.parametrize("widths, lanes", [
+    ((16, 32, 128, 128), 1024), ((2, 4, 128, 128), 256),
+    ((2, 2, 128, 256), 256), ((2, 2, 256, 128), 256),
+    ((2, 4, 16, 8), None), ((1, 2, 4, 4), None), ((1, 1, 128, 256), None)],
+    ids=["the-cell", "two-heads", "wide-values", "wide-keys", "tiny",
+         "layer-sweep", "no-common-block"])
+def test_column_blocks_are_whole_heads_in_whole_lane_tiles(widths, lanes):
+    dims = gck.Dims(*widths)
+    assert gck.lane_block(dims) == lanes
+    assert gd.conv_is_fused(*widths) == (lanes is not None)
+    if lanes:
+        assert dims.nq % lanes == 0 and dims.nv % lanes == 0
+        assert lanes % dims.dk == 0 and lanes % dims.dv == 0
+
+
+def test_delta_rule_layer_takes_the_fused_prologue_at_heads_of_128(
+        f32_products, monkeypatch):
+    """The whole layer at one key and two value heads of 128 lanes: with
+    the kernels and, the shape test turned off, with the XLA composition;
+    forward and gradients."""
+    hk, hv, d = 1, 2, 128
+    p = {"w_qkvz": normal(61, E, 6 * d, std=E ** -0.5),
+         "w_ba": normal(62, E, 2 * hv, std=E ** -0.5),
+         "conv": normal(63, 4 * d, 4, std=0.5),
+         "a_log": normal(64, hv, std=0.5) - 2.0,
+         "dt_bias": normal(65, hv, std=0.5) - 1.0,
+         "norm": 1 + normal(66, d, std=0.1),
+         "wo": normal(67, hv * d, E, std=(hv * d) ** -0.5)}
+    _, seg = segments([70, 2, 56])
+    x, probe = normal(68, 128, E), normal(69, 128, E)
+
+    def layer_(x, p):
+        return jnp.sum(probe * gd.gated_delta_net(
+            x, seg, p, num_k_heads=hk, num_v_heads=hv, head_k_dim=d,
+            head_v_dim=d))
+
+    calls = []
+    fused = gck.qkv_conv
+    monkeypatch.setattr(gck, "qkv_conv",
+                        lambda *a: calls.append(1) or fused(*a))
+    got = jax.value_and_grad(layer_, argnums=(0, 1))(x, p)
+    assert calls
+    monkeypatch.setattr(gd, "conv_is_fused", lambda *a: False)
+    del calls[:]
+    want = jax.value_and_grad(layer_, argnums=(0, 1))(x, p)
+    assert not calls
+    close(got[0], want[0], rtol=1e-5)
+    close(got[1][0], want[1][0], rtol=1e-4)
+    for k, g in want[1][1].items():
+        close(got[1][1][k], g, rtol=1e-4)
+
+
+def test_delta_rule_layer_counts_the_rows_its_kernels_took():
+    """``gdn_conv_fused_rows_total``: the buffer's rows a step where the
+    layer's heads fill whole lane tiles, and no series at other widths."""
+    from paddle_tpu import layer
+    from paddle_tpu.obs import default_registry
+
+    def train(name, d):
+        paddle.topology.reset_name_scope()
+        words = layer.data(
+            name="w", type=paddle.data_type.integer_value_sequence(30))
+        y = layer.data(name="y", type=paddle.data_type.integer_value(2))
+        mixed = layer.gated_delta_net(
+            layer.embedding(input=words, size=16), num_k_heads=1,
+            num_v_heads=2, head_k_dim=d, head_v_dim=d, name=name)
+        cost = layer.classification_cost(
+            input=layer.fc(input=layer.pooling(input=mixed), size=2),
+            label=y)
+        sgd = trainer.SGD(
+            cost=cost, update_equation=optimizer.Adam(learning_rate=1e-3),
+            parameters=paddle.Parameters.from_topology(
+                paddle.topology.Topology([cost]), seed=0))
+        rng = np.random.RandomState(4)
+        rows = [([int(t) for t in rng.randint(0, 30, size=n)], 1)
+                for n in (40, 20)]
+        before = default_registry().snapshot()
+        sgd.train(lambda: iter([rows, rows]), num_passes=1,
+                  event_handler=lambda ev: None)
+        after = default_registry().snapshot()
+        key = "gdn_conv_fused_rows_total{layer=%s}" % name
+        return after.get(key, 0) - before.get(key, 0), key in after
+
+    grown, there = train("wide_gdn", 128)
+    assert there and grown > 0 and grown % 2 == 0      # two steps' buffers
+    assert train("narrow_gdn", 8) == (0, False)
 
 
 # ---- gated attention -----------------------------------------------------------

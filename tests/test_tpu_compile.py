@@ -13,6 +13,7 @@ the next run would warn and recompile.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -373,6 +374,40 @@ def test_delta_rule_kernels_compile_for_v5e_at_the_cell_size(topo, mode,
     assert n == {"fwd": 1, "grad": 2}[mode]
 
 
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_delta_rule_prologue_kernels_compile_for_v5e_at_the_cell_size(
+        topo, mode, monkeypatch):
+    """``qkv_conv_fwd`` and, through the gradient, ``qkv_conv_bwd`` at the
+    qwen3-next cell's size: the projection ``[8192, 12288]``, of which the
+    first 8192 columns (16 + 16 + 32 heads of 128) are convolved over 4
+    taps: blocks of 256 rows by 1024 lanes, their halos, the shifted
+    slices of a chunk's window and three parked outputs have to pass
+    Mosaic and fit its VMEM."""
+    from paddle_tpu.ops import gdn_conv_kernels as gck
+
+    monkeypatch.setattr(gck, "interpret_default", lambda: False)
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    dims = gck.Dims(16, 32, 128, 128)
+    avals = (aval((8192, 12288), jnp.float32),
+             aval((dims.channels, 4), jnp.float32), aval((8192,), jnp.int32))
+
+    def loss(x, w, seg):
+        q, k, v = gck.qkv_conv(x, w, seg, dims)
+        return jnp.sum(q * q) + jnp.sum(k) + jnp.sum(v * v)
+
+    fn = (lambda x, w, seg: gck.qkv_conv(x, w, seg, dims)) if mode == "fwd" \
+        else jax.grad(loss, argnums=(0, 1))
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert text.count("tpu_custom_call") == {"fwd": 1, "grad": 2}[mode]
+    if mode == "fwd":
+        # the projection is read where it lies and q, k, v leave as the
+        # kernel wrote them: XLA makes no array of 8192 rows of its own
+        made = [line[:160] for line in text.splitlines()
+                if " = f32[8192," in line and "parameter(" not in line
+                and "get-tuple-element(" not in line]
+        assert not made, made
+
+
 # ---- a whole train step of the delta-rule / gated-attention model ------------
 
 def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
@@ -388,13 +423,14 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
     from paddle_tpu.analysis import retrace
     from paddle_tpu.models import qwen3_next
     from paddle_tpu.ops import attention as pattn
-    from paddle_tpu.ops import gdn_kernels
+    from paddle_tpu.ops import gdn_conv_kernels, gdn_kernels
     from paddle_tpu.ops import grouped_matmul as gm
 
     # the code asks jax.default_backend() and sees the CPU
     monkeypatch.setattr(pattn, "_interpret_default", lambda: False)
     monkeypatch.setattr(gm, "interpret_default", lambda: False)
     monkeypatch.setattr(gdn_kernels, "interpret_default", lambda: False)
+    monkeypatch.setattr(gdn_conv_kernels, "interpret_default", lambda: False)
     monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
     paddle.topology.reset_name_scope()
     *_, cost = qwen3_next.build(
@@ -424,13 +460,44 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
         assert scope + "/" in text, scope
     # one attention block: forward twice (remat), dKV, dQ; four expert
     # layers: 6 + 3 moe_gmm and 3 moe_tgmm each; three delta-rule layers:
-    # gdn_chunk_fwd twice (remat), gdn_chunk_bwd once
-    assert text.count("tpu_custom_call") == 4 + 4 * 12 + 3 * 3
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "%gdn_" in line.split("=")[0]]
-    assert len(kernels) == 3 * 3
+    # gdn_chunk_fwd twice (remat), gdn_chunk_bwd once, and before them
+    # qkv_conv_fwd twice, qkv_conv_bwd once
+    assert text.count("tpu_custom_call") == 4 + 4 * 12 + 3 * 3 + 3 * 3
+    by_name = {line.split("=")[0].split()[-1]: line
+               for line in text.splitlines() if " = " in line}
+    calls = {n: line for n, line in by_name.items()
+             if "tpu_custom_call" in line}
+    named = lambda prefix: sorted(  # noqa: E731
+        n.lstrip("%").split(".")[0] for n in calls if n.startswith(prefix))
+    # the scan's roofline reads every kernel named ``gdn_*``: still two
+    assert named("%gdn_") == ["gdn_chunk_bwd"] * 3 + ["gdn_chunk_fwd"] * 6
     assert all("gdn/gdn.scan/" in line or "gdn.scan)" in line
-               for line in kernels), kernels
+               for n, line in calls.items() if n.startswith("%gdn_"))
+    assert named("%qkv_conv") == ["qkv_conv_bwd"] * 3 + ["qkv_conv_fwd"] * 6
+    assert all("gdn/gdn.conv/" in line
+               for n, line in calls.items() if n.startswith("%qkv_conv"))
+    # q, k, v go from the one kernel to the other as they are, and their
+    # cotangents back: no copy, slice or re-tiling of a [T, H d] between
+
+    def made_by(operand):
+        through = re.search(r" (?:get-tuple-element|bitcast)\((%[\w.-]+)",
+                            by_name[operand])
+        return made_by(through.group(1)) if through else operand
+
+    def operands(line):
+        return re.findall(r"%[\w.-]+", line.split("custom-call(")[1]
+                          .split(")")[0])
+
+    for n, line in calls.items():
+        if n.startswith("%gdn_chunk_fwd"):
+            left = [by_name[made_by(o)][:200] for o in operands(line)[:3]
+                    if not made_by(o).startswith("%qkv_conv_fwd")]
+            assert not left, left
+        if n.startswith("%qkv_conv_bwd"):
+            left = [by_name[made_by(o)][:200]
+                    for o in operands(line)[6:12]
+                    if not made_by(o).startswith("%gdn_chunk_bwd")]
+            assert not left, left
     # [n, Hv, c, c]: 4 chunks of 64 rows, 4 value heads; no K K^T, Q K^T,
     # decay, A or inverse of every chunk and head as an array in HBM
     assert "f32[4,4,64,64]" not in text and "bf16[4,4,64,64]" not in text
