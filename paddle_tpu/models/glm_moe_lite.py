@@ -65,7 +65,11 @@ def build(vocab_size: int = 154880, hidden_size: int = 2048,
     are the ``target`` column moved one row up inside each sequence
     (``layer.next_token_cost``), so no further column is fed.
     ``held_experts`` defaults to all of them.  ``remat`` recomputes each
-    block in the backward pass (``topology.remat_scope``)."""
+    block in the backward pass (``topology.remat_scope``), all of it but
+    the router's scores, choice and row plan, which the expert layer tags
+    to be kept (``topology.KEPT``; 3 MB a block of 8192 tokens at the
+    published widths): latent attention, its flash kernels and the
+    experts' products run a second time."""
     assert mtp_layers in (0, 1), "one MTP module at most"
     seq = paddle.data_type.integer_value_sequence
     tokens = layer.data(name="tokens", type=seq(vocab_size))

@@ -31,7 +31,13 @@ def block(x, pos, *, name: str, full_attention: bool, attn: dict,
     attention where ``full_attention``, else the gated delta-rule layer.
     With ``remat`` each half is a recomputed segment of its own, so that
     the backward pass never holds the mixing layer's intermediates beside
-    the expert layer's."""
+    the expert layer's.  A segment keeps what its ops tag as dear to
+    rebuild and small to hold (``topology.KEPT``): the delta-rule layer's
+    projections, the scan's operands, output and chunk states (1.07 GB a
+    layer at the 80B model's widths and 8192 tokens), so none of its
+    kernels above the gated norm runs twice, and the router's scores,
+    choice and row plan (18 MB), so a step routes once a layer; gated
+    attention and the experts' products are recomputed whole."""
     def scope(part):
         return _topo.remat_scope(f"{name}_{part}") if remat \
             else contextlib.nullcontext()
@@ -70,8 +76,8 @@ def build(vocab_size: int = 151936, hidden_size: int = 2048,
     its sequence.  Layer ``l`` (from 0) is gated attention where ``(l + 1)
     % full_attention_interval == 0``, else a delta-rule layer.
     ``held_experts`` defaults to all of them.  ``remat`` recomputes each
-    block in the backward pass, its two halves apart
-    (``topology.remat_scope``)."""
+    block in the backward pass, its two halves apart, but for what
+    ``block`` says is kept (``topology.remat_scope``)."""
     seq = paddle.data_type.integer_value_sequence
     tokens = layer.data(name="tokens", type=seq(vocab_size))
     pos = layer.data(name="pos", type=seq(max_len))

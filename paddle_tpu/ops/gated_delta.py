@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from paddle_tpu.ops import gdn_conv_kernels, gdn_kernels
 from paddle_tpu.ops import math as pmath
 from paddle_tpu.ops.norm import rms_norm
+from paddle_tpu.topology import keep
 
 CHUNK = 64
 
@@ -190,8 +191,10 @@ def gated_delta_net(x: jax.Array, segment_ids: jax.Array,
     nv = hv * dv
     with jax.named_scope("gdn"):
         with jax.named_scope("gdn.proj"):
-            qkvz = pmath.matmul(x, p["w_qkvz"])
-            ba = pmath.matmul(x, p["w_ba"])
+            # kept across a recomputed segment: the prologue's backward
+            # kernel and ``gdn.out`` read qkvz, the gates ba
+            qkvz, ba = keep("gdn_proj", pmath.matmul(x, p["w_qkvz"]),
+                            pmath.matmul(x, p["w_ba"]))
         with jax.named_scope("gdn.conv"):
             dims = gdn_conv_kernels.Dims(hk, hv, dk, dv)
             prologue = gdn_conv_kernels.qkv_conv \

@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.kernel_util import interpret_default
+from paddle_tpu.topology import keep
 
 NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -378,7 +379,13 @@ def delta_rule_chunks(q2, k2, v2, rows, marks, ct):
     a row's segment as a number that is equal inside the chunk exactly
     where the ids are, whether it reads the incoming state (its segment
     began before the chunk), whether it writes the outgoing one (it is of
-    the chunk's last segment).  Returns o [T, Hv dv] float32."""
+    the chunk's last segment).  Returns o [T, Hv dv] float32.
+
+    Differentiated inside a ``topology.remat_scope``, the forward rule's
+    residuals (these operands and the state each chunk starts from) and o
+    are kept by name, so the backward pass runs ``gdn_chunk_bwd`` alone:
+    1.0 GB a layer at the qwen3-next cell's shape against a second run of
+    ``gdn_chunk_fwd`` and of the prologue before it."""
     return _chunks_fwd(q2, k2, v2, rows, marks, ct=ct,
                        interpret=interpret_default())[0]
 
@@ -386,7 +393,16 @@ def delta_rule_chunks(q2, k2, v2, rows, marks, ct):
 def _chunks_vjp_fwd(q2, k2, v2, rows, marks, ct):
     o, states = _chunks_fwd(q2, k2, v2, rows, marks, ct=ct,
                             interpret=interpret_default())
-    return o, (q2, k2, v2, rows, marks, states)
+    # under ``train.remat`` the segment keeps what the backward kernel
+    # reads (``topology.KEPT``), tagged here on the residuals themselves:
+    # o and the states, or this kernel runs a second time to rebuild
+    # them, and the operands, or all that made them does.  The kernel
+    # above takes the operands untagged: JAX rounds a kept value that the
+    # forward pass goes on to read (``reduce_precision``, against excess
+    # precision), which behind a kernel is a pass over it in HBM.
+    return keep("gdn_scan", o), (
+        *keep("gdn_operands", q2, k2, v2, rows, marks),
+        keep("gdn_scan", states))
 
 
 def _chunks_vjp_bwd(ct, res, do):

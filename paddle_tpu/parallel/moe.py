@@ -50,6 +50,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.platform.enforce import enforce_that
+from paddle_tpu.topology import keep
 
 
 # the audited compiled-path site every expert-parallel dispatch runs
@@ -366,16 +367,50 @@ def _moe_jit(mesh, axis: str, e: int, cap: int, d: int, act, top_k: int,
 # ---------------------------------------------------------------------------
 
 
+def _router_logits(x, router_w):
+    """``x W_r`` in float32 at the highest matmul precision.  Kept across
+    a recomputed segment (``topology.keep``): what follows it is
+    elementwise and its gradient needs the scores, so with the logits held
+    the backward pass forms no such product again."""
+    return keep("moe_route", jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(p, k: int):
+    """``jax.lax.top_k`` whose gradient reads the KEPT choice: the rule of
+    ``lax.top_k`` gathers by the indices of its own forward, which a
+    recomputed segment would have to sort again to get."""
+    return tuple(jax.lax.top_k(p, k))
+
+
+def _top_k_fwd(p, k):
+    g, experts = keep("moe_route", *jax.lax.top_k(p, k))
+    return (g, experts), (experts, jnp.arange(p.shape[-1],
+                                              dtype=experts.dtype))
+
+
+def _top_k_bwd(k, res, cots):
+    experts, lanes = res
+    # a row's choices differ, so a sum over them moves each weight's
+    # cotangent to its expert's column and adds nothing to it
+    return (jnp.sum(jnp.where(experts[..., None] == lanes,
+                              cots[0][..., None], 0), axis=-2),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def route_sigmoid_topk(x, router_w, bias, top_k: int, scaling: float = 1.0):
     """Bias-corrected sigmoid routing (``noaux_tc``): scores
     ``s = sigmoid(x W_r)`` in float32 at the highest matmul precision;
     the ``top_k`` experts of ``s + bias`` are chosen; their weights are
     ``s[chosen] / sum(s[chosen]) * scaling``, without the bias.
     Returns (experts [T, k] int32, weights [T, k] float32)."""
-    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
+    s = jax.nn.sigmoid(_router_logits(x, router_w))
     _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    experts = keep("moe_route", experts)
     g = jnp.take_along_axis(s, experts, axis=-1)
     g = g / jnp.sum(g, axis=-1, keepdims=True) * scaling
     return experts.astype(jnp.int32), g
@@ -387,9 +422,8 @@ def route_softmax_topk(x, router_w, top_k: int):
     chosen and their weights renormalised, ``p[chosen] / sum(p[chosen])``
     (``norm_topk_prob``).  No bias, no scaling.
     Returns (experts [T, k] int32, weights [T, k] float32)."""
-    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    g, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    g, experts = _top_k(jax.nn.softmax(_router_logits(x, router_w), axis=-1),
+                        top_k)
     return experts.astype(jnp.int32), g / jnp.sum(g, axis=-1, keepdims=True)
 
 
@@ -521,6 +555,10 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
             pair, mode="drop")
         row_token = jnp.where(row_pair < t * top_k, row_pair // top_k, t)
         dest = dest.reshape(t, top_k)
+        # all that ``moe.experts`` reads of this scope: a recomputed
+        # segment holds it (a few MB) and routes once a step
+        g, dest, row_token, row_pair, tile_group, n_active = keep(
+            "moe_route", g, dest, row_token, row_pair, tile_group, n_active)
     with jax.named_scope("moe.experts"):
         ct = operand_dtype or pmath.compute_dtype(x)
         xs = _dispatch(x.astype(ct), row_token, dest)

@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.attr import ParamAttr
 from paddle_tpu.platform.enforce import EnforceError, enforce_that
@@ -62,9 +63,11 @@ class remat_scope:
     The classic TPU memory/compute trade: nodes sharing a group are executed
     as ONE ``jax.checkpoint``-wrapped segment by ``Topology.forward``, so the
     backward pass recomputes the segment's activations from its boundary
-    inputs instead of keeping them in HBM. Wrapping each transformer block
-    buys O(n_layers) activation memory for ~1 extra forward of FLOPs — the
-    lever that lets the bench run bigger batch/sequence tiers.
+    inputs instead of keeping them in HBM, all but the few an op has
+    tagged with :func:`keep` (``KEPT``: dear to rebuild, small to hold).
+    Wrapping each transformer block buys O(n_layers) activation memory for
+    ~1 extra forward of FLOPs, less what is kept — the lever that lets
+    the bench run bigger batch/sequence tiers.
 
     Reference analog: none — the reference keeps every layer's output alive
     for backward (gserver NeuralNetwork keeps per-layer Arguments); remat is
@@ -86,6 +89,39 @@ class remat_scope:
     def __exit__(self, *exc):
         _remat_stack.pop()
         return False
+
+
+# What a recomputed segment holds on to from its forward pass, by the name
+# its maker tags it with (``keep``), in order of milliseconds saved a byte
+# held.  A kernel behind ``jax.custom_vjp`` tags its residuals INSIDE the
+# forward rule: a name on its output alone leaves the residuals missing and
+# the kernel runs again.  Not here, because the benchmark's readers count
+# their calls a step: what ``moe_gmm`` / ``moe_tgmm`` and ``flash_*`` make.
+KEPT = (
+    "moe_route",        # the router's scores, choice, weights and row plan
+    "gdn_scan",         # the delta rule's o and the state a chunk starts from
+    "gdn_proj",         # the delta layer's projections qkvz and ba
+    "gdn_operands",     # the scan's q, k, v and scalars, in its own layout
+)
+
+# (one object for every group: JAX's caches key on the policy's identity)
+_KEEP_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT)
+
+# bytes tagged while the innermost remat group is being traced
+_kept_bytes: List[int] = []
+
+
+def keep(name: str, *values):
+    """Tag ``values`` as worth keeping across a recomputed segment: under
+    a ``remat_scope`` the backward pass reads them where it would build
+    them again; anywhere else this is the identity and lowers to nothing.
+    ``name`` is one of ``KEPT``.  Returns the values (one: itself)."""
+    enforce_that(name in KEPT, f"{name!r} is not one of {KEPT}",
+                 context="remat")
+    if _kept_bytes:
+        _kept_bytes[-1] += sum(v.size * v.dtype.itemsize for v in values)
+    out = tuple(checkpoint_name(v, name) for v in values)
+    return out[0] if len(out) == 1 else out
 
 
 @dataclass
@@ -371,7 +407,11 @@ class Topology:
         The segment is a pure function of (its params, the step rng, its
         boundary inputs) -> (boundary outputs, state updates); XLA drops
         the segment's internal activations after forward and recomputes
-        them during backward.
+        them during backward, all but those tagged with a name of ``KEPT``
+        (``keep``): a segment that holds none compiles as it would with no
+        policy.  What a differentiated segment keeps is published as the
+        gauge ``remat_kept_bytes{group}`` (the tagged shapes' sum, fixed
+        at trace time).
         """
         nodes = [n for n in order if n.remat_group == group]
         in_group = {n.name for n in nodes}
@@ -419,16 +459,23 @@ class Topology:
                     raise
             return [local[nm] for nm in ext_out], sub.state_out, sub.counters
 
-        with jax.named_scope(f"remat_{group}"):
-            outs, state_out, counted = jax.checkpoint(segment)(
-                {k: params[k] for k in pkeys}, rng_arg,
-                [values[nm] for nm in ext_in])
+        _kept_bytes.append(0)
+        try:
+            with jax.named_scope(f"remat_{group}"):
+                outs, state_out, counted = jax.checkpoint(
+                    segment, policy=_KEEP_POLICY)(
+                    {k: params[k] for k in pkeys}, rng_arg,
+                    [values[nm] for nm in ext_in])
+        finally:
+            kept = _kept_bytes.pop()
         for nm, v in zip(ext_out, outs):
             values[nm] = v
         for ns, slots in state_out.items():
             ctx.state_out.setdefault(ns, {}).update(slots)
         for key, v in counted.items():
             ctx.publish(key, v)
+        if kept:
+            ctx.gauge("remat_kept_bytes", kept, group=group)
 
     def __repr__(self):
         return f"Topology({len(self.nodes)} nodes, outputs={[o.name for o in self.outputs]})"

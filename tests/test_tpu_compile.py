@@ -460,9 +460,10 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
         assert scope + "/" in text, scope
     # one attention block: forward twice (remat), dKV, dQ; four expert
     # layers: 6 + 3 moe_gmm and 3 moe_tgmm each; three delta-rule layers:
-    # gdn_chunk_fwd twice (remat), gdn_chunk_bwd once, and before them
-    # qkv_conv_fwd twice, qkv_conv_bwd once
-    assert text.count("tpu_custom_call") == 4 + 4 * 12 + 3 * 3 + 3 * 3
+    # gdn_chunk_fwd and gdn_chunk_bwd once each, and before them
+    # qkv_conv_fwd and qkv_conv_bwd once each: the segment keeps the
+    # forward kernels' outputs by name (``topology.KEPT``)
+    assert text.count("tpu_custom_call") == 4 + 4 * 12 + 3 * 2 + 3 * 2
     by_name = {line.split("=")[0].split()[-1]: line
                for line in text.splitlines() if " = " in line}
     calls = {n: line for n, line in by_name.items()
@@ -470,10 +471,10 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
     named = lambda prefix: sorted(  # noqa: E731
         n.lstrip("%").split(".")[0] for n in calls if n.startswith(prefix))
     # the scan's roofline reads every kernel named ``gdn_*``: still two
-    assert named("%gdn_") == ["gdn_chunk_bwd"] * 3 + ["gdn_chunk_fwd"] * 6
+    assert named("%gdn_") == ["gdn_chunk_bwd"] * 3 + ["gdn_chunk_fwd"] * 3
     assert all("gdn/gdn.scan/" in line or "gdn.scan)" in line
                for n, line in calls.items() if n.startswith("%gdn_"))
-    assert named("%qkv_conv") == ["qkv_conv_bwd"] * 3 + ["qkv_conv_fwd"] * 6
+    assert named("%qkv_conv") == ["qkv_conv_bwd"] * 3 + ["qkv_conv_fwd"] * 3
     assert all("gdn/gdn.conv/" in line
                for n, line in calls.items() if n.startswith("%qkv_conv"))
     # q, k, v go from the one kernel to the other as they are, and their
