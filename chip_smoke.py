@@ -234,12 +234,12 @@ def make_requests(cfg: SmokeConfig) -> List[List[int]]:
 def build_engine(cfg: SmokeConfig, model, params, **engine_kw):
     """Default page size and slots, pool sized by bytes, fused tick; a
     sequence may hold ``cfg.seq`` tokens."""
-    from paddle_tpu.platform.flags import FLAGS
     from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.serving.kv_cache import PAGE_SIZE
 
     return ServingEngine(
         model, params, eos_id=cfg.vocab - 1, pool_bytes=cfg.pool_bytes,
-        max_pages_per_seq=-(-cfg.seq // int(FLAGS.serving_page_size)),
+        max_pages_per_seq=-(-cfg.seq // PAGE_SIZE),
         buckets=cfg.buckets, **engine_kw)
 
 

@@ -120,8 +120,8 @@ def snapshot_checkpoint(parameters, opt_state: Any = None,
     an async save that stalls the train loop).  ``shard_plan`` (a
     ``parallel.zero.ZeroPlan``): ZeRO-1 flat slot shards gather back to
     full tensor shapes through the plan's compiled-identity path so the
-    artifact stays layout-independent — a zero_stage=1 save loads under
-    zero_stage=0 (or a different mesh size) and vice versa."""
+    artifact stays layout-independent — a zero=1 save loads under
+    zero=0 (or a different mesh size) and vice versa."""
     if shard_plan is not None and opt_state is not None:
         opt_state = shard_plan.gather_state(opt_state)
     params = parameters.as_dict() if hasattr(parameters, "as_dict") \

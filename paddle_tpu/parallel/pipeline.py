@@ -54,11 +54,12 @@ class PipelineConfig:
     (``trainer.SGD(pipeline=PipelineConfig(...))``).
 
     - ``num_stages``: S.  0 derives it from the mesh's ``axis`` size
-      (or, when the trainer builds the mesh, from
-      ``FLAGS.pipeline_stages`` falling back to the device count).
-    - ``microbatches``: M per step.  0 reads
-      ``FLAGS.pipeline_microbatches``.  Bubble fraction is the GPipe
-      closed form ``(S-1)/(M+S-1)`` — raise M to amortize.
+      (or, when the trainer builds the mesh, from the device count).
+      The model's layer count must divide by S.
+    - ``microbatches``: M per step; the batch must divide by M.  Bubble
+      fraction is the GPipe closed form ``(S-1)/(M+S-1)`` — raise M to
+      amortize the fill/drain bubble, at the cost of smaller
+      per-microbatch matmuls.
     - ``n_layers`` / ``n_heads``: the transformer-zoo geometry the
       trainer partitions (``blk{i}_*`` params -> S stages of
       ``n_layers/S`` blocks; embed + loss/head ride the boundary-stage
@@ -67,7 +68,7 @@ class PipelineConfig:
     """
 
     num_stages: int = 0
-    microbatches: int = 0
+    microbatches: int = 8
     axis: str = "stage"
     remat: bool = False
     n_layers: int = 0

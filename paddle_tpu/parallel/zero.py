@@ -198,7 +198,7 @@ class ZeroPlan:
     def gather_state(self, state: Any) -> Any:
         """Inverse of :meth:`shard_state`: flat shard views back to
         full-shape host arrays, so checkpoints stay layout-independent
-        (a zero_stage=1 save loads under zero_stage=0 and vice versa)."""
+        (a zero=1 save loads under zero=0 and vice versa)."""
         if not isinstance(state, dict):
             return state
         out = dict(state)

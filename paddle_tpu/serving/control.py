@@ -187,7 +187,7 @@ class TenantRegistry:
 
     @classmethod
     def from_flag(cls, text: str) -> "TenantRegistry":
-        """Parse ``FLAGS.serving_tenant_classes``: a comma list of
+        """Parse the registry's text form: a comma list of
         ``name:class`` pairs (``alice:interactive,bulk:batch``).  A
         bare name (no colon) registers as standard."""
         reg = cls()

@@ -10,7 +10,7 @@ ragged invocation:
 
 - **Sequence-packed rows.**  The query batch is a flat ``[T, H, D]`` row
   stack: decode slots contribute one row each, in-flight prefill chunks
-  contribute up to ``serving_prefill_chunk`` rows each.  A row→sequence
+  contribute up to ``prefill_chunk`` rows each.  A row→sequence
   map (``row_seq``) and a per-row absolute position (``qpos``, −1 for
   padding) drive ONE causal/offset mask — ``token t is visible to the
   row at position p iff t <= p`` — which subsumes decode length masking,

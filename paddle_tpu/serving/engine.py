@@ -21,7 +21,7 @@ row count, the prefill bucket the padded total of this tick's packed
 prefill-chunk rows (0 on decode-only ticks).  One dispatch embeds the
 tick's decode tokens AND every in-flight prefill chunk, scatters all
 their K/V into pages (quantizing on write when the pool is int8 — see
-``FLAGS.serving_kv_dtype``), and runs ONE ragged paged attention
+``kv_dtype=``), and runs ONE ragged paged attention
 (``ragged_paged_attention``: sequence-packed rows, GQA head-group
 packing, in-register dequant) over the whole mixed batch — where the
 v1 engine paid two dispatches and two softmax passes per tick with
@@ -47,7 +47,7 @@ Robustness layer (round 8): every request moves through a real
 pages immediately.  The decode tick carries a finite-logits guard that
 fails ONLY the poisoned slot (the rest of the fused batch keeps
 running), retries transiently-failing ticks, and a progress watchdog
-fails slots stuck past ``serving_watchdog_ticks``.  Deadlocked demand is
+fails slots stuck past ``watchdog_ticks``.  Deadlocked demand is
 shed: queued requests whose deadline is provably unmeetable are
 early-rejected instead of burning prefill work.  All failure paths are
 driven deterministically by a :class:`~paddle_tpu.serving.faults.FaultPlan`
@@ -55,18 +55,18 @@ driven deterministically by a :class:`~paddle_tpu.serving.faults.FaultPlan`
 free-list conservation check runs after every drain.
 
 Prefix caching + chunked prefill (round 9): with
-``FLAGS.serving_prefix_cache`` on (the default), admission splits every
+``prefix_cache`` on (the default), admission splits every
 prompt into ``cached_prefix_pages + tail`` against a chained-hash
 :class:`~paddle_tpu.serving.kv_cache.PrefixCache` — the prefix pages are
 refcount-shared (charged zero new pages), the tail prefills with its
 positions offset by the cached length, and a full-cover hit
 copy-on-write-forks the last shared page and recomputes only the final
-token.  Prompts longer than ``FLAGS.serving_prefill_chunk`` prefill one
+token.  Prompts longer than ``prefill_chunk`` (256) prefill one
 chunk per tick — since round 12 riding the SAME unified dispatch as the
 decode rows rather than a second one — so a long prompt in the queue
 no longer degrades running slots' latency.
 
-Tensor-parallel serving (round 13): ``ServingEngine(mesh=, tp_axis=)``
+Tensor-parallel serving (round 13): ``ServingEngine(mesh=)``
 places the model megatron-style over a ``model`` mesh axis — attention
 heads (and GQA KV heads) + FFN columns column-parallel, the output/FFN-
 down projections row-parallel with ONE psum each per layer — using the
@@ -217,7 +217,8 @@ from paddle_tpu.serving.decode_attention import (
     ragged_paged_attention_tp, ragged_walk, tall_rows_for, visit_counts)
 from paddle_tpu.serving.faults import (FaultPlan, InjectedDeviceError,
                                        PageLeakError)
-from paddle_tpu.serving.kv_cache import (NULL_PAGE, _CHAIN_SEED, HostPageTier,
+from paddle_tpu.serving.kv_cache import (NULL_PAGE, NUM_PAGES, PAGE_SIZE,
+                                         _CHAIN_SEED, HostPageTier,
                                          KVPages, PagedKVConfig, PagePool,
                                          PrefixCache, RecurrentState,
                                          WindowRing, append_token,
@@ -243,6 +244,12 @@ __all__ = ["DecodeModel", "DecoderLM", "SamplingParams", "ServingEngine",
            "greedy_decode_reference", "validate_tp"]
 
 _SPEC_MODES = ("off", "ngram", "draft")
+# the mesh axis a tensor-parallel engine places heads and FFN columns
+# over: what ``kv_pool_specs``, ``shard_plan`` and ``bind_tp`` default to
+TP_AXIS = "model"
+# the default ladder of padded prefill row counts (``buckets=``); its top
+# and the chunk bound the packer's row budget a tick (``_prefill_budget``)
+PREFILL_BUCKETS = (32, 64, 128, 256, 512)
 
 
 class DecodeModel:
@@ -584,47 +591,67 @@ def _settle_heap() -> None:
     gc.freeze()
 
 
-def _parse_buckets(spec: str) -> Tuple[int, ...]:
-    return tuple(sorted(int(t) for t in spec.split(",") if t.strip()))
-
-
 class ServingEngine:
     """Paged-KV continuous-batching inference engine (see module doc)."""
 
     def __init__(self, model: DecodeModel, params, *, eos_id: int,
-                 page_size: Optional[int] = None,
+                 page_size: int = PAGE_SIZE,
                  num_pages: Optional[int] = None,
                  max_pages_per_seq: Optional[int] = None,
-                 max_slots: Optional[int] = None,
-                 buckets: Optional[Sequence[int]] = None,
+                 max_slots: int = 8,
+                 buckets: Sequence[int] = PREFILL_BUCKETS,
                  max_queue: Optional[int] = None,
-                 dtype=None, kv_dtype=None,
+                 kv_dtype="float32",
                  pool_bytes: Optional[int] = None,
                  use_kernel: Optional[bool] = None,
-                 queue_deadline_s: Optional[float] = None,
-                 preempt_budget: Optional[int] = None,
-                 watchdog_ticks: Optional[int] = None,
+                 queue_deadline_s: float = 0.0,
+                 preempt_budget: int = 3,
+                 watchdog_ticks: int = 16,
                  decode_retries: int = 2,
                  transient_errors: Tuple[type, ...] = (InjectedDeviceError,),
                  max_retained: int = 10000,
                  prefix_cache: Optional[bool] = None,
-                 prefill_chunk: Optional[int] = None,
+                 prefill_chunk: int = 256,
                  faults: Optional[FaultPlan] = None,
                  time_fn: Optional[Callable[[], float]] = None,
                  tracer=None, registry: Optional[MetricsRegistry] = None,
-                 mesh=None, tp_axis: str = "model",
-                 spec_mode: Optional[str] = None,
-                 spec_k: Optional[int] = None,
-                 spec_ngram: Optional[int] = None,
+                 mesh=None,
+                 spec_mode: str = "off",
+                 spec_k: int = 4,
                  draft_model=None, draft_params=None,
                  draft_pool_pages: Optional[int] = None,
-                 xla_peak_bytes: Optional[int] = None,
-                 xla_flops: Optional[float] = None,
-                 xla_comm_bytes: Optional[float] = None,
                  role: str = "unified",
-                 host_tier_bytes: Optional[int] = None,
-                 swap_in_budget: Optional[int] = None,
-                 host_kv_dtype: Optional[str] = None):
+                 host_tier_bytes: int = 0,
+                 swap_in_budget: int = 8,
+                 host_kv_dtype: str = "stored"):
+        """What an engine is built with (the module doc has the
+        mechanisms):
+
+        - ``num_pages`` None: ``pool_bytes`` worth of pages where that is
+          given, else ``kv_cache.NUM_PAGES`` (page 0 is the null page);
+          ``max_pages_per_seq`` None: half the usable pool.
+        - ``buckets``: padded prefill row counts; a tick's packed chunks
+          pad to the smallest that holds them, so the step compiles once
+          a bucket.  ``prefill_chunk`` should be one of them (a chunk
+          above the top bucket rounds up and wastes the excess); 0
+          prefills whole prompts.
+        - ``kv_dtype``: "float32" | "bfloat16" | "int8" or a dtype; int8
+          adds per-token, per-KV-head f32 scales (amax/127 on every
+          write), so a ``pool_bytes`` budget holds about 3-4x the pages.
+        - ``prefix_cache`` None: built, unless the model has window
+          layers or a recurrent state (which refuse an explicit True).
+        - ``queue_deadline_s``: a request still queued this long is shed
+          as TIMED_OUT (0: never).  ``preempt_budget``: re-prefills
+          before a request escalates (requeues ahead of all others and
+          is no victim again; 0: unlimited).  ``watchdog_ticks``: a
+          RUNNING request silent this many ticks is FAILED (0: off).
+        - ``spec_k`` drafts a slot and tick is a jit dimension (one
+          compile a ``(bucket, k + 1)`` pair).
+        - ``host_tier_bytes`` 0: no host tier (eviction destroys);
+          ``swap_in_budget`` host pages promoted a tick (0: spill
+          only); ``host_kv_dtype`` "stored" | "int8" (float pages
+          transcoded on spill, about 4x the pages in the same bytes).
+        """
         from paddle_tpu.platform.enforce import enforce_that
 
         self.eos_id = int(eos_id)
@@ -637,15 +664,8 @@ class ServingEngine:
         enforce_that(self.role in ("prefill", "decode", "unified"),
                      f"role must be prefill/decode/unified, got {role!r}",
                      context="serving")
-        page_size = int(page_size or FLAGS.serving_page_size)
-        max_slots = int(max_slots or FLAGS.serving_max_slots)
-        # (as asked for, for what refuses a kind of model below)
-        spec_asked = spec_mode if spec_mode is not None \
-            else FLAGS.serving_spec_mode
-        tier_asked = host_tier_bytes if host_tier_bytes is not None \
-            else FLAGS.serving_host_tier_bytes
-        if prefill_chunk is None:
-            prefill_chunk = int(FLAGS.serving_prefill_chunk)
+        page_size = int(page_size)
+        max_slots = int(max_slots)
         # a block model (see the module doc): B rows a slot and tick,
         # B / S tokens fixed a denoising pass.  None: one token a tick.
         self._block: Optional[int] = None
@@ -657,11 +677,9 @@ class ServingEngine:
             self._fix_rows = self._block // self._denoise_steps
             self._ticks_per_token = (self._denoise_steps + 1) / self._block
             self._refuse_for_block_model(
-                page_size, int(prefill_chunk), mesh, spec_asked, tier_asked)
-        # KV storage dtype: explicit kv_dtype > legacy dtype param >
-        # FLAGS.serving_kv_dtype.  int8 turns on quantized pages.
-        if kv_dtype is None:
-            kv_dtype = dtype if dtype is not None else FLAGS.serving_kv_dtype
+                page_size, int(prefill_chunk), mesh, spec_mode,
+                host_tier_bytes)
+        # int8 turns on quantized pages
         kv_dtype = resolve_kv_dtype(kv_dtype)
         num_kv_heads = int(getattr(model, "num_kv_heads", 0)
                            or model.num_heads)
@@ -671,7 +689,8 @@ class ServingEngine:
         kinds = layer_kinds(model)
         if len(kinds) > 1:
             self._refuse_for_window_model(
-                kinds, mesh, kv_dtype, prefix_cache, spec_asked, tier_asked)
+                kinds, mesh, kv_dtype, prefix_cache, spec_mode,
+                host_tier_bytes)
             prefix_cache = False
         # and what a layer keeps BESIDE them: a constant-size state a slot
         # (the module doc: "recurrent state"); None for a model without
@@ -679,7 +698,7 @@ class ServingEngine:
             model, max_slots)
         if self._recurrent is not None:
             self._refuse_for_recurrent_model(
-                mesh, prefix_cache, spec_asked, tier_asked)
+                mesh, prefix_cache, spec_mode, host_tier_bytes)
             prefix_cache = False
         rows = int(prefill_chunk) if int(prefill_chunk) > 0 else 1 << 30
         self._rings: Tuple[WindowRing, ...] = tuple(
@@ -701,7 +720,6 @@ class ServingEngine:
         # the `model` axis, the paged pool shards its KV-head dim the
         # same way, and every byte/contract below becomes per-chip.
         self.mesh = mesh
-        self.tp_axis = str(tp_axis)
         self.tp = 1
         self._shard_plan: Optional[Dict[str, Tuple]] = None
         self.param_sharding = None
@@ -711,12 +729,12 @@ class ServingEngine:
         self._tick_sharding = None
         if mesh is not None:
             enforce_that(
-                self.tp_axis in mesh.axis_names,
-                f"mesh has no {self.tp_axis!r} axis (axes: "
+                TP_AXIS in mesh.axis_names,
+                f"mesh has no {TP_AXIS!r} axis (axes: "
                 f"{tuple(mesh.axis_names)}) — build one with "
                 "make_mesh((tp,), ('model',))", context="serving-tp")
-            self.tp = int(mesh.shape[self.tp_axis])
-            validate_tp(model, self.tp, self.tp_axis)
+            self.tp = int(mesh.shape[TP_AXIS])
+            validate_tp(model, self.tp, TP_AXIS)
             enforce_that(
                 hasattr(model, "shard_plan"),
                 "ServingEngine(mesh=...) needs the model to expose "
@@ -728,7 +746,7 @@ class ServingEngine:
                 "param dict (the shard_plan key space)",
                 context="serving-tp")
             self._shard_plan = {k: tuple(v) for k, v in
-                                model.shard_plan(axis=self.tp_axis,
+                                model.shard_plan(axis=TP_AXIS,
                                                  tp=self.tp).items()}
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -743,7 +761,7 @@ class ServingEngine:
                 # a TP-bound VIEW (bind_tp must not mutate): the bound
                 # forward asserts the activation shardings, so each
                 # row-parallel block lowers to exactly one psum
-                model = model.bind_tp(mesh, self.tp_axis)
+                model = model.bind_tp(mesh, TP_AXIS)
         self.model = model
         self.params = params
         if num_pages is None and pool_bytes is not None:
@@ -757,16 +775,10 @@ class ServingEngine:
                 full_layers,
                 model.num_heads, model.head_dim, page_size, kv_dtype,
                 num_kv_heads=num_kv_heads, tp=self.tp)
-        num_pages = int(num_pages or FLAGS.serving_max_pages)
+        num_pages = int(num_pages or NUM_PAGES)
         if max_pages_per_seq is None:
             # default: one sequence may claim up to half the usable pool
             max_pages_per_seq = max(1, (num_pages - 1) // 2)
-        if queue_deadline_s is None:
-            queue_deadline_s = float(FLAGS.serving_queue_deadline_s)
-        if preempt_budget is None:
-            preempt_budget = int(FLAGS.serving_preempt_budget)
-        if watchdog_ticks is None:
-            watchdog_ticks = int(FLAGS.serving_watchdog_ticks)
         self.queue_deadline_s = queue_deadline_s or None   # 0 = disabled
         self.watchdog_ticks = int(watchdog_ticks)          # 0 = disabled
         self.decode_retries = max(0, int(decode_retries))
@@ -792,7 +804,7 @@ class ServingEngine:
             num_pages=num_pages, max_pages_per_seq=int(max_pages_per_seq),
             dtype=kv_dtype, num_kv_heads=num_kv_heads, tp=self.tp)
         self._kv: KVPages = init_kv_pages(self.kv_cfg, mesh=self.mesh,
-                                          axis=self.tp_axis)
+                                          axis=TP_AXIS)
         self._ring_kv: Tuple[KVPages, ...] = tuple(
             init_kv_pages(ring.cfg) for ring in self._rings)
         # the recurrent kind's arrays ({leaf: [slots, ...]} a layer of the
@@ -803,7 +815,7 @@ class ServingEngine:
             self._recurrent.layers if self._recurrent is not None else ())}
         self.pool = PagePool(num_pages)
         if prefix_cache is None:
-            prefix_cache = bool(FLAGS.serving_prefix_cache)
+            prefix_cache = True
         self._prefill_chunk = max(0, int(prefill_chunk))
         self.cache: Optional[PrefixCache] = None
         if prefix_cache:
@@ -813,20 +825,14 @@ class ServingEngine:
         # demote to host RAM (checksummed) instead of being destroyed;
         # lookups that run off the device index swap the continuation
         # back in, verified, charged like chunk prefill.  Off unless a
-        # byte budget is set (flag default 0 keeps prior behavior).
+        # byte budget is set.
         self.host_tier: Optional[HostPageTier] = None
-        self._swap_in_budget = int(
-            swap_in_budget if swap_in_budget is not None
-            else FLAGS.serving_swap_in_budget)
+        self._swap_in_budget = int(swap_in_budget)
         self._host_hits = 0   # swap-in events that promoted >= 1 page
-        host_bytes = int(host_tier_bytes if host_tier_bytes is not None
-                         else FLAGS.serving_host_tier_bytes)
+        host_bytes = int(host_tier_bytes)
         if self.cache is not None and host_bytes > 0:
             self.host_tier = HostPageTier(
-                host_bytes,
-                dtype=str(host_kv_dtype if host_kv_dtype is not None
-                          else FLAGS.serving_host_kv_dtype),
-                faults=faults)
+                host_bytes, dtype=str(host_kv_dtype), faults=faults)
             self.cache.host_tier = self.host_tier
             # read at call time: self._kv is rebound every step
             self.cache.page_reader = \
@@ -899,8 +905,7 @@ class ServingEngine:
             collections.Counter(int(heads[l]) // self.kv_cfg.kv_heads
                                 for l in kind.layers)
             for kind in kinds)
-        self._buckets = tuple(sorted(int(b) for b in buckets)) if buckets \
-            else _parse_buckets(FLAGS.serving_prefill_buckets)
+        self._buckets = tuple(sorted(int(b) for b in buckets))
         self._max_slots = max_slots
         # prefill-row packing: the kernel needs each sequence's rows
         # padded to whole BLOCK_ROWS blocks; the per-tick row budget
@@ -919,19 +924,17 @@ class ServingEngine:
         # and rolling rejected tokens back via COW-guarded page forks.
         # k+1 is a jit dimension: the step ladder is keyed
         # (prefill_bucket, k1), one compile per pair.
-        self.spec_mode = str(spec_mode if spec_mode is not None
-                             else FLAGS.serving_spec_mode)
+        self.spec_mode = str(spec_mode)
         enforce_that(self.spec_mode in _SPEC_MODES,
                      f"spec_mode must be one of {_SPEC_MODES}, got "
                      f"{self.spec_mode!r}", context="serving-spec")
-        self.spec_k = int(spec_k if spec_k is not None
-                          else FLAGS.serving_spec_k)
+        self.spec_k = int(spec_k)
         enforce_that(self.spec_mode == "off" or self.spec_k >= 1,
                      "spec_k must be >= 1 when speculation is on",
                      context="serving-spec")
         self._proposer = None
         if self.spec_mode == "ngram":
-            self._proposer = NGramProposer(n=spec_ngram)
+            self._proposer = NGramProposer()
         elif self.spec_mode == "draft":
             enforce_that(
                 draft_model is not None and draft_params is not None,
@@ -972,8 +975,7 @@ class ServingEngine:
         # per-signature footprint stays under an order-of-magnitude
         # budget — generous slack constants make the budgets guardrails
         # against asymptotic surprises (a duplicated pool, an O(B*S^2)
-        # broadcast), not cycle predictions.  Callers with exact models
-        # tighten them via ServingEngine(xla_peak_bytes=, xla_flops=).
+        # broadcast), not cycle predictions.
         param_bytes = param_count = 0
         for leaf in jax.tree.leaves(params):
             if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
@@ -1009,10 +1011,7 @@ class ServingEngine:
         # 2*b*(N-1)/N each over the [rows, E] f32 activation — so the
         # gate proves the decode hot path stays reduce-not-gather: one
         # implicit all-gather anywhere and the audited estimate leaves
-        # the closed form.  Override via ServingEngine(xla_comm_bytes=).
-        comm_budget = xla_comm_bytes if xla_comm_bytes is not None \
-            else self.tp_step_comm_bytes(rows)
-        kv_comm = xla_comm_bytes if xla_comm_bytes is not None else 0.0
+        # the closed form.
         if self.mesh is None:
             step_in: Tuple = ((),)
             step_out: Tuple = ((),)
@@ -1021,7 +1020,7 @@ class ServingEngine:
             mesh_axes: Tuple = ()
             expect = ()
         else:
-            kvspec = kv_pool_specs(self.tp_axis)
+            kvspec = kv_pool_specs(TP_AXIS)
             # per-leaf param specs (keyed by name: the auditor resolves
             # dict entries against the pytree path) + the pool spec for
             # both the donated input and the aliased output
@@ -1031,23 +1030,23 @@ class ServingEngine:
             step_out = ((), ()) + (kvspec,) * 4
             kv_in = (kvspec, (), ())
             kv_out = (kvspec,) * 4
-            mesh_axes = ((self.tp_axis, self.tp),)
+            mesh_axes = ((TP_AXIS, self.tp),)
             expect = (0, 1)      # params and pool must arrive sharded
         self._step_contract = SiteContract(
             per_tick=True, donate=self._donate_kv,
             allow_upcast=allow_upcast,
-            peak_bytes=xla_peak_bytes if xla_peak_bytes is not None else
-            2 * kv_bytes + 8 * param_bytes + 16 * act_bytes + (1 << 26),
-            flops=xla_flops if xla_flops is not None else
-            64.0 * rows * (param_count
-                           + self.kv_cfg.max_seq_len * e) + 1e9,
+            peak_bytes=2 * kv_bytes + 8 * param_bytes + 16 * act_bytes
+            + (1 << 26),
+            flops=64.0 * rows * (param_count
+                                 + self.kv_cfg.max_seq_len * e) + 1e9,
             in_specs=step_in, out_specs=step_out, mesh_axes=mesh_axes,
-            comm_bytes=comm_budget, expect_sharded=expect)
+            comm_bytes=self.tp_step_comm_bytes(rows),
+            expect_sharded=expect)
         kv_contract = SiteContract(
             per_tick=True, donate=(0,),
             peak_bytes=2 * kv_bytes + (1 << 24),
             in_specs=kv_in, out_specs=kv_out, mesh_axes=mesh_axes,
-            comm_bytes=kv_comm)
+            comm_bytes=0.0)
         # audit_jit == jax.jit unless FLAGS.jit_audit is on, in which
         # case each named site's compiles are counted by the retrace
         # auditor (paddle_tpu.analysis.retrace): the unified step must
@@ -1081,7 +1080,7 @@ class ServingEngine:
             per_tick=True, donate=(0,),
             peak_bytes=3 * kv_bytes + (1 << 24),
             in_specs=imp_in, out_specs=imp_out, mesh_axes=mesh_axes,
-            comm_bytes=kv_comm)
+            comm_bytes=0.0)
         if self.kv_cfg.quantized:
             def _import_pages(kv, ids, k, v, ks, vs):
                 return write_pages(kv, ids, k, v, ks, vs)
@@ -1313,7 +1312,7 @@ class ServingEngine:
             return kv
         from paddle_tpu.serving.kv_cache import kv_pool_sharding
 
-        sh = kv_pool_sharding(self.mesh, self.tp_axis)
+        sh = kv_pool_sharding(self.mesh, TP_AXIS)
         return jax.tree.map(
             lambda a: jax.lax.with_sharding_constraint(a, sh), kv)
 
@@ -1330,7 +1329,7 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         return jax.lax.with_sharding_constraint(
-            ctx, NamedSharding(self.mesh, P(None, self.tp_axis, None)))
+            ctx, NamedSharding(self.mesh, P(None, TP_AXIS, None)))
 
     def _attend(self, kv: KVPages, layer: int, q, table, att_lens,
                 row_seq, qpos, k1: int = 1, window: Optional[int] = None,
@@ -1391,7 +1390,7 @@ class ServingEngine:
             pool["walk"] = walks[window, h]
         if self.mesh is not None and self.tp > 1:
             ctx = ragged_paged_attention_tp(
-                self.mesh, self.tp_axis, qe, kv.k, kv.v, table, att_lens,
+                self.mesh, TP_AXIS, qe, kv.k, kv.v, table, att_lens,
                 rs, qp, **pool)
         else:
             ctx = ragged_paged_attention(qe, kv.k, kv.v, table, att_lens,
@@ -1604,7 +1603,7 @@ class ServingEngine:
         ``status``/``result`` instead of a bare ``None`` sentinel.
 
         ``queue_deadline_s`` bounds time waiting for admission (engine
-        default: ``FLAGS.serving_queue_deadline_s``); ``deadline_s``
+        default: its ``queue_deadline_s=``); ``deadline_s``
         bounds submit-to-last-token.  Either lapsing marks the request
         ``TIMED_OUT`` and frees everything it held.
 
@@ -1619,8 +1618,8 @@ class ServingEngine:
         t = self._time() if now is None else now
         if queue_deadline_s is None:
             # engine-wide default; self.queue_deadline_s is None when
-            # the flag is 0 (the 0-means-off semantic lives on the FLAG,
-            # not on the per-request parameters)
+            # the engine was built with 0 (0 means off THERE, not on the
+            # per-request parameters)
             queue_deadline_s = self.queue_deadline_s
         if queue_deadline_s is not None:
             req.queue_deadline_at = t + float(queue_deadline_s)

@@ -38,7 +38,7 @@ again), the replica's chain-key ownership is forgotten, its engine-side
 in-flight work is cancelled (pages return to its pool), and every
 not-yet-terminal fleet request it carried is **resubmitted** to a
 survivor through the normal dispatch path — deadlines carry over as
-absolute times, resubmits are budgeted (``serving_fleet_resubmit_budget``)
+absolute times, resubmits are budgeted (``resubmit_budget``)
 and then FAILED, and the rid map is severed BEFORE resubmission so one
 fleet rid can never complete twice (``duplicate_completions`` is a
 counter precisely so the conservation check can assert it stayed 0).
@@ -73,7 +73,6 @@ from paddle_tpu.master.service import LeaseTable
 from paddle_tpu.obs.registry import MetricsRegistry
 from paddle_tpu.obs.trace import NULL_TRACER, tracer_for
 from paddle_tpu.platform.enforce import enforce_that
-from paddle_tpu.platform.flags import FLAGS
 from paddle_tpu.serving.control import (AdmissionLedger, Autoscaler,
                                         AutoscalePolicy, TenantRegistry,
                                         WeightedFairQueue)
@@ -88,6 +87,11 @@ from paddle_tpu.serving.scheduler import RequestStatus
 __all__ = ["FleetRouter", "Replica", "ReplicaState"]
 
 _frid_counter = itertools.count()
+
+# replicas of a fleet that is told no count
+REPLICAS = 4
+# bound of the chain-hash -> owner map (``FleetRouter._prefix_owner``)
+_MAX_OWNER_KEYS = 16384
 
 
 class ReplicaState(str, Enum):
@@ -172,7 +176,7 @@ class Replica:
 @dataclass
 class _Transfer:
     """One pending page transfer, queued per DESTINATION and admitted
-    against its per-tick page credit (``serving_migrate_budget``) —
+    against its per-tick page credit (``migrate_budget``) —
     charged to the destination like chunked prefill, never blocking its
     decode tick.  ``kind="chain"`` hands a live request off;
     ``kind="seed"`` warms a peer's PrefixCache."""
@@ -195,48 +199,63 @@ class FleetRouter:
     ``time_fn=time_fn`` (and no per-engine fault clock), so the whole
     fleet shares the router's clock — the same determinism contract the
     single-engine fault plans use.
+
+    What a fleet is built with:
+
+    - ``num_replicas`` (None: ``REPLICAS``) engines behind the one front
+      door.
+    - ``heartbeat_s``: the lease scale on the fleet's clock; the TTL is
+      3x this and leases renew every fleet tick, so a replica dies when
+      its renewals stop for the TTL.  On a wall clock set it above the
+      worst single tick (first compiles), since a tick longer than the
+      TTL lapses every lease mid-tick.
+    - ``resubmit_budget``: death-driven resubmits a request gets (with
+      its ORIGINAL absolute deadline) before it is FAILED; 0 fails it
+      on the first death.
+    - ``roles``: a role a replica ("prefill" | "decode" | "unified"); a
+      shorter list pads with "unified", and empty is the classic fleet
+      with every migration path dormant.  A prefill-class replica hands
+      each request to the least-loaded decode-class one after its first
+      token (``migrate.export_chain`` / ``import_chain``).
+    - ``migrate_budget``: KV pages a DESTINATION replica accepts a fleet
+      tick across in-flight migrations (a blob of n pages waits
+      ceil(n / budget) ticks in its transfer queue and never blocks its
+      decode tick); 0: no migration, prefill-class replicas decode
+      their own requests to the end.
+    - ``tenants`` (a ``TenantRegistry``; None: submits keep their
+      explicit deadlines, quotas and precedence are off), ``wfq``
+      (weighted fair queuing ahead of dispatch) and ``autoscale`` (True
+      or an ``AutoscalePolicy``) are the control plane of
+      ``serving/control.py``, all off unless asked for.
     """
 
     def __init__(self, make_engine: Callable[[int, Callable[[], float]],
                                              ServingEngine],
                  num_replicas: Optional[int] = None, *,
-                 heartbeat_s: Optional[float] = None,
-                 resubmit_budget: Optional[int] = None,
+                 heartbeat_s: float = 1.0,
+                 resubmit_budget: int = 2,
                  routing: str = "affinity",
                  overflow_queue_depth: Optional[int] = None,
                  max_retained: int = 10000,
-                 max_owner_keys: int = 16384,
                  faults: Optional[FleetFaultPlan] = None,
                  time_fn: Optional[Callable[[], float]] = None,
                  tracer=None,
                  registry: Optional[MetricsRegistry] = None,
-                 roles: Optional[Sequence[str]] = None,
-                 migrate_budget: Optional[int] = None,
+                 roles: Sequence[str] = (),
+                 migrate_budget: int = 16,
                  tenants: Optional[TenantRegistry] = None,
-                 wfq: Optional[bool] = None,
-                 autoscale=None):
+                 wfq: bool = False,
+                 autoscale=False):
         enforce_that(routing in ("affinity", "round_robin"),
                      f"unknown routing policy {routing!r}",
                      context="serving")
         if num_replicas is None:
-            num_replicas = int(FLAGS.serving_fleet_replicas)
-        if heartbeat_s is None:
-            heartbeat_s = float(FLAGS.serving_fleet_heartbeat_s)
-        if resubmit_budget is None:
-            resubmit_budget = int(FLAGS.serving_fleet_resubmit_budget)
-        # disaggregation (round 16): per-replica roles; a shorter list
-        # pads with "unified", empty = the classic every-replica-unified
-        # fleet with every migration path dormant
-        if roles is None:
-            raw = str(FLAGS.serving_fleet_roles).strip()
-            roles = [s.strip() for s in raw.split(",")
-                     if s.strip()] if raw else []
+            num_replicas = REPLICAS
+        # disaggregation (round 16): per-replica roles
         self._roles: List[str] = [str(r) for r in roles]
         for r in self._roles:
             enforce_that(r in ("prefill", "decode", "unified"),
                          f"unknown replica role {r!r}", context="serving")
-        if migrate_budget is None:
-            migrate_budget = int(FLAGS.serving_migrate_budget)
         self.migrate_budget = max(0, int(migrate_budget))
         self._disagg = any(r != "unified" for r in self._roles)
         enforce_that(num_replicas >= 1, "fleet needs >= 1 replica",
@@ -250,7 +269,6 @@ class FleetRouter:
         self.resubmit_budget = max(0, int(resubmit_budget))
         self.overflow_queue_depth = overflow_queue_depth
         self.max_retained = max(1, int(max_retained))
-        self.max_owner_keys = max(1, int(max_owner_keys))
         self.faults = faults
         if faults is not None and faults.clock is not None:
             self._time = faults.clock
@@ -275,7 +293,7 @@ class FleetRouter:
         self._requests: Dict[int, _FleetRequest] = {}
         self._live: Set[int] = set()          # non-terminal fleet rids
         self._retired: Deque[int] = deque()   # terminal rids, oldest first
-        # chain hash -> owning replica, LRU-bounded at max_owner_keys:
+        # chain hash -> owning replica, LRU-bounded at _MAX_OWNER_KEYS:
         # like every other long-lived structure here (max_retained
         # history, the engines' LRU caches) it must not grow per unique
         # prompt forever.  Eviction only degrades affinity to a load-
@@ -295,22 +313,14 @@ class FleetRouter:
         self._mig_seq = 0
         # control plane (round 17): tenant SLO classes, weighted fair
         # queuing ahead of dispatch, and the autoscaler policy loop.
-        # All three default off via flags, so the classic fleet is
+        # All three are off unless asked for, so the classic fleet is
         # byte-identical; the admission ledger ALWAYS runs (it is free
         # and the CONTROL-LEAK gate asserts it even with WFQ off).
-        if tenants is None:
-            raw = str(FLAGS.serving_tenant_classes).strip()
-            tenants = TenantRegistry.from_flag(raw) if raw else None
         self.tenants = tenants
-        if wfq is None:
-            wfq = bool(FLAGS.serving_wfq)
         self.wfq = WeightedFairQueue() if wfq else None
         self.ledger = AdmissionLedger()
-        if autoscale is None:
-            autoscale = bool(FLAGS.serving_autoscale)
         if autoscale is True:
-            autoscale = AutoscalePolicy(
-                cooldown_ticks=int(FLAGS.serving_autoscale_cooldown))
+            autoscale = AutoscalePolicy()
         self.autoscaler = Autoscaler(self, autoscale) \
             if isinstance(autoscale, AutoscalePolicy) else None
         for _ in range(num_replicas):
@@ -545,7 +555,7 @@ class FleetRouter:
         for h in hashes:
             owner[h] = idx
             owner.move_to_end(h)
-        while len(owner) > self.max_owner_keys:
+        while len(owner) > _MAX_OWNER_KEYS:
             owner.popitem(last=False)
 
     def _mark_dead(self, rep: Replica, now: float, reason: str) -> None:

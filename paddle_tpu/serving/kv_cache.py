@@ -112,14 +112,23 @@ _KV_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
 
 
 def resolve_kv_dtype(name):
-    """Map ``FLAGS.serving_kv_dtype`` (or an explicit dtype) to a jnp
-    dtype.  Accepts the flag strings and dtype objects alike."""
+    """Map a ``kv_dtype=`` ("float32" | "bfloat16" | "int8", or an
+    explicit dtype) to a jnp dtype."""
     if isinstance(name, str):
         enforce_that(name in _KV_DTYPES,
-                     f"serving_kv_dtype must be one of {sorted(_KV_DTYPES)},"
+                     f"kv_dtype must be one of {sorted(_KV_DTYPES)},"
                      f" got {name!r}", context="serving")
         return _KV_DTYPES[name]
     return jnp.dtype(name)
+
+
+# A page's tokens where the engine is told none: 128 is the TPU's lane
+# width, so a page's K/V tile feeds the MXU without padding (tests and
+# small models pass a smaller ``page_size``).  And the pool's pages where
+# it is given neither ``num_pages`` nor ``pool_bytes``; its HBM cost is
+# 2 * layers * pages * page_size * kv_heads * head_dim * dtype bytes.
+PAGE_SIZE = 128
+NUM_PAGES = 512
 
 
 @dataclass(frozen=True)
@@ -1134,7 +1143,7 @@ class HostPageTier:
     def __init__(self, capacity_bytes: int, dtype: str = "stored",
                  faults=None, tracer=None):
         enforce_that(dtype in ("stored", "int8"),
-                     "serving_host_kv_dtype must be 'stored' or 'int8', "
+                     "host_kv_dtype must be 'stored' or 'int8', "
                      f"got {dtype!r}", context="serving")
         self.capacity_bytes = int(capacity_bytes)
         self.dtype = dtype

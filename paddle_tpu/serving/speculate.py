@@ -11,7 +11,7 @@ token, rolling the rejected suffix back.  Every tick still emits at
 least one token, so speculation can slow nothing down besides the
 proposer's own (cheap) cost.
 
-Two proposers, selected by ``FLAGS.serving_spec_mode``:
+Two proposers, selected by ``ServingEngine(spec_mode=)``:
 
 - :class:`NGramProposer` — prompt lookup: match the last ``n`` tokens
   of the slot's own prompt+output history against earlier occurrences
@@ -52,7 +52,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from paddle_tpu.platform.enforce import enforce_that
-from paddle_tpu.platform.flags import FLAGS
 
 __all__ = ["SamplingParams", "NGramProposer", "DraftProposer",
            "next_token", "accept_tokens", "warp_probs", "position_rng"]
@@ -254,8 +253,8 @@ class NGramProposer(Proposer):
     low acceptance rate is pure profit; repetitive traffic (the chatty
     serving shape) accepts most drafts."""
 
-    def __init__(self, n: Optional[int] = None):
-        self.n = int(n if n is not None else FLAGS.serving_spec_ngram)
+    def __init__(self, n: int = 3):
+        self.n = int(n)
         enforce_that(self.n >= 1, "n-gram size must be >= 1",
                      context="serving-spec")
 

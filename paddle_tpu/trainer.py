@@ -60,24 +60,29 @@ def _metric_scalar(value) -> jax.Array:
 class SGD:
     """v2-compatible trainer: SGD(cost, parameters, update_equation).train(...).
 
-    ``metrics`` maps display names to metric LayerOutputs (the evaluator
-    analog — see paddle_tpu.evaluator); they are computed in-graph per batch
-    and averaged across the pass for EndPass events.
+    ``extra_layers`` are metric LayerOutputs (the evaluator analog — see
+    paddle_tpu.evaluator), kept by name in ``self.metrics``; they are
+    computed in-graph per batch and averaged across the pass for EndPass
+    events.
+
+    ``zero`` (0 or None: replicated optimizer state; 1: ZeRO-1, arXiv
+    2004.13336) reduce-scatters gradients, updates a 1/N optimizer-state
+    shard a replica over the mesh's 'data' axis and all-gathers the
+    updated weights.  ``guard`` (a ``resilience.BadStepGuard``; None: the
+    classic unguarded step) fuses a global-norm + finiteness check over
+    the gradients into the jitted step and skips bad steps in-graph.
     """
 
     def __init__(self, cost, parameters: Parameters, update_equation: Optimizer,
                  extra_layers: Optional[Sequence[LayerOutput]] = None,
-                 is_local: bool = True, mesh=None,
-                 metrics: Optional[Dict[str, LayerOutput]] = None,
+                 mesh=None,
                  zero_axis: Optional[str] = None,
-                 zero: Optional[int] = None,
+                 zero: Optional[int] = 0,
                  pipeline=None,
                  faults=None, guard=None, tracer=None):
         costs = [cost] if isinstance(cost, LayerOutput) else list(cost)
-        self.metrics = dict(metrics or {})
-        # auto-collect evaluator nodes passed via extra_layers
-        for n in (extra_layers or []):
-            self.metrics.setdefault(n.name, n)
+        # evaluator nodes, by name
+        self.metrics = {n.name: n for n in (extra_layers or [])}
         outputs = costs + list(self.metrics.values())
         self.topology = Topology(outputs)
         self._n_costs = len(costs)
@@ -105,38 +110,35 @@ class SGD:
         # then inherit the committed shardings, so no device ever
         # materializes a full slot replica of a sharded weight
         self._place_on_mesh(slots_too=False)
-        # ZeRO-1 (zero= arg, default FLAGS.zero_stage): shard optimizer
-        # state 1/N over the 'data' axis while params stay replicated —
+        # ZeRO-1 (zero=1): shard optimizer state 1/N over the 'data'
+        # axis while params stay replicated —
         # the plan threads through init_state so slots are sharded from
         # step 0, and through apply for the per-step reduce-scatter /
         # all-gather pair (parallel/zero.py)
         self._zero_plan = None
-        stage = int(FLAGS.zero_stage if zero is None else zero)
+        stage = int(zero or 0)
         if stage:
-            enforce_that(stage == 1, f"zero_stage={stage} not implemented "
+            enforce_that(stage == 1, f"zero={stage} not implemented "
                          "(0 = off, 1 = optimizer-state sharding)",
                          context="trainer")
-            usable = mesh is not None and "data" in mesh.axis_names
-            # an EXPLICIT zero= request that cannot take effect is an
-            # error (silently training replicated would fake the N x
-            # memory claim); the process-wide FLAGS.zero_stage stays
-            # permissive so single-device tools keep working
-            enforce_that(usable or zero is None,
+            # a zero= request that cannot take effect is an error
+            # (silently training replicated would fake the N x memory
+            # claim)
+            enforce_that(mesh is not None and "data" in mesh.axis_names,
                          "zero=1 needs mesh= with a 'data' axis (got "
                          + ("no mesh" if mesh is None else
                             f"axes {tuple(mesh.axis_names)}") + ")",
                          context="trainer")
-            if usable:
-                from paddle_tpu.parallel.zero import build_zero_plan
+            from paddle_tpu.parallel.zero import build_zero_plan
 
-                # merged specs: pipeline stage weights carry explicit
-                # stage sharding and are therefore EXCLUDED from ZeRO —
-                # "the ZeRO-sharded remainder" resolves through the same
-                # placement plan as everything else
-                self._zero_plan = build_zero_plan(
-                    mesh, parameters.as_dict(),
-                    specs=self._param_specs(),
-                    zero_axis=self._zero_axis)
+            # merged specs: pipeline stage weights carry explicit
+            # stage sharding and are therefore EXCLUDED from ZeRO —
+            # "the ZeRO-sharded remainder" resolves through the same
+            # placement plan as everything else
+            self._zero_plan = build_zero_plan(
+                mesh, parameters.as_dict(),
+                specs=self._param_specs(),
+                zero_axis=self._zero_axis)
         # unconditional (including None): a reused optimizer instance must
         # not carry a previous trainer's plan into this one
         self.optimizer.set_zero_plan(self._zero_plan)
@@ -150,25 +152,14 @@ class SGD:
         # TrainFaultPlan drives injected deaths/NaNs/slow steps, the
         # BadStepGuard fuses the skip-or-rollback policy into the jitted
         # step, and the tracer puts guard/checkpoint edges on the obs
-        # timeline.  guard=None falls back to FLAGS.train_bad_step_policy
-        # ("off" by default, so the unguarded step signature — and every
-        # existing compiled program — is unchanged).
+        # timeline.  guard=None is the unguarded step (its signature, and
+        # every compiled program without a guard, is the classic one).
         self._faults = faults
-        if guard is None:
-            policy = str(FLAGS.train_bad_step_policy or "off")
-            if policy != "off":
-                from paddle_tpu.resilience.guard import BadStepGuard
-
-                guard = BadStepGuard(
-                    policy=policy,
-                    max_norm=float(FLAGS.train_bad_step_max_norm),
-                    rollback_after=int(FLAGS.train_bad_step_window))
         if faults is not None and faults.injects_grads():
             enforce_that(guard is not None,
                          "TrainFaultPlan injects non-finite gradients "
                          "but no bad-step guard is set — pass "
-                         "SGD(guard=BadStepGuard()) (or set "
-                         "FLAGS.train_bad_step_policy) so the poison "
+                         "SGD(guard=BadStepGuard()) so the poison "
                          "is screened instead of corrupting optimizer "
                          "slots", context="trainer")
         self._guard = guard
@@ -236,14 +227,14 @@ class SGD:
             enforce_that(sorted(d) == list(range(n_layers)),
                          f"blk*_{suffix} layer ids {sorted(d)} do not "
                          f"cover 0..{n_layers - 1}", context="trainer")
-        # stage count: config > flag > the mesh's stage axis > all devices
-        s = int(cfg.num_stages) or int(FLAGS.pipeline_stages)
+        # stage count: config > the mesh's stage axis > all devices
+        s = int(cfg.num_stages)
         if not s:
             s = (int(mesh.shape[axis])
                  if mesh is not None and axis in mesh.axis_names
                  else jax.device_count())
-        m = int(cfg.microbatches) or int(FLAGS.pipeline_microbatches)
-        enforce_that(m >= 1, f"pipeline_microbatches={m} must be >= 1",
+        m = int(cfg.microbatches)
+        enforce_that(m >= 1, f"microbatches={m} must be >= 1",
                      context="trainer")
         enforce_that(n_layers % s == 0,
                      f"n_layers={n_layers} does not divide into "
@@ -333,7 +324,7 @@ class SGD:
             b = int(tok.shape[0])
             enforce_that(b % m == 0,
                          f"batch of {b} sequences does not divide into "
-                         f"pipeline_microbatches={m}", context="trainer")
+                         f"microbatches={m}", context="trainer")
 
             def split(a):
                 return a.reshape((m, b // m) + a.shape[1:])
@@ -658,12 +649,12 @@ class SGD:
     # ------------------------------------------------------------------
 
     def train(self, reader=None, num_passes: int = 1, event_handler=None,
-              feeding=None, test_reader=None, save_dir: Optional[str] = None,
+              feeding=None, save_dir: Optional[str] = None,
               start_pass: int = 0, saving_period: int = 1, master=None,
               record_parser=None, heartbeat_ttl_s: Optional[float] = None,
               prefetch: int = 0, save_period_steps: int = 0,
-              resume: bool = False, async_save: Optional[bool] = None,
-              keep: Optional[int] = None) -> None:
+              resume: bool = False, async_save: bool = False,
+              keep: int = 2) -> None:
         """``save_dir``/``start_pass``/``saving_period`` are the
         --save_dir/--start_pass/--saving_period flags of the reference
         trainer (ParamUtil.h:77-111): checkpoints (params + optimizer
@@ -679,11 +670,13 @@ class SGD:
         CKPT-CORRUPT line and the next-older one wins) and fast-forwards
         the data cursor, so a killed run re-joins mid-pass with the same
         rng stream — final params equal an uninterrupted run's.
-        ``async_save=True`` (default ``FLAGS.train_ckpt_async``) writes
-        blobs on a background thread (AsyncCheckpointer): training
-        stalls only for the device->host snapshot.  ``keep`` bounds the
-        checkpoint dir (verified-aware pruning; default
-        ``FLAGS.train_ckpt_keep``).
+        ``async_save=True`` writes blobs on a background thread
+        (AsyncCheckpointer, depth-one pipelined: a new save first waits
+        out the previous write): training stalls only for the
+        device->host snapshot, never the tar/pkl/md5/meta disk commit.
+        ``keep`` bounds the checkpoint dir to that many newest VERIFIED
+        checkpoints (corrupt dirs never count, so torn young saves
+        cannot reap the only good artifact; 0: no pruning).
 
         With ``master=MasterClient(...)`` training is elastic/task-driven
         instead of reader-driven (reference: cloud_reader + etcd
@@ -692,9 +685,7 @@ class SGD:
         sample tuple), the lease is heartbeat per batch, and a lapsed
         lease triggers re-register + auto-resume from the latest
         checkpoint in ``save_dir``."""
-        use_async = bool(FLAGS.train_ckpt_async) if async_save is None \
-            else bool(async_save)
-        keep = int(FLAGS.train_ckpt_keep) if keep is None else int(keep)
+        use_async, keep = bool(async_save), int(keep)
         if master is not None:
             enforce_that(record_parser is not None,
                          "master= training needs record_parser=",
@@ -709,7 +700,7 @@ class SGD:
             return self._train_elastic(master, record_parser, num_passes,
                                        event_handler, feeding, save_dir,
                                        heartbeat_ttl_s, saving_period,
-                                       test_reader, use_async, keep)
+                                       use_async, keep)
         enforce_that(reader is not None, "train() needs a reader "
                      "(or master=)", context="trainer")
         enforce_that(not (resume and start_pass > 0),
@@ -947,18 +938,13 @@ class SGD:
                                  batch_id,
                                  np.mean(pass_costs[-FLAGS.log_period:]),
                                  mtxt)
-                # pass end: sync back, fire event (+ test if reader given)
+                # pass end: sync back, fire event
                 flush()
                 sync_back()
                 result_metrics = {k: float(np.mean(v)) if v else 0.0
                                   for k, v in pass_metrics.items()}
-                if test_reader is not None:
-                    tr = self.test(test_reader, feeding)
-                    event_handler(v2_event.EndPass(pass_id, tr.metrics,
-                                                   self.parameters))
-                else:
-                    event_handler(v2_event.EndPass(pass_id, result_metrics,
-                                                   self.parameters))
+                event_handler(v2_event.EndPass(pass_id, result_metrics,
+                                               self.parameters))
                 if gstate is not None:
                     # before the pass-end save: a save-kill must not
                     # swallow this pass's bad-step accounting
@@ -1119,8 +1105,7 @@ class SGD:
     def _train_elastic(self, master, record_parser, num_passes: int,
                        event_handler, feeding, save_dir: Optional[str],
                        ttl_s: Optional[float], saving_period: int,
-                       test_reader, use_async: bool = False,
-                       keep: int = 2) -> None:
+                       use_async: bool, keep: int) -> None:
         """Task-driven elastic training (the kill/resume e2e productized).
 
         One SGD step per master task; the step counter (== applied task
@@ -1173,7 +1158,7 @@ class SGD:
         if save_dir is not None and use_async:
             from paddle_tpu.resilience.checkpointer import AsyncCheckpointer
 
-            # keep=0 stays "pruning disabled" (the documented flag
+            # keep=0 stays "pruning disabled" (train()'s documented
             # semantics); only a positive budget gets the >= 2 floor
             self._async_ckpt = AsyncCheckpointer(
                 keep=keep if keep == 0 else max(2, keep))
@@ -1425,13 +1410,8 @@ class SGD:
                 # expose their trainOneBatch timings through obs too
                 stats.timer_stats().publish(default_registry(),
                                             prefix="trainer_")
-                if test_reader is not None:
-                    tr = self.test(test_reader, feeding)
-                    event_handler(v2_event.EndPass(pass_id - 1, tr.metrics,
-                                                   self.parameters))
-                else:
-                    event_handler(v2_event.EndPass(pass_id - 1, {},
-                                                   self.parameters))
+                event_handler(v2_event.EndPass(pass_id - 1, {},
+                                               self.parameters))
         except BaseException:
             # unwind (injected death, rollback, real error): let the
             # in-flight write finish — its meta either commits (resume

@@ -165,8 +165,7 @@ def gradient_check(cost, parameters, feeds, *, sample_entries: int = 8,
 def compare_topologies(node_a, node_b, feeds_a, feeds_b=None, *,
                        seed: int = 0, param_link: Optional[Dict[str, str]] = None,
                        check_inputs: tuple = (), rtol: float = 1e-4,
-                       atol: float = 1e-5, flags_a: Optional[Dict] = None,
-                       flags_b: Optional[Dict] = None):
+                       atol: float = 1e-5):
     """Assert two differently-expressed topologies compute the SAME function:
     identical outputs AND identical gradients on the same data.
 
@@ -185,9 +184,7 @@ def compare_topologies(node_a, node_b, feeds_a, feeds_b=None, *,
     give corresponding weights the same name. Gradients of the
     mean-reduced first output are compared for every linked parameter and
     for each feed name in ``check_inputs`` (feeds must then be identical
-    dense arrays in both feed dicts). ``flags_a``/``flags_b`` override
-    FLAGS around each side's forward+grad (e.g. ``flags_b={"use_pallas":
-    False}`` to compare a pallas kernel against its plain-XLA fallback).
+    dense arrays in both feed dicts).
     Returns (out_a, out_b, grads_a, grads_b).
     """
     import jax
@@ -219,10 +216,7 @@ def compare_topologies(node_a, node_b, feeds_a, feeds_b=None, *,
                          context="compare")
             pb[nb] = pa[na]
 
-        def run(topo, params, feeds, overrides):
-            olds = {k: getattr(FLAGS, k) for k in (overrides or {})}
-            for k, v in (overrides or {}).items():
-                setattr(FLAGS, k, v)
+        def run(topo, params, feeds):
             in_names = list(check_inputs)
 
             # one forward+backward: params and checked inputs differentiate
@@ -235,16 +229,12 @@ def compare_topologies(node_a, node_b, feeds_a, feeds_b=None, *,
                                          else o)
 
             fvals = [jnp.asarray(feeds[n], jnp.float32) for n in in_names]
-            try:
-                (loss, out), (gp, gf) = jax.value_and_grad(
-                    loss_fn, argnums=(0, 1), has_aux=True)(params, fvals)
-            finally:
-                for k, v in olds.items():
-                    setattr(FLAGS, k, v)
+            (loss, out), (gp, gf) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True)(params, fvals)
             return out, gp, dict(zip(in_names, gf))
 
-        out_a, gpa, gia = run(topo_a, pa, feeds_a, flags_a)
-        out_b, gpb, gib = run(topo_b, pb, feeds_b, flags_b)
+        out_a, gpa, gia = run(topo_a, pa, feeds_a)
+        out_b, gpb, gib = run(topo_b, pb, feeds_b)
 
         oa, ob = np.asarray(out_a), np.asarray(out_b)
         # image layers may emit [B,H,W,C] where an equivalent mixed/operator

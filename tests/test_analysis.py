@@ -738,6 +738,26 @@ def test_lint_import_time_flags():
         == ["import-time-flags"]
 
 
+def test_every_flag_is_read_outside_the_registry():
+    """A flag that nothing reads is a name with no effect, and one that
+    only shadows a keyword is that keyword's second spelling: every name
+    ``platform/flags.py`` defines is read (``FLAGS.<name>`` or
+    ``getattr(FLAGS, "<name>", ...)``) somewhere under ``paddle_tpu/``
+    outside the registry itself."""
+    import re
+    from pathlib import Path
+
+    import paddle_tpu
+
+    pkg = Path(paddle_tpu.__file__).resolve().parent
+    registry = pkg / "platform" / "flags.py"
+    src = "\n".join(p.read_text() for p in sorted(pkg.rglob("*.py"))
+                    if p != registry)
+    unread = [n for n in sorted(FLAGS.to_dict())
+              if not re.search(rf'FLAGS\.{n}\b|FLAGS, "{n}"', src)]
+    assert unread == [], unread
+
+
 def test_repo_lints_clean():
     """The acceptance bar: the linter lands clean on its own repo (real
     findings fixed, justified ones allowlisted inline)."""

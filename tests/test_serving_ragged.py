@@ -4,7 +4,7 @@ prefill+decode batches, GQA head-group packing, int8-quantized KV pages.
 Covers the kernel/reference parity matrix (mixed batches, ragged
 lengths, offset masks, GQA, int8, and every number of KV heads a grid
 cell may take), the single dispatch chooser, the
-bytes-per-page accounting behind ``FLAGS.serving_kv_dtype`` and
+bytes-per-page accounting behind ``kv_dtype=`` and
 ``ServingEngine(pool_bytes=...)``, the unified-step engine (fused vs
 v1-shaped split ticks, token-identical), GQA greedy parity against a
 head-replicated MHA oracle, int8 chaos conservation, and the
@@ -843,7 +843,7 @@ def test_attention_path_single_chooser():
 
 
 # ---------------------------------------------------------------------------
-# bytes-per-page accounting + pool byte budgets (serving_kv_dtype)
+# bytes-per-page accounting + pool byte budgets (kv_dtype=)
 # ---------------------------------------------------------------------------
 
 
@@ -879,20 +879,15 @@ def test_bytes_per_page_accounting():
 
 @ragged
 @serving
-def test_bf16_kv_pool_via_flag_and_param(rng):
-    """Satellite: serving_kv_dtype plumbs through the cache config —
+def test_bf16_kv_pool_via_param(rng):
+    """Satellite: kv_dtype plumbs through the cache config —
     bf16 KV works end to end even without int8."""
     model = DecoderLM(vocab_size=50, num_layers=1, num_heads=2, head_dim=8,
                       max_positions=64)
     params = model.init_params(jax.random.PRNGKey(0))
-    old = FLAGS.serving_kv_dtype
-    try:
-        FLAGS.serving_kv_dtype = "bfloat16"
-        eng = ServingEngine(model, params, eos_id=1, page_size=4,
-                            num_pages=20, max_pages_per_seq=5, max_slots=2,
-                            buckets=(4, 8))
-    finally:
-        FLAGS.serving_kv_dtype = old
+    eng = ServingEngine(model, params, eos_id=1, page_size=4,
+                        num_pages=20, max_pages_per_seq=5, max_slots=2,
+                        buckets=(4, 8), kv_dtype="bfloat16")
     assert eng.kv_cfg.dtype == jnp.bfloat16
     assert eng._kv.k.dtype == jnp.bfloat16 and eng._kv.k_scale is None
     rid = eng.submit(rng.randint(2, 50, size=6).tolist(), max_tokens=6)
@@ -900,7 +895,6 @@ def test_bf16_kv_pool_via_flag_and_param(rng):
     assert eng.status(rid) is RequestStatus.COMPLETED and len(res[rid]) >= 1
     assert eng.healthz()["kv_dtype"] == "bfloat16"
     assert_drained(eng)
-    # explicit param wins over the flag
     eng2 = ServingEngine(model, params, eos_id=1, page_size=4,
                          num_pages=20, max_pages_per_seq=5, max_slots=2,
                          buckets=(4, 8), kv_dtype="int8")

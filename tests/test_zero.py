@@ -134,7 +134,7 @@ def _run(sgd, batches):
 @pytest.mark.parametrize("z_save,z_load", [(1, 0), (0, 1)],
                          ids=["zero1_to_zero0", "zero0_to_zero1"])
 def test_step_cursor_resume_across_zero_stages(tmp_path, z_save, z_load):
-    """Cross-layout resume under chaos, STEP-granular: a zero_stage=z
+    """Cross-layout resume under chaos, STEP-granular: a zero=z
     run is killed MID-PASS between step checkpoints; a trainer under the
     OTHER zero stage resumes via the cursor (pass, step-in-pass, rng)
     and the post-resume loss trajectory + final params match the

@@ -1,17 +1,21 @@
 #!/usr/bin/env bash
-# Tier-1 verify wrapper: runs the ROADMAP.md tier-1 command verbatim and
-# prints DOTS_PASSED, so the verify line is one script instead of a paste.
+# Tier-1 verify wrapper: runs the command the driver runs after every PR
+# (six xdist workers, 1,470 s, the count from the junit file where there
+# is one) and prints DOTS_PASSED, then the ladder's gates.
 #
-#   ./tools_tier1.sh            # exit code = pytest's; last line DOTS_PASSED=N
+#   ./tools_tier1.sh            # exit code = pytest's; DOTS_PASSED=N
 set -o pipefail
 cd "$(dirname "$0")"
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
-    --durations=10 \
-    --continue-on-collection-errors -p no:cacheprovider -p no:xdist \
+rm -rf /tmp/_t1.log /tmp/_t1.xml
+timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+    python -m pytest tests/ -q -m 'not slow' \
+    --continue-on-collection-errors -p no:cacheprovider \
+    -p xdist -n 6 --dist load --junitxml=/tmp/_t1.xml \
     -p no:randomly 2>&1 | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}')
+echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)}
+echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null)
 # flight-recorder surfacing (paddle_tpu.obs): when a conservation
 # invariant trips with tracing on, the engine/fleet dumps the recent
 # event ring to a postmortem file and stamps its path into the log —
