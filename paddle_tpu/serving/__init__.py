@@ -20,6 +20,7 @@ from paddle_tpu.serving.engine import (DecodeModel, DecoderLM, ServingEngine,
 from paddle_tpu.serving.block_moe_lm import BlockMoeLM
 from paddle_tpu.serving.window_moe_lm import WindowMoeLM
 from paddle_tpu.serving.hybrid_ssm_lm import HybridSsmLM
+from paddle_tpu.serving.looped_lm import LoopedLM
 from paddle_tpu.serving.speculate import (DraftProposer, NGramProposer,
                                           SamplingParams, accept_tokens,
                                           next_token, warp_probs)
@@ -47,7 +48,7 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
 
 __all__ = [
     "ServingEngine", "DecodeModel", "DecoderLM", "BlockMoeLM",
-    "WindowMoeLM", "HybridSsmLM",
+    "WindowMoeLM", "HybridSsmLM", "LoopedLM",
     "greedy_decode_reference",
     "ragged_paged_attention", "ragged_paged_attention_reference",
     "ragged_paged_attention_tp", "attention_path", "BLOCK_ROWS",

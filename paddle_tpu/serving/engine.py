@@ -147,6 +147,33 @@ speculation, the host tier, chain migration, tensor parallelism, a block
 model.  Preemption works: the pages go back, the state is overwritten
 from zero at the re-prefill.
 
+Looped models: a model that says ``loops`` (``T > 1``) runs its stack of
+``num_layers`` weight layers ``T`` times a tick over the SAME parameters
+(a looped, or universal, transformer: ``2.7 B`` parameters doing the work
+of a four times deeper forward).  A layer's weights are then no longer
+its cache: the keys and values that pass ``t`` makes in weight layer
+``l`` are read by pass ``t`` alone, at every later position, so THE RULE
+is that weight layer ``l`` at pass ``t`` writes and attends over CACHE
+layer ``t * num_layers + l``.  The KV manager's layer axis counts cache
+layers (``kv_cfg.num_layers = loops x`` the model's layers): a token, and
+so a page, costs ``loops`` times the K/V, and ``pool_bytes -> pages``,
+admission, growth, preemption and the accounting after a drain all read
+that one number.  The compiled step unrolls the passes (each under a
+scope ``pass<t>`` around the layers' ``l<l>``), carries every row of the
+tick (decode rows and a prefill chunk's alike) through all of them, and
+between two passes calls the model's ``close_pass`` where it has one (a
+final norm whose output is the next pass's input; it also hands back the
+pass's exit probability a row, from which the step counts ``loop_passes``,
+``exit_rows`` and ``exit_step_milli`` on its decode rows:
+:func:`exit_distribution`).  Every token runs every pass (no row leaves
+early), the logits are the last pass's.  Chunked prefill, the prefix
+cache (a page holds every cache layer of its tokens), preemption by
+re-prefill and sampling work as for any model; window layers, a recurrent
+state, a block model, speculation, tensor parallelism, the host tier and
+chain migration refuse a looped model at construction
+(``_refuse_for_looped_model``).  A model without ``loops`` (or with 1)
+builds the pool and the step it always built.
+
 What lands when (both step kinds): a step chooses its tokens itself.
 A one-token tick takes, inside the compiled step, the first maximum of
 each decode row's and of each chunk-final row's logits and whether the
@@ -195,6 +222,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import gc
+import itertools
 import math
 import time
 from collections import deque
@@ -241,7 +269,7 @@ from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                           pack_prefill_chunks)
 
 __all__ = ["DecodeModel", "DecoderLM", "SamplingParams", "ServingEngine",
-           "greedy_decode_reference", "validate_tp"]
+           "exit_distribution", "greedy_decode_reference", "validate_tp"]
 
 _SPEC_MODES = ("off", "ngram", "draft")
 # the mesh axis a tensor-parallel engine places heads and FFN columns
@@ -304,6 +332,17 @@ class DecodeModel:
       chunk's rows contiguous).  ``mixed`` is the model's own (the
       branch's rows, its counts) and reaches ``attn_out`` /
       ``attn_out_counted`` as a further last argument.
+
+    - ``loops`` (optional, an int; absent: 1) with ``close_pass(params,
+      t, x) -> (x, lam)`` (optional): a LOOPED model, whose ``num_layers``
+      weight layers run ``loops`` times a tick over the same parameters
+      (``ServingEngine``: "looped models").  Weight layer ``l`` at pass
+      ``t`` keeps cache layer ``t * num_layers + l``.  ``close_pass`` is
+      called behind the last layer of pass ``t`` (0-based) on the tick's
+      rows ``x [T, E]``; what it returns is the next pass's input (the
+      last pass's goes to ``logits``), and ``lam [T]`` (or None) the
+      probability with which a row would leave after this pass, which
+      the engine only counts (no row leaves early).
 
     Tensor-parallel serving (``ServingEngine(mesh=...)``) additionally
     needs:
@@ -368,6 +407,17 @@ def validate_tp(model: "DecodeModel", tp: int, axis: str = "model") -> None:
             f"axis size ({tp}): the column-parallel up projection places "
             f"whole FFN columns per chip — pick a tp that divides {ffn}",
             context="serving-tp")
+
+
+def exit_distribution(lam):
+    """The step a row of a looped model leaves at, as a distribution over
+    its passes: ``lam [T, ...]`` is the exit gate's probability behind
+    each pass; ``p_t = lam_t prod_{j<t} (1 - lam_j)`` for ``t < T`` and
+    the last pass takes what is left, ``p_T = prod_{j<T} (1 - lam_j)``,
+    so the ``p_t`` sum to one whatever the last gate says."""
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), stay])
+    return jnp.concatenate([lam[:-1] * before[:-1], before[-1:]])
 
 
 def _rms(x, eps: float = 1e-6):
@@ -700,6 +750,13 @@ class ServingEngine:
             self._refuse_for_recurrent_model(
                 mesh, prefix_cache, spec_mode, host_tier_bytes)
             prefix_cache = False
+        # a looped model (the module doc: "looped models") runs its weight
+        # layers this many times a tick, each pass on cache layers of its
+        # own.  1 for every other model
+        self._loops = int(getattr(model, "loops", 1))
+        if self._loops != 1:
+            self._refuse_for_looped_model(
+                kinds, mesh, spec_mode, host_tier_bytes)
         rows = int(prefill_chunk) if int(prefill_chunk) > 0 else 1 << 30
         self._rings: Tuple[WindowRing, ...] = tuple(
             make_window_ring(
@@ -714,7 +771,8 @@ class ServingEngine:
         # among the kind's layers, which is its layer in the kind's arrays)
         self._layer_state = {l: (i, j) for i, kind in enumerate(kinds)
                              for j, l in enumerate(kind.layers)}
-        full_layers = len(kinds[0].layers)
+        # the pool's layer axis is CACHE layers: a pass's own a weight layer
+        full_layers = len(kinds[0].layers) * self._loops
         # tensor-parallel placement (ROADMAP item 1): with a mesh, the
         # megatron shard_plan places attention heads + FFN columns over
         # the `model` axis, the paged pool shards its KV-head dim the
@@ -848,6 +906,11 @@ class ServingEngine:
         # counters the model's layers return from inside the step
         self._counted: Tuple[str, ...] = tuple(
             getattr(model, "step_counters", ()))
+        # what the step itself counts of a looped model's passes and of
+        # the exit gate on its decode rows, behind them in the words
+        self._loop_counted: Tuple[str, ...] = (
+            "loop_passes", "exit_rows", "exit_step_milli") \
+            if self._loops != 1 else ()
         # and what the host counts of the kinds' state a step
         # (``_kind_counts``), under these names beside them
         self._kind_counted: Tuple[str, ...] = (
@@ -861,7 +924,8 @@ class ServingEngine:
                 "state_slots_live", "state_bytes_live")
         self.metrics = ServingMetrics(
             pool_pages=self.pool.num_usable,
-            model_counters=self._counted + self._kind_counted)
+            model_counters=self._counted + self._loop_counted
+            + self._kind_counted)
         # the step in the air, and the words a step takes where none is
         # (no token is pending then)
         self._flying: Optional[_Flight] = None
@@ -903,7 +967,7 @@ class ServingEngine:
             (model.num_heads,) * int(model.num_layers)
         self._kind_groups = tuple(
             collections.Counter(int(heads[l]) // self.kv_cfg.kv_heads
-                                for l in kind.layers)
+                                for l in kind.layers * self._loops)
             for kind in kinds)
         self._buckets = tuple(sorted(int(b) for b in buckets))
         self._max_slots = max_slots
@@ -994,7 +1058,7 @@ class ServingEngine:
             sum(ring.kv_bytes() for ring in self._rings) + \
             (self._recurrent.kv_bytes() if self._recurrent is not None
              else 0)
-        act_bytes = 4 * rows * (8 * e * model.num_layers
+        act_bytes = 4 * rows * (8 * e * model.num_layers * self._loops
                                 + model.vocab_size)
         kv_name = jnp.dtype(self.kv_cfg.dtype).name
         allow_upcast = (kv_name,) if kv_name != "float32" else ()
@@ -1037,8 +1101,8 @@ class ServingEngine:
             allow_upcast=allow_upcast,
             peak_bytes=2 * kv_bytes + 8 * param_bytes + 16 * act_bytes
             + (1 << 26),
-            flops=64.0 * rows * (param_count
-                                 + self.kv_cfg.max_seq_len * e) + 1e9,
+            flops=64.0 * rows * self._loops * (
+                param_count + self.kv_cfg.max_seq_len * e) + 1e9,
             in_specs=step_in, out_specs=step_out, mesh_axes=mesh_axes,
             comm_bytes=self.tp_step_comm_bytes(rows),
             expect_sharded=expect)
@@ -1235,6 +1299,54 @@ class ServingEngine:
                      "the recurrent state behind: a model with a recurrent "
                      "state serves as 'unified'", context=ctx)
 
+    def _refuse_for_looped_model(self, kinds, mesh, spec_mode: str,
+                                 host_tier_bytes: int) -> None:
+        """What a looped model (``loops > 1``) cannot be built with, said
+        at construction, each by its mechanism (nothing takes a silent
+        second path).  The prefix cache, chunked prefill and preemption
+        need nothing of it: a page holds every cache layer of its
+        tokens."""
+        from paddle_tpu.platform.enforce import enforce_that
+
+        ctx = "serving-looped"
+        enforce_that(self._loops >= 1,
+                     f"loops must be at least 1, got {self._loops}",
+                     context=ctx)
+        enforce_that(self._block is None,
+                     "a block model rewrites its current block at every "
+                     "denoising pass; that inside a stack which itself "
+                     "runs several times a tick is not built: serve a "
+                     "block model without loops", context=ctx)
+        enforce_that(len(kinds) == 1,
+                     "a window layer's ring holds ONE layer's last tokens "
+                     "a slot; a ring for every pass of a looped stack is "
+                     "not built: serve a looped model without "
+                     "layer_window", context=ctx)
+        enforce_that(self._recurrent is None,
+                     "a recurrent state is advanced once a row; a looped "
+                     "stack would advance it once a pass, and a state for "
+                     "every pass is not built: serve a looped model "
+                     "without layer_state", context=ctx)
+        enforce_that(str(spec_mode) == "off",
+                     "speculative decoding has not been driven through a "
+                     "looped stack (a verify row costs every pass, and "
+                     "the draft proposer's own step runs one): build it "
+                     "with spec_mode='off'", context=ctx)
+        enforce_that(mesh is None,
+                     "tensor-parallel serving (mesh=) of a looped model is "
+                     "not built: the step's closed-form collective budget "
+                     "counts one pass; serve it with mesh=None",
+                     context=ctx)
+        enforce_that(int(host_tier_bytes) <= 0,
+                     "the host tier has not been driven with a looped "
+                     "model's pages (every pass's cache layers a page): "
+                     "build it with host_tier_bytes=0", context=ctx)
+        enforce_that(self.role == "unified",
+                     f"role={self.role!r} hands requests over by chain "
+                     "migration, which has not been driven with a looped "
+                     "model's pages: a looped model serves as 'unified'",
+                     context=ctx)
+
     # ---- observability wiring -------------------------------------------
 
     def set_tracer(self, tracer) -> None:
@@ -1398,6 +1510,30 @@ class ServingEngine:
         cd = ctx[:td].reshape(b, rbk, h, d)[:, :k1].reshape(bd, h, d)
         return self._tp_ctx(jnp.concatenate([cd, ctx[td:]]))
 
+    def _cache_layer(self, t: int, layer: int) -> int:
+        """Where weight layer ``layer`` (its index in its kind's arrays)
+        keeps its K/V at pass ``t``: THE cache-layer rule of the module
+        doc ("looped models").  Pass 0 is the layer itself, so a model of
+        one pass reads what it always read."""
+        return t * int(self.model.num_layers) + layer
+
+    def _loop_counts(self, lams, valid):
+        """What a looped model's step counts itself, int32 under
+        ``_loop_counted``'s names: the passes it ran, and from the exit
+        gate on its decode rows (``valid [B * k1]``; ``lams``: each
+        pass's ``lam [T]`` as ``close_pass`` handed it back, None for a
+        model without a gate) how many rows were read and the step they
+        would leave at were the threshold lower, the expectation ``sum_t
+        t p_t`` (:func:`exit_distribution`) in thousandths, summed."""
+        seen = step = 0
+        if lams and lams[0] is not None:
+            p = exit_distribution(jnp.stack(lams)[:, :valid.shape[0]])
+            at = jnp.sum(p * jnp.arange(1, len(lams) + 1)[:, None], axis=0)
+            seen = jnp.sum(valid)
+            step = jnp.sum(jnp.where(valid, jnp.round(1e3 * at), 0.0))
+        return jnp.stack([jnp.asarray(n, jnp.int32)
+                          for n in (self._loops, seen, step)])
+
     def _step_fn(self, pb: int, k1: int = 1):
         """The unified per-tick step for prefill bucket ``pb`` (0 =
         decode-only) at ``k1`` decode/verify rows per slot (1 = plain
@@ -1435,6 +1571,13 @@ class ServingEngine:
 
         rec_layer = self._rec_layer
         mix = getattr(model, "mix", None)
+        # a looped model's passes (the module doc: "looped models"): the
+        # stack runs ``loops`` times, pass ``t`` on cache layers ``t *
+        # num_layers + l``; a model of one pass names none
+        loops, n_layers = self._loops, int(model.num_layers)
+        close_pass = getattr(model, "close_pass", None)
+        pass_scope = (lambda t: jax.named_scope(f"pass{t}")) \
+            if loops != 1 else (lambda t: contextlib.nullcontext())
 
         def raw(params, kv: KVPages, packed, *last):
             # (behind the last step's words: each ring's arrays, then the
@@ -1506,11 +1649,13 @@ class ServingEngine:
             walks = {}      # the kernel's walks, by kind and head count
             # the tick's row layout, for a layer that keeps a state a slot
             rows = dict(row_seq=row_seq, pos=pos, live=live, decode_rows=bd)
-            for l in range(model.num_layers):
+            lams = []       # a pass's exit probability a row, where said
+            for t, l in itertools.product(range(loops), range(n_layers)):
                 # named_scope: blocks and their parts show up by name in
                 # xplane/profiler traces (as topology.forward's layers)
                 i, li = self._layer_state[l]
-                with jax.named_scope(f"l{l}"):
+                li = self._cache_layer(t, li)
+                with pass_scope(t), jax.named_scope(f"l{l}"):
                     with jax.named_scope("attn"), kind_scope(i):
                         q, k, v = model.qkv(params, l, x)
                         if rotate is not None:
@@ -1533,10 +1678,16 @@ class ServingEngine:
                         counts = counts + n
                     else:
                         x = model.attn_out(params, l, ctx, x, *more)
+                if close_pass is not None and l == n_layers - 1:
+                    with pass_scope(t), jax.named_scope("close"):
+                        x, lam = close_pass(params, t, x)
+                    lams.append(lam)
             kv, ring_kv = state[0], tuple(state[1:])
             if rec_kv:
                 ring_kv += (tuple(rec),)
             more = (counts,) if self._counted else ()
+            if self._loop_counted:
+                more += (self._loop_counts(lams, dv),)
             if blk is not None:
                 # logits for the rows a denoising pass fixes only.  What
                 # crosses to the host is ONE small int32 vector (a read
@@ -2527,7 +2678,8 @@ class ServingEngine:
             n = b * (self._fix_rows + 2) if self._block is not None \
                 else 2 * (b * self._k1 + b)
             self._no_words = jax.device_put(
-                np.zeros(n + len(self._counted), np.int32),
+                np.zeros(n + len(self._counted) + len(self._loop_counted),
+                         np.int32),
                 self._tick_sharding)
         return self._no_words
 
@@ -2619,7 +2771,8 @@ class ServingEngine:
                 attn_cells=flight.attn_cells,
                 # (the model's counts are the words' tail)
                 model_counts=tuple(
-                    words[words.size - len(self._counted):])
+                    words[words.size - len(self._counted)
+                          - len(self._loop_counted):])
                 + flight.kind_counts, lagged=lagged)
             # stamp AFTER the sync so TTFT includes the step compute
             now = self._time()

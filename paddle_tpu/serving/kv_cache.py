@@ -48,7 +48,14 @@ bound needs, the free list gets the rest):
    forked, cached, rolled back, migrated) by page, for as long as the
    sequence lives.  A model whose layers all attend over everything and
    keep nothing else has this kind alone and builds exactly the pool it
-   always built (the pool then holds every layer).
+   always built (the pool then holds every layer).  The pool's layer
+   axis counts CACHE layers: a looped model (``model.loops = T > 1``: its
+   weight layers run ``T`` times a tick over the same parameters) keeps
+   pass ``t``'s K/V of weight layer ``l`` in cache layer ``t * layers +
+   l``, read by pass ``t`` alone, so its pool has ``T x layers`` of them,
+   a token and a page cost ``T`` times the K/V, and ``pool_bytes ->
+   pages``, admission, growth, preemption and the accounting after a
+   drain all read that one number (:attr:`PagedKVConfig.num_layers`).
 2. A RING of pages a slot (``model.layer_window(l)``; exclusive with 1 in
    a layer): a layer with a WINDOW attends over the last ``window`` tokens
    only, so its kind keeps no more than those, the chunk in flight and
@@ -136,7 +143,9 @@ class PagedKVConfig:
     """Static geometry of one kind's paged pool (shared by all the
     kind's layers: page id ``p`` addresses the kind's ``l``-th layer's
     slice ``k[l, p]`` for every l; a model with no window layer has the
-    one kind, and ``num_layers`` is the model's).  ``num_heads`` is what
+    one kind, and ``num_layers`` is the model's; a looped model's is its
+    CACHE layers, ``loops x`` its weight layers: the module doc).
+    ``num_heads`` is what
     the GQA check below reads: the fewest query heads any layer brings
     (a layer's own count is its ``q``'s).
 

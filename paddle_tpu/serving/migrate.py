@@ -151,6 +151,10 @@ def export_chain(engine, rid: int) -> MigrationBlob:
                  "a model with a recurrent state is not handed over: the "
                  "chain holds pages only and would leave the slot's state "
                  "behind", context="serving-migrate")
+    enforce_that(engine._loops == 1,
+                 "a looped model is not handed over: chain migration has "
+                 "not been driven with pages that hold every pass's cache "
+                 "layers", context="serving-migrate")
     engine.land()    # the tokens of a step in the air belong to the chain
     enforce_that(req.status is RequestStatus.RUNNING and
                  not req.prefilling and bool(req.generated),

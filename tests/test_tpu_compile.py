@@ -741,6 +741,139 @@ def test_hybrid_ssm_serving_step_compiles_for_v5e_at_published_widths(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
 
 
+@pytest.mark.parametrize("pb", [0, 512])
+def test_looped_serving_step_compiles_for_v5e_at_published_widths(
+        topo, monkeypatch, pb):
+    """``serving.LoopedLM`` behind ``ServingEngine`` at the cell's widths
+    (hidden 2048, 16 heads of 128, SwiGLU 5632, the whole vocabulary; 8
+    layers run 4 times), 32 slots under the cell's 10 GiB pool of 32
+    cache layers, the decode-only step and the one with the 512-row
+    prefill bucket: both lower and compile for a described v5e with one
+    ragged kernel a CACHE layer; the step holds each weight leaf once
+    (the arguments are the 2.45 GB of parameters and the pool, nothing
+    stacked over the passes), no slab of the pool is copied, and it holds
+    under the chip's 16 GB."""
+    from paddle_tpu.analysis import retrace
+    from paddle_tpu.serving import LoopedLM, ServingEngine
+    from paddle_tpu.serving import decode_attention as da
+    from paddle_tpu.serving import engine as eng_mod
+
+    monkeypatch.setattr(da, "_interpret_default", lambda: False)
+    monkeypatch.setattr(retrace, "_backend_jit_kwargs", lambda kw: kw)
+    monkeypatch.setattr(eng_mod, "attention_path", lambda *a, **k: "kernel")
+    make_pool = eng_mod.init_kv_pages
+    monkeypatch.setattr(
+        eng_mod, "init_kv_pages",
+        lambda cfg, **kw: jax.eval_shape(lambda: make_pool(cfg, **kw)))
+    model = LoopedLM(vocab_size=49152, embed_dim=2048, num_layers=8,
+                     num_heads=16, head_dim=128, ffn_dim=5632, loops=4,
+                     rope_theta=1e6, norm_eps=1e-6)
+    aval = _on(SingleDeviceSharding(topo.devices[0]))
+    params = {k: aval(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init_params, jax.random.PRNGKey(0)).items()}
+    weights = sum(int(np.prod(v.shape)) * 4 for v in params.values())
+    assert weights == 612_438_017 * 4
+    eng = ServingEngine(model, params, eos_id=model.vocab_size,
+                        page_size=128, max_slots=32, pool_bytes=10 << 30,
+                        max_pages_per_seq=10, buckets=(512,),
+                        prefill_chunk=256)
+    assert eng._ragged_kernel and eng._k1 == 1 and eng._loops == 4
+    assert eng.kv_cfg.num_layers == 32 and eng.kv_cfg.num_pages == 160
+    assert eng.kv_cfg.bytes_per_page() == 64 << 20
+    buf = eng._empty_tick(pb, 1)
+    words = np.zeros(2 * (32 + 32) + 3, np.int32)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: aval(a.shape, a.dtype), tree)
+    compiled = eng._step_fn(pb, 1).lower(
+        params, on_chip(eng._kv), aval(buf.shape, buf.dtype),
+        aval(words.shape, words.dtype)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 32      # a cache layer: the kernel
+    for scope in ("pass0/l0/attn/proj", "pass3/l7/ffn", "pass3/close",
+                  "head"):
+        assert scope + "/" in text, scope
+    # a pass's K/V go into the pool where it lies
+    copies = [line for line in text.splitlines() if " copy(" in line
+              and ("f32[32,160,128,2048]" in line
+                   or "f32[160,128,2048]" in line)]
+    assert not copies, copies[:2]
+    mem = compiled.memory_analysis()
+    print("looped step", pb, "temp bytes", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes, "aliased", mem.alias_size_in_bytes)
+    assert mem.alias_size_in_bytes == 10 << 30       # the pool, donated
+    # the parameters once and the pool: nothing tiled over the passes
+    assert mem.argument_size_in_bytes < weights + (10 << 30) + (1 << 20)
+    assert mem.temp_size_in_bytes < 200e6
+
+
+# ---- a model of one pass builds the step it built ---------------------------
+
+# sha256 (16 hex digits) of the StableHLO text of the tiny engines' steps
+# at the parent of PR 47 (f8f22da), decode-only and with a prefill bucket:
+# the passes' loop, the cache-layer rule and the counters of PR 47 change
+# nothing for a model that says no ``loops``
+PARENT_STEPS = {
+    ("dense", 0): "bf364f38ecb6dc86", ("dense", 8): "dc7b0946b8254b69",
+    ("falcon", 0): "34b79fc639f3b914", ("falcon", 16): "4da76c14b71cd70b",
+    ("laguna", 0): "867bf6d3f7813a27", ("laguna", 16): "a4c51928964e110f",
+    ("sdar", 0): "c6ff7302b9b3c9ea", ("sdar", 16): "46c5449d98478cea"}
+TINY_FAMILIES = {"falcon": ("falcon_h1", "tiny-falcon-h1"),
+                 "laguna": ("laguna", "tiny-laguna"),
+                 "sdar": ("sdar_moe", "tiny-sdar")}
+
+
+@pytest.fixture(scope="module")
+def tiny_engines():
+    """The dense model and the three special families at their tiny
+    sizes, each behind an engine (built once: two steps a model)."""
+    import sys
+
+    from paddle_tpu.serving import DecoderLM, ServingEngine
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import cells, weights
+
+    model = DecoderLM(vocab_size=64, num_layers=2, num_heads=4, head_dim=8,
+                      num_kv_heads=2)
+    engines = {"dense": ServingEngine(
+        model, model.init_params(jax.random.PRNGKey(0)), eos_id=1,
+        page_size=8, pool_bytes=100_000, max_pages_per_seq=8, max_slots=4,
+        buckets=(8,))}
+    for name, (family, tiny) in TINY_FAMILIES.items():
+        fam = cells.load_module(os.path.join(bench, "families",
+                                             family + ".py"))
+        cfg = cells.load_json(os.path.join(bench, "tests", "configs",
+                                           tiny + ".json"))
+        prog = fam.serve_program(cfg, [None])
+        made = weights.make(fam.leaves(cfg, "serve"), 7)
+        kw = dict(page_size=16, num_pages=40, max_pages_per_seq=4,
+                  max_slots=4, buckets=(16,), prefill_chunk=8) \
+            if name == "sdar" else dict(
+                page_size=4, num_pages=80, max_pages_per_seq=24,
+                max_slots=4, buckets=(8, 16), prefill_chunk=8)
+        engines[name] = ServingEngine(
+            prog["model"], {n: made[r] for n, r in prog["names"].items()},
+            eos_id=cfg["vocab_size"], **kw)
+    return engines
+
+
+@pytest.mark.parametrize("name,pb", sorted(PARENT_STEPS))
+def test_a_model_of_one_pass_lowers_to_the_parents_step(tiny_engines, name,
+                                                        pb):
+    import hashlib
+
+    eng = tiny_engines[name]
+    assert eng._loops == 1 and eng._loop_counted == ()
+    text = eng._step_fn(pb, eng._k1).lower(
+        eng.params, eng._kv, eng._empty_tick(pb, eng._k1),
+        eng._last_words(), *eng._kind_kv()).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_STEPS[name, pb]
+
+
 # ---- names in the device trace ---------------------------------------------
 
 def _kernel_names(fn, *avals):
