@@ -2315,7 +2315,9 @@ def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
     (The path with a capacity and dropped tokens is :func:`moe_ffn`.)
 
     Publishes per step, labelled ``layer=<name>``: ``moe_rows_total``,
-    ``moe_rows_held_total``, ``moe_max_expert_rows``."""
+    ``moe_rows_held_total``, ``moe_max_expert_rows`` and, for each length
+    the sorted buffer may run at (``rows=<length>``; the longest is the
+    worst case), ``moe_rung_steps_total``: the steps that ran at it."""
     from paddle_tpu.parallel import moe as pmoe
 
     inp = input
@@ -2355,6 +2357,8 @@ def moe_dropless(input, *, n_routed: int, held: Tuple[int, int],
         ctx.count("moe_rows_held_total", stats["rows_held"], layer=name)
         ctx.count("moe_max_expert_rows", stats["max_expert_rows"],
                   layer=name)
+        for rows, ran in stats["rung_steps"].items():
+            ctx.count("moe_rung_steps_total", ran, layer=name, rows=rows)
         if valid is not None:
             y = jnp.where(valid[:, None], y, 0)
         return _like(v, y.astype(pmath.dense_activation_dtype()))

@@ -5,11 +5,13 @@ Layout (``tile_plan``): every group's rows start at a multiple of
 ``tile_m`` and a group takes at least one tile, so a row tile belongs to
 exactly one group and the kernels need no masks inside a tile.  The rows
 between a group's last real row and its tile's end are zeros of the
-caller's making; the tiles after the last group are dead.  The buffer is
-sized for the worst case (every (token, choice) pair on a held expert) and
-only the live tiles are computed: a dead grid step repeats the last live
-tile's block indices, so it moves nothing and computes nothing, and the
-output rows of dead tiles are never written (the caller never reads them).
+caller's making; the tiles after the last group are dead.  The plan is
+made for the worst case (every (token, choice) pair on a held expert); the
+buffer a call is handed may be any shorter length that holds the live
+tiles (``parallel/moe.py dropless_rungs``), and only the live tiles are
+computed: a dead grid step repeats the last live tile's block indices, so
+it moves nothing and computes nothing, and the output rows of dead tiles
+are never written (the caller never reads them).
 
 Three kernels, as JAX's ``pallas.ops.tpu.megablox`` splits the work:
 ``moe_gmm`` (rows x group matrix, also with the matrix transposed, which is

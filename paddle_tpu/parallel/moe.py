@@ -483,6 +483,90 @@ def _combine_bwd(res, dout):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def dropless_rungs(t: int, top_k: int, count: int, n_routed: int,
+                   tile_m: int) -> Tuple[int, ...]:
+    """The lengths the sorted buffer of a dropless layer may run at,
+    ascending, from the call's own shapes.  The last is the worst case a
+    step can meet, ``padded_rows(t * top_k, count)`` (every choice of every
+    token on a held expert).  Before it stand twice and four times what
+    even routing sends to ``count`` of ``n_routed`` experts, each only
+    where it is at most half the worst case: a rank that holds half or
+    all of the experts has the worst case alone, and no branch."""
+    from paddle_tpu.ops.grouped_matmul import padded_rows
+
+    worst = padded_rows(t * top_k, count, tile_m)
+    even = -(-t * top_k * count // n_routed)
+    rungs = [padded_rows(times * even, count, tile_m) for times in (2, 4)]
+    return tuple(r for r in rungs if 2 * r <= worst) + (worst,)
+
+
+def _experts_at(rows: int, tile_m: int, ct, plan, x, g, w_gate, w_up,
+                w_down):
+    """The held experts over a sorted buffer of ``rows`` rows: dispatch,
+    the gated feed-forward as three grouped products (operands of type
+    ``ct``), combine.  The plan is made at the worst-case length; a
+    shorter buffer reads its first ``rows`` rows and ``rows // tile_m``
+    tiles, which hold every live row where ``n_active * tile_m <= rows``
+    (``dest``'s "nowhere" stays past the end, where ``_take_rows`` gives
+    zeros)."""
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    dest, row_token, row_pair, tile_group, n_active = plan
+    if rows < row_token.shape[0]:
+        row_token, row_pair = row_token[:rows], row_pair[:rows]
+        tile_group = tile_group[:rows // tile_m]
+    xs = _dispatch(x.astype(ct), row_token, dest)
+    h = gm.grouped_matmul(xs, w_gate, tile_group, n_active, tile_m)
+    u = gm.grouped_matmul(xs, w_up, tile_group, n_active, tile_m)
+    a = (jax.nn.silu(h) * u).astype(ct)
+    y = gm.grouped_matmul(a, w_down, tile_group, n_active, tile_m)
+    return _combine(y, g, dest, row_token, row_pair)
+
+
+# One branch of ``_experts``, each way.  Jitted: the expert layers of a
+# model call a rung at the same shapes, so its kernels are traced and
+# lowered once a model and not once a layer (tracing is paid by every
+# start, the warm ones too).  ``at = (rows, tile_m, ct, interpret)``; the
+# last is in the key because the kernels read the mode where they are traced.
+@functools.partial(jax.jit, static_argnums=(0,))
+def _rung_forward(at, plan, *operands):
+    return _experts_at(*at[:3], plan, *operands)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _rung_backward(at, plan, operands, dout):
+    return jax.vjp(functools.partial(_experts_at, *at[:3], plan),
+                   *operands)[1](dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(static, rung, plan, *operands):
+    """:func:`_experts_at` at the rung ``rung`` of ``static = (rungs,
+    tile_m, ct, interpret)``, chosen on the device.  No array of a
+    buffer's length leaves a branch, forward or backward: the gradient's
+    rule keeps the operands and the plan and branches again, and the taken
+    branch runs its forward once more on the way (under ``train.remat``
+    that IS the recomputed forward: the segment's own has nothing left to
+    make)."""
+    return jax.lax.switch(
+        rung, [functools.partial(_rung_forward, (r,) + static[1:])
+               for r in static[0]], plan, *operands)
+
+
+def _experts_fwd(static, rung, plan, *operands):
+    return _experts(static, rung, plan, *operands), (rung, plan, operands)
+
+
+def _experts_bwd(static, res, dout):
+    rung, plan, operands = res
+    return (None, None) + jax.lax.switch(
+        rung, [functools.partial(_rung_backward, (r,) + static[1:])
+               for r in static[0]], plan, operands, dout)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
 def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
                  held: Tuple[int, int], routing: str = "sigmoid",
                  scaling: float = 1.0, valid: Optional[jax.Array] = None,
@@ -503,9 +587,14 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
 
     (token, choice) pairs on held experts are placed expert by expert
     into a buffer whose groups start at multiples of ``tile_m``
-    (``ops/grouped_matmul.py``); it has ``T * top_k + count * tile_m``
-    rows, the worst case a step can meet (every choice of every token on
-    a held expert), and only the tiles that hold rows are computed.
+    (``ops/grouped_matmul.py``).  The plan is made for ``T * top_k +
+    count * tile_m`` rows, the worst case a step can meet (every choice of
+    every token on a held expert); the buffer itself, and every pass over
+    it, forward and backward, has the length of the first of
+    :func:`dropless_rungs` that holds the step's live tiles, chosen on
+    the device, and the worst case only in a step that needs it (or
+    where it is the one rung: a rank that holds half or all of the
+    experts has no branch).  Only the tiles that hold rows are computed.
     ``valid`` [T] keeps padding rows of a packed buffer out.
     ``operand_dtype`` is the type the rows take for the products (the
     matrices are rounded to it), the shared expert's products too: the
@@ -518,7 +607,8 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
     landed on held experts), ``max_expert_rows`` (the fullest held
     expert), ``live_experts`` (held experts with a row) and ``live_tiles``
     (row tiles of ``tile_m`` the products compute: a held expert takes
-    one even with no row), as device scalars."""
+    one even with no row), as device scalars, and ``rung_steps`` {rows of
+    a rung: 1.0 where the step ran at it, else 0.0}."""
     from paddle_tpu.ops import grouped_matmul as gm
     from paddle_tpu.ops import math as pmath
 
@@ -559,14 +649,19 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
         # segment holds it (a few MB) and routes once a step
         g, dest, row_token, row_pair, tile_group, n_active = keep(
             "moe_route", g, dest, row_token, row_pair, tile_group, n_active)
+    rungs = dropless_rungs(t, top_k, count, p["router"].shape[1], tile_m)
     with jax.named_scope("moe.experts"):
         ct = operand_dtype or pmath.compute_dtype(x)
-        xs = _dispatch(x.astype(ct), row_token, dest)
-        h = gm.grouped_matmul(xs, p["w_gate"], tile_group, n_active, tile_m)
-        u = gm.grouped_matmul(xs, p["w_up"], tile_group, n_active, tile_m)
-        a = (jax.nn.silu(h) * u).astype(ct)
-        y = gm.grouped_matmul(a, p["w_down"], tile_group, n_active, tile_m)
-        out = _combine(y, g, dest, row_token, row_pair)
+        plan = (dest, row_token, row_pair, tile_group, n_active)
+        operands = (x, g, p["w_gate"], p["w_up"], p["w_down"])
+        # the first rung that holds the live tiles: those the rows pass
+        rung = jnp.sum(n_active[0] * tile_m > jnp.asarray(
+            rungs[:-1], jnp.int32)).astype(jnp.int32)
+        if len(rungs) == 1:
+            out = _experts_at(rungs[0], tile_m, ct, plan, *operands)
+        else:
+            out = _experts((rungs, tile_m, ct, gm.interpret_default()), rung,
+                           plan, *operands)
     if "shared_gate" in p:
         with jax.named_scope("moe.shared"):
             if operand_dtype is None:
@@ -587,7 +682,9 @@ def moe_dropless(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int,
              "rows_held": jnp.sum(counts).astype(jnp.float32),
              "max_expert_rows": jnp.max(counts).astype(jnp.float32),
              "live_experts": jnp.sum(counts > 0).astype(jnp.float32),
-             "live_tiles": n_active[0].astype(jnp.float32)}
+             "live_tiles": n_active[0].astype(jnp.float32),
+             "rung_steps": {rows: (rung == i).astype(jnp.float32)
+                            for i, rows in enumerate(rungs)}}
     return out, stats
 
 

@@ -122,15 +122,22 @@ def _inner_jaxprs(eqn):
                 yield j
 
 
-def ops_by_half(jaxpr, backward=False, found=None):
+def ops_by_half(jaxpr, backward=False, found=None, branch=None):
     """{(in the backward half, what): count} over a gradient's jaxpr: the
     kernels by name, the products by output shape and precision, ``top_k``.
     The forward pass of a differentiated ``jax.checkpoint`` lies inline;
     its recomputation and backward pass lie inside ``remat2`` equations
-    (the primitive of ``jax.checkpoint``)."""
+    (the primitive of ``jax.checkpoint``).  With ``branch`` a ``cond``
+    counts for that branch alone (its last where it has fewer): the path
+    a step takes."""
     found = collections.Counter() if found is None else found
     for e in jaxpr.eqns:
         prim = e.primitive.name
+        if prim == "cond" and branch is not None:
+            taken = e.params["branches"][min(branch, len(
+                e.params["branches"]) - 1)]
+            ops_by_half(taken.jaxpr, backward, found, branch)
+            continue
         if prim == "pallas_call":
             found[backward, e.params["name"]] += 1
         elif prim == "dot_general":
@@ -139,7 +146,7 @@ def ops_by_half(jaxpr, backward=False, found=None):
         elif prim == "top_k":
             found[backward, "top_k"] += 1
         for inner in _inner_jaxprs(e):
-            ops_by_half(inner, backward or prim == "remat2", found)
+            ops_by_half(inner, backward or prim == "remat2", found, branch)
     return found
 
 
@@ -165,6 +172,35 @@ def test_backward_of_a_qwen3_next_block_runs_no_scan_prologue_or_router_again(
     assert bare[True, "gdn_chunk_fwd"] == bare[True, "qkv_conv_fwd"] == 1
     assert bare[(True,) + router] == bare[True, "top_k"] == 2
     assert {k: bare[True, k] for k in calls} == calls
+
+
+@pytest.mark.parametrize("rung", [0, 1, 2])
+def test_a_step_at_any_rung_runs_twelve_grouped_kernels_a_layer(
+        rung, monkeypatch):
+    """One rank of sixteen (1 of 16 experts, tiles of 8 rows): the expert
+    layer's buffer has three lengths (40, 72 and the worst case, 264 rows)
+    and a ``cond`` each way chooses.  Whichever a step takes, a layer runs
+    its three products forward and, in the backward half, three again (the
+    gradient's branch makes its own forward: the segment's recomputation
+    has nothing of the experts left to make) and the six gradients: the 12
+    the benchmark's reader divides a trace's calls by."""
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.parallel import moe as pmoe
+
+    monkeypatch.setattr(gm, "TILE_M", 8)
+    monkeypatch.setitem(globals(), "ROUTED", 16)
+    monkeypatch.setitem(globals(), "HELD", 1)
+    assert pmoe.dropless_rungs(T, TOP_K, 1, 16, 8) == (40, 72, 264)
+    loss, params, _ = program(qwen3_next_cost)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: loss(p)[0]))(params).jaxpr
+    taken = ops_by_half(jaxpr, branch=rung)
+    calls = {k: taken[k] for k in taken if k[1] in ("moe_gmm", "moe_tgmm")}
+    assert calls == {(False, "moe_gmm"): 2 * 3, (True, "moe_gmm"): 2 * 6,
+                     (True, "moe_tgmm"): 2 * 3}
+    # and the program holds every rung's: three branches each way
+    every = ops_by_half(jaxpr)
+    assert every[False, "moe_gmm"] == 3 * 2 * 3
+    assert every[True, "moe_gmm"] == 3 * 2 * 6
 
 
 # ---- (c) a segment with no tagged value ---------------------------------------
