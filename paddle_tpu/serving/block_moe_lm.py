@@ -7,13 +7,14 @@ layer in every block, an untied head) as a
 The layer equations are those of ``benchmarks/references/sdar_moe.py``.
 Generation is by diffusion over blocks: the engine reads
 ``block_length`` / ``denoise_steps`` / ``mask_token_id`` off the model,
-computes ``block_length`` rows a slot and tick, lets a row see its whole
-block, and fixes ``block_length / denoise_steps`` tokens a denoising pass
-(``engine.py``: "block models").  The model's part of that is three
-optional members of the contract: :meth:`rotate` (positions reach q and k
-after ``qkv``), :meth:`attn_out_counted` (the rows' validity reaches the
-expert layer, its counters reach ``ServingMetrics``) and
-``step_counters`` (their names).
+computes a slot's open block of ``block_length`` rows a tick (and, in the
+tick that commits a full block, that block's rows before them), lets a
+row see its whole block, and fixes ``block_length / denoise_steps`` tokens
+a denoising pass (``engine.py``: "block models").  The model's part of
+that is three optional members of the contract: :meth:`rotate`
+(positions reach q and k after ``qkv``), :meth:`attn_out_counted` (the
+rows' validity reaches the expert layer, its counters reach
+``ServingMetrics``) and ``step_counters`` (their names).
 
 Parameters are one flat ``{name: array}`` dictionary, float32, used as
 they are handed over: the experts' matrices are stacked ``[experts, ...]``

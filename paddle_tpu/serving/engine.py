@@ -89,14 +89,29 @@ absolute positions; a row sees every earlier block and its own block
 WHOLE (the one ``token <= position`` mask, with each row attending as
 its block's last position while its rotary position stays its own).  A
 prompt's whole blocks are prefilled (chunks end on block boundaries; no
-token comes of a prompt's last row); then a slot brings ``B`` rows a
-tick (the step's ``k1``): its current block, unfixed positions holding
-the mask token, rewritten in its page at every pass.  A denoising pass
-fixes the next ``B / S`` masked positions from the left to their best
-token, the mask token excluded (``on_token`` per fixed token, in
-position order, never past ``max_tokens``); when the block is full a
-committing pass writes its clean K/V and only then does ``cache_len``
-move past it.  Which pass a slot stands at follows from its request
+token comes of a prompt's last row); then a slot brings its OPEN block's
+``B`` rows a tick, unfixed positions holding the mask token, rewritten in
+its page at every pass.  A denoising pass fixes the next ``B / S`` masked
+positions from the left to their best token, the mask token excluded
+(``on_token`` per fixed token, in position order, never past
+``max_tokens``).  A full block's clean K/V are written once more and only
+then does ``cache_len`` move past it; the tick that writes them also
+opens the next block (THE FOLD): the slot brings ``2 B`` rows, the full
+block's and, behind them, the next block's ``B`` mask tokens, whose first
+``B / S`` positions that same pass fixes.  The step writes every row's
+K/V and then attends, so the next block's rows see the clean block and
+the clean block's rows see nothing of the next: the mathematics of a
+committing pass and a first pass in two ticks, in one.  A block so costs
+``S`` ticks of its slot, not ``S + 1``.  The same rule holds behind a
+prompt: the tick that prefills its last chunk carries the slot's first
+open block as its decode rows, so the first tokens come of that tick.
+The fold takes the next block's page where the full block ended its own
+(as speculation's lookahead does: never by preemption); without one the
+slot commits alone and opens the next block a tick later.  An answer's
+last block is never committed (nothing follows it).  The step's ``k1`` is
+``2 B`` rows a slot; the rows a slot does not bring are invalid rows,
+which write no K/V, attend to nothing and take no expert.  Which pass a
+slot stands at follows from its request
 alone (``cache_len`` and the tokens it has), so a preempted or cancelled
 request needs no unwinding, and the step's shapes do not depend on the
 pass: it computes logits for ``[slots, B / S]`` selected rows and
@@ -187,7 +202,8 @@ then reads what tick ``k`` came to, so the host's part of a tick
 between two of its steps.  What a step will come to is known at its
 dispatch but for the tokens' values (a decode row yields one token, a
 prompt's final chunk its first, a pass fixes so many, a full block is
-committed, a chunk ends there), so the request moves on then
+committed and in a folded tick the next one opened, a chunk ends there),
+so the request moves on then
 (``cache_len``, ``Request.pending``: :meth:`ServingEngine._advance`) and
 the next step takes the pending tokens from the last step's words, on
 the device; the finite guards, the prefix cache's inserts and
@@ -195,9 +211,11 @@ the device; the finite guards, the prefix cache's inserts and
 one ``step()`` call later, and ``has_work`` stays true while a step is
 in the air (``_flying``).  A request that ended meanwhile is passed
 over; an EOS that lands while a further row of its slot is in the air
-drops that row.  A step lands in its OWN call where the host needs its
-tokens or its logits before the next step is assembled, which the
-engine sees from its own state: a fault plan is bound; a proposer is
+drops that row (a block model's folded pass among them: the block it
+committed and the one it opened are the ended request's).  A step lands
+in its OWN call where the host needs its tokens or its logits before the
+next step is assembled, which the engine sees from its own state: a
+fault plan is bound; a proposer is
 bound (the verify walk reads the rows' logits and drafts from what it
 accepts); the engine's ``role`` hands requests over between calls; a
 request that rode the step samples (its slot's rows are fetched for it
@@ -275,6 +293,11 @@ _SPEC_MODES = ("off", "ngram", "draft")
 # the mesh axis a tensor-parallel engine places heads and FFN columns
 # over: what ``kv_pool_specs``, ``shard_plan`` and ``bind_tp`` default to
 TP_AXIS = "model"
+# what else a tick of a block model's slot wrote beside its open block
+# (``_Flight.passes``, ``ServingEngine._passes``): nothing; the full block
+# before it, committed in the tick that opens the next; the last chunk of
+# its prompt, with the first open block behind it
+_FOLD_NONE, _FOLD_BLOCK, _FOLD_CHUNK = 0, 1, 2
 # the default ladder of padded prefill row counts (``buckets=``); its top
 # and the chunk bound the packer's row budget a tick (``_prefill_budget``)
 PREFILL_BUCKETS = (32, 64, 128, 256, 512)
@@ -604,9 +627,12 @@ class _Flight:
 
     # every slot that rode the step: (request, slot) and then what its
     # walk needs that the request will no longer say when the words
-    # arrive.  A block model's: block start, tokens the block had,
-    # tokens the pass fixes (0 is the committing pass).  Else: the
-    # drafts the slot's rows verify, and their proposal probabilities
+    # arrive.  A block model's: the start of the block the pass works in,
+    # the tokens that block had, the tokens the pass fixes (0 is a lone
+    # committing pass) and what clean K/V the same tick wrote before the
+    # block (``_FOLD_*``: none, the full block before it, a prompt's
+    # last chunk).  Else: the drafts the slot's rows verify, and their
+    # proposal probabilities
     passes: List[tuple]
     chunks: list                   # as ``pack_prefill_chunks`` gave them
     words: Any                     # the step's words, on the device
@@ -716,8 +742,10 @@ class ServingEngine:
                      context="serving")
         page_size = int(page_size)
         max_slots = int(max_slots)
-        # a block model (see the module doc): B rows a slot and tick,
-        # B / S tokens fixed a denoising pass.  None: one token a tick.
+        # a block model (see the module doc): up to 2 B rows a slot and
+        # tick, B / S tokens fixed a denoising pass, a block in S ticks
+        # (its commit rides with the next block's first pass).  None: one
+        # token a tick.
         self._block: Optional[int] = None
         self._ticks_per_token = 1.0
         if hasattr(model, "block_length"):
@@ -725,7 +753,7 @@ class ServingEngine:
             self._denoise_steps = int(model.denoise_steps)
             self._mask_id = int(model.mask_token_id)
             self._fix_rows = self._block // self._denoise_steps
-            self._ticks_per_token = (self._denoise_steps + 1) / self._block
+            self._ticks_per_token = self._denoise_steps / self._block
             self._refuse_for_block_model(
                 page_size, int(prefill_chunk), mesh, spec_mode,
                 host_tier_bytes)
@@ -1016,8 +1044,9 @@ class ServingEngine:
                 max_pages_per_seq=int(max_pages_per_seq),
                 max_slots=max_slots)
         # rows per decode slot: 1 (plain decode) + spec_k drafts to
-        # verify, or a block model's whole block
-        self._k1 = self._block or \
+        # verify, or a block model's two blocks (the full one it commits
+        # and the next one it opens in the same tick)
+        self._k1 = 2 * self._block if self._block is not None else \
             1 + (self.spec_k if self._proposer is not None else 0)
         # donate the incoming KV pool: every call overwrites self._kv
         # with the returned pool, so XLA may update pages in place —
@@ -1538,7 +1567,8 @@ class ServingEngine:
         """The unified per-tick step for prefill bucket ``pb`` (0 =
         decode-only) at ``k1`` decode/verify rows per slot (1 = plain
         decode; ``1 + spec_k`` when speculating — the widened verify
-        step): ONE dispatch embeds every slot's verify rows and the
+        step; ``2 B`` for a block model, the block a slot commits and the
+        one it opens): ONE dispatch embeds every slot's verify rows and the
         packed prefill-chunk rows, scatters every row's K/V into its
         page (quantize-on-write on int8 pools; masked rows write ZEROS
         to the shared null page so computed junk can never leak into
@@ -1615,7 +1645,8 @@ class ServingEngine:
             # the packed stack (0 for slots not prefilling).  table:
             # [B, Pm]; att_lens: [B] — valid KV per slot AFTER this
             # step's writes.  d_sel (block models only): [B, B / S] —
-            # the rows of each slot's block that the step computes logits for.
+            # the rows of each slot's open block (among its 2 B) that the
+            # step computes logits for.
             d_seq = jnp.repeat(jnp.arange(b), k1)
             dt = d_tokens.reshape(bd)
             dp = d_pos.reshape(bd)
@@ -2454,7 +2485,7 @@ class ServingEngine:
                 continue
             # load shedding, on the WORST-CASE length assumption: at one
             # token per tick (the engine's best rate; a block model's is
-            # B tokens in S + 1 ticks), a request that
+            # B tokens in S ticks), a request that
             # runs to its full max_tokens cannot finish by its deadline.
             # An early EOS could beat the estimate — callers who rely on
             # early stopping should size max_tokens to what they
@@ -2620,7 +2651,15 @@ class ServingEngine:
         next real tokens overwrite."""
         k1, tick, phase = self._k1, self._tick, self._tracer.phase
         with phase("tick.assemble", tick=tick):
-            packed = self._assemble(running, chunks, total_rows, drafts)
+            if self._block is not None:
+                passes = self._passes(running, chunks)
+                n_rows = sum(self._pass_rows(p[5]) for p in passes)
+            else:
+                passes = [(r, r.slot) + drafts.get(r.rid, ((), None))
+                          for r in running]
+                n_rows = sum(1 + len(p[2]) for p in passes)
+            packed = self._assemble(running, chunks, total_rows, drafts,
+                                    passes)
         parts = self._tick_parts(packed, k1)
         p_seq, att_lens = parts[5], parts[8]
         pb = p_seq.shape[0]                     # the prefill bucket
@@ -2638,12 +2677,6 @@ class ServingEngine:
                 self._ring_kv = tuple(kinds[:len(self._rings)])
                 if self._recurrent is not None:
                     self._rec_kv = kinds[-1]
-        if self._block is not None:
-            passes, n_rows = self._passes(running), len(running) * k1
-        else:
-            passes = [(r, r.slot) + drafts.get(r.rid, ((), None))
-                      for r in running]
-            n_rows = sum(1 + len(p[2]) for p in passes)
         flight = _Flight(
             passes, chunks, words, logits,
             (n_rows, total_rows, pb - sum(c[2] for c in chunks)),
@@ -2712,15 +2745,47 @@ class ServingEngine:
                    if r.cache_len >= len(r.pages) * page)
         return need > self.pool.num_free + self.pool.num_reclaimable
 
-    def _passes(self, running: List[Request]):
-        """Which pass each running slot stands at: ``_Flight.passes``."""
+    def _pass_rows(self, fold: int) -> int:
+        """The rows a slot really brings for a pass: its open block, and
+        at a fold the full block before it."""
+        return self._block * (2 if fold == _FOLD_BLOCK else 1)
+
+    def _passes(self, running: List[Request], chunks=()):
+        """Which pass each slot with block rows in this tick stands at:
+        ``_Flight.passes``, from the requests alone (``cache_len``, the
+        tokens a request has, ``pending``).  A running slot works in the
+        block at ``cache_len``: a denoising pass while it has masked
+        positions.  Once it is full (the pending tokens counted) the tick
+        that writes its clean K/V also OPENS THE NEXT BLOCK: the slot
+        brings both blocks' rows, the next one's all mask tokens, and the
+        pass fixes the next block's first ``B / S`` tokens
+        (``_FOLD_BLOCK``).  The next block's page is taken here where the
+        full block ended its page, the way speculation's lookahead takes
+        one (``grant_lookahead``: the cache may give a page up, nothing is
+        preempted); without one the slot commits alone (``n`` 0) and the
+        next tick's growth finds the page.  The same holds behind a
+        prompt: the tick that prefills a prompt's last chunk carries the
+        slot's first open block as its decode rows (``_FOLD_CHUNK``)."""
         blk, out = self._block, []
         for req in running:
             at, got = req.cache_len, len(req.generated) + req.pending
             have = len(req.prompt) + got - at
-            n = 0 if have >= blk else min(self._fix_rows, blk - have,
-                                          req.max_tokens - got)
-            out.append((req, req.slot, at, have, n))
+            n = min(self._fix_rows, req.max_tokens - got)
+            if have < blk:
+                out.append((req, req.slot, at, have, min(n, blk - have),
+                            _FOLD_NONE))
+            elif self.scheduler.grant_lookahead(req, 2 * blk - 1) \
+                    == 2 * blk - 1:
+                out.append((req, req.slot, at + blk, 0, n, _FOLD_BLOCK))
+            else:
+                out.append((req, req.slot, at, have, 0, _FOLD_NONE))
+        for req, start, n, _rows in chunks:
+            at, got = start + n, len(req.generated)
+            if at >= self._prefill_target(req):
+                have = len(req.prompt) + got - at
+                out.append((req, req.slot, at, have, min(
+                    self._fix_rows, blk - have, req.max_tokens - got),
+                    _FOLD_CHUNK))
         return out
 
     def _advance(self, flight: _Flight) -> None:
@@ -2729,7 +2794,9 @@ class ServingEngine:
         first token, which is pending; not a block model's), a decode
         row's token is pending and in the cache (what a verify walk
         accepts beyond it is added when it lands), a full block is
-        committed, a denoising pass's tokens are pending."""
+        committed (alone, or in the tick that opens the next block:
+        ``cache_len`` moves past it either way), a denoising pass's
+        tokens are pending."""
         blk = self._block
         for req, start, n, _rows in flight.chunks:
             self._advance_chunk(req, start, n)
@@ -2740,13 +2807,13 @@ class ServingEngine:
                 req.cache_len += 1
                 req.pending += 1
             return
-        for req, _slot, at, _have, n in flight.passes:
-            if n:
-                req.pending += n
-            else:
-                req.cache_len = at + blk
+        for req, _slot, at, _have, n, fold in flight.passes:
+            req.pending += n
+            if fold == _FOLD_BLOCK or not n:
+                old = req.cache_len
+                req.cache_len = at if n else at + blk
                 # a block that a prompt's tail shares was still owed
-                self.scheduler.note_prefill_progress(req, at)
+                self.scheduler.note_prefill_progress(req, old)
 
     def _land(self, flight: _Flight, lagged: bool = False) -> None:
         """Read a dispatched step's words and walk them: chunk
@@ -2793,14 +2860,14 @@ class ServingEngine:
         for req, start, n, _rows in flight.chunks:
             if req.status is RequestStatus.RUNNING:
                 self._finish_chunk(req, start, n, guard[req.slot], now)
-        for req, slot, at, have, n in flight.passes:
+        for req, slot, *stood in flight.passes:
             if req.status is not RequestStatus.RUNNING:
                 continue
             mine = picks[slot]
             if req.rid in poisoned:
                 mine = mine.copy()
                 mine[-1] = 0                  # "not finite"
-            self._finish_block_pass(req, (at, have, n), mine,
+            self._finish_block_pass(req, tuple(stood), mine,
                                     flight.logits, now)
 
     def _walk_rows(self, flight: _Flight, words: np.ndarray, poisoned,
@@ -2985,8 +3052,8 @@ class ServingEngine:
         b, pm = self._max_slots, self.kv_cfg.max_pages_per_seq
         shapes = ((b, k1),) * 3 + ((pb,),) * 3 + ((b,), (b, pm), (b,))
         if self._block is not None:
-            # a tenth, ``d_sel`` ``[B, B / S]``: the rows of each slot's
-            # block whose logits the step returns
+            # a tenth, ``d_sel`` ``[B, B / S]``: the rows, of those a
+            # slot brings, whose logits the step returns
             shapes += ((b, self._fix_rows),)
         return shapes
 
@@ -3014,10 +3081,14 @@ class ServingEngine:
         return packed
 
     def _assemble(self, running: List[Request], chunks, total_rows: int,
-                  drafts: Dict[int, Tuple]) -> np.ndarray:
+                  drafts: Dict[int, Tuple], passes=()) -> np.ndarray:
         """The host side of one unified step's inputs: ONE int32 buffer
         (``_tick_shapes`` says what lies where), which ``_step_fn``
-        takes after the parameters and the pool."""
+        takes after the parameters and the pool.  A block model's decode
+        rows are laid out from ``passes`` (:meth:`_passes`): a slot's
+        open block and, at a fold, the full block before it; the rows of
+        its ``2 B`` that a slot does not bring stay invalid (they write
+        no K/V, attend to nothing and take no expert)."""
         b, k1 = self._max_slots, self._k1
         cfg = self.kv_cfg
         pb = 0
@@ -3029,43 +3100,8 @@ class ServingEngine:
         packed = self._empty_tick(pb, k1)
         (d_tokens, d_pos, d_valid, p_tokens, p_qpos, p_seq, p_last, table,
          att_lens, *d_sel) = self._tick_parts(packed, k1)
-        if self._block is not None:
-            block_rows, fix_rows = np.arange(k1), np.arange(self._fix_rows)
-        else:
-            # whose pending token is a prompt's first: the pick of the
-            # chunk-final row of the step in the air (-2), not of a
-            # decode row (-1)
-            first = {c[0].rid for c in self._flying.chunks} \
-                if self._flying is not None else ()
-        for req in running:
-            s = req.slot
-            table[s, :len(req.pages)] = req.pages
-            if self._block is not None:
-                # the slot's current block, whole: the tokens it has and
-                # the mask token where none is fixed yet
-                at, n_p = req.cache_len, len(req.prompt)
-                have = req.prompt[at:at + k1] + req.generated[
-                    max(0, at - n_p):max(0, at + k1 - n_p)]
-                d_tokens[s] = self._mask_id
-                d_tokens[s, :len(have)] = have
-                # and those the flight in the air is fixing: its j-th pick
-                n = len(have) + req.pending
-                d_tokens[s, len(have):n] = -1 - np.arange(req.pending)
-                d_pos[s] = at + block_rows
-                d_valid[s] = 1
-                att_lens[s] = at + k1
-                # the rows this pass fixes (a committing pass has none
-                # left: what it selects is read by no one)
-                d_sel[0][s] = np.minimum(n + fix_rows, k1 - 1)
-                continue
-            dr = drafts.get(req.rid, ((), None))[0]
-            n = 1 + len(dr)
-            d_tokens[s, 0] = req.generated[-1] if not req.pending \
-                else -2 if req.rid in first else -1
-            d_tokens[s, 1:n] = dr
-            d_pos[s, :n] = req.cache_len + np.arange(n)
-            d_valid[s, :n] = 1
-            att_lens[s] = req.cache_len + n
+        # the chunks first: a block model's slot may bring its first open
+        # block behind its prompt's last chunk, and then attends that far
         off = 0
         for req, start, n, rows in chunks:
             s = req.slot
@@ -3081,26 +3117,68 @@ class ServingEngine:
             att_lens[s] = start + n
             table[s, :len(req.pages)] = req.pages
             off += rows
+        if self._block is not None:
+            blk, fix_rows = self._block, np.arange(self._fix_rows)
+            for req, s, at, have, _n, fold in passes:
+                table[s, :len(req.pages)] = req.pages
+                # the slot's rows: its open block, whole (the tokens it
+                # has, the mask token where none is fixed yet), behind the
+                # full block a fold commits
+                rows, n_p = self._pass_rows(fold), len(req.prompt)
+                lo = at + blk - rows
+                known = req.prompt[lo:lo + rows] + req.generated[
+                    max(0, lo - n_p):max(0, lo + rows - n_p)]
+                d_tokens[s, :rows] = self._mask_id
+                d_tokens[s, :len(known)] = known
+                # and those the flight in the air is fixing: its j-th pick
+                d_tokens[s, len(known):len(known) + req.pending] = \
+                    -1 - np.arange(req.pending)
+                d_pos[s, :rows] = lo + np.arange(rows)
+                d_valid[s, :rows] = 1
+                att_lens[s] = lo + rows
+                # the open block's rows this pass fixes (a lone committing
+                # pass has none left: what it selects is read by no one)
+                d_sel[0][s] = np.minimum(at - lo + have + fix_rows, rows - 1)
+            return packed
+        # whose pending token is a prompt's first: the pick of the
+        # chunk-final row of the step in the air (-2), not of a
+        # decode row (-1)
+        first = {c[0].rid for c in self._flying.chunks} \
+            if self._flying is not None else ()
+        for req in running:
+            s = req.slot
+            table[s, :len(req.pages)] = req.pages
+            dr = drafts.get(req.rid, ((), None))[0]
+            n = 1 + len(dr)
+            d_tokens[s, 0] = req.generated[-1] if not req.pending \
+                else -2 if req.rid in first else -1
+            d_tokens[s, 1:n] = dr
+            d_pos[s, :n] = req.cache_len + np.arange(n)
+            d_valid[s, :n] = 1
+            att_lens[s] = req.cache_len + n
         return packed
 
-    def _finish_block_pass(self, req: Request, stood: Tuple[int, int, int],
+    def _finish_block_pass(self, req: Request,
+                           stood: Tuple[int, int, int, int],
                            picks: np.ndarray, logits, now: float) -> None:
         """What one pass over a slot's block came to.  ``stood``: where
-        the block starts, how many tokens it had and how many the pass
-        was to fix, as the dispatch found them (``_Flight.passes``; the
-        request has moved on since).  ``picks``: the slot's row of the
-        step's words, the best unmasked token of each position the pass
-        was to fix and, last, whether their logits were all finite;
-        ``logits``: the step's ``[B, B / S, V]`` logits, on the device.
-        With nothing to fix it was the committing pass: the block's clean
-        K/V lie in its page (``cache_len`` moved past it at the
-        dispatch).  Else the next masked positions, from the left, take
-        their best token with the mask token left out (a request that
-        samples draws from the logits of its slot, fetched for it
-        alone), each emitted in position order; the answer may end
-        (``max_tokens``, EOS) inside the block."""
+        the block starts, how many tokens it had, how many the pass was
+        to fix and what else the tick wrote for the slot (``_FOLD_*``), as
+        the dispatch found them (``_Flight.passes``; the request has moved
+        on since).  ``picks``: the slot's row of the step's words, the
+        best unmasked token of each position the pass was to fix and,
+        last, whether their logits were all finite; ``logits``: the
+        step's ``[B, B / S, V]`` logits, on the device.  With nothing to
+        fix it was a lone committing pass: the block's clean K/V lie in
+        its page (``cache_len`` moved past it at the dispatch).  Else the
+        next masked positions, from the left, take their best token with
+        the mask token left out (a request that samples draws from the
+        logits of its slot, fetched for it alone), each emitted in
+        position order; the answer may end (``max_tokens``, EOS) inside
+        the block.  A folded pass did both in one tick: the block before
+        ``stood``'s was committed beside this block's first pass."""
         blk, m = self._block, self.metrics
-        _at, _have, n = stood
+        _at, _have, n, fold = stood
         if not n:
             req.last_progress_tick = self._tick
             m.on_block_pass(blk)
@@ -3123,7 +3201,9 @@ class ServingEngine:
             self._emit(req, tok, now)
             if req.finished:
                 break
-        m.on_block_pass(blk, fixed)
+        m.on_block_pass(self._pass_rows(fold), fixed,
+                        committed=fold == _FOLD_BLOCK,
+                        behind_prompt=fold == _FOLD_CHUNK)
 
     def _prefill_target(self, req: Request) -> int:
         """How many of ``cache_tokens`` are prefilled before decoding:

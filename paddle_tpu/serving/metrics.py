@@ -91,8 +91,13 @@ class ServingMetrics:
         # block models (generation by diffusion over blocks): a slot's
         # rows, passes and tokens stand in no fixed ratio to its ticks
         self.denoise_passes = 0       # slot participations that fixed tokens
-        self.commit_passes = 0        # ... that wrote a full block's K/V
-        self.block_rows = 0           # rows computed for blocks, both kinds
+        self.commit_passes = 0        # ... that only wrote a full block's K/V
+        self.folded_passes = 0        # of the denoising ones, those that
+        #                               committed the block before theirs in
+        #                               the same tick (2 B rows)
+        self.folded_first_passes = 0  # ... that rode with their prompt's
+        #                               last chunk
+        self.block_rows = 0           # rows the slots brought for blocks
         self.tokens_fixed = 0         # tokens the denoising passes fixed
         # speculative decoding (round 18)
         self.spec_ticks = 0           # verify ticks with >= 1 drafted token
@@ -187,15 +192,22 @@ class ServingMetrics:
         """Rows of a step's logits read to the host beside its words."""
         self.d2h_bytes += nbytes
 
-    def on_block_pass(self, rows: int, fixed: Optional[int] = None) -> None:
-        """One slot's pass over its block of ``rows`` rows: a denoising
-        pass that fixed ``fixed`` tokens, or (None) the committing one."""
+    def on_block_pass(self, rows: int, fixed: Optional[int] = None,
+                      committed: bool = False,
+                      behind_prompt: bool = False) -> None:
+        """One slot's pass, for which it brought ``rows`` rows: a
+        denoising pass that fixed ``fixed`` tokens, or (None) a lone
+        committing one.  ``committed``: the denoising pass opened its
+        block in the tick that committed the block before it (a folded
+        pass); ``behind_prompt``: in the tick of its prompt's last chunk."""
         self.block_rows += rows
         if fixed is None:
             self.commit_passes += 1
         else:
             self.denoise_passes += 1
             self.tokens_fixed += fixed
+            self.folded_passes += bool(committed)
+            self.folded_first_passes += bool(behind_prompt)
 
     def on_prefix(self, requested: int, saved: int) -> None:
         """One admission's prefix-cache outcome: ``requested`` tokens
@@ -378,6 +390,8 @@ class ServingMetrics:
             "attn_pages_needed": self.attn_pages_needed,
             "denoise_passes": self.denoise_passes,
             "commit_passes": self.commit_passes,
+            "folded_passes": self.folded_passes,
+            "folded_first_passes": self.folded_first_passes,
             "block_rows": self.block_rows,
             "tokens_fixed": self.tokens_fixed,
             **self.model_counters,

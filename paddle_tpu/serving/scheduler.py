@@ -154,7 +154,10 @@ class SchedulerConfig:
     # page of the next position to be cached, so the page that
     # ``admit`` / ``ensure_decode_pages`` take for ``cache_len`` is the
     # whole block's, taken before its first pass and returned by
-    # preemption or release between passes like any other
+    # preemption or release between passes like any other.  (The engine's
+    # fold opens a block in the tick that commits the one before it, and
+    # takes the page through ``grant_lookahead`` where that one ended its
+    # own.)
     block_length: Optional[int] = None
 
     @property

@@ -4,9 +4,14 @@
 plain reference (``benchmarks/references/sdar_moe.py``).  What is compared
 is LOGITS: at every position a pass fixed, the engine's row of the tick
 that fixed it against the reference's row of that state (earlier blocks
-clean, the position's block masked from where that pass found it)."""
+clean, the position's block masked from where that pass found it).  A
+block is committed in the tick that opens the next one (the fold: the
+slot brings both blocks' rows), and a prompt's last chunk carries the first
+open block, so a block costs ``S`` ticks and the first tokens come of the
+tick that ends the prefill."""
 
 import gc
+import hashlib
 import os
 import sys
 
@@ -65,10 +70,12 @@ def record(eng):
     walk = eng._finish_block_pass
 
     def spy(req, stood, picks, logits, now):
-        at, have, n = stood          # as the pass's dispatch found the block
-        if not n:
-            commits.append((req.rid, at))
-        else:
+        # as the pass's dispatch found the block; ``fold`` 1: the tick also
+        # committed the block before it
+        at, have, n, fold = stood
+        if not n or fold == 1:
+            commits.append((req.rid, at if not n else at - B))
+        if n:
             nth = passes[req.rid, at] = passes.get((req.rid, at), -1) + 1
             rows = np.asarray(logits[req.slot])     # they stay on the device
             for i in range(n):
@@ -78,6 +85,20 @@ def record(eng):
 
     eng._finish_block_pass = spy
     return fixed, commits
+
+
+def dispatched(eng):
+    """Every slot participation as its step's dispatch laid it out, landed
+    or not: [(rid, block start, tokens the block had, tokens to fix,
+    fold)]."""
+    out, advance = [], eng._advance
+
+    def spy(flight):
+        out.extend((p[0].rid,) + tuple(p[2:]) for p in flight.passes)
+        advance(flight)
+
+    eng._advance = spy
+    return out
 
 
 def prompt_of(seed: int, n: int):
@@ -141,6 +162,11 @@ def test_logits_at_each_fixed_position_are_the_references(made, n_prompt,
     # (the answer's last block is not: nothing will read it)
     end = (n_prompt + max_tokens - 1) // B * B
     assert commits == [(rid, at) for at in range(n_prompt // B * B, end, B)]
+    # ... each in the tick that opened the next block, none alone; the
+    # first pass rode with the prompt's last chunk where there was one
+    m = eng.metrics.snapshot()
+    assert (m["folded_passes"], m["commit_passes"]) == (len(commits), 0)
+    assert m["folded_first_passes"] == (n_prompt >= B)
     ref = reference_rows(made, prompt, out, prompt_len=n_prompt)
     assert worst_gap(fixed[rid], ref) < TOL
     # (f) the family's account of which pass fixed which position, told
@@ -198,9 +224,12 @@ def test_a_batch_of_slots_at_different_passes(made):
     m = eng.metrics.snapshot()
     assert m["tokens_fixed"] == m["tokens_generated"] == sum(
         mt for _, mt in sizes)
-    assert m["commit_passes"] == len(commits)
-    assert m["block_rows"] == B * (m["denoise_passes"] + m["commit_passes"]) \
-        == m["decode_rows"]
+    # a block is committed alone or in the tick that opens the next one,
+    # which brings both blocks' rows; a slot's other rows are not counted
+    assert m["commit_passes"] + m["folded_passes"] == len(commits)
+    assert m["folded_passes"] > 0
+    assert m["block_rows"] == B * (m["denoise_passes"] + m["commit_passes"]
+                                   + m["folded_passes"]) == m["decode_rows"]
     assert m["decode_slots"] == m["denoise_passes"] + m["commit_passes"]
     # the expert layer's counters came back with the logits: every valid
     # row took top-2 experts in each of the 2 layers
@@ -212,12 +241,71 @@ def test_a_batch_of_slots_at_different_passes(made):
     assert m["step_dispatches"] <= m["ticks"]
 
 
+@pytest.mark.parametrize("n_prompt,blocks", [(8, 2), (8, 5), (6, 3),
+                                             (16, 4)])
+def test_an_answer_of_n_blocks_takes_n_times_s_ticks(made, n_prompt, blocks):
+    """A block costs ``S`` ticks of its slot: its first pass rides in the
+    tick that commits the block before it (or prefills the prompt's last
+    chunk), so ``n`` blocks take ``n x S`` slot participations, ``n - 1``
+    of them folded, and no commit runs alone (the answer's last block is
+    not committed: nothing follows it)."""
+    eng = engine(made)
+    log = dispatched(eng)
+    # the answer fills its blocks to the end of the last one
+    max_tokens = blocks * B - n_prompt % B
+    rid = eng.submit(prompt_of(20 + blocks, n_prompt), max_tokens)
+    assert len(eng.run()[rid]) == max_tokens
+    m = eng.metrics.snapshot()
+    # (the prompt's tail takes its share of the first block's passes)
+    passes = blocks * S - n_prompt % B // (B // S)
+    assert m["decode_slots"] == m["denoise_passes"] == len(log) == passes
+    assert (m["folded_passes"], m["commit_passes"]) == (blocks - 1, 0)
+    assert m["tokens_fixed"] == max_tokens
+    # rows: a folded tick brings two blocks, every other one
+    assert m["block_rows"] == m["decode_rows"] == B * (passes + blocks - 1)
+    # a prompt's last chunk and the slot's first pass shared a tick: a
+    # step more than the passes only where the prompt needs two chunks
+    assert m["step_dispatches"] == passes + (n_prompt > 8)
+    assert eng._ticks_per_token == S / B
+    eng.check_page_conservation()
+
+
+def test_a_full_block_at_its_pages_end_commits_alone_without_a_page(made):
+    """The fold takes the next block's page the way lookahead does: where
+    the pool is dry the slot commits alone, nothing is preempted for it,
+    and growth finds the page a tick later.  The tokens are the same."""
+    prompt = prompt_of(26, 8)
+    calm = engine(made)
+    want = calm.submit(prompt, 24)
+    want = calm.run()[want]
+    # ticks 0-3: chunk + pass, pass, fold, pass: the block [12, 16) is full
+    # at tick 4, at the end of page 0, and every free page is held then
+    plan = FaultPlan(page_pressure=(1, 5, 100))
+    eng = engine(made, faults=plan)
+    log = dispatched(eng)
+    fixed, commits = record(eng)
+    rid = eng.submit(prompt, 24)
+    for _ in range(5):
+        eng.step()
+    assert log[-1] == (rid, 12, B, 0, 0)          # the lone commit
+    assert eng.pool.num_free == 0 and eng.metrics.preemptions == 0
+    out = eng.run()[rid]
+    assert out == want
+    m = eng.metrics.snapshot()
+    # six blocks: four folds, and the one commit that ran alone
+    assert (m["folded_passes"], m["commit_passes"]) == (4, 1)
+    assert m["decode_slots"] == 6 * S + 1 and m["preemptions"] == 0
+    assert commits == [(rid, at) for at in range(8, 28, B)]
+    assert worst_gap(fixed[rid], reference_rows(made, prompt, out)) < TOL
+    eng.check_page_conservation()
+
+
 def test_step_compiles_once_per_prefill_bucket(made):
     eng = engine(made)
     for i, n in enumerate((8, 5, 16, 3)):
         eng.submit(prompt_of(30 + i, n), 6)
     eng.run()
-    assert set(eng._step_fns) <= {(0, B), (16, B)}
+    assert set(eng._step_fns) <= {(0, 2 * B), (16, 2 * B)}
 
 
 def test_a_compiled_step_leaves_the_collectors_generations(made):
@@ -250,9 +338,10 @@ def test_the_read_back_lags_its_dispatch_by_one_step(made):
     while eng.has_work:
         eng.step()
         calls.append((len(seen), eng._flying is not None))
-    # prefill, pass, pass, commit, ...: the first tokens come with the
-    # third call, not the second, and a step is in the air meanwhile
-    assert calls[:4] == [(0, True), (0, True), (2, True), (4, True)]
+    # prefill with the first pass, pass, commit with the next block's
+    # first pass, ...: the first tokens come with the second call, not the
+    # first, and a step is in the air meanwhile
+    assert calls[:4] == [(0, True), (2, True), (4, True), (6, True)]
     assert calls[-1] == (10, False) and eng._flying is None
     ticks = 0
     while own.has_work:
@@ -266,52 +355,71 @@ def test_the_read_back_lags_its_dispatch_by_one_step(made):
     assert all(fn._cache_size() == 1 for fn in eng._step_fns.values())
     a, b = eng.metrics.snapshot(), own.metrics.snapshot()
     for name in ("step_dispatches", "decode_rows", "denoise_passes",
-                 "commit_passes", "tokens_fixed", "moe_rows_total"):
+                 "commit_passes", "folded_passes", "folded_first_passes",
+                 "tokens_fixed", "moe_rows_total"):
         assert a[name] == b[name], name
     eng.check_page_conservation()
 
 
-def test_an_answer_that_ends_early_leaves_a_pass_in_the_air(made):
+@pytest.mark.parametrize("eos_at,fold_in_the_air", [(3, True), (4, False)])
+def test_an_answer_that_ends_early_leaves_a_pass_in_the_air(
+        made, eos_at, fold_in_the_air):
     """EOS is known when the words arrive, a tick after the next pass was
     dispatched: that pass is passed over, and its slot and page serve the
-    next request."""
+    next request.  The pass in the air is a folded one where the EOS ends
+    a block (the block was committed and the next one opened for nothing),
+    a block's second pass where the EOS came of a folded pass."""
     probe = engine(made)
-    prompt = prompt_of(34, 8)
+    prompt = prompt_of(54, 8)
     rid, other = probe.submit(prompt, 12), probe.submit(prompt_of(35, 9), 6)
     full, rest = probe.run()[rid], probe.result(other)
-    eos = next(t for t in full[2:] if t not in rest)
-    cut = full[:full.index(eos) + 1]
-    assert 1 < len(cut) < len(full)
+    eos = full[eos_at]
+    assert eos not in full[:eos_at] and eos not in rest
+    cut = full[:eos_at + 1]
     prog = FAMILY.serve_program(TINY, [None])
     params = {name: made[ref] for name, ref in prog["names"].items()}
     eng = ServingEngine(prog["model"], params, eos_id=eos, page_size=16,
                         num_pages=40, max_pages_per_seq=4, max_slots=1,
                         buckets=(16,), prefill_chunk=8)
+    log = dispatched(eng)
     fixed, _ = record(eng)
     rid = eng.submit(prompt, 12)
     after = eng.submit(prompt_of(35, 9), 6)
     out = eng.run()
     assert out[rid] == cut and out[after] == rest
+    mine = [p for p in log if p[0] == rid]
+    assert (mine[-1][-1] == 1) == fold_in_the_air
+    # that pass was dispatched and never walked
+    assert len(mine) == len(fixed[rid]) // (B // S) + 1
+    assert eng.metrics.folded_passes == sum(
+        p[-1] == 1 for p in log) - fold_in_the_air
     ref = reference_rows(made, prompt_of(35, 9), out[after], prompt_len=9)
     assert worst_gap(fixed[after], ref) < TOL
     eng.check_page_conservation()
 
 
-def test_cancelled_with_a_pass_in_the_air(made):
+@pytest.mark.parametrize("landed,fold_in_the_air", [(1, False), (2, True)])
+def test_cancelled_with_a_pass_in_the_air(made, landed, fold_in_the_air):
+    """... a block's second pass, or the folded pass that committed the
+    block and opened the next (``cache_len`` has moved on: the request is
+    gone, nothing is unwound)."""
     eng = engine(made, max_slots=1)
+    log = dispatched(eng)
     fixed, _ = record(eng)
     rid = eng.submit(prompt_of(36, 8), 12)
-    while not fixed.get(rid):
+    while len(fixed.get(rid, ())) < landed * (B // S):
         eng.step()
+    assert (log[-1][-1] == 1) == fold_in_the_air
     assert eng._flying is not None and eng.cancel(rid)
     eng.check_page_conservation()
     prompt = prompt_of(37, 6)
     after = eng.submit(prompt, 7)           # takes the slot and the page
     out = eng.run()
-    assert rid not in out and len(fixed[rid]) == B // S
+    assert rid not in out and len(fixed[rid]) == landed * (B // S)
     ref = reference_rows(made, prompt, out[after], prompt_len=6)
     assert worst_gap(fixed[after], ref) < TOL
     assert not eng.has_work and eng._flying is None
+    eng.check_page_conservation()
 
 
 # ---- (d) preemption and cancellation between passes -------------------------
@@ -378,6 +486,36 @@ def test_non_finite_logits_fail_their_slot_alone(made):
     out = eng.run()
     assert eng.status(poisoned) is RequestStatus.FAILED
     assert out[rid] == want          # its batchmate's passes went on
+    eng.check_page_conservation()
+
+
+def test_non_finite_logits_with_a_folded_pass_in_the_air(made):
+    """The finite flag is read a call behind the dispatch: the slot whose
+    block's second pass comes back non-finite fails while the tick that
+    committed that block and opened the next is in the air; that tick is
+    passed over, the pages are scrubbed, the batchmate goes on."""
+    plan = FaultPlan()
+    eng = engine(made, faults=plan)
+    eng._lands_now = lambda flight: False     # the plan poisons; the read lags
+    log = dispatched(eng)
+    fixed, _ = record(eng)
+    calm = engine(made)
+    good, bad = prompt_of(53, 8), prompt_of(54, 8)
+    want = calm.submit(good, 12)
+    want = calm.run()[want]
+    rid, poisoned = eng.submit(good, 12), eng.submit(bad, 12)
+    while len(fixed.get(poisoned, ())) < B // S:
+        eng.step()                    # its first pass landed, the second flies
+    plan.poison_nan(poisoned)
+    eng.step()                        # dispatches the fold, reads the second
+    assert eng.status(poisoned) is RequestStatus.FAILED
+    assert [p for p in log if p[0] == poisoned][-1][-1] == 1
+    assert eng._flying is not None
+    out = eng.run()
+    # (the failing pass was walked, and fixed nothing)
+    assert out[rid] == want
+    assert len(eng._requests[poisoned].generated) == B // S
+    assert eng.metrics.failed == 1
     eng.check_page_conservation()
 
 
@@ -456,6 +594,14 @@ def test_prefill_chunks_end_on_block_boundaries():
 
 # ---- (e) the one-token-a-tick model through the changed contract ------------
 
+# sha256 (16 hex digits) of the StableHLO text of this model's steps at the
+# parent of PR 49 (1ed5ac8), decode-only and with the prefill bucket: the
+# fold is a block model's, and a model that says no ``block_length`` lowers
+# the step it lowered
+PARENT_STEPS = {(2, 0): "f633be80c9c16375", (2, 16): "e77ce542de4bb4f1",
+                (1, 0): "6a73855ce39f4a53", (1, 16): "5dbbac5a9cdfd6b7"}
+
+
 @pytest.mark.parametrize("kv_heads", [2, 1])
 def test_decoder_lm_serves_the_same_tokens_as_before(kv_heads):
     model = DecoderLM(vocab_size=61, num_layers=2, num_heads=2, head_dim=8,
@@ -482,3 +628,9 @@ def test_decoder_lm_serves_the_same_tokens_as_before(kv_heads):
                             eng._last_words())
     assert len(shapes) == 3 and shapes[0].shape == (2 * 6,) \
         and shapes[0].dtype == jnp.int32 and shapes[1].shape == (6, 61)
+    for pb in (0, 16):
+        text = eng._step_fn(pb, 1).lower(
+            params, eng._kv, eng._empty_tick(pb, 1),
+            eng._last_words()).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            PARENT_STEPS[kv_heads, pb]

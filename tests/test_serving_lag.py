@@ -367,7 +367,9 @@ def test_steps_lagged_counts_the_steps_read_a_call_later(lm, kind, plan):
         eng.submit(p, 8)
     eng.run()
     snap = eng.metrics.snapshot()
-    assert snap["step_dispatches"] >= 6
+    # (a block model's 8 tokens are two blocks of two ticks, the first
+    # beside the prompt's last chunk)
+    assert snap["step_dispatches"] >= 5
     if plan == "fault-plan":
         assert snap["steps_lagged"] == 0
     else:

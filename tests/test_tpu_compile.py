@@ -506,15 +506,17 @@ def test_qwen3_next_train_step_compiles_for_v5e_with_its_scopes(topo,
 
 # ---- a serving step of the block-diffusion expert model -----------------------
 
+@pytest.mark.parametrize("pb", [0, 256])
 def test_block_moe_serving_step_compiles_for_v5e_at_published_widths(
-        topo, monkeypatch):
+        topo, monkeypatch, pb):
     """``serving.BlockMoeLM`` behind ``ServingEngine`` at the cell's
     widths (hidden 2048, 32 : 4 heads of 128, 128 experts of 768 top-8,
-    the whole vocabulary; 2 of its 4 layers), 32 slots of 4 rows: the
-    decode-only step lowers and compiles for a described v5e; the
-    experts' float32 matrices go into ``moe_gmm`` as they lie (no rounded
-    or re-laid copy), and what leaves the step for the host is a slot's
-    best tokens, not its logits."""
+    the whole vocabulary; 2 of its 4 layers), 32 slots of 8 rows (the
+    block a slot commits and the one it opens): the decode-only step and
+    the one with the prefill bucket lower and compile for a described
+    v5e; the experts' float32 matrices go into ``moe_gmm`` as they lie
+    (no rounded or re-laid copy), and what leaves the step for the host
+    is a slot's best tokens, not its logits."""
     from paddle_tpu.analysis import retrace
     from paddle_tpu.ops import grouped_matmul as gm
     from paddle_tpu.serving import BlockMoeLM, ServingEngine
@@ -543,11 +545,11 @@ def test_block_moe_serving_step_compiles_for_v5e_at_published_widths(
                         page_size=128, max_slots=32, pool_bytes=1 << 29,
                         max_pages_per_seq=10, buckets=(256,),
                         prefill_chunk=256)
-    assert eng._ragged_kernel and eng._k1 == 4
-    buf = eng._empty_tick(0, 4)
+    assert eng._ragged_kernel and eng._k1 == 8
+    buf = eng._empty_tick(pb, 8)
     # (and the words of the step before: the tokens it fixed are read there)
     words = np.zeros(32 * 3 + 32 + 5, np.int32)
-    compiled = eng._step_fn(0, 4).lower(
+    compiled = eng._step_fn(pb, 8).lower(
         params, jax.tree.map(lambda a: aval(a.shape, a.dtype), eng._kv),
         aval(buf.shape, buf.dtype), aval(words.shape, words.dtype)).compile()
     text = compiled.as_text()
@@ -562,12 +564,14 @@ def test_block_moe_serving_step_compiles_for_v5e_at_published_widths(
                 and shape in line.split(" = ")[1].split("(")[0]
                 and "parameter(" not in line]
         assert not made, made[:2]
-    out = jax.eval_shape(eng._step_fn(0, 4), params, eng._kv, buf, words)
+    out = jax.eval_shape(eng._step_fn(pb, 8), params, eng._kv, buf, words)
     # one small vector: picks [32, 3], the chunk guard's [32], five counts
     assert out[0].shape == (32 * 3 + 32 + 5,) and out[0].dtype == jnp.int32
     assert out[1].shape == (32, 2, 151936)       # the logits stay behind
     # parameters 5.0 GB and the pool; a few tens of MB beside them
     mem = compiled.memory_analysis()
+    print("block step", pb, "temp bytes", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes)
     assert mem.temp_size_in_bytes < 200e6
 
 
@@ -816,10 +820,16 @@ PARENT_STEPS = {
     ("dense", 0): "bf364f38ecb6dc86", ("dense", 8): "dc7b0946b8254b69",
     ("falcon", 0): "34b79fc639f3b914", ("falcon", 16): "4da76c14b71cd70b",
     ("laguna", 0): "867bf6d3f7813a27", ("laguna", 16): "a4c51928964e110f",
-    ("sdar", 0): "c6ff7302b9b3c9ea", ("sdar", 16): "46c5449d98478cea"}
+    # (the block model's are PR 49's: a slot brings the block it commits
+    # and the one it opens, ``k1 = 2 B``)
+    ("sdar", 0): "f996ce5d52884da0", ("sdar", 16): "b72a8fa27853838a",
+    # (the looped model's at the parent of PR 49, 1ed5ac8: the fold is a
+    # block model's and changes nothing for a model of several passes)
+    ("ouro", 0): "1e311d9f6febf472", ("ouro", 16): "af8d484f7fe64b44"}
 TINY_FAMILIES = {"falcon": ("falcon_h1", "tiny-falcon-h1"),
                  "laguna": ("laguna", "tiny-laguna"),
-                 "sdar": ("sdar_moe", "tiny-sdar")}
+                 "sdar": ("sdar_moe", "tiny-sdar"),
+                 "ouro": ("ouro", "tiny-ouro")}
 
 
 @pytest.fixture(scope="module")
@@ -866,7 +876,7 @@ def test_a_model_of_one_pass_lowers_to_the_parents_step(tiny_engines, name,
     import hashlib
 
     eng = tiny_engines[name]
-    assert eng._loops == 1 and eng._loop_counted == ()
+    assert (eng._loops == 1 and eng._loop_counted == ()) == (name != "ouro")
     text = eng._step_fn(pb, eng._k1).lower(
         eng.params, eng._kv, eng._empty_tick(pb, eng._k1),
         eng._last_words(), *eng._kind_kv()).as_text()
